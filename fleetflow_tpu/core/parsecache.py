@@ -14,8 +14,8 @@ Two tiers:
   * an in-memory LRU (``FLEET_PARSE_CACHE_MEM`` entries, default 128) —
     warm re-loads inside one process (CP reconverge, chaos replay, watch
     loops) skip the parser entirely;
-  * an optional on-disk pickle directory (``FLEET_PARSE_CACHE=dir``, the
-    knob mirroring ``FLEET_COMPILE_CACHE``) — a fresh process (CP restart,
+  * an optional on-disk pickle directory (``FLEET_PARSE_CACHE=dir``) — a
+    fresh process (CP restart,
     ``fleet lint`` in CI, the bench's cold/warm children) reuses fragments
     parsed by an earlier one. Entries are versioned; a format bump
     invalidates stale files instead of mispickling them.

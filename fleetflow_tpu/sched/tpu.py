@@ -143,6 +143,11 @@ class TpuSolverScheduler:
         # through unbounded stage keys must not grow host memory forever
         self._max_evicted = max(4 * self._max_residents, 64)
 
+    @staticmethod
+    def _platform() -> str:
+        from ..platform import init_platform
+        return init_platform()["platform"]
+
     def _bucket_enabled(self, pt: ProblemTensors) -> bool:
         from ..solver.buckets import bucket_config
         if self.bucket is False:
@@ -407,7 +412,7 @@ class TpuSolverScheduler:
             feasible=res.feasible,
             violations=res.violations,
             soft=res.soft,
-            source="tpu-anneal",
+            source=f"{self._platform()}-anneal",
             solve_ms=ms,
             raw=res.assignment,
         )
@@ -424,13 +429,9 @@ class TpuSolverScheduler:
         the caller's stable stage key, used to keep one resident slot per
         stage (two stages of one project can carry identical service
         names, so the key is the only reliable identity)."""
-        # First device use on the CP path: bootstrap the platform the same
-        # way bench/__graft_entry__ do (probe the inherited platform
-        # out-of-process, fall back to virtual CPU) — a control plane must
-        # degrade to CPU solves, not die, when the accelerator is absent or
-        # its runtime is broken (round-1 failure mode).
-        from ..platform import ensure_platform
-        ensure_platform(min_devices=1)
+        # first device use on the CP path: whatever platform JAX
+        # initialises in this process, logged once (platform.py)
+        self._platform()
         # imported lazily so the host path never pays JAX startup
         from ..solver.sharded import sharded_route
 
@@ -466,8 +467,7 @@ class TpuSolverScheduler:
         rest solve serially. Results come back in request order, each
         identical to what a solo `place()` would have produced (parity is
         property-pinned)."""
-        from ..platform import ensure_platform
-        ensure_platform(min_devices=1)
+        self._platform()
         from ..solver.multiplex import MuxEntry, solve_multiplexed
         from ..solver.sharded import sharded_route
 

@@ -425,11 +425,12 @@ def stage_problem_tiers(pt, cfg: Optional[BucketConfig] = None,
         return jax.device_put(x, device=device)
 
     def put_arena(arr):
-        # jax's CPU backend ZERO-COPIES device_put for large aligned
-        # arrays (verified on jax 0.4.37): handing the shared arena
-        # buffer straight to device_put would alias it into the returned
-        # DeviceProblem, and the next restage of this tier would rewrite
-        # a live staging's tensors in place. Upload a private copy — the
+        # jax's CPU backend MAY zero-copy device_put for large aligned
+        # arrays (device_put does not promise a copy; on jax 0.9.0 a
+        # 16 MB page-aligned plane was copied, on 0.4.37 it was aliased):
+        # handing the shared arena buffer straight to device_put could
+        # alias it into the returned DeviceProblem, and the next restage
+        # of this tier would rewrite a live staging's tensors in place. Upload a private copy — the
         # fresh buffer is then solely owned by (and may be aliased by)
         # the device array. One memcpy per plane; still no XLA ops. The
         # device-CONSTANT arenas below stay zero-copy: they are written

@@ -402,11 +402,9 @@ def hot_path_kernels() -> list[KernelContract]:
             qualname="_subsolve_fn.subsolve",
             # deliberately NO donation (must_alias empty): the original
             # assignment must outlive the dispatch — a gate-rejected
-            # sub-solve re-seeds the full path from it — and a donated
-            # variant of this kernel deserialized from the persistent
-            # compile cache corrupted its output (r09 bring-up). The
-            # contract pins the ABSENCE: a donated_params entry
-            # appearing here is a reviewed golden diff.
+            # sub-solve re-seeds the full path from it. The contract
+            # pins the ABSENCE: a donated_params entry appearing here is
+            # a reviewed golden diff.
             cases=_subsolve_cases),
         KernelContract(
             name="sharded.merge",

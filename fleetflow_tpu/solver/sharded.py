@@ -37,7 +37,6 @@ implicit reshard.
 
 from __future__ import annotations
 
-import inspect
 import os
 from functools import lru_cache, partial
 from typing import NamedTuple, Optional
@@ -45,12 +44,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.8
-except ImportError:                                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from .anneal import (W_CAP, W_CONF, W_ELIG, _move_delta_core, _skew_pen,
                      violation_total_from_parts)
@@ -77,17 +72,6 @@ _M_SH_BYTES = REGISTRY.gauge(
     "Per-device bytes of the most recent sharded solve: problem tensors "
     "(service-axis shards + replicated node state) plus the anneal's "
     "chain/tempering working state")
-
-# the replication-check kwarg was renamed across jax versions
-_SM_KW = ("check_rep" if "check_rep" in inspect.signature(_shard_map).parameters
-          else "check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-          else None)
-
-
-def shard_map(*args, **kw):
-    if _SM_KW is not None:
-        kw[_SM_KW] = False
-    return _shard_map(*args, **kw)
 
 __all__ = ["anneal_sharded", "pad_problem", "shard_problem",
            "per_device_bytes", "SVC_AXIS", "REPLICA_AXIS", "ShardedStats",
@@ -755,7 +739,8 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
                       P(SVC_AXIS, None),
                       P(), P(), P(), P(SVC_AXIS), P()),
             out_specs=(P(SVC_AXIS), P(), P(), P(), P(), P(), P(), P(), P(),
-                       P()))
+                       P()),
+            check_vma=False)
         out = sharded(prob.demand, prob.conflict_ids, prob.coloc_ids,
                       prob.eligible, prob.preferred, prob.capacity,
                       prob.node_valid, prob.node_topology,
@@ -772,7 +757,8 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
                       P(SVC_AXIS, None), P(SVC_AXIS, None),
                       P(), P(), P(), P(SVC_AXIS), P()),
             out_specs=(P(SVC_AXIS), P(), P(), P(), P(), P(), P(), P(), P(),
-                       P()))
+                       P()),
+            check_vma=False)
         out = sharded(prob.demand, prob.conflict_ids, prob.coloc_ids,
                       prob.eligible, prob.capacity,
                       prob.node_valid, prob.node_topology,
@@ -857,8 +843,13 @@ class ShardedResident(ResidentProblem):
         # device receives only its own slice.
         try:
             return jax.local_devices(backend="cpu")[0]
-        except RuntimeError:                         # pragma: no cover
-            return None                              # cpu backend disabled
+        except RuntimeError as e:
+            raise RuntimeError(
+                "the sharded path stages its (S, N) planes on the host CPU "
+                "backend, which this process did not initialise "
+                f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}); "
+                "allow it beside the accelerator, e.g. "
+                "JAX_PLATFORMS=tpu,cpu") from e
 
     def cold_stage(self, pt) -> None:
         import dataclasses

@@ -436,12 +436,9 @@ def _subsolve_fn():
         # deliberately NOT donated: (a) a gate-rejected sub-solve must
         # re-run the full fused path from the ORIGINAL seed — stranded
         # rows intact, the battle-tested prerepair path — so the old
-        # buffer has to survive; (b) an (S,) i32 copy is ~40 KB at fleet
-        # scale, noise next to the planes the merge kernel's donation
-        # exists for; and (c) a donated-aliased executable of THIS kernel
-        # deserialized from the persistent XLA compile cache corrupted
-        # the output buffer (garbage node indices) — observed on
-        # jax 0.4.x CPU, BENCH r09 bring-up
+        # buffer has to survive; and (b) an (S,) i32 copy is ~40 KB at
+        # fleet scale, noise next to the planes the merge kernel's
+        # donation exists for
         new_assignment = assignment.at[rows].set(winner, mode="drop")
         # the acceptance gate: exact full-problem stats of the scattered
         # result — whatever the mini anneal believed, THIS decides

