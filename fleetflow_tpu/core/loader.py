@@ -26,11 +26,10 @@ from typing import Optional
 from .discovery import DiscoveredFiles, discover_files_with_stage, find_project_root
 from .errors import FlowError
 from .model import Flow
-from .parsecache import (M_FRONTEND_PHASE_MS, _env_int,
-                         default_parse_cache)
+from .parsecache import _env_int, default_parse_cache
 from .parser import merge_flow_fragment, read_kdl_with_includes
 from .template import TemplateProcessor, extract_variables_with_stage, parse_dotenv
-from ..obs import get_logger, span
+from ..obs import get_logger, phase, span
 
 log = get_logger("loader")
 
@@ -246,8 +245,6 @@ def load_project_from_root_with_stage(root: str, stage: Optional[str] = None,
     objects get source locations (`fleet lint`); pair it with a ``debug``
     collector to build a SourceMap from the rendered per-file segments.
     """
-    import time
-
     with span(log, "load_project", root=root, stage=stage) as sp:
         files = discover_files_with_stage(root, stage)
         if files.main_file is None:
@@ -260,23 +257,21 @@ def load_project_from_root_with_stage(root: str, stage: Optional[str] = None,
         # parse per-file fragments (content-addressed cache; optional
         # worker pool) and merge in the concatenation order — spans and
         # error positions keep concatenation coordinates via line_offset
-        t0 = time.perf_counter()
-        try:
-            flow = Flow()
-            for frag in _parse_parts(parts, want_spans):
-                merge_flow_fragment(flow, frag)
-        except FlowError:
-            # compat guard: a construct SPANNING file boundaries (a brace
-            # opened in one discovered file and closed in the next) parsed
-            # under the historical whole-concatenation parse but fails as
-            # a fragment. Re-parse the concatenation once; if that also
-            # fails, its error carries the same coordinates the old path
-            # reported — raise it.
-            from .parser import parse_kdl_string
-            flow = parse_kdl_string("\n".join(r for _, r, _ in parts),
-                                    want_spans=want_spans, cache=False)
-        M_FRONTEND_PHASE_MS.set((time.perf_counter() - t0) * 1e3,
-                                phase="parse")
+        with phase("frontend.parse", files=len(parts)):
+            try:
+                flow = Flow()
+                for frag in _parse_parts(parts, want_spans):
+                    merge_flow_fragment(flow, frag)
+            except FlowError:
+                # compat guard: a construct SPANNING file boundaries (a
+                # brace opened in one discovered file and closed in the
+                # next) parsed under the historical whole-concatenation
+                # parse but fails as a fragment. Re-parse the concatenation
+                # once; if that also fails, its error carries the same
+                # coordinates the old path reported — raise it.
+                from .parser import parse_kdl_string
+                flow = parse_kdl_string("\n".join(r for _, r, _ in parts),
+                                        want_spans=want_spans, cache=False)
         # expose the final variable context on the flow
         merged = dict(tp.variables)
         merged.update(flow.variables)

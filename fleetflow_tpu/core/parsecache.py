@@ -49,23 +49,13 @@ from ..obs.metrics import REGISTRY
 
 __all__ = ["ParseCache", "default_parse_cache", "parse_cache_stats",
            "parse_cache_clear", "PARSE_CACHE_VERSION",
-           "disk_pickle_get", "disk_pickle_put", "M_FRONTEND_PHASE_MS"]
+           "disk_pickle_get", "disk_pickle_put"]
 
 log = get_logger("parsecache")
 
 # bump when the parser's output shape changes (KdlNode/model fields,
 # fragment semantics) — stale disk entries then miss instead of mispickle
 PARSE_CACHE_VERSION = 1
-
-# the front-end phase gauge lives here (the front end's neutral leaf
-# module): core/loader.py, registry/aggregate.py and solver/api.py all
-# import it rather than re-registering or importing each other
-M_FRONTEND_PHASE_MS = REGISTRY.gauge(
-    "fleet_frontend_phase_ms",
-    "Milliseconds of the most recent front-end phase: parse (per-file "
-    "fragment parsing incl. cache lookups), lower (aggregation + tensor "
-    "lowering), stage (host->device staging)",
-    labels=("phase",))
 
 _M_CACHE = REGISTRY.counter(
     "fleet_frontend_parse_cache_total",

@@ -21,7 +21,7 @@ from typing import Callable, Optional, Sequence, Union
 
 from ..core.errors import (AgentCommandError, AgentCommandFailed,
                            AgentUnreachable, ControlPlaneError)
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from .protocol import Connection
 from .shards import ShardTable
@@ -335,42 +335,43 @@ class AgentRegistry:
             self.last_batch_stats = {"items": 0, "label_lookups": 0,
                                      "epoch_lookups": 0, "shards": 0}
             return []
-        counts: dict[str, int] = {}
-        for _, command, _ in items:
-            counts[command] = counts.get(command, 0) + 1
-        for command, n in counts.items():
-            _M_COMMANDS.inc(n, command=command)
-        epoch = self.epoch_source() if self.epoch_source is not None else None
-        shards = [self.shard_of(slug) for slug, _, _ in items]
-        t0 = time.perf_counter()
-        done_at: dict[int, float] = {}
+        with phase("agents.send_batch", items=len(items)):
+            counts: dict[str, int] = {}
+            for _, command, _ in items:
+                counts[command] = counts.get(command, 0) + 1
+            for command, n in counts.items():
+                _M_COMMANDS.inc(n, command=command)
+            epoch = self.epoch_source() if self.epoch_source is not None else None
+            shards = [self.shard_of(slug) for slug, _, _ in items]
+            t0 = time.perf_counter()
+            done_at: dict[int, float] = {}
 
-        async def run(shard: int, slug: str, command: str,
-                      payload: Optional[dict]) -> dict:
-            async with self._shard_sem(shard):
-                try:
-                    return await self._send_one(slug, command, payload,
-                                                timeout, epoch=epoch,
-                                                metered=False)
-                finally:
-                    done_at[shard] = time.perf_counter()
+            async def run(shard: int, slug: str, command: str,
+                          payload: Optional[dict]) -> dict:
+                async with self._shard_sem(shard):
+                    try:
+                        return await self._send_one(slug, command, payload,
+                                                    timeout, epoch=epoch,
+                                                    metered=False)
+                    finally:
+                        done_at[shard] = time.perf_counter()
 
-        # tasks start in item order: in production the per-shard
-        # semaphores pipeline each lane independently; under the chaos
-        # harness's inline sim transport nothing blocks, so execution
-        # stays in creation order and schedules replay digest-stable
-        tasks = [asyncio.ensure_future(run(shard, slug, command, payload))
-                 for shard, (slug, command, payload) in zip(shards, items)]
-        results = await asyncio.gather(*tasks, return_exceptions=True)
-        if self.shard_table is not None:
-            for shard, at in sorted(done_at.items()):
-                self.shard_table.observe_fanout_ms(
-                    shard, (at - t0) * 1000.0)
-        self.last_batch_stats = {
-            "items": len(items), "label_lookups": len(counts),
-            "epoch_lookups": 0 if epoch is None else 1,
-            "shards": len(done_at)}
-        return list(results)
+            # tasks start in item order: in production the per-shard
+            # semaphores pipeline each lane independently; under the chaos
+            # harness's inline sim transport nothing blocks, so execution
+            # stays in creation order and schedules replay digest-stable
+            tasks = [asyncio.ensure_future(run(shard, slug, command, payload))
+                     for shard, (slug, command, payload) in zip(shards, items)]
+            results = await asyncio.gather(*tasks, return_exceptions=True)
+            if self.shard_table is not None:
+                for shard, at in sorted(done_at.items()):
+                    self.shard_table.observe_fanout_ms(
+                        shard, (at - t0) * 1000.0)
+            self.last_batch_stats = {
+                "items": len(items), "label_lookups": len(counts),
+                "epoch_lookups": 0 if epoch is None else 1,
+                "shards": len(done_at)}
+            return list(results)
 
     async def fire_and_forget(self, slug: str, command: str,
                               payload: dict | None = None) -> None:

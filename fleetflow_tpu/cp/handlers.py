@@ -12,11 +12,10 @@ ProtocolServer.
 from __future__ import annotations
 
 import asyncio
-import time
 from typing import TYPE_CHECKING
 
 from ..core.serialize import flow_from_dict
-from ..obs import get_logger, span
+from ..obs import get_logger, phase, span
 from ..obs.metrics import REGISTRY
 from ..obs.trace import new_trace_id, use_trace
 from ..runtime.engine import DeployEngine, DeployRequest
@@ -112,14 +111,15 @@ def _timed(channel: str, handler):
     lines around every channel, the agent session included)."""
 
     async def timed(conn: Connection, method: str, p: dict):
-        t0 = time.perf_counter()
+        ph = phase("cp.handler", channel=channel, method=method)
         try:
-            return await handler(conn, method, p)
+            with ph:
+                return await handler(conn, method, p)
         except Exception:
             _M_REQUEST_ERRORS.inc(channel=channel)
             raise
         finally:
-            _M_REQUEST_S.observe(time.perf_counter() - t0, channel=channel)
+            _M_REQUEST_S.observe(ph.ms / 1e3, channel=channel)
 
     return timed
 

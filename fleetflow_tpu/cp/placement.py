@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from dataclasses import dataclass, replace as _dc_replace
 from typing import Optional
 
@@ -29,7 +28,7 @@ import numpy as np
 
 from ..core.model import Flow, ResourceSpec, ServerLabels, ServerResource
 from ..lower.tensors import ProblemTensors, lower_stage
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
 from ..sched import (HostGreedyScheduler, Placement, TpuSolverScheduler,
@@ -207,59 +206,63 @@ class PlacementService:
         a reservation. Returns (placement, reservation_id)."""
         stage = flow.stage(stage_name)
         key = f"{flow.name}/{stage_name}"
-        with self._lock:
-            # a full re-lower rebuilds the stage from the flow, which the
-            # admission controller keeps tombstone-free
-            self._masked.pop(key, None)
-            # This stage's own churn hold is the placement this solve
-            # supersedes, so it must not count against itself — but the
-            # hold is only RELEASED when a real reservation replaces it
-            # (_reserve): a reserve=False preview or an infeasible solve
-            # must leave the double-book protection standing.
-            own_churn: dict[str, np.ndarray] = {}
-            for r in self._reservations.values():
-                if r.churn and r.stage_key == key:
-                    for slug, d in r.demand_by_node.items():
-                        own_churn[slug] = own_churn.get(slug, 0) + d
-            nodes, valid = self._inventory(tenant, stage.servers or None,
-                                           exclude_demand=own_churn)
-            # Config-declared labels back-fill: agents register slug +
-            # capacity only, so live store records usually carry NO labels,
-            # and a blank label passes every gate (_server_matches treats
-            # tier=None as match-any, tensors.py) — a tier-gated stage
-            # could silently place services on a declared-off-tier node
-            # (found by the full-stack smoke: api landed on the standard
-            # node).  Fill per FIELD: only fields the server API has not
-            # set inherit the flow's declaration; API-set fields win.
-            for n in nodes:
-                decl = flow.servers.get(n.name)
-                if decl is None:
-                    continue
-                d, got = decl.labels, n.labels
-                n.labels = ServerLabels(
-                    tier=got.tier if got.tier is not None else d.tier,
-                    region=got.region if got.region is not None else d.region,
-                    clazz=got.clazz if got.clazz is not None else d.clazz,
-                    arch=got.arch if got.arch is not None else d.arch,
-                    extra={**d.extra, **got.extra})
-            pt = lower_stage(flow, stage_name, nodes=nodes)
-            pt.node_valid &= valid
-            prev = self._last.get(key)
-            if self.use_tpu:
-                warm = (prev is not None
-                        and prev[0].S == pt.S and prev[0].N == pt.N)
-                placement = self._sched_tpu.place(pt, warm_start=warm,
-                                                  stage=key)
-                if not placement.feasible and pt.relax_order:
-                    placement, _ = place_with_fallback(
-                        self._sched_tpu, pt, initial=placement,
-                        place_kwargs={"stage": key})
-            else:
-                placement, _ = place_with_fallback(self._sched_host, pt)
-            self._last[key] = (pt, placement)
-            rid = None
-            if reserve and placement.feasible:
-                rid = self._reserve(key, pt, placement)
+        with phase("cp.solve_stage", stage=key), self._lock:
+            with phase("cp.solve_stage.inventory"):
+                # a full re-lower rebuilds the stage from the flow, which the
+                # admission controller keeps tombstone-free
+                self._masked.pop(key, None)
+                # This stage's own churn hold is the placement this solve
+                # supersedes, so it must not count against itself — but the
+                # hold is only RELEASED when a real reservation replaces it
+                # (_reserve): a reserve=False preview or an infeasible solve
+                # must leave the double-book protection standing.
+                own_churn: dict[str, np.ndarray] = {}
+                for r in self._reservations.values():
+                    if r.churn and r.stage_key == key:
+                        for slug, d in r.demand_by_node.items():
+                            own_churn[slug] = own_churn.get(slug, 0) + d
+                nodes, valid = self._inventory(tenant, stage.servers or None,
+                                               exclude_demand=own_churn)
+                # Config-declared labels back-fill: agents register slug +
+                # capacity only, so live store records usually carry NO labels,
+                # and a blank label passes every gate (_server_matches treats
+                # tier=None as match-any, tensors.py) — a tier-gated stage
+                # could silently place services on a declared-off-tier node
+                # (found by the full-stack smoke: api landed on the standard
+                # node).  Fill per FIELD: only fields the server API has not
+                # set inherit the flow's declaration; API-set fields win.
+                for n in nodes:
+                    decl = flow.servers.get(n.name)
+                    if decl is None:
+                        continue
+                    d, got = decl.labels, n.labels
+                    n.labels = ServerLabels(
+                        tier=got.tier if got.tier is not None else d.tier,
+                        region=got.region if got.region is not None else d.region,
+                        clazz=got.clazz if got.clazz is not None else d.clazz,
+                        arch=got.arch if got.arch is not None else d.arch,
+                        extra={**d.extra, **got.extra})
+            with phase("cp.solve_stage.lower"):
+                pt = lower_stage(flow, stage_name, nodes=nodes)
+                pt.node_valid &= valid
+            with phase("cp.solve_stage.solve"):
+                prev = self._last.get(key)
+                if self.use_tpu:
+                    warm = (prev is not None
+                            and prev[0].S == pt.S and prev[0].N == pt.N)
+                    placement = self._sched_tpu.place(pt, warm_start=warm,
+                                                      stage=key)
+                    if not placement.feasible and pt.relax_order:
+                        placement, _ = place_with_fallback(
+                            self._sched_tpu, pt, initial=placement,
+                            place_kwargs={"stage": key})
+                else:
+                    placement, _ = place_with_fallback(self._sched_host, pt)
+            with phase("cp.solve_stage.reserve"):
+                self._last[key] = (pt, placement)
+                rid = None
+                if reserve and placement.feasible:
+                    rid = self._reserve(key, pt, placement)
         return placement, rid
 
     def rehydrate(self, stage_key: str, flow: Flow,
@@ -458,19 +461,22 @@ class PlacementService:
         (2-phase step 2, model.rs:421-427). A redeploy of the same stage
         SUPERSEDES its previous commit — the old containers were stopped and
         replaced, so their allocation is returned first."""
-        with self._lock:
+        with phase("cp.commit"), self._lock:
             r = self._reservations.pop(rid, None)
             if r is None or r.committed:
                 return False
             prev = self._committed.pop(r.stage_key, None)
-            if prev is not None:
-                self._apply_allocation_delta(prev, r)
-            else:
-                self._apply_allocation(r, +1.0)
+            with phase("cp.commit.apply_allocation",
+                       records=len(r.demand_by_node)):
+                if prev is not None:
+                    self._apply_allocation_delta(prev, r)
+                else:
+                    self._apply_allocation(r, +1.0)
             r.committed = True
             self._committed[r.stage_key] = r
             self._drop_churn(r.stage_key)   # commitment reflects reality now
-            self._persist_committed(r.stage_key)
+            with phase("cp.commit.persist"):
+                self._persist_committed(r.stage_key)
             return True
 
     def release(self, rid: str, *, undo_commit: bool = False) -> bool:
@@ -496,24 +502,29 @@ class PlacementService:
         re-solve's assignment was actually redeployed to the surviving
         agents, so the churn hold graduates to the commitment, superseding
         the pre-churn one (same supersede semantics as commit())."""
-        with self._lock:
+        with phase("cp.commit_retained", stage=stage_key), self._lock:
             entry = self._last.get(stage_key)
             if entry is None:
                 return False
             pt, placement = entry
             if not placement.feasible:
                 return False
-            r = Reservation(
-                id=f"rsv_{next(self._ids)}", stage_key=stage_key,
-                demand_by_node=self._demand_by_node(pt, placement),
-                assignment=dict(placement.assignment), committed=True)
+            with phase("cp.commit.demand", rows=pt.S):
+                r = Reservation(
+                    id=f"rsv_{next(self._ids)}", stage_key=stage_key,
+                    demand_by_node=self._demand_by_node(pt, placement),
+                    assignment=dict(placement.assignment), committed=True)
             prev = self._committed.pop(stage_key, None)
-            if prev is not None:
-                self._apply_allocation(prev, -1.0)
-            self._apply_allocation(r, +1.0)
+            with phase("cp.commit.apply_allocation",
+                       records=len(r.demand_by_node)
+                       + (len(prev.demand_by_node) if prev else 0)):
+                if prev is not None:
+                    self._apply_allocation(prev, -1.0)
+                self._apply_allocation(r, +1.0)
             self._committed[stage_key] = r
             self._drop_churn(stage_key)
-            self._persist_committed(stage_key)
+            with phase("cp.commit.persist"):
+                self._persist_committed(stage_key)
             return True
 
     def release_stage(self, stage_key: str) -> bool:
@@ -690,11 +701,18 @@ class PlacementService:
         re-solve per stage, not four, and the solver sees the true final
         world instead of three intermediate ones (sequential re-solves can
         bounce services onto a node that the next event kills)."""
-        for slug, online in events:
-            s = self.store.server_by_slug(slug)
-            if s is not None:
-                self.store.update("servers", s.id,
-                                  status="online" if online else "offline")
+        with phase("cp.node_events", events=len(events)):
+            return self._node_events(events)
+
+    def _node_events(self, events: list[tuple[str, bool]]
+                     ) -> list[tuple[str, Placement]]:
+        with phase("cp.node_events.mark"):
+            for slug, online in events:
+                s = self.store.server_by_slug(slug)
+                if s is not None:
+                    self.store.update(
+                        "servers", s.id,
+                        status="online" if online else "offline")
         moved: list[tuple[str, Placement]] = []
         # stages re-solved earlier in THIS burst -> (stage-demand snapshot,
         # new per-node demand), so later re-solves see them at their new
@@ -702,7 +720,8 @@ class PlacementService:
         # survivor node)
         overrides: dict[str, tuple] = {}
         with self._lock:
-            server_map = {s.slug: s for s in self.store.list("servers")}
+            with phase("cp.node_events.mark"):
+                server_map = {s.slug: s for s in self.store.list("servers")}
             for key, (pt, placement) in list(self._last.items()):
                 needs_resolve = False
                 flipped = False
@@ -736,82 +755,84 @@ class PlacementService:
                 # stage's own commitment + in-flight reservations (its
                 # services are the ones being re-placed) and substituting
                 # burst-mates' already-re-solved positions.
-                pt = self._refresh_capacity(pt, key, overrides, server_map)
+                with phase("cp.node_events.refresh_capacity", stage=key):
+                    pt = self._refresh_capacity(pt, key, overrides,
+                                                server_map)
                 degraded = False
-                t_solve = time.perf_counter()
-                try:
-                    if self.use_tpu:
-                        # structured churn instead of a full re-staging:
-                        # validity flips + refreshed capacity ride a
-                        # ProblemDelta, which the scheduler merges into
-                        # its device-resident problem when the bucket
-                        # identity holds (solver/resident.py) — the
-                        # (S, N) problem planes never re-cross the host
-                        # boundary on a reconvergence burst. Content
-                        # drift beyond the delta cold-stages safely.
-                        from ..solver.resident import ProblemDelta
-                        new = self._sched_tpu.reschedule(
-                            pt, delta=ProblemDelta(node_valid=pt.node_valid,
-                                                   capacity=pt.capacity),
-                            stage=key)
-                    else:
+                with phase("cp.node_events.solve", stage=key) as ph_solve:
+                    try:
+                        if self.use_tpu:
+                            # structured churn instead of a full re-staging:
+                            # validity flips + refreshed capacity ride a
+                            # ProblemDelta, which the scheduler merges into
+                            # its device-resident problem when the bucket
+                            # identity holds (solver/resident.py) — the
+                            # (S, N) problem planes never re-cross the host
+                            # boundary on a reconvergence burst. Content
+                            # drift beyond the delta cold-stages safely.
+                            from ..solver.resident import ProblemDelta
+                            new = self._sched_tpu.reschedule(
+                                pt, delta=ProblemDelta(node_valid=pt.node_valid,
+                                                       capacity=pt.capacity),
+                                stage=key)
+                        else:
+                            new = self._sched_host.place(pt)
+                    except Exception as e:
+                        # graceful degradation: a churn re-solve is on the
+                        # self-healing critical path — a solver crash/timeout
+                        # must cost solution quality, not convergence. The
+                        # greedy host path solves the same tensors.
+                        _M_CHURN_FALLBACKS.inc()
+                        degraded = True
+                        log.error("churn solve failed; greedy host fallback %s",
+                                  kv(stage=key, error=e))
                         new = self._sched_host.place(pt)
-                except Exception as e:
-                    # graceful degradation: a churn re-solve is on the
-                    # self-healing critical path — a solver crash/timeout
-                    # must cost solution quality, not convergence. The
-                    # greedy host path solves the same tensors.
-                    _M_CHURN_FALLBACKS.inc()
-                    degraded = True
-                    log.error("churn solve failed; greedy host fallback %s",
-                              kv(stage=key, error=e))
-                    new = self._sched_host.place(pt)
-                if not new.feasible and pt.relax_order:
-                    # a stage placed via declared relaxation must keep its
-                    # relaxation through churn re-solves (and a crashed
-                    # device solver stays benched for the ladder too)
-                    sched = (self._sched_host if degraded or not self.use_tpu
-                             else self._sched_tpu)
-                    new, _ = place_with_fallback(
-                        sched, pt, initial=new,
-                        place_kwargs=({"stage": key}
-                                      if sched is self._sched_tpu else None))
+                    if not new.feasible and pt.relax_order:
+                        # a stage placed via declared relaxation must keep its
+                        # relaxation through churn re-solves (and a crashed
+                        # device solver stays benched for the ladder too)
+                        sched = (self._sched_host if degraded or not self.use_tpu
+                                 else self._sched_tpu)
+                        new, _ = place_with_fallback(
+                            sched, pt, initial=new,
+                            place_kwargs=({"stage": key}
+                                          if sched is self._sched_tpu else None))
                 # the warm-reschedule latency SLO stream (obs/slo.py):
                 # one sample per stage re-solve, relax-ladder included —
                 # this IS the placement-p99-ms an operator declares
-                slo_observe("placement_ms",
-                            (time.perf_counter() - t_solve) * 1e3)
-                # a streaming stage's tombstoned rows stay masked through
-                # churn re-solves too
-                new = self._apply_mask(key, new)
-                self._last[key] = (pt, new)
-                if new.feasible:
-                    new_dem = self._demand_by_node(pt, new)
-                    # hold the displaced stage's NEW nodes until its
-                    # redeploy re-commits: an admission landing between
-                    # the burst and the redeploy must not double-book
-                    # them.  Reserve only the DELTA above the stage's
-                    # still-standing demand (committed allocation AND any
-                    # in-flight reservation of its own), so no service is
-                    # counted twice.
-                    self._drop_churn(key)
-                    old = self._stage_demand(key)
-                    delta = {}
-                    for slug, d in new_dem.items():
-                        extra = np.maximum(
-                            np.asarray(d, dtype=np.float64)
-                            - old.get(slug, 0), 0.0)
-                        if extra.any():
-                            delta[slug] = extra
-                    if delta:
-                        rid = f"rsv_{next(self._ids)}"
-                        self._reservations[rid] = Reservation(
-                            id=rid, stage_key=key, demand_by_node=delta,
-                            assignment=dict(new.assignment), churn=True)
-                    # snapshot AFTER the churn reservation exists: burst-
-                    # mates' refreshes subtract this exact view and add
-                    # new_dem, cancelling the reservation they also see
-                    # in _reserved_by_node
-                    overrides[key] = (self._stage_demand(key), new_dem)
+                slo_observe("placement_ms", ph_solve.ms)
+                with phase("cp.node_events.hold", stage=key):
+                    # a streaming stage's tombstoned rows stay masked through
+                    # churn re-solves too
+                    new = self._apply_mask(key, new)
+                    self._last[key] = (pt, new)
+                    if new.feasible:
+                        new_dem = self._demand_by_node(pt, new)
+                        # hold the displaced stage's NEW nodes until its
+                        # redeploy re-commits: an admission landing between
+                        # the burst and the redeploy must not double-book
+                        # them.  Reserve only the DELTA above the stage's
+                        # still-standing demand (committed allocation AND any
+                        # in-flight reservation of its own), so no service is
+                        # counted twice.
+                        self._drop_churn(key)
+                        old = self._stage_demand(key)
+                        delta = {}
+                        for slug, d in new_dem.items():
+                            extra = np.maximum(
+                                np.asarray(d, dtype=np.float64)
+                                - old.get(slug, 0), 0.0)
+                            if extra.any():
+                                delta[slug] = extra
+                        if delta:
+                            rid = f"rsv_{next(self._ids)}"
+                            self._reservations[rid] = Reservation(
+                                id=rid, stage_key=key, demand_by_node=delta,
+                                assignment=dict(new.assignment), churn=True)
+                        # snapshot AFTER the churn reservation exists: burst-
+                        # mates' refreshes subtract this exact view and add
+                        # new_dem, cancelling the reservation they also see
+                        # in _reserved_by_node
+                        overrides[key] = (self._stage_demand(key), new_dem)
                 moved.append((key, new))
         return moved

@@ -30,7 +30,7 @@ from dataclasses import dataclass, field
 from typing import Any, Awaitable, Callable, Optional
 
 from ..core.errors import ControlPlaneError
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 
 log = get_logger("cp.protocol")
 
@@ -56,11 +56,15 @@ async def read_frame(reader: asyncio.StreamReader) -> Optional[dict]:
         body = await reader.readexactly(size)
     except (asyncio.IncompleteReadError, ConnectionResetError):
         return None
-    return json.loads(body)
+    # the decode only: the awaits above are the peer's time, not the codec's
+    with phase("protocol.decode", bytes=size):
+        return json.loads(body)
 
 
 def encode_frame(msg: dict) -> bytes:
-    body = json.dumps(msg, separators=(",", ":")).encode()
+    with phase("protocol.encode") as ph:
+        body = json.dumps(msg, separators=(",", ":")).encode()
+        ph.set(bytes=len(body))
     if len(body) > MAX_FRAME:
         raise RpcError(f"frame too large: {len(body)}")
     return len(body).to_bytes(4, "big") + body

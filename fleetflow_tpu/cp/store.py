@@ -44,6 +44,7 @@ ex-primary's entries are refusable forever after a failover.
 from __future__ import annotations
 
 import json
+import operator
 import os
 import threading
 from pathlib import Path
@@ -75,6 +76,15 @@ class ReplicationFenced(ControlPlaneError):
 _M_STORE_OPS = REGISTRY.counter(
     "fleet_store_ops_total", "Store mutations by table and op (put/del)",
     labels=("table", "op"))
+_M_ROWS_SCANNED = REGISTRY.counter(
+    "fleet_store_rows_scanned_total",
+    "Rows a lookup examined, by table: once per find_one (up to its hit) "
+    "and per list(where=...) (the whole table) — the tables have no index, "
+    "so this is what a lookup costs", labels=("table",))
+_M_JOURNAL_BYTES = REGISTRY.counter(
+    "fleet_store_journal_bytes_total",
+    "Bytes of serialized journal entries handed to the local journal and "
+    "to the replication sink (each counted); 0 only in a store with neither")
 _M_HEARTBEATS = REGISTRY.counter(
     "fleet_heartbeats_total", "Agent heartbeats recorded")
 _M_COMPACTIONS = REGISTRY.counter(
@@ -101,6 +111,12 @@ _TABLES: dict[str, type] = {
     "parked_work": ParkedWork, "placements": PlacementRecord,
     "admission_parked": ParkedArrival,
 }
+
+
+# find_one and _emit run a thousand times in one commit of a 1,000-server
+# stage: the counters' children are looked up here, once
+_ROWS_SCANNED = {t: _M_ROWS_SCANNED.bind(table=t) for t in _TABLES}
+_count_journal_bytes = _M_JOURNAL_BYTES.bind()
 
 
 class Store:
@@ -219,6 +235,7 @@ class Store:
         with self._lock:
             rows = list(self._tables[table].values())
         if where is not None:
+            _ROWS_SCANNED[table](len(rows))
             rows = [r for r in rows if where(r)]
         return sorted(rows, key=lambda r: r.created_at)
 
@@ -226,11 +243,18 @@ class Store:
                  where: Callable[[Record], bool]) -> Optional[Record]:
         # hot path (server_by_slug on every heartbeat/alert/inventory):
         # early-exit scan, no copy/sort like list()
+        found = None
         with self._lock:
-            for r in self._tables[table].values():
+            rows = iter(self._tables[table].values())
+            for r in rows:
                 if where(r):
-                    return r
-        return None
+                    found = r
+                    break
+            # rows examined = the table less what the iterator has left,
+            # counted once per lookup and never per row
+            _ROWS_SCANNED[table](
+                len(self._tables[table]) - operator.length_hint(rows))
+        return found
 
     # ------------------------------------------------------------------
     # domain queries (the named fns of db.rs)
@@ -448,8 +472,10 @@ class Store:
         entry["e"] = self._epoch
         line = json.dumps(entry)
         if self._journal_path is not None:
+            _count_journal_bytes(len(line))
             self._log_line(line)
         if self.replication_sink is not None:
+            _count_journal_bytes(len(line))
             if self._batch_depth > 0:
                 self._repl_buf.append((self._seq, line))
             else:

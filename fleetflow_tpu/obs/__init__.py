@@ -15,6 +15,13 @@ the aggregation layer the reference leaves to its operators:
   minted on entry when absent, rendered by `kv()` into every log line
   inside the span, and — when `FLEET_TRACE_FILE` is set — recorded as
   begin/end/fail JSONL events in the flight recorder.
+- `phase("cp.commit.persist", records=n)` is the span's light half and
+  the one timing primitive (obs.trace.Phase; `span` is built on it): no
+  ids, no log line, a few microseconds, always on. Every phase lands in
+  the profiler's trace as `fleet/<name>` (same clock as the device
+  trace), in a bounded in-memory ring (`obs.trace.spans_between`), in
+  the `fleet_phase_ms{phase}` histogram, and in the flight recorder
+  under its enclosing span.
 - `obs.metrics.REGISTRY` is the process-wide metrics registry
   (Counter/Gauge/Histogram, Prometheus text exposition at the daemon's
   `GET /metrics`).
@@ -37,16 +44,19 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
-import time
 from typing import Iterator, Optional
 
 from . import metrics  # noqa: F401  (re-export: obs.metrics.REGISTRY)
 from .metrics import REGISTRY
-from .trace import (_span_id, _trace_id, _use_span, current_span_id,
+from .trace import (Phase, _span_id, _trace_id, _use_span, current_span_id,
                     current_trace_id, new_span_id, new_trace_id,
                     record_span_event, use_trace)
 
-__all__ = ["get_logger", "span", "configure", "profile_trace", "kv",
+# `with obs.phase("cp.commit.persist", records=n): ...` — the one timing
+# primitive (obs/trace.py); obs.span is a phase with ids and log lines
+phase = Phase
+
+__all__ = ["get_logger", "span", "phase", "configure", "profile_trace", "kv",
            "TRACE", "REGISTRY", "metrics", "use_trace", "new_trace_id",
            "current_trace_id", "current_span_id"]
 
@@ -155,7 +165,9 @@ def span(log: logging.Logger, name: str, level: int = logging.INFO,
     is active), mints a span_id, and records the enclosing span as parent.
     The ids render via kv() in the span's own lines and every kv() line
     inside its body, and land in the flight recorder when FLEET_TRACE_FILE
-    is set."""
+    is set. The timing, the profiler annotation, the ring, the histogram
+    and the recorder's end/fail event are the phase's (obs.trace.Phase):
+    a span is a phase with ids and log lines."""
     extra: dict = {}
     parent = _span_id.get()
     sid = new_span_id()
@@ -164,23 +176,18 @@ def span(log: logging.Logger, name: str, level: int = logging.INFO,
         log.debug("%s started%s", name, f" {head}" if head else "")
         record_span_event("begin", name, log.name, trace=tid, span=sid,
                           parent=parent, fields=fields or None)
-        t0 = time.perf_counter()
+        ph = Phase(name, **fields)
+        ph._owner = (log.name, tid, sid, parent, extra)
         try:
-            yield extra
+            with ph:
+                yield extra
         except Exception as e:
-            ms = (time.perf_counter() - t0) * 1e3
             log.error("%s failed %s", name,
-                      kv(duration_ms=f"{ms:.1f}", error=e, **fields, **extra))
-            record_span_event("fail", name, log.name, trace=tid, span=sid,
-                              parent=parent, duration_ms=ms, error=str(e),
-                              fields={**fields, **extra} or None)
+                      kv(duration_ms=f"{ph.ms:.1f}", error=e, **fields,
+                         **extra))
             raise
-        ms = (time.perf_counter() - t0) * 1e3
         log.log(level, "%s %s", name,
-                kv(duration_ms=f"{ms:.1f}", **fields, **extra))
-        record_span_event("end", name, log.name, trace=tid, span=sid,
-                          parent=parent, duration_ms=ms,
-                          fields={**fields, **extra} or None)
+                kv(duration_ms=f"{ph.ms:.1f}", **fields, **extra))
 
 
 @contextlib.contextmanager

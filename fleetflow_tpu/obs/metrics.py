@@ -23,9 +23,11 @@ Semantics follow the Prometheus client contract where it matters:
 
 from __future__ import annotations
 
+import bisect
+import functools
 import math
 import threading
-from typing import Iterable, Optional, Sequence
+from typing import Callable, Iterable, Optional, Sequence
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
            "DEFAULT_BUCKETS", "MS_BUCKETS", "SOLVE_SECONDS_BUCKETS"]
@@ -125,12 +127,20 @@ class Counter(_Metric):
         return [0.0]
 
     def inc(self, amount: float = 1.0, **labels) -> None:
+        self._inc(self._child(labels), amount)
+
+    def _inc(self, child: list, amount: float = 1.0) -> None:
         if amount < 0:
             raise ValueError(f"counter {self.name} cannot decrease "
                              f"(inc({amount}))")
-        child = self._child(labels)
         with self._lock:
             child[0] += amount
+
+    def bind(self, **labels) -> Callable[..., None]:
+        """`inc` for one label set with the label lookup done once: for a
+        hot path that increments the same child every time (a store
+        lookup, a phase)."""
+        return functools.partial(self._inc, self._child(labels))
 
     def value(self, **labels) -> float:
         child = self._children.get(self._key(labels))
@@ -186,16 +196,19 @@ class Histogram(_Metric):
                 "sum": 0.0, "count": 0}
 
     def observe(self, value: float, **labels) -> None:
-        child = self._child(labels)
+        self._observe(self._child(labels), value)
+
+    def _observe(self, child: dict, value: float) -> None:
         with self._lock:
-            idx = len(self.buckets)
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    idx = i
-                    break
-            child["counts"][idx] += 1
+            # first bucket with value <= le; past the last one is +Inf
+            child["counts"][bisect.bisect_left(self.buckets, value)] += 1
             child["sum"] += value
             child["count"] += 1
+
+    def bind(self, **labels) -> Callable[[float], None]:
+        """`observe` for one label set with the label lookup done once (as
+        Counter.bind)."""
+        return functools.partial(self._observe, self._child(labels))
 
     def count(self, **labels) -> int:
         child = self._children.get(self._key(labels))

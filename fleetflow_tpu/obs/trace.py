@@ -12,6 +12,18 @@ Contextvars propagate through async/await but NOT into
 `loop.run_in_executor` threads; code that hops threads re-enters the trace
 explicitly from the id it carried (`with use_trace(req.trace_id): ...`),
 which is exactly what DeployEngine.execute does.
+
+`Phase` is the one primitive every span goes through, `obs.span` included.
+It is always on and has no switch: on exit it has written the span (a) into
+the profiler's trace as `fleet/<name>` (`jax.profiler.TraceAnnotation`, the
+clock the device trace shares; only where `jax` is already imported and a
+profiler session is collecting), (b) into a
+bounded in-memory ring on `time.perf_counter()` (`spans_between`), (c) into
+the `fleet_phase_ms{phase}` histogram, and (d) into the flight recorder
+where `FLEET_TRACE_FILE` is set and an `obs.span` encloses it (the span is
+its `parent`). A phase mints no ids and logs nothing; it
+costs a few microseconds, so it belongs around a step of a request and
+never inside a loop over servers, rows or records (count those instead).
 """
 
 from __future__ import annotations
@@ -20,15 +32,20 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
+from collections import deque
 from typing import Iterator, Optional
+
+from .metrics import REGISTRY
 
 __all__ = ["new_trace_id", "new_span_id", "current_trace_id",
            "current_span_id", "use_trace", "FlightRecorder",
            "flight_recorder", "record_span_event", "read_trace_file",
-           "read_trace_files"]
+           "read_trace_files", "Phase", "SpanRing", "SpansDropped",
+           "spans_between", "RING_CAPACITY", "PROFILER_PREFIX"]
 
 _trace_id: contextvars.ContextVar[str] = contextvars.ContextVar(
     "fleet_trace_id", default="")
@@ -75,6 +92,157 @@ def _use_span(span_id: str) -> Iterator[str]:
         yield span_id
     finally:
         _span_id.reset(token)
+
+
+# --------------------------------------------------------------------------
+# phases: the profiler's clock, the ring, the histogram
+# --------------------------------------------------------------------------
+
+PROFILER_PREFIX = "fleet/"
+# a served op opens some tens of phases, so a 10 s window of 70 ms ops is
+# about 5,000 spans; 2**17 holds minutes of a busy CP (~12 MB when full)
+RING_CAPACITY = 1 << 17
+PHASE_LOGGER = "fleetflow.phase"
+
+# metric catalog: docs/guide/10-observability.md
+_M_PHASE_MS = REGISTRY.histogram(
+    "fleet_phase_ms",
+    "Wall milliseconds of every phase and span the program opened, by "
+    "name (obs.phase / obs.span)", labels=("phase",),
+    buckets=(0.01, 0.05, 0.25, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
+             250.0, 500.0, 1000.0, 2500.0, 10000.0))
+_M_SPANS_DROPPED = REGISTRY.counter(
+    "fleet_obs_spans_dropped_total",
+    "Spans the in-memory ring overwrote before anyone read them")
+_count_dropped = _M_SPANS_DROPPED.bind()
+
+
+class SpansDropped(RuntimeError):
+    """The ring overwrote a span that ended inside the window asked for."""
+
+
+class SpanRing:
+    """The last `capacity` finished spans as `(name, t0, t1, thread id)` on
+    `time.perf_counter()`, in the order they ended. Appends come from any
+    thread (`deque.append` is atomic); readers take a copy."""
+
+    def __init__(self, capacity: int = RING_CAPACITY):
+        self._spans: deque = deque(maxlen=capacity)
+        self._evicted_until = 0.0    # latest end of an overwritten span
+
+    def append(self, name: str, t0: float, t1: float, tid: int) -> None:
+        spans = self._spans
+        if len(spans) == spans.maxlen:
+            self._evicted_until = max(self._evicted_until, spans[0][2])
+            _count_dropped()
+        spans.append((name, t0, t1, tid))
+
+    def between(self, t0: float, t1: float) -> list[tuple]:
+        """The spans that lie wholly inside [t0, t1]. Raises SpansDropped
+        when a span that ended after t0 has been overwritten: a sum over
+        the window would then be short without saying so."""
+        if self._evicted_until > t0:
+            raise SpansDropped(
+                f"the span ring ({self._spans.maxlen} spans) overwrote "
+                f"spans that ended after t0={t0:.6f}")
+        return [s for s in list(self._spans) if s[1] >= t0 and s[2] <= t1]
+
+
+RING = SpanRing()
+
+
+def spans_between(t0: float, t1: float) -> list[tuple]:
+    """`RING.between`: what the program did between two readings of
+    `time.perf_counter()`, for a benchmark reader or a debugger."""
+    return RING.between(t0, t1)
+
+
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _profiler_annotation():
+    """The profiler's annotation class where `jax` is already imported;
+    the CLI and host-only paths import nothing for a phase's sake."""
+    global _annotation
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is not None:
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+_observe: dict = {}     # phase name -> fleet_phase_ms{phase=name}.observe
+
+
+def _bind_observe(name: str):
+    return _observe.setdefault(name, _M_PHASE_MS.bind(phase=name))
+
+
+class Phase:
+    """Context manager around one step of a request: `with
+    phase("cp.commit.persist", records=n) as ph: ...`; afterwards `ph.ms`
+    is its wall time. See the module docstring for where it is written."""
+
+    __slots__ = ("name", "fields", "t0", "t1", "_ann", "_owner")
+
+    def __init__(self, name: str, /, **fields):
+        self.name = name
+        self.fields = fields
+        self.t0 = self.t1 = 0.0
+        self._ann = None
+        # obs.span owns its phase: (logger, trace id, span id, parent span
+        # id, the dict of fields collected in the body)
+        self._owner: Optional[tuple] = None
+
+    @property
+    def ms(self) -> float:
+        return (self.t1 - self.t0) * 1e3
+
+    def set(self, **fields) -> None:
+        """Fields known only inside the body (a frame's size, a count)."""
+        self.fields.update(fields)
+        if self._ann is not None:
+            self._ann.set_metadata(**fields)
+
+    def __enter__(self) -> "Phase":
+        # an annotation only while a profiler session is collecting them:
+        # the profiler's own switch, read in tens of nanoseconds
+        ann = _annotation or _profiler_annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(PROFILER_PREFIX + self.name, **self.fields)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t0, t1 = self.t0, time.perf_counter()
+        self.t1 = t1
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        name = self.name
+        RING.append(name, t0, t1, threading.get_ident())
+        (_observe.get(name) or _bind_observe(name))((t1 - t0) * 1e3)
+        # the recorder files a phase under its enclosing span; outside any
+        # span there is nothing to hang it on (and no environment read)
+        if _span_id.get() and os.environ.get("FLEET_TRACE_FILE"):
+            self._record(exc)
+        return False
+
+    def _record(self, exc) -> None:
+        """The flight recorder's `end` (or `fail`) event. A bare phase has
+        no id of its own: its `parent` is the enclosing obs.span."""
+        fields = self.fields
+        if self._owner is not None:
+            logger, trace, span, parent, extra = self._owner
+            fields = {**fields, **extra}
+        else:
+            logger, trace, span, parent = (
+                PHASE_LOGGER, _trace_id.get(), "", _span_id.get())
+        record_span_event(
+            "end" if exc is None else "fail", self.name, logger,
+            trace=trace, span=span, parent=parent, duration_ms=self.ms,
+            error=None if exc is None else str(exc), fields=fields or None)
 
 
 # --------------------------------------------------------------------------

@@ -31,7 +31,6 @@ from __future__ import annotations
 import hashlib
 import inspect
 import os
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -40,10 +39,9 @@ import numpy as np
 from ..core.discovery import CONFIG_DIR_NAME
 from ..core.loader import (_parse_workers as _ingest_workers,
                            load_project_from_root_with_stage)
-from ..core.parsecache import M_FRONTEND_PHASE_MS as _M_PHASE_MS
 from ..core.model import Flow, Service, Stage
 from ..lower.tensors import ProblemTensors, lower_stage
-from ..obs import get_logger
+from ..obs import get_logger, phase
 from ..obs.metrics import REGISTRY
 from .model import Registry
 
@@ -456,7 +454,12 @@ def aggregate_fleets(
     form. With ``FLEET_PARSE_WORKERS>1`` and the default loader, cache
     misses load across a process pool.
     """
-    t_lower0 = time.perf_counter()
+    with phase("frontend.lower", fleets=len(registry.fleets)):
+        return _aggregate_fleets(registry, stages, loader, cache,
+                                 content_hash)
+
+
+def _aggregate_fleets(registry, stages, loader, cache, content_hash):
     default_loader = loader is None
     loader = loader or (lambda path, stage:
                         load_project_from_root_with_stage(path, stage))
@@ -535,16 +538,12 @@ def aggregate_fleets(
         if cache.lowered is not None and cache.lowered[0] == inst_key:
             cache.instance_hits += 1
             _M_CACHE.inc(outcome="instance_hit")
-            _M_PHASE_MS.set((time.perf_counter() - t_lower0) * 1e3,
-                            phase="lower")
             return cache.lowered[1], cache.lowered[2]
         disk = _instance_disk_get(inst_key)
         if disk is not None:
             cache.lowered = (inst_key,) + disk
             cache.instance_hits += 1
             _M_CACHE.inc(outcome="instance_disk_hit")
-            _M_PHASE_MS.set((time.perf_counter() - t_lower0) * 1e3,
-                            phase="lower")
             return disk
 
     # pass 2: load the misses — across the worker pool when allowed
@@ -610,5 +609,4 @@ def aggregate_fleets(
     if cache is not None and inst_key is not None:
         cache.lowered = (inst_key, pt, index)
         _instance_disk_put(inst_key, pt, index)
-    _M_PHASE_MS.set((time.perf_counter() - t_lower0) * 1e3, phase="lower")
     return pt, index

@@ -49,7 +49,7 @@ import numpy as np
 
 from .base import Placement, level_schedule, record_placement
 from ..lower.tensors import ProblemTensors
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 
 log = get_logger("sched.tpu")
@@ -435,28 +435,32 @@ class TpuSolverScheduler:
         # imported lazily so the host path never pays JAX startup
         from ..solver.sharded import sharded_route
 
-        t0 = time.perf_counter()
-        # pod-scale route: above the FLEET_SHARDED threshold the stage's
-        # resident state lives mesh-sharded and the solve runs through
-        # solver/sharded.solve_sharded (an explicit scheduler mesh= means
-        # the caller chose chain sharding — leave it alone)
-        sh_mesh = sharded_route(pt) if self.mesh is None else None
-        slot, resident_warm = self._stage(pt, delta, warm_start, stage,
-                                          mesh=sh_mesh)
+        with phase("sched.place", stage=stage, rows=pt.S) as ph:
+            # pod-scale route: above the FLEET_SHARDED threshold the
+            # stage's resident state lives mesh-sharded and the solve runs
+            # through solver/sharded.solve_sharded (an explicit scheduler
+            # mesh= means the caller chose chain sharding — leave it alone)
+            with phase("sched.stage"):
+                sh_mesh = sharded_route(pt) if self.mesh is None else None
+                slot, resident_warm = self._stage(pt, delta, warm_start,
+                                                  stage, mesh=sh_mesh)
 
-        # cold fallback on a warm request still warm-starts from THIS
-        # stage's last HOST assignment when shapes line up (the
-        # pre-resident behavior; slots are per stage so the seed can
-        # never come from a different stage's placement)
-        init = None
-        if (warm_start and not resident_warm
-                and slot.last_assignment is not None
-                and slot.last_assignment.shape[0] == pt.S):
-            init = slot.last_assignment
-        res = self._solve_one(pt, slot, resident_warm, sh_mesh, init,
-                              overlap_host_work=overlap_host_work)
-        ms = (time.perf_counter() - t0) * 1e3
-        return self._finalize(pt, res, slot, ms, stage)
+            # cold fallback on a warm request still warm-starts from THIS
+            # stage's last HOST assignment when shapes line up (the
+            # pre-resident behavior; slots are per stage so the seed can
+            # never come from a different stage's placement)
+            with phase("sched.solve") as ph_solve:
+                init = None
+                if (warm_start and not resident_warm
+                        and slot.last_assignment is not None
+                        and slot.last_assignment.shape[0] == pt.S):
+                    init = slot.last_assignment
+                res = self._solve_one(pt, slot, resident_warm, sh_mesh, init,
+                                      overlap_host_work=overlap_host_work)
+            # solve_ms: staging + dispatch + the fetch of the result
+            ms = (ph_solve.t1 - ph.t0) * 1e3
+            with phase("sched.finalize", rows=pt.S):
+                return self._finalize(pt, res, slot, ms, stage)
 
     def place_many(self, requests: list[dict]) -> list[Placement]:
         """Batched placement across stages — the tenant multiplexer
@@ -471,44 +475,49 @@ class TpuSolverScheduler:
         from ..solver.multiplex import MuxEntry, solve_multiplexed
         from ..solver.sharded import sharded_route
 
-        t0 = time.perf_counter()
-        staged = []
-        for req in requests:
-            pt = req["pt"]
-            warm = bool(req.get("warm_start"))
-            sh_mesh = sharded_route(pt) if self.mesh is None else None
-            slot, resident_warm = self._stage(
-                pt, req.get("delta"), warm, req.get("stage"), mesh=sh_mesh)
-            staged.append((pt, slot, resident_warm, sh_mesh,
-                           req.get("stage"), warm))
+        with phase("sched.place", requests=len(requests)) as ph:
+            staged = []
+            with phase("sched.stage"):
+                for req in requests:
+                    pt = req["pt"]
+                    warm = bool(req.get("warm_start"))
+                    sh_mesh = sharded_route(pt) if self.mesh is None else None
+                    slot, resident_warm = self._stage(
+                        pt, req.get("delta"), warm, req.get("stage"),
+                        mesh=sh_mesh)
+                    staged.append((pt, slot, resident_warm, sh_mesh,
+                                   req.get("stage"), warm))
 
-        results: list = [None] * len(staged)
-        mux_idx = [i for i, (_, slot, rw, mesh, _, _w) in enumerate(staged)
-                   if rw and mesh is None and slot.resident.mesh is None]
-        if len(mux_idx) >= 2:
-            entries = [MuxEntry(pt=staged[i][0],
-                                resident=staged[i][1].resident,
-                                seed=self.seed, stage=staged[i][4])
-                       for i in mux_idx]
-            mres = solve_multiplexed(entries, chains=self.chains,
-                                     steps=self.steps)
-            for i, r in zip(mux_idx, mres):
-                results[i] = r
-        for i, (pt, slot, resident_warm, sh_mesh, _stg,
-                warm) in enumerate(staged):
-            if results[i] is not None:
-                continue
-            init = None
-            if (warm and not resident_warm
-                    and slot.last_assignment is not None
-                    and slot.last_assignment.shape[0] == pt.S):
-                init = slot.last_assignment
-            results[i] = self._solve_one(pt, slot, resident_warm,
-                                         sh_mesh, init)
-        ms = (time.perf_counter() - t0) * 1e3
-        return [self._finalize(pt, res, slot, ms, stg)
-                for (pt, slot, _rw, _mesh, stg, _w), res
-                in zip(staged, results)]
+            results: list = [None] * len(staged)
+            with phase("sched.solve") as ph_solve:
+                mux_idx = [i for i, (_, slot, rw, mesh, _, _w)
+                           in enumerate(staged)
+                           if rw and mesh is None and slot.resident.mesh is None]
+                if len(mux_idx) >= 2:
+                    entries = [MuxEntry(pt=staged[i][0],
+                                        resident=staged[i][1].resident,
+                                        seed=self.seed, stage=staged[i][4])
+                               for i in mux_idx]
+                    mres = solve_multiplexed(entries, chains=self.chains,
+                                             steps=self.steps)
+                    for i, r in zip(mux_idx, mres):
+                        results[i] = r
+                for i, (pt, slot, resident_warm, sh_mesh, _stg,
+                        warm) in enumerate(staged):
+                    if results[i] is not None:
+                        continue
+                    init = None
+                    if (warm and not resident_warm
+                            and slot.last_assignment is not None
+                            and slot.last_assignment.shape[0] == pt.S):
+                        init = slot.last_assignment
+                    results[i] = self._solve_one(pt, slot, resident_warm,
+                                                 sh_mesh, init)
+            ms = (ph_solve.t1 - ph.t0) * 1e3
+            with phase("sched.finalize"):
+                return [self._finalize(pt, res, slot, ms, stg)
+                        for (pt, slot, _rw, _mesh, stg, _w), res
+                        in zip(staged, results)]
 
     def reschedule(self, pt: ProblemTensors, *, delta=None,
                    overlap_host_work=None,

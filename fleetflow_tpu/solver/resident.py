@@ -132,24 +132,25 @@ def _merge_fn():
 
     def merge(prob, assignment, node_valid, capacity, dem_idx, dem_val,
               elig_idx, elig_rows, n_real, *, has_demand, has_eligible):
-        # scatter rows ride padded tiers; pad slots carry an out-of-range
-        # index and mode="drop" discards them. The static has_* flags keep
-        # the common mask/capacity-only delta from touching the big (S, ·)
-        # planes at all — they alias straight through the donation.
-        demand = (prob.demand.at[dem_idx].set(dem_val, mode="drop")
-                  if has_demand else prob.demand)
-        eligible = (prob.eligible.at[elig_idx].set(elig_rows, mode="drop")
-                    if has_eligible else prob.eligible)
-        # re-park phantom rows on a valid node: the previous winner may
-        # have left them on a node this delta just killed, and a phantom
-        # on an invalid node is the one way it stops being inert
-        first_valid = jnp.argmax(node_valid).astype(jnp.int32)
-        ar = jnp.arange(prob.S)
-        assignment = jnp.where(ar >= n_real, first_valid, assignment)
-        prob = dataclasses.replace(
-            prob, demand=demand, eligible=eligible, node_valid=node_valid,
-            capacity=capacity, n_real=n_real)
-        return prob, assignment
+        with jax.named_scope("resident.merge"):     # metadata only
+            # scatter rows ride padded tiers; pad slots carry an out-of-range
+            # index and mode="drop" discards them. The static has_* flags keep
+            # the common mask/capacity-only delta from touching the big (S, ·)
+            # planes at all — they alias straight through the donation.
+            demand = (prob.demand.at[dem_idx].set(dem_val, mode="drop")
+                      if has_demand else prob.demand)
+            eligible = (prob.eligible.at[elig_idx].set(elig_rows, mode="drop")
+                        if has_eligible else prob.eligible)
+            # re-park phantom rows on a valid node: the previous winner may
+            # have left them on a node this delta just killed, and a phantom
+            # on an invalid node is the one way it stops being inert
+            first_valid = jnp.argmax(node_valid).astype(jnp.int32)
+            ar = jnp.arange(prob.S)
+            assignment = jnp.where(ar >= n_real, first_valid, assignment)
+            prob = dataclasses.replace(
+                prob, demand=demand, eligible=eligible, node_valid=node_valid,
+                capacity=capacity, n_real=n_real)
+            return prob, assignment
 
     # donation: the stale problem/assignment buffers are dead the moment
     # the merge lands, so XLA reuses them in place — no second copy of the

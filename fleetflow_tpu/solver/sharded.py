@@ -52,7 +52,7 @@ from .anneal import (W_CAP, W_CONF, W_ELIG, _move_delta_core, _skew_pen,
 from .buckets import pad_problem
 from .problem import DeviceProblem, eligible_lookup
 from .resident import ResidentProblem, transfer_guard_ctx
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 
 log = get_logger("solver.sharded")
@@ -931,113 +931,113 @@ def solve_sharded(pt, *, resident: ShardedResident,
     FLEET_TEMPER_LADDER, default 1.3 — measured best of {1.3, 1.6, 2.0, 3.0} on the partitioned-seed curve) and `exchange_every` (sweep-blocks
     between exchange rounds, FLEET_TEMPER_EXCHANGE, default 1)."""
     import contextlib
-    import time
 
     from .api import SolveResult
     from .buckets import soft_score_host
     from .repair import RepairResult, repair, verify
 
-    t = time.perf_counter
     timings: dict = {}
-    t_start = t()
-    rp = resident
-    mesh = rp.mesh
-    prob = rp.prob
-    D = mesh.shape[SVC_AXIS]
-    n_rep = mesh.shape.get(REPLICA_AXIS, 1)
-    if ladder is None:
-        try:
-            ladder = float(os.environ.get("FLEET_TEMPER_LADDER") or "1.3")
-        except ValueError:
-            ladder = 1.3
-    if exchange_every is None:
-        try:
-            exchange_every = max(
-                1, int(os.environ.get("FLEET_TEMPER_EXCHANGE") or "1"))
-        except ValueError:
-            exchange_every = 1
-    warm = bool(resident_warm and rp.assignment is not None)
-    if warm:
-        timings["delta_stage_ms"] = rp.consume_delta_ms()
-    timings["stage_ms"] = (t() - t_start) * 1e3
+    with phase("solver.stage") as ph_stage:
+        rp = resident
+        mesh = rp.mesh
+        prob = rp.prob
+        D = mesh.shape[SVC_AXIS]
+        n_rep = mesh.shape.get(REPLICA_AXIS, 1)
+        if ladder is None:
+            try:
+                ladder = float(os.environ.get("FLEET_TEMPER_LADDER") or "1.3")
+            except ValueError:
+                ladder = 1.3
+        if exchange_every is None:
+            try:
+                exchange_every = max(
+                    1, int(os.environ.get("FLEET_TEMPER_EXCHANGE") or "1"))
+            except ValueError:
+                exchange_every = 1
+        warm = bool(resident_warm and rp.assignment is not None)
+        if warm:
+            timings["delta_stage_ms"] = rp.consume_delta_ms()
+    timings["stage_ms"] = ph_stage.ms
 
-    t_seed = t()
-    if warm:
-        # seed already mesh-resident: the previous padded winner, phantoms
-        # re-parked at delta time; nothing crosses the host boundary
-        seed_assignment = rp.assignment
-        t0 = min(t0, 0.1)   # warm start: refine, don't re-scramble
-    else:
-        if init_assignment is not None:
-            seed_np = np.asarray(init_assignment, dtype=np.int32)
-            t0 = min(t0, 0.1)   # host warm seed: same refine contract
+    with phase("solver.seed") as ph_seed:
+        if warm:
+            # seed already mesh-resident: the previous padded winner,
+            # phantoms re-parked at delta time; nothing crosses the host
+            # boundary
+            seed_assignment = rp.assignment
+            t0 = min(t0, 0.1)   # warm start: refine, don't re-scramble
         else:
-            seed_np = _host_seed(pt, D)
-        # adopt_host pads to the mesh tier and commits P(SVC_AXIS)
-        rp.adopt_host(seed_np, pt.node_valid, warm=False)
-        seed_assignment = rp.assignment
-    timings["seed_ms"] = (t() - t_seed) * 1e3
+            if init_assignment is not None:
+                seed_np = np.asarray(init_assignment, dtype=np.int32)
+                t0 = min(t0, 0.1)   # host warm seed: same refine contract
+            else:
+                seed_np = _host_seed(pt, D)
+            # adopt_host pads to the mesh tier and commits P(SVC_AXIS)
+            rp.adopt_host(seed_np, pt.node_valid, warm=False)
+            seed_assignment = rp.assignment
+    timings["seed_ms"] = ph_seed.ms
     _M_SHARDED.inc(outcome="delta" if warm else "cold")
 
-    t_anneal = t()
-    t0_d, t1_d, lad_d = rp.warm_scalars(t0, t1, float(ladder))
-    # the PRNG key is minted and committed BEFORE the guard arms: it is
-    # not a problem tensor (same contract as api._solve)
-    key = jax.device_put(jax.random.PRNGKey(seed),
-                         NamedSharding(mesh, P()))
-    from .anneal import solve_trace_blocks
-    trace_blocks = solve_trace_blocks()
-    guard = transfer_guard_ctx() if warm else contextlib.nullcontext()
-    cache_before = anneal_sharded._cache_size()
-    with guard:
-        res = anneal_sharded(
-            prob, seed_assignment, key, steps=steps, t0=t0_d, t1=t1_d,
-            proposals_per_step=proposals_per_step, mesh=mesh,
-            adaptive=adaptive, block=block, ladder=lad_d,
-            exchange_every=exchange_every, return_stats=True,
-            trace_blocks=trace_blocks)
-    compile_events = anneal_sharded._cache_size() - cache_before
-    # the padded winner stays mesh-resident as the next warm seed
-    rp.adopt(res.assignment)
-    if overlap_host_work is not None:
-        t_ov = t()
-        overlap_host_work()
-        timings["overlap_host_ms"] = (t() - t_ov) * 1e3
-    # ONE fetch for everything the host decision needs (the flight-deck
-    # buffer rides it)
-    (assignment, sweeps, capF, confF, inelF, skewF, _softF, att,
-     acc, htelem) = jax.device_get(tuple(res))
-    # FORCE a host copy before slicing: on the CPU backend device_get
-    # returns a VIEW of the device buffer, and the padded winner was just
-    # adopted as the mesh-resident seed (rp.adopt above) — the next warm
-    # sharded dispatch DONATES that buffer, clobbering every retained
-    # result in place (the same aliasing api._solve pins against)
-    assignment = np.array(assignment, dtype=np.int32, copy=True)[: pt.S]
-    timings["anneal_ms"] = (t() - t_anneal) * 1e3
+    with phase("solver.anneal") as ph_anneal:
+        t0_d, t1_d, lad_d = rp.warm_scalars(t0, t1, float(ladder))
+        # the PRNG key is minted and committed BEFORE the guard arms: it is
+        # not a problem tensor (same contract as api._solve)
+        key = jax.device_put(jax.random.PRNGKey(seed),
+                             NamedSharding(mesh, P()))
+        from .anneal import solve_trace_blocks
+        trace_blocks = solve_trace_blocks()
+        guard = transfer_guard_ctx() if warm else contextlib.nullcontext()
+        cache_before = anneal_sharded._cache_size()
+        with guard, phase("solver.dispatch.sharded"):
+            res = anneal_sharded(
+                prob, seed_assignment, key, steps=steps, t0=t0_d, t1=t1_d,
+                proposals_per_step=proposals_per_step, mesh=mesh,
+                adaptive=adaptive, block=block, ladder=lad_d,
+                exchange_every=exchange_every, return_stats=True,
+                trace_blocks=trace_blocks)
+        compile_events = anneal_sharded._cache_size() - cache_before
+        # the padded winner stays mesh-resident as the next warm seed
+        rp.adopt(res.assignment)
+        if overlap_host_work is not None:
+            with phase("solver.overlap_host") as ph_ov:
+                overlap_host_work()
+            timings["overlap_host_ms"] = ph_ov.ms
+        # ONE fetch for everything the host decision needs (the flight-deck
+        # buffer rides it)
+        with phase("solver.fetch"):
+            (assignment, sweeps, capF, confF, inelF, skewF, _softF, att,
+             acc, htelem) = jax.device_get(tuple(res))
+        # FORCE a host copy before slicing: on the CPU backend device_get
+        # returns a VIEW of the device buffer, and the padded winner was just
+        # adopted as the mesh-resident seed (rp.adopt above) — the next warm
+        # sharded dispatch DONATES that buffer, clobbering every retained
+        # result in place (the same aliasing api._solve pins against)
+        assignment = np.array(assignment, dtype=np.int32, copy=True)[: pt.S]
+    timings["anneal_ms"] = ph_anneal.ms
 
-    t_verify = t()
-    moves = 0
-    pre_repair = 0
-    if float(capF + confF + inelF + skewF) == 0:
-        stats = {"capacity": 0, "conflicts": 0, "eligibility": 0,
-                 "skew": 0, "total": 0}
-    else:
-        stats = {k: int(v) for k, v in verify(pt, assignment).items()}
-        pre_repair = int(stats["total"])
-        if do_repair and stats["total"] > 0:
-            rr: RepairResult = repair(pt, assignment)
-            assignment, moves = rr.assignment, rr.moves
-            stats = {k: int(v) for k, v in rr.stats.items()}
-            if moves:
-                # the resident seed must track what the fleet actually
-                # runs; on the warm path this is the host-transfer event
-                # the counter exists for
-                rp.adopt_host(assignment, pt.node_valid, warm=warm)
-    # the real rows' soft score (the device number counts phantoms in its
-    # /S mean denominators)
-    soft = soft_score_host(pt, assignment)
-    timings["verify_repair_ms"] = (t() - t_verify) * 1e3
-    timings["total_ms"] = (t() - t_start) * 1e3
+    with phase("solver.verify_repair") as ph_verify:
+        moves = 0
+        pre_repair = 0
+        if float(capF + confF + inelF + skewF) == 0:
+            stats = {"capacity": 0, "conflicts": 0, "eligibility": 0,
+                     "skew": 0, "total": 0}
+        else:
+            stats = {k: int(v) for k, v in verify(pt, assignment).items()}
+            pre_repair = int(stats["total"])
+            if do_repair and stats["total"] > 0:
+                rr: RepairResult = repair(pt, assignment)
+                assignment, moves = rr.assignment, rr.moves
+                stats = {k: int(v) for k, v in rr.stats.items()}
+                if moves:
+                    # the resident seed must track what the fleet actually
+                    # runs; on the warm path this is the host-transfer event
+                    # the counter exists for
+                    rp.adopt_host(assignment, pt.node_valid, warm=warm)
+        # the real rows' soft score (the device number counts phantoms in its
+        # /S mean denominators)
+        soft = soft_score_host(pt, assignment)
+    timings["verify_repair_ms"] = ph_verify.ms
+    timings["total_ms"] = (ph_verify.t1 - ph_stage.t0) * 1e3
 
     # the CORE solver families too, not just the sharded ones: above the
     # routing threshold these are the only solves a fleet runs, and the

@@ -380,69 +380,75 @@ def _subsolve_fn():
                  migration_weight, *, chains, steps, block,
                  proposals_per_step, prerepair_moves, Gc_sub,
                  trace_blocks=0):
-        S_sub = rows.shape[0]
-        rows_g = jnp.minimum(rows, prob.S - 1)   # clamp-safe gather index
-        real = jnp.arange(S_sub) < n_sub
-        demand_sub = jnp.where(real[:, None], prob.demand[rows_g], 0.0)
-        if prob.eligible.dtype == jnp.uint32:
-            elig_fill = jnp.uint32(0xFFFFFFFF)
-        else:
-            elig_fill = jnp.asarray(True)
-        eligible_sub = jnp.where(real[:, None], prob.eligible[rows_g],
-                                 elig_fill)
-        pref_sub = None
-        if prob.preferred is not None:
-            pref_sub = jnp.where(real[:, None], prob.preferred[rows_g], 0.0)
-        # phantom sub rows park on a valid node (inert: zero demand, no
-        # ids, eligible everywhere — the bucket-phantom construction)
-        park = jnp.argmax(prob.node_valid).astype(jnp.int32)
-        seed_sub = jnp.where(real, assignment[rows_g], park).astype(jnp.int32)
-        sub = DeviceProblem(
-            demand=demand_sub, capacity=prob.capacity,
-            conflict_ids=sub_conflict, coloc_ids=sub_coloc,
-            eligible=eligible_sub, node_valid=prob.node_valid,
-            node_topology=prob.node_topology,
-            S=S_sub, N=prob.N, G=used0.shape[1], Gc=Gc_sub, T=prob.T,
-            strategy=prob.strategy, max_skew=prob.max_skew,
-            preferred=pref_sub, n_real=n_sub)
-        # warm stickiness rides the sub proposal delta exactly as on the
-        # full path: staying on the previous still-eligible node earns
-        # migration_weight; churn-forced moves stay free
-        sub_a = dataclasses.replace(
-            sub, sticky_prev=seed_sub,
-            sticky_w=jnp.asarray(migration_weight, jnp.float32))
-        st0 = chain_states_from_assignment(
-            sub_a, seed_sub, base=(load0, used0, coloc0, topo0))
-        st0, prerepair_applied = prerepair_state_counted(
-            sub_a, st0, prerepair_moves)
-        init_states = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (chains,) + x.shape), st0)
-        inits = jnp.broadcast_to(st0.assignment[None], (chains, S_sub))
-        (best_assign_c, best_viol_c, best_soft_c, sweeps_run, accepted_c,
-         telem) = anneal_adaptive_states(
-                sub_a, inits, key, max_steps=steps, block=block,
-                t0=t0, t1=t1, proposals_per_step=proposals_per_step,
-                init_states=init_states, exit_on_feasible_init=True,
-                trace_blocks=trace_blocks)
-        accepted = accepted_c.sum()
-        telem = dict(telem, prerepair_moves=prerepair_applied)
-        # same lexicographic (violations, soft) rank as the full pipeline
-        min_viol = best_viol_c.min()
-        best = jnp.argmin(jnp.where(best_viol_c == min_viol,
-                                    best_soft_c, jnp.inf))
-        winner = best_assign_c[best]
-        # scatter the accepted rows back into a FRESH assignment buffer;
-        # pad slots carry prob.S and are dropped. The input is
-        # deliberately NOT donated: (a) a gate-rejected sub-solve must
-        # re-run the full fused path from the ORIGINAL seed — stranded
-        # rows intact, the battle-tested prerepair path — so the old
-        # buffer has to survive; and (b) an (S,) i32 copy is ~40 KB at
-        # fleet scale, noise next to the planes the merge kernel's
-        # donation exists for
-        new_assignment = assignment.at[rows].set(winner, mode="drop")
-        # the acceptance gate: exact full-problem stats of the scattered
-        # result — whatever the mini anneal believed, THIS decides
-        stats, soft = exact_stats_and_soft(prob, new_assignment)
+        # named scopes are metadata: the profiler shows the device's ops
+        # under subsolve.localized/<step>, the program is the same
+        with jax.named_scope("subsolve.localized/gather"):
+            S_sub = rows.shape[0]
+            rows_g = jnp.minimum(rows, prob.S - 1)   # clamp-safe gather index
+            real = jnp.arange(S_sub) < n_sub
+            demand_sub = jnp.where(real[:, None], prob.demand[rows_g], 0.0)
+            if prob.eligible.dtype == jnp.uint32:
+                elig_fill = jnp.uint32(0xFFFFFFFF)
+            else:
+                elig_fill = jnp.asarray(True)
+            eligible_sub = jnp.where(real[:, None], prob.eligible[rows_g],
+                                     elig_fill)
+            pref_sub = None
+            if prob.preferred is not None:
+                pref_sub = jnp.where(real[:, None], prob.preferred[rows_g], 0.0)
+            # phantom sub rows park on a valid node (inert: zero demand, no
+            # ids, eligible everywhere — the bucket-phantom construction)
+            park = jnp.argmax(prob.node_valid).astype(jnp.int32)
+            seed_sub = jnp.where(real, assignment[rows_g], park).astype(jnp.int32)
+        with jax.named_scope("subsolve.localized/anneal"):
+            sub = DeviceProblem(
+                demand=demand_sub, capacity=prob.capacity,
+                conflict_ids=sub_conflict, coloc_ids=sub_coloc,
+                eligible=eligible_sub, node_valid=prob.node_valid,
+                node_topology=prob.node_topology,
+                S=S_sub, N=prob.N, G=used0.shape[1], Gc=Gc_sub, T=prob.T,
+                strategy=prob.strategy, max_skew=prob.max_skew,
+                preferred=pref_sub, n_real=n_sub)
+            # warm stickiness rides the sub proposal delta exactly as on the
+            # full path: staying on the previous still-eligible node earns
+            # migration_weight; churn-forced moves stay free
+            sub_a = dataclasses.replace(
+                sub, sticky_prev=seed_sub,
+                sticky_w=jnp.asarray(migration_weight, jnp.float32))
+            st0 = chain_states_from_assignment(
+                sub_a, seed_sub, base=(load0, used0, coloc0, topo0))
+            st0, prerepair_applied = prerepair_state_counted(
+                sub_a, st0, prerepair_moves)
+            init_states = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x[None], (chains,) + x.shape), st0)
+            inits = jnp.broadcast_to(st0.assignment[None], (chains, S_sub))
+            (best_assign_c, best_viol_c, best_soft_c, sweeps_run, accepted_c,
+             telem) = anneal_adaptive_states(
+                    sub_a, inits, key, max_steps=steps, block=block,
+                    t0=t0, t1=t1, proposals_per_step=proposals_per_step,
+                    init_states=init_states, exit_on_feasible_init=True,
+                    trace_blocks=trace_blocks)
+            accepted = accepted_c.sum()
+            telem = dict(telem, prerepair_moves=prerepair_applied)
+            # same lexicographic (violations, soft) rank as the full pipeline
+            min_viol = best_viol_c.min()
+            best = jnp.argmin(jnp.where(best_viol_c == min_viol,
+                                        best_soft_c, jnp.inf))
+            winner = best_assign_c[best]
+        with jax.named_scope("subsolve.localized/scatter"):
+            # scatter the accepted rows back into a FRESH assignment buffer;
+            # pad slots carry prob.S and are dropped. The input is
+            # deliberately NOT donated: (a) a gate-rejected sub-solve must
+            # re-run the full fused path from the ORIGINAL seed — stranded
+            # rows intact, the battle-tested prerepair path — so the old
+            # buffer has to survive; and (b) an (S,) i32 copy is ~40 KB at
+            # fleet scale, noise next to the planes the merge kernel's
+            # donation exists for
+            new_assignment = assignment.at[rows].set(winner, mode="drop")
+        with jax.named_scope("subsolve.localized/gate"):
+            # the acceptance gate: exact full-problem stats of the scattered
+            # result — whatever the mini anneal believed, THIS decides
+            stats, soft = exact_stats_and_soft(prob, new_assignment)
         return new_assignment, stats, soft, sweeps_run, accepted, telem
 
     return jax.jit(subsolve,
