@@ -413,48 +413,78 @@ class PlacementService:
             assignment=dict(placement.assignment))
         return rid
 
-    def _apply_allocation(self, r: Reservation, sign: float) -> None:
+    def _write_allocation(self, slug: str, d) -> int:
+        """Add the cpu/memory/disk vector `d` to one server's `allocated`,
+        clamped at 0, through Store.update (journaled and handed to the
+        replication sink). Returns the server records written: 1, or 0
+        for a slug the store no longer has."""
+        s = self.store.server_by_slug(slug)
+        if s is None:
+            return 0
+        self.store.update("servers", s.id, allocated=type(s.allocated)(
+            cpu=max(s.allocated.cpu + float(d[0]), 0.0),
+            memory=max(s.allocated.memory + float(d[1]), 0.0),
+            disk=max(s.allocated.disk + float(d[2]), 0.0),
+            reserved_cpu=s.allocated.reserved_cpu,
+            reserved_memory=s.allocated.reserved_memory,
+            reserved_disk=s.allocated.reserved_disk,
+        ))
+        return 1
+
+    def _apply_allocation(self, r: Reservation, sign: float) -> int:
+        """Add (`sign` +1) or return (-1) the whole of `r` on every node
+        that carries demand of it. Returns the server records written."""
+        written = 0
         for slug, dem in r.demand_by_node.items():
-            s = self.store.server_by_slug(slug)
-            if s is None:
-                continue
-            self.store.update("servers", s.id, allocated=type(s.allocated)(
-                cpu=max(s.allocated.cpu + sign * float(dem[0]), 0.0),
-                memory=max(s.allocated.memory + sign * float(dem[1]), 0.0),
-                disk=max(s.allocated.disk + sign * float(dem[2]), 0.0),
-                reserved_cpu=s.allocated.reserved_cpu,
-                reserved_memory=s.allocated.reserved_memory,
-                reserved_disk=s.allocated.reserved_disk,
-            ))
+            written += self._write_allocation(
+                slug, sign * np.asarray(dem, dtype=np.float64))
+        return written
 
     def _apply_allocation_delta(self, prev: Reservation,
-                                new: Reservation) -> None:
+                                new: Reservation) -> int:
         """Supersede `prev` by `new` touching only the nodes whose demand
-        actually CHANGED. Numerically identical to apply(prev, -1) +
-        apply(new, +1), but a streaming micro-solve commit (one per drain
-        tick, cp/admission.py) only moves a batch's worth of nodes —
-        rewriting every server record of a 10k-service stage per commit
-        was the admission bench's bottleneck, not the solve."""
-        slugs = set(prev.demand_by_node) | set(new.demand_by_node)
+        actually CHANGED; returns the server records written. BOTH commit
+        paths supersede this way: commit() (a redeploy; a streaming
+        micro-solve commit per drain tick, cp/admission.py) and
+        commit_retained() (the reconverger's commit after churn). Such a
+        commit moves a batch's or one dead server's worth of nodes, and
+        a lookup, a write and a serialized journal entry for every server
+        of a 10k-service stage cost more than the solve.
+
+        Every server's `allocated` ends where apply(prev, -1) +
+        apply(new, +1) would leave it, to floating-point rounding (the
+        clamp at 0 acts on the same quantity wherever the book is
+        consistent: the server still holds what `prev` booked on it), and
+        every changed record goes through Store.update. A record whose
+        value does not change is not rewritten — the same state, with one
+        visible consequence: a server the commit does not touch does not
+        have `updated_at` bumped by it. cp/autoscaler.py ages an OFFLINE
+        server by max(last_heartbeat, updated_at): a dead server is
+        written once, by the commit that moves its rows away, then left
+        alone, which is what the reaper's clock wants."""
         zero = np.zeros(3)
-        for slug in slugs:
+        written = 0
+        for slug in set(prev.demand_by_node) | set(new.demand_by_node):
             d = (np.asarray(new.demand_by_node.get(slug, zero),
                             dtype=np.float64)
                  - np.asarray(prev.demand_by_node.get(slug, zero),
                               dtype=np.float64))
-            if not d.any():
-                continue
-            s = self.store.server_by_slug(slug)
-            if s is None:
-                continue
-            self.store.update("servers", s.id, allocated=type(s.allocated)(
-                cpu=max(s.allocated.cpu + float(d[0]), 0.0),
-                memory=max(s.allocated.memory + float(d[1]), 0.0),
-                disk=max(s.allocated.disk + float(d[2]), 0.0),
-                reserved_cpu=s.allocated.reserved_cpu,
-                reserved_memory=s.allocated.reserved_memory,
-                reserved_disk=s.allocated.reserved_disk,
-            ))
+            if d.any():
+                written += self._write_allocation(slug, d)
+        return written
+
+    def _supersede_allocation(self, prev: Optional[Reservation],
+                              r: Reservation) -> None:
+        """The `cp.commit.apply_allocation` phase of both commit paths:
+        book `r` on the servers in place of the stage's previous
+        commitment, if it has one. The phase's `records` field is the
+        number of server records written."""
+        with phase("cp.commit.apply_allocation") as ph:
+            if prev is None:
+                written = self._apply_allocation(r, +1.0)
+            else:
+                written = self._apply_allocation_delta(prev, r)
+            ph.set(records=written)
 
     def commit(self, rid: str) -> bool:
         """Deploy succeeded: move reserved -> committed on the servers
@@ -466,12 +496,7 @@ class PlacementService:
             if r is None or r.committed:
                 return False
             prev = self._committed.pop(r.stage_key, None)
-            with phase("cp.commit.apply_allocation",
-                       records=len(r.demand_by_node)):
-                if prev is not None:
-                    self._apply_allocation_delta(prev, r)
-                else:
-                    self._apply_allocation(r, +1.0)
+            self._supersede_allocation(prev, r)
             r.committed = True
             self._committed[r.stage_key] = r
             self._drop_churn(r.stage_key)   # commitment reflects reality now
@@ -501,7 +526,17 @@ class PlacementService:
         — the reconverger's commit path (cp/reconverge.py): a churn
         re-solve's assignment was actually redeployed to the surviving
         agents, so the churn hold graduates to the commitment, superseding
-        the pre-churn one (same supersede semantics as commit())."""
+        the pre-churn one. Like commit() it supersedes by DIFFERENCE
+        (_apply_allocation_delta; a first commitment is added whole): a
+        commit after one server died writes the dead server and those
+        that took its rows, not every server of the stage twice. When it
+        returns, every server's `allocated` is what subtract-then-add
+        would have left (to rounding), each changed record went through
+        Store.update — journaled, replicated — and the placement record
+        is persisted whole, so a standby or a restart reloads the same
+        book: the op is committed before it is acknowledged. Servers the
+        commit does not touch keep their `updated_at` (what reads it:
+        _apply_allocation_delta)."""
         with phase("cp.commit_retained", stage=stage_key), self._lock:
             entry = self._last.get(stage_key)
             if entry is None:
@@ -515,12 +550,7 @@ class PlacementService:
                     demand_by_node=self._demand_by_node(pt, placement),
                     assignment=dict(placement.assignment), committed=True)
             prev = self._committed.pop(stage_key, None)
-            with phase("cp.commit.apply_allocation",
-                       records=len(r.demand_by_node)
-                       + (len(prev.demand_by_node) if prev else 0)):
-                if prev is not None:
-                    self._apply_allocation(prev, -1.0)
-                self._apply_allocation(r, +1.0)
+            self._supersede_allocation(prev, r)
             self._committed[stage_key] = r
             self._drop_churn(stage_key)
             with phase("cp.commit.persist"):
