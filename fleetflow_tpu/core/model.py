@@ -255,6 +255,13 @@ class Service:
     # Placement hints (extensions; reference keeps these CP-side)
     colocate_with: list[str] = field(default_factory=list)
     anti_affinity: list[str] = field(default_factory=list)
+    # label -> the stages of this project the label reaches into besides
+    # the service's own (`anti_affinity "color=green" stages="a,b"`): no
+    # declarer of the label in a listed stage may share a server with this
+    # service, whichever of the two was placed first (the CP holds it over
+    # committed and reserved placements, cp/placement.py). A label without
+    # an entry separates declarers inside one stage only.
+    anti_affinity_stages: dict[str, list[str]] = field(default_factory=dict)
     replicas: int = 1
 
     _resources_set: bool = field(default=False, repr=False, compare=False)
@@ -314,6 +321,8 @@ class Service:
             labels=_merge_map(self.labels, other.labels),
             colocate_with=_merge_vec(self.colocate_with, other.colocate_with),
             anti_affinity=_merge_vec(self.anti_affinity, other.anti_affinity),
+            anti_affinity_stages=_merge_map(self.anti_affinity_stages,
+                                            other.anti_affinity_stages),
             replicas=other.replicas if other._replicas_set else self.replicas,
             _resources_set=self._resources_set or other._resources_set,
             _replicas_set=self._replicas_set or other._replicas_set,

@@ -34,6 +34,7 @@ from .model import (
     ServerLabels, ServerResource, Service, ServiceType, SourceLoc,
     SpreadConstraint, Stage, TenantSpec, Volume, WaitConfig,
 )
+from ..obs import phase
 
 __all__ = [
     "parse_kdl_string", "parse_kdl_file", "read_kdl_with_includes",
@@ -397,7 +398,16 @@ def parse_service(node: KdlNode, source: Optional[str] = None) -> Service:
         elif n in ("colocate_with", "colocate-with"):
             svc.colocate_with.extend(_str_args(c))
         elif n in ("anti_affinity", "anti-affinity"):
-            svc.anti_affinity.extend(_str_args(c))
+            labels = _str_args(c)
+            svc.anti_affinity.extend(labels)
+            reach = c.props.get("stages")
+            if reach is not None:
+                # stages="a,b": the stages of this project the labels of
+                # this node reach into (core/model.py Service)
+                stages = [s.strip() for s in _as_str(reach).split(",")
+                          if s.strip()]
+                for label in labels:
+                    svc.anti_affinity_stages[label] = stages
         elif n == "replicas":
             svc.replicas = int(c.arg(0, 1))
             svc._replicas_set = True
@@ -779,26 +789,27 @@ def parse_kdl_string(text: str, flow: Optional[Flow] = None, *,
     fragment merge when ``flow`` is passed), sharing leaf objects under the
     read-only contract.
     """
-    if cache is None:
-        cache = len(text) >= _cache_min_bytes()
-    if not cache:
-        frag = _parse_kdl_fragment(text, want_spans=want_spans,
-                                   source=source, line_offset=line_offset)
-        if flow is None:
-            return frag
-        return merge_flow_fragment(flow, frag)
+    with phase("frontend.parse", bytes=len(text)):
+        if cache is None:
+            cache = len(text) >= _cache_min_bytes()
+        if not cache:
+            frag = _parse_kdl_fragment(text, want_spans=want_spans,
+                                       source=source, line_offset=line_offset)
+            if flow is None:
+                return frag
+            return merge_flow_fragment(flow, frag)
 
-    from .parsecache import default_parse_cache
-    pc = default_parse_cache()
-    key = pc.key(text, want_spans, source, line_offset)
-    frag = pc.get(key)
-    if frag is None:
-        frag = _parse_kdl_fragment(text, want_spans=want_spans,
-                                   source=source, line_offset=line_offset)
-        pc.put(key, frag)
-    if flow is None:
-        return _thaw_fragment(frag)
-    return merge_flow_fragment(flow, frag)
+        from .parsecache import default_parse_cache
+        pc = default_parse_cache()
+        key = pc.key(text, want_spans, source, line_offset)
+        frag = pc.get(key)
+        if frag is None:
+            frag = _parse_kdl_fragment(text, want_spans=want_spans,
+                                       source=source, line_offset=line_offset)
+            pc.put(key, frag)
+        if flow is None:
+            return _thaw_fragment(frag)
+        return merge_flow_fragment(flow, frag)
 
 
 def include_patterns_of_line(stripped: str) -> Optional[list[str]]:

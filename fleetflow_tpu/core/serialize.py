@@ -186,6 +186,7 @@ def service_to_dict(s: Service) -> dict:
     _put(d, "registry", s.registry, None)
     _put(d, "colocate_with", s.colocate_with, [])
     _put(d, "anti_affinity", s.anti_affinity, [])
+    _put(d, "anti_affinity_stages", s.anti_affinity_stages, {})
     if s._replicas_set or s.replicas != 1:
         # _replicas_set tracks an explicit config declaration, but a
         # programmatically built Flow (tests, chaos harness, API users)
@@ -219,6 +220,7 @@ def service_from_dict(d: dict) -> Service:
         registry=d.get("registry"),
         colocate_with=d.get("colocate_with", []),
         anti_affinity=d.get("anti_affinity", []),
+        anti_affinity_stages=d.get("anti_affinity_stages", {}),
         replicas=d.get("replicas", 1),
         _resources_set="resources" in d,
         _replicas_set="replicas" in d,

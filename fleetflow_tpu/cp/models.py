@@ -286,7 +286,8 @@ class ParkedArrival(Record):
 @dataclass
 class PlacementRecord(Record):
     """A stage's COMMITTED placement (cp/placement.py): the assignment the
-    fleet actually runs and the per-node demand it books. Persisted so a
+    fleet actually runs, the per-node demand it books and the conflict keys
+    its rows hold on each server. Persisted so a
     restarted or promoted CP rebuilds its capacity ledger from the store
     instead of double-counting the next commit — the in-memory `_committed`
     map alone dies with the process, but the `servers.allocated` numbers it
@@ -295,6 +296,11 @@ class PlacementRecord(Record):
     assignment: dict[str, str] = field(default_factory=dict)  # row -> slug
     # slug -> [cpu, memory, disk] booked by this placement
     demand_by_node: dict[str, list[float]] = field(default_factory=dict)
+    # conflict key (lower/tensors.py: host port, exclusive volume,
+    # anti-affinity label) -> slugs of the servers on which a row of this
+    # placement holds it; no other stage's row declaring the key may land
+    # there while the record stands
+    held_keys: dict[str, list[str]] = field(default_factory=dict)
 
 
 # --------------------------------------------------------------------------

@@ -15,19 +15,25 @@ capacity as occupied, so racing placements can't double-book a node.
 Churn handling (BASELINE config 5): `node_event` flips the validity bit and
 triggers an incremental warm-start re-solve that moves only what churn
 forces (solver migration stickiness).
+
+Conflicts reach across stages: a commitment and an open reservation record,
+beside the demand they book, which conflict keys their rows hold on which
+server (`Reservation.held_keys`; the keys are lower/tensors.py's), and every
+lowering against live inventory bars the stage's rows from the servers on
+which another stage holds one of their keys.
 """
 
 from __future__ import annotations
 
 import itertools
 import threading
-from dataclasses import dataclass, replace as _dc_replace
+from dataclasses import dataclass, field, replace as _dc_replace
 from typing import Optional
 
 import numpy as np
 
 from ..core.model import Flow, ResourceSpec, ServerLabels, ServerResource
-from ..lower.tensors import ProblemTensors, lower_stage
+from ..lower.tensors import ProblemTensors, bar_held, lower_stage
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
@@ -46,6 +52,11 @@ _M_CHURN_FALLBACKS = REGISTRY.counter(
     "Churn re-solves that fell back to the greedy host scheduler after a "
     "solver failure")
 
+_M_HELD_KEYS = REGISTRY.counter(
+    "fleet_placement_held_keys_total",
+    "Server x conflict-key pairs held by other stages' committed and "
+    "reserved placements, as handed to a lowering")
+
 __all__ = ["PlacementService", "Reservation"]
 
 
@@ -61,6 +72,10 @@ class Reservation:
     # landing in that window cannot double-book them; superseded by the
     # stage's next solve/commit/release (never committed themselves)
     churn: bool = False
+    # conflict key -> servers on which a row of this placement holds it
+    # (lower/tensors.py). It lives and dies with the reservation: commit
+    # moves it to the committed book, release and supersession drop it.
+    held_keys: dict[str, list[str]] = field(default_factory=dict)
 
 
 def _alloc_vector(s: Server) -> np.ndarray:
@@ -85,6 +100,17 @@ def _server_to_resource(s: Server) -> ServerResource:
 
 
 class PlacementService:
+    """Solves stages against live inventory and keeps the 2-phase book.
+
+    Guarantee: a host port, an exclusive host volume, and an anti-affinity
+    label declared to reach a stage, are held at most once per server over
+    every committed and reserved placement the CP knows, not only inside
+    the stage being solved. It holds on `solve_stage` (so on
+    `placement.solve`, `deploy.execute`), on `rehydrate`, and on the churn
+    re-solve of `node_events`; `admit_batch` does not yet bar its arrivals
+    (its docstring). A stage alone on its servers takes the same path with
+    nothing held."""
+
     def __init__(self, store: Store, *, use_tpu: bool = False,
                  chains=None, steps: int = 128):
         self.store = store
@@ -118,7 +144,8 @@ class PlacementService:
                 id=f"rsv_{next(self._ids)}", stage_key=rec.stage_key,
                 demand_by_node={slug: np.asarray(d, dtype=np.float64)
                                 for slug, d in rec.demand_by_node.items()},
-                assignment=dict(rec.assignment), committed=True)
+                assignment=dict(rec.assignment), committed=True,
+                held_keys={k: list(v) for k, v in rec.held_keys.items()})
 
     def _persist_committed(self, key: str) -> None:
         """Mirror the stage's committed reservation into the store (one
@@ -133,7 +160,8 @@ class PlacementService:
         attrs = dict(
             assignment=dict(r.assignment),
             demand_by_node={slug: [float(x) for x in np.asarray(d)]
-                            for slug, d in r.demand_by_node.items()})
+                            for slug, d in r.demand_by_node.items()},
+            held_keys={k: list(v) for k, v in r.held_keys.items()})
         if rec is None:
             self.store.create("placements",
                               PlacementRecord(stage_key=key, **attrs))
@@ -183,6 +211,34 @@ class PlacementService:
             for node, dem in r.demand_by_node.items():
                 out[node] = out.get(node, 0) + dem
         return out
+
+    def _held_by_others(self, key: str) -> dict[str, list[str]]:
+        """Conflict key -> servers on which a stage OTHER than `key` holds
+        it, over the committed book and every open reservation (churn
+        holds included: a displaced stage holds its keys on its old
+        servers and on its new ones until its redeploy commits). The
+        stage's own holdings are left out: its rows are the ones being
+        re-placed. Caller holds the lock."""
+        out: dict[str, list[str]] = {}
+        for r in itertools.chain(self._committed.values(),
+                                 self._reservations.values()):
+            if r.stage_key == key:
+                continue
+            for k, slugs in r.held_keys.items():
+                have = out.get(k)
+                out[k] = slugs if have is None else have + slugs
+        return out
+
+    def _held_for_lowering(self, key: str) -> dict[str, list[str]]:
+        """`_held_by_others` as the `cp.solve_stage.held` phase."""
+        with phase("cp.solve_stage.held") as ph:
+            held = self._held_by_others(key)
+            if held:
+                pairs = sum(map(len, held.values()))
+                _M_HELD_KEYS.inc(pairs)
+                ph.set(keys=len(held), pairs=pairs,
+                       servers=len(set().union(*held.values())))
+        return held
 
     # ------------------------------------------------------------------
     # solve + 2-phase reservation
@@ -242,8 +298,9 @@ class PlacementService:
                         clazz=got.clazz if got.clazz is not None else d.clazz,
                         arch=got.arch if got.arch is not None else d.arch,
                         extra={**d.extra, **got.extra})
+                held = self._held_for_lowering(key)
             with phase("cp.solve_stage.lower"):
-                pt = lower_stage(flow, stage_name, nodes=nodes)
+                pt = lower_stage(flow, stage_name, nodes=nodes, held=held)
                 pt.node_valid &= valid
             with phase("cp.solve_stage.solve"):
                 prev = self._last.get(key)
@@ -293,7 +350,8 @@ class PlacementService:
             nodes, valid = self._inventory(
                 tenant, flow.stage(stage_name).servers or None,
                 exclude_demand=exclude)
-            pt = lower_stage(flow, stage_name, nodes=nodes)
+            pt = lower_stage(flow, stage_name, nodes=nodes,
+                             held=self._held_for_lowering(stage_key))
             pt.node_valid &= valid
             node_idx = {n: i for i, n in enumerate(pt.node_names)}
             raw = np.zeros(pt.S, dtype=np.int64)
@@ -335,7 +393,14 @@ class PlacementService:
         on its own success. Returns (placement, reservation_id, pt_used);
         on an infeasible solve the retained (pt, placement) entry is left
         standing (the stage IS still feasibly placed without the batch)
-        and reservation_id is None."""
+        and reservation_id is None.
+
+        NOT YET: the candidate is built by cp/admission.py, not lowered
+        here, so the cross-stage guarantee of the class docstring is not
+        held for it — a row is not barred from a server on which another
+        stage holds its key (streamed arrivals carry no ports, volumes or
+        anti-affinity; the rows the stage was opened with can). The
+        reservation does record what the stage's own rows hold."""
         with self._lock:
             server_map = {s.slug: s for s in self.store.list("servers")}
             valid = np.array(
@@ -394,6 +459,19 @@ class PlacementService:
             out[slug] = out.get(slug, 0) + dem.astype(np.float64)
         return out
 
+    @staticmethod
+    def _held_keys(pt: ProblemTensors,
+                   placement: Placement) -> dict[str, list[str]]:
+        """Conflict key -> servers on which a row of `placement` holds it.
+        A stage that declares no key (no host port, exclusive volume or
+        anti-affinity label) pays one truth test."""
+        if not pt.holds:
+            return {}
+        raw = np.asarray(placement.raw)
+        names = pt.node_names
+        return {k: sorted({names[j] for j in raw[rows].tolist()})
+                for k, rows in pt.holds.items()}
+
     def _drop_churn(self, key: str) -> None:
         """A stage's newly-created reservation (_reserve), a fresh
         commitment, or its teardown supersedes any churn reservation still
@@ -410,7 +488,8 @@ class PlacementService:
         self._reservations[rid] = Reservation(
             id=rid, stage_key=key,
             demand_by_node=self._demand_by_node(pt, placement),
-            assignment=dict(placement.assignment))
+            assignment=dict(placement.assignment),
+            held_keys=self._held_keys(pt, placement))
         return rid
 
     def _write_allocation(self, slug: str, d) -> int:
@@ -548,7 +627,8 @@ class PlacementService:
                 r = Reservation(
                     id=f"rsv_{next(self._ids)}", stage_key=stage_key,
                     demand_by_node=self._demand_by_node(pt, placement),
-                    assignment=dict(placement.assignment), committed=True)
+                    assignment=dict(placement.assignment), committed=True,
+                    held_keys=self._held_keys(pt, placement))
             prev = self._committed.pop(stage_key, None)
             self._supersede_allocation(prev, r)
             self._committed[stage_key] = r
@@ -586,11 +666,13 @@ class PlacementService:
         return {
             "in_flight": [
                 {"id": r.id, "stage": r.stage_key, "churn": r.churn,
-                 "demand_by_node": dem(r.demand_by_node)}
+                 "demand_by_node": dem(r.demand_by_node),
+                 "held_keys": r.held_keys}
                 for r in self._reservations.values()],
             "committed": [
                 {"id": r.id, "stage": key,
-                 "demand_by_node": dem(r.demand_by_node)}
+                 "demand_by_node": dem(r.demand_by_node),
+                 "held_keys": r.held_keys}
                 for key, r in self._committed.items()],
         }
 
@@ -716,6 +798,29 @@ class PlacementService:
             return pt
         return _dc_replace(pt, capacity=cap)
 
+    def _rebar(self, pt: ProblemTensors, key: str) -> ProblemTensors:
+        """The retained problem of stage `key` with its rows barred from
+        the servers on which another stage holds one of their keys NOW
+        (a stage committed since this one was lowered, a burst-mate's
+        churn hold). Bars are only added: one another stage has since
+        given up stays until the stage's next solve_stage lowers it anew.
+        Returns pt unchanged (same object: the resident delta path) when
+        no bit moves — always, for a stage that declares no key; else a
+        copy with a new eligibility plane, which the scheduler stages
+        cold (ProblemDelta does not cover it)."""
+        if not pt.barred_by:
+            return pt
+        held = self._held_by_others(key)
+        if not pt.barred_by.keys() & held.keys():
+            return pt
+        _M_HELD_KEYS.inc(sum(map(len, held.values())))
+        eligible = pt.eligible.copy()
+        if not bar_held(eligible, pt.barred_by, pt.node_names, held):
+            return pt
+        return _dc_replace(pt, eligible=eligible, held={
+            k: pt.held.get(k, []) + held.get(k, [])
+            for k in pt.held.keys() | held.keys()})
+
     def node_event(self, slug: str, *, online: bool) -> list[tuple[str, Placement]]:
         """Churn: flip the node's validity and warm-start re-solve every
         stage that had services there. Returns [(stage_key, new placement)].
@@ -788,6 +893,7 @@ class PlacementService:
                 with phase("cp.node_events.refresh_capacity", stage=key):
                     pt = self._refresh_capacity(pt, key, overrides,
                                                 server_map)
+                    pt = self._rebar(pt, key)
                 degraded = False
                 with phase("cp.node_events.solve", stage=key) as ph_solve:
                     try:
@@ -854,11 +960,13 @@ class PlacementService:
                                 - old.get(slug, 0), 0.0)
                             if extra.any():
                                 delta[slug] = extra
-                        if delta:
+                        held = self._held_keys(pt, new)
+                        if delta or held:
                             rid = f"rsv_{next(self._ids)}"
                             self._reservations[rid] = Reservation(
                                 id=rid, stage_key=key, demand_by_node=delta,
-                                assignment=dict(new.assignment), churn=True)
+                                assignment=dict(new.assignment), churn=True,
+                                held_keys=held)
                         # snapshot AFTER the churn reservation exists: burst-
                         # mates' refreshes subtract this exact view and add
                         # new_dem, cancelling the reservation they also see

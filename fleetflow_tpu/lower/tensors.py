@@ -21,6 +21,23 @@ model.rs:82-95,400-442) — become dense, device-ready arrays:
 Everything is numpy here (host, pure, unit-testable); the solver uploads
 once and keeps the tensors device-resident across re-solves.
 
+Conflicts ACROSS stages do not get ids: a conflict id group is minted from
+the rows of the one stage being lowered. What another stage already holds on
+a server — a host port, an exclusive volume, an anti-affinity label declared
+to reach across stages — arrives as `held` (key -> servers, gathered by
+cp/placement.py from every other committed and reserved placement) and is
+lowered to the eligibility plane: the bit of every (row, server) whose row
+declares a key held there is cleared (`bar_held`). Keys are strings:
+
+  port:<host ip>/<port>/<protocol>     volume:<host path>
+  anti:<project>:<label>@<stage>       a declarer of <label> in <stage>
+  anti:<project>:<label>><stage>       a declarer that reaches into <stage>
+
+A row of stage T declaring label L with `stages=` R holds L@T and L>S for
+every S in R, and is barred by L>T and by L@S for every S in R: two
+declarers on one server collide when either reaches the other's stage.
+Ports and volumes are facts about the host: held key = barring key.
+
 Replicas are expanded at lowering time: `service "w" { replicas 3 }` becomes
 rows w#0, w#1, w#2 sharing demand/ports/volumes; replica host-port conflicts
 make replicas of a port-publishing service mutually anti-affine exactly like
@@ -38,9 +55,16 @@ import numpy as np
 from ..core.errors import SolverError
 from ..core.model import (ServiceType, Flow, PlacementPolicy, PlacementStrategy,
                           ResourceSpec, ServerResource, Service)
+from ..obs.metrics import REGISTRY
 
-__all__ = ["ProblemTensors", "lower_stage", "dependency_depths",
+__all__ = ["ProblemTensors", "lower_stage", "bar_held", "dependency_depths",
            "LOCAL_NODE_NAME", "local_node", "synthetic_problem"]
+
+# metric catalog: docs/guide/10-observability.md
+_M_BARRED_CELLS = REGISTRY.counter(
+    "fleet_lower_barred_cells_total",
+    "Eligibility bits cleared because another stage holds a conflict key "
+    "of the row on that server")
 
 LOCAL_NODE_NAME = "local"
 
@@ -75,6 +99,17 @@ class ProblemTensors:
     # constraint classes to relax, in order, when infeasible (stage
     # placement fallback{}; reference model.rs:49 FallbackPolicy)
     relax_order: list[str] = field(default_factory=list)
+    # Cross-stage conflict keys (module docstring). None of the three is
+    # read by the solver: other stages' holdings reach it as cleared bits
+    # of `eligible`.
+    #   holds      key -> rows that hold it on whatever server they land on
+    #   barred_by  key -> rows that may not share a server with another
+    #              stage's holder of it
+    #   held       key -> servers on which another stage holds it, as the
+    #              caller gathered them when `eligible` was last barred
+    holds: dict[str, list[int]] = field(default_factory=dict)
+    barred_by: dict[str, list[int]] = field(default_factory=dict)
+    held: dict[str, list[str]] = field(default_factory=dict)
 
     @property
     def S(self) -> int:
@@ -215,10 +250,47 @@ def local_node(name: str = LOCAL_NODE_NAME) -> ServerResource:
         capacity=ResourceSpec(cpu=1e6, memory=1e9, disk=1e9))
 
 
+def bar_held(eligible: np.ndarray, barred_by: dict[str, list[int]],
+             node_names: list[str], held: dict[str, list[str]]) -> int:
+    """Clear, in place, the eligibility bit of every (row, server) whose
+    row is barred by a key that `held` says another stage holds on that
+    server. Returns the bits cleared. One pass over the barred rows per
+    key the stage and `held` share, and nothing at all when they share
+    none."""
+    shared = barred_by.keys() & held.keys()
+    if not shared:
+        return 0
+    node_index = {n: j for j, n in enumerate(node_names)}
+    cleared = 0
+    for key in shared:
+        cols = [node_index[n] for n in held[key] if n in node_index]
+        if not cols:
+            continue
+        # whole rows against a server mask: a (rows x servers) fancy
+        # index costs several times as much at 1,000 x 1,000
+        free = np.ones(eligible.shape[1], dtype=bool)
+        free[cols] = False
+        rows = barred_by[key]
+        sub = eligible[rows]
+        before = np.count_nonzero(sub)
+        sub &= free
+        eligible[rows] = sub
+        cleared += before - np.count_nonzero(sub)
+    _M_BARRED_CELLS.inc(cleared)
+    return cleared
+
+
 def lower_stage(flow: Flow, stage_name: str,
                 nodes: Optional[list[ServerResource]] = None,
-                local: bool = False) -> ProblemTensors:
+                local: bool = False,
+                held: Optional[dict[str, list[str]]] = None,
+                ) -> ProblemTensors:
     """Lower one stage of a Flow into ProblemTensors.
+
+    `held` (key -> servers) is what OTHER stages hold on the servers, in
+    the keys of the module docstring; the stage's rows are barred from
+    those servers through `eligible`. Empty or None lowers the stage as
+    if it were alone.
 
     Node set: explicit `nodes` arg > stage.servers > all flow.servers > a
     single implicit "local" node with generous capacity (the `fleet up local`
@@ -406,10 +478,26 @@ def lower_stage(flow: Flow, stage_name: str,
     # only the pairwise anti groups are per-row and merged below.
     port_groups, vol_groups, anti_groups, coloc_groups = [], [], [], []
     _empty: list[int] = []     # shared by constraint-free rows, never mutated
+    # cross-stage conflict keys (module docstring), collected only from the
+    # services that declare one
+    holds: dict[str, list[int]] = {}
+    barred_by: dict[str, list[int]] = {}
+    anti_scope = f"anti:{flow.name}:"
+
+    def host_key(key: str, first: int, reps: int) -> None:
+        # a fact about the host: what holds it is what it bars
+        rows = barred_by[key] = holds.setdefault(key, [])
+        rows.extend(range(first, first + reps))
+
     i = 0
     for svc, reps in zip(services, reps_arr):
-        pg = ([port_key_ids.setdefault(p.key(), len(port_key_ids))
-               for p in svc.ports] if svc.ports else _empty)
+        pg = _empty
+        if svc.ports:
+            pg = []
+            for p in svc.ports:
+                pk = p.key()
+                pg.append(port_key_ids.setdefault(pk, len(port_key_ids)))
+                host_key("port:" + "/".join(map(str, pk)), i, reps)
         vg = _empty
         if svc.volumes:
             vg = []
@@ -417,13 +505,27 @@ def lower_stage(flow: Flow, stage_name: str,
                 ck = v.conflict_key()
                 if ck is not None:
                     vg.append(vol_key_ids.setdefault(ck, len(vol_key_ids)))
+                    host_key("volume:" + ck, i, reps)
         # anti_affinity keys that do NOT name a stage service stay
         # LABEL-style: all declarers of "db-tier" mutually exclude.
         # Target-style keys (naming a service) are handled via the
         # pairwise groups prepared above the loop.
-        base_ag = ([anti_key_ids.setdefault(k, len(anti_key_ids))
-                    for k in svc.anti_affinity if k not in base_index]
-                   if svc.anti_affinity and not local else _empty)
+        base_ag = _empty
+        if svc.anti_affinity and not local:
+            base_ag = []
+            for k in svc.anti_affinity:
+                if k in base_index:
+                    continue
+                base_ag.append(anti_key_ids.setdefault(k, len(anti_key_ids)))
+                label = anti_scope + k
+                reach = [t for t in svc.anti_affinity_stages.get(k, ())
+                         if t != stage_name]
+                for key in [f"{label}@{stage_name}",
+                            *(f"{label}>{t}" for t in reach)]:
+                    holds.setdefault(key, []).extend(range(i, i + reps))
+                for key in [f"{label}>{stage_name}",
+                            *(f"{label}@{t}" for t in reach)]:
+                    barred_by.setdefault(key, []).extend(range(i, i + reps))
         cg = _empty
         if svc.colocate_with or svc.name in coloc_targets:
             cg = [coloc_key_ids.setdefault(k, len(coloc_key_ids))
@@ -460,6 +562,9 @@ def lower_stage(flow: Flow, stage_name: str,
     # plane, which ProblemTensors represents as preferred=None)
     preferred = (np.broadcast_to(node_pref, (S, N)).copy()
                  if node_pref.any() else None)
+    held = held or {}
+    if held:
+        bar_held(eligible, barred_by, [n.name for n in nodes], held)
     # quota enforcement (model.rs:40 ResourceQuota, FSC-26 Phase B-3): the
     # stage's aggregate demand must fit the declared ceiling — a violated
     # quota is a config error, reported at lowering with the excess named
@@ -524,6 +629,9 @@ def lower_stage(flow: Flow, stage_name: str,
         preferred=preferred,
         relax_order=relax_order,
         replica_of=replica_of,
+        holds=holds,
+        barred_by=barred_by,
+        held=held,
     )
     pt.validate()
     return pt

@@ -24,7 +24,7 @@ from .base import Placement, Scheduler
 from ..lower.tensors import (ELIGIBILITY_RELAX_CLASSES as _ELIG,
                              PREF_RELAX_CLASSES as _PREF,
                              SPREAD_RELAX_CLASSES as _SPREAD,
-                             ProblemTensors)
+                             ProblemTensors, bar_held)
 from ..obs import get_logger, kv
 
 __all__ = ["place_with_fallback", "relax_problem"]
@@ -44,10 +44,13 @@ def relax_problem(pt: ProblemTensors, what: str) -> Optional[ProblemTensors]:
             return None
         return dataclasses.replace(pt, max_skew=0)
     if what in _ELIG:
-        if pt.eligible.all():
+        # what another stage holds on a server is physical, like the
+        # stage's own conflicts: the relaxed plane keeps those bars
+        eligible = np.ones_like(pt.eligible)
+        bar_held(eligible, pt.barred_by, pt.node_names, pt.held)
+        if np.array_equal(eligible, pt.eligible):
             return None
-        return dataclasses.replace(
-            pt, eligible=np.ones_like(pt.eligible))
+        return dataclasses.replace(pt, eligible=eligible)
     log.warning("unknown fallback class %s", kv(what=what))
     return None
 

@@ -379,7 +379,8 @@ def test_kdl_guide_examples_parse_and_mean_something():
     svc = flow.services["api"]
     assert svc.replicas == 3
     assert svc.colocate_with == ["cache"]
-    assert svc.anti_affinity == ["db"]
+    assert svc.anti_affinity == ["db", "color=green"]
+    assert svc.anti_affinity_stages == {"color=green": ["sched-0", "sched-1"]}
     assert svc.deploy is not None and svc.deploy.output == "dist"
     assert svc.build is not None and svc.healthcheck is not None
     assert svc.readiness is not None and svc.wait is not None
