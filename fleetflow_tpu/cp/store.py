@@ -29,6 +29,12 @@ Thread-safe: one RLock guards all tables (handler tasks run on one asyncio
 loop, but the REST surface and background checkers may call from executor
 threads).
 
+Lookups: `find_one` and `list(where=...)` scan a table with a predicate.
+A table's unique key (`_INDEXED`: `servers.slug`) has an in-memory index,
+kept under the same lock wherever a record enters, leaves or is replaced
+— create, update, delete, a replicated or replayed journal entry, a loaded
+snapshot — so `server_by_slug` reads one row, and a miss is authoritative.
+
 Replication (docs/guide/13-cp-replication.md): every journal entry —
 including the batched/coalesced paths — carries a monotonic sequence
 number (`"q"`) and the store's fencing epoch (`"e"`), and is handed to an
@@ -78,9 +84,14 @@ _M_STORE_OPS = REGISTRY.counter(
     labels=("table", "op"))
 _M_ROWS_SCANNED = REGISTRY.counter(
     "fleet_store_rows_scanned_total",
-    "Rows a lookup examined, by table: once per find_one (up to its hit) "
-    "and per list(where=...) (the whole table) — the tables have no index, "
-    "so this is what a lookup costs", labels=("table",))
+    "Rows a lookup examined, by table: once per find_one (up to its hit), "
+    "per list(where=...) (the whole table) and per lookup through an index "
+    "(1 for a hit, 0 for a miss) — what a lookup costs", labels=("table",))
+_M_LOOKUPS = REGISTRY.counter(
+    "fleet_store_lookups_total",
+    "Single-record lookups by table and path: index (answered from the "
+    "table's unique-key index, servers.slug) or scan (find_one with a "
+    "predicate)", labels=("table", "path"))
 _M_JOURNAL_BYTES = REGISTRY.counter(
     "fleet_store_journal_bytes_total",
     "Bytes of serialized journal entries handed to the local journal and "
@@ -113,9 +124,19 @@ _TABLES: dict[str, type] = {
 }
 
 
-# find_one and _emit run a thousand times in one commit of a 1,000-server
+# The unique key of a table that has one: table -> field. The store keeps
+# an index on it (Store._index) at every place a record enters, leaves or
+# is replaced, and a lookup by that key reads the index instead of the
+# table. A second indexed table is one more entry here and its named
+# query beside server_by_slug.
+_INDEXED: dict[str, str] = {"servers": "slug"}
+
+# a lookup and _emit run a thousand times in one commit of a 1,000-server
 # stage: the counters' children are looked up here, once
 _ROWS_SCANNED = {t: _M_ROWS_SCANNED.bind(table=t) for t in _TABLES}
+_LOOKUPS_SCAN = {t: _M_LOOKUPS.bind(table=t, path="scan") for t in _TABLES}
+_LOOKUPS_INDEX = {t: _M_LOOKUPS.bind(table=t, path="index")
+                  for t in _INDEXED}
 _count_journal_bytes = _M_JOURNAL_BYTES.bind()
 
 
@@ -132,6 +153,11 @@ class Store:
         # under replay instead of depending on real elapsed time
         self._clock = clock
         self._tables: dict[str, dict[str, Record]] = {t: {} for t in _TABLES}
+        # table -> key -> ids of the records that carry the key, in table
+        # order (nothing forbids two servers with one slug; a lookup
+        # returns the first, as the scan did)
+        self._index: dict[str, dict[object, list[str]]] = {
+            t: {} for t in _INDEXED}
         self._path = Path(path) if path else None
         self._journal_path = (self._path.with_name(self._path.name + ".journal")
                               if self._path else None)
@@ -201,7 +227,7 @@ class Store:
                 rec.id = new_id(table.rstrip("s"))
             rec.created_at = rec.created_at or self._clock()
             rec.updated_at = self._clock()
-            self._tables[table][rec.id] = rec
+            self._put(table, rec)
             self._log_put(table, rec)
             self._notify("put", table, rec)
             return rec
@@ -215,6 +241,10 @@ class Store:
             rec = self._tables[table].get(rec_id)
             if rec is None:
                 return None
+            field = _INDEXED.get(table)   # None is no key of `changes`
+            if field in changes and changes[field] != getattr(rec, field):
+                self._index_add(table, changes[field], rec_id)
+                self._index_drop(table, getattr(rec, field), rec_id)
             for k, v in changes.items():
                 setattr(rec, k, v)
             rec.updated_at = self._clock()
@@ -224,7 +254,7 @@ class Store:
 
     def delete(self, table: str, rec_id: str) -> bool:
         with self._lock:
-            gone = self._tables[table].pop(rec_id, None) is not None
+            gone = self._pop(table, rec_id)
             if gone:
                 self._log_del(table, rec_id)
                 self._notify("del", table, rec_id)
@@ -241,8 +271,8 @@ class Store:
 
     def find_one(self, table: str,
                  where: Callable[[Record], bool]) -> Optional[Record]:
-        # hot path (server_by_slug on every heartbeat/alert/inventory):
-        # early-exit scan, no copy/sort like list()
+        # early-exit scan, no copy/sort like list(); a lookup by a
+        # table's unique key does not come here (_lookup)
         found = None
         with self._lock:
             rows = iter(self._tables[table].values())
@@ -254,7 +284,83 @@ class Store:
             # counted once per lookup and never per row
             _ROWS_SCANNED[table](
                 len(self._tables[table]) - operator.length_hint(rows))
+        _LOOKUPS_SCAN[table]()
         return found
+
+    # ------------------------------------------------------------------
+    # the unique-key index (_INDEXED)
+    # ------------------------------------------------------------------
+
+    def _lookup(self, table: str, key: object) -> Optional[Record]:
+        """The record of an indexed table whose key field equals `key`,
+        read from the index: what find_one with that predicate returns —
+        the first in table order where several records carry the key —
+        without the scan. A miss is authoritative: every path by which a
+        record enters, leaves or is replaced keeps the index."""
+        with self._lock:
+            try:
+                ids = self._index[table].get(key)
+            except TypeError:   # unhashable (a malformed request): no
+                ids = None      # record's key equals it
+            found = self._tables[table][ids[0]] if ids else None
+        _LOOKUPS_INDEX[table]()
+        if found is not None:
+            _ROWS_SCANNED[table](1)
+        return found
+
+    def _index_add(self, table: str, key: object, rec_id: str) -> None:
+        # caller holds the lock; the record is in its table already (its
+        # place there orders it among others that carry the key)
+        ids = self._index[table].setdefault(key, [])
+        ids.append(rec_id)
+        if len(ids) > 1:
+            place = {rid: i for i, rid in enumerate(self._tables[table])}
+            ids.sort(key=place.__getitem__)
+
+    def _index_drop(self, table: str, key: object, rec_id: str) -> None:
+        ids = self._index[table][key]
+        ids.remove(rec_id)
+        if not ids:
+            del self._index[table][key]
+
+    def _put(self, table: str, rec: Record) -> None:
+        """Place `rec` in its table under its id — a record already there
+        is replaced and keeps its place in table order — and keep the
+        index. Caller holds the lock."""
+        rows = self._tables[table]
+        field = _INDEXED.get(table)
+        if field is None:
+            rows[rec.id] = rec
+            return
+        key = getattr(rec, field)
+        hash(key)   # an unhashable key raises before the table changes
+        old = rows.get(rec.id)
+        rows[rec.id] = rec
+        if old is None:
+            self._index_add(table, key, rec.id)
+        elif getattr(old, field) != key:
+            self._index_add(table, key, rec.id)
+            self._index_drop(table, getattr(old, field), rec.id)
+
+    def _pop(self, table: str, rec_id: str) -> bool:
+        """Take a record out of its table and out of the index; False
+        where the table has no such id. Caller holds the lock."""
+        rec = self._tables[table].pop(rec_id, None)
+        if rec is None:
+            return False
+        field = _INDEXED.get(table)
+        if field is not None:
+            self._index_drop(table, getattr(rec, field), rec_id)
+        return True
+
+    def _reindex(self) -> None:
+        """Rebuild every index from its table, after the tables were
+        loaded whole (a snapshot)."""
+        for table, field in _INDEXED.items():
+            index: dict[object, list[str]] = {}
+            for rid, rec in self._tables[table].items():
+                index.setdefault(getattr(rec, field), []).append(rid)
+            self._index[table] = index
 
     # ------------------------------------------------------------------
     # domain queries (the named fns of db.rs)
@@ -323,7 +429,7 @@ class Store:
 
     # servers ----------------------------------------------------------
     def server_by_slug(self, slug: str) -> Optional[Server]:
-        return self.find_one("servers", lambda s: s.slug == slug)
+        return self._lookup("servers", slug)  # type: ignore[return-value]
 
     def register_server(self, slug: str, tenant: str = "default",
                         **attrs) -> Server:
@@ -654,12 +760,12 @@ class Store:
                 rec = cls.from_dict(entry["r"])
             except (KeyError, TypeError):
                 return
-            self._tables[table][rec.id] = rec
+            self._put(table, rec)
             if notify:
                 self._notify("put", table, rec)
         elif op == "del":
             rid = entry.get("id")
-            if self._tables[table].pop(rid, None) is not None and notify:
+            if self._pop(table, rid) and notify:
                 self._notify("del", table, rid)
 
     def _load(self) -> None:
@@ -674,6 +780,7 @@ class Store:
             for row in doc.get(table, []):
                 rec = cls.from_dict(row)
                 self._tables[table][rec.id] = rec
+        self._reindex()
 
     def _replay_journal(self) -> None:
         """Apply surviving journal entries over the loaded snapshot.

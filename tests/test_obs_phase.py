@@ -421,24 +421,35 @@ class TestServedPath:
                 "cp.commit.persist"} <= under
 
     @pytest.mark.parametrize("lookup", ["find_one_miss", "find_one_hit",
-                                        "list_where", "list_all"])
+                                        "list_where", "list_all",
+                                        "indexed_miss", "indexed_hit"])
     def test_rows_scanned_counts_what_a_lookup_examined(self, lookup):
         store, _svc, _flow = _cp(n_servers=5)
+        for s in store.list("servers"):
+            store.update("servers", s.id, hostname=f"h-{s.slug}")
         scanned = REGISTRY.get("fleet_store_rows_scanned_total")
         before = scanned.value(table="servers")
         if lookup == "find_one_miss":
-            assert store.server_by_slug("nope") is None
+            assert store.find_one(
+                "servers", lambda s: s.hostname == "nope") is None
             want = 5                      # the whole table, for nothing
         elif lookup == "find_one_hit":
-            assert store.server_by_slug("n1").slug == "n1"
+            assert store.find_one(
+                "servers", lambda s: s.hostname == "h-n1").slug == "n1"
             want = 2                      # up to its hit
         elif lookup == "list_where":
             assert len(store.list("servers",
                                   where=lambda s: s.slug == "n4")) == 1
             want = 5
-        else:
+        elif lookup == "list_all":
             assert len(store.list("servers")) == 5
             want = 0                      # no predicate, nothing examined
+        elif lookup == "indexed_miss":
+            assert store.server_by_slug("nope") is None
+            want = 0                      # the index says so, no row read
+        else:
+            assert store.server_by_slug("n1").slug == "n1"
+            want = 1                      # the one row
         assert scanned.value(table="servers") - before == want
 
     @pytest.mark.parametrize("journaled", [False, True])
