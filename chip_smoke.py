@@ -208,7 +208,7 @@ def phase_device(dry: bool) -> dict:
 
 
 def gen_registry(S: int, N: int, seed: int, fleets: int = 8):
-    """The registry bench.py's pipeline leg generates: `fleets` tenant
+    """A multi-tenant registry: `fleets` tenant
     fleets of S/fleets services (ports / volumes / anti-affinity on) over
     one N-node server pool, as KDL text."""
     from fleetflow_tpu.core.parser import parse_kdl_string

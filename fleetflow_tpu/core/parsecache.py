@@ -1,10 +1,10 @@
 """Content-addressed parse cache: the front end's analog of the XLA
 compile cache.
 
-BENCH_r06 showed the KDL front end dominating end-to-end placement
-(parse_ms ~1.4 s vs solve_ms 138 ms at 10k x 1k), and even a process that
-reuses compiled XLA binaries re-paid ~0.9 s of parsing on startup. Parsing
-is a pure function of the rendered text, so it caches the same way
+The KDL front end dominates a cold end-to-end placement at 10k x 1k
+(parsing costs several times the solve; PERF.md section 5, rs), and even a
+process that reuses compiled XLA binaries re-pays the parsing on startup.
+Parsing is a pure function of the rendered text, so it caches the same way
 compilation does:
 
   sha256(rendered file bytes) -> parsed Flow fragment
@@ -15,8 +15,7 @@ Two tiers:
     warm re-loads inside one process (CP reconverge, chaos replay, watch
     loops) skip the parser entirely;
   * an optional on-disk pickle directory (``FLEET_PARSE_CACHE=dir``) — a
-    fresh process (CP restart,
-    ``fleet lint`` in CI, the bench's cold/warm children) reuses fragments
+    fresh process (CP restart, ``fleet lint`` in CI) reuses fragments
     parsed by an earlier one. Entries are versioned; a format bump
     invalidates stale files instead of mispickling them.
 
@@ -47,7 +46,7 @@ from typing import Any, Optional
 from ..obs import get_logger
 from ..obs.metrics import REGISTRY
 
-__all__ = ["ParseCache", "default_parse_cache", "parse_cache_stats",
+__all__ = ["ParseCache", "default_parse_cache",
            "parse_cache_clear", "PARSE_CACHE_VERSION",
            "disk_pickle_get", "disk_pickle_put"]
 
@@ -223,7 +222,7 @@ _default_lock = threading.Lock()
 def default_parse_cache() -> ParseCache:
     """Process-wide cache instance (env-configured, built on first use).
     Re-built if FLEET_PARSE_CACHE / FLEET_PARSE_CACHE_MEM changed since —
-    tests and the bench's subprocess legs flip these at runtime."""
+    tests flip these at runtime."""
     global _default
     want_dir = os.environ.get("FLEET_PARSE_CACHE", "").strip() or None
     want_mem = _env_int("FLEET_PARSE_CACHE_MEM", 128)
@@ -232,10 +231,6 @@ def default_parse_cache() -> ParseCache:
                 or _default.max_entries != want_mem):
             _default = ParseCache(max_entries=want_mem, disk_dir=want_dir)
         return _default
-
-
-def parse_cache_stats() -> dict:
-    return default_parse_cache().stats()
 
 
 def parse_cache_clear() -> None:

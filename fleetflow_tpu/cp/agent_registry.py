@@ -87,8 +87,8 @@ class AgentRegistry:
         # changes (tests spin a fresh loop per case)
         self._shard_sems: dict[int, asyncio.Semaphore] = {}
         self._sems_loop: Optional[asyncio.AbstractEventLoop] = None
-        # stats of the most recent send_batch, pinned by the bench
-        # (BENCH_AGENTS_ASSERT): label_lookups < items proves the
+        # stats of the most recent send_batch, pinned by
+        # tests/test_cp_sharding.py: label_lookups < items proves the
         # per-command metric lookups stayed coalesced out of the loop
         self.last_batch_stats: dict = {}
         # delivery hook: fn(slug, command) consulted before every command
@@ -328,8 +328,8 @@ class AgentRegistry:
 
         Batch-level coalescing (vs the per-call path): one per-command
         counter bump per DISTINCT command, one fencing-epoch resolution
-        for the whole batch — `last_batch_stats` exposes the counts the
-        bench pins (BENCH_AGENTS_ASSERT=1)."""
+        for the whole batch — `last_batch_stats` exposes the counts
+        tests/test_cp_sharding.py pins."""
         items = list(items)
         if not items:
             self.last_batch_stats = {"items": 0, "label_lookups": 0,

@@ -48,8 +48,7 @@ deadline; the stale heap entry pops at the old deadline, re-derives the
 lease's real state, and re-schedules itself. Entry staleness is tracked
 with per-lease generation counters; `use_heap=False` retains the full
 scan, which doubles as the property-test oracle (the two sweeps must
-emit identical verdict streams on any schedule) and the bench's
-unsharded baseline.
+emit identical verdict streams on any schedule).
 """
 
 from __future__ import annotations
@@ -261,7 +260,7 @@ class FailureDetector:
         streams are property-tested identical on seeded schedules):
         the default expiry heap touches only due leases — O(expired ·
         log n); `use_heap=False` scans the full table — O(agents) — and
-        serves as oracle and bench baseline."""
+        serves as the oracle."""
         now = self.clock()
         with self._lock:
             out, self._pending = self._pending, []

@@ -579,8 +579,7 @@ def check_bucket_waste(r: Rule, ctx: LintContext, stage: Stage):
     rows = sum(_replicas(s) for s in ctx.container_services(stage))
     if rows < cfg.minimum:
         return          # below the first tier, padding is noise-level
-    lower, upper = bucket_bounds(rows, growth=cfg.growth,
-                                 minimum=cfg.minimum, align=cfg.align)
+    lower, upper = bucket_bounds(rows, minimum=cfg.minimum, align=cfg.align)
     waste = 1.0 - rows / upper
     if waste < 0.15:
         return
@@ -590,7 +589,7 @@ def check_bucket_waste(r: Rule, ctx: LintContext, stage: Stage):
            f"{upper} rows ({waste:.0%} phantom pad-waste per re-solve)",
         loc=stage.loc, stage=stage,
         hint=f"dropping {rows - lower} row(s) would fit the {lower} "
-             f"bucket; or tune FLEET_BUCKET_GROWTH/FLEET_BUCKET_MIN "
+             f"bucket; or tune FLEET_BUCKET_MIN "
              f"(docs/guide/11-performance.md)")
 
 
@@ -632,8 +631,8 @@ def check_plane_memory(r: Rule, ctx: LintContext, stage: Stage):
     from ..solver.problem import packed_width
 
     cfg = bucket_config()
-    S_pad = (bucket_size(rows, growth=cfg.growth, minimum=cfg.minimum,
-                         align=cfg.align) if cfg.enabled else rows)
+    S_pad = (bucket_size(rows, minimum=cfg.minimum, align=cfg.align)
+             if cfg.enabled else rows)
     N = len(nodes)
     R = len(ResourceSpec.axes())
     # the packed (S, N) planes + per-row tables the staging materializes

@@ -6,13 +6,11 @@ raw, gauges direct, histograms as _sum/_count), runs the registered
 *deep sources* (callables the CP wires over live subsystem state —
 per-tenant admission queues, slot-manager byte accounting, log-router
 backlogs, reconverger debt), and folds agent-shipped heartbeat
-snapshots into agent-labeled series. Three deployment shapes, one
+snapshots into agent-labeled series. Two deployment shapes, one
 class:
 
   CP daemon    `spawn()` on the server's asyncio loop (cp/server.py
                _build_collector), stopped with the server
-  bench        `start_thread()` — a plain daemon thread at a fast
-               cadence while a leg runs (bench.py)
   chaos        no loop at all: the runner calls `sample_once()` at
                deterministic points on the VirtualClock with
                `registry=None`, so the capture holds only world-derived
@@ -37,7 +35,6 @@ heartbeat at the default 30 s cadence.
 from __future__ import annotations
 
 import asyncio
-import threading
 import time
 from typing import Callable, Iterable, Optional
 
@@ -141,8 +138,6 @@ class Collector:
         self._agents_seen: set[str] = set()
         self._last_sample_t: Optional[float] = None
         self._task: Optional[asyncio.Task] = None
-        self._thread: Optional[threading.Thread] = None
-        self._thread_stop = threading.Event()
 
     def add_source(self, fn: Callable[[float], Optional[Iterable]]) -> None:
         self._sources.append(fn)
@@ -244,28 +239,6 @@ class Collector:
         if self._task is not None:
             self._task.cancel()
             self._task = None
-
-    # -- thread loop (bench) -------------------------------------------
-
-    def start_thread(self) -> None:
-        self._thread_stop.clear()
-
-        def _loop() -> None:
-            while not self._thread_stop.wait(self.interval_s):
-                try:
-                    self.sample_once()
-                except Exception:
-                    log.exception("collector sampling pass failed")
-
-        self._thread = threading.Thread(
-            target=_loop, name="fleet-obs-collector", daemon=True)
-        self._thread.start()
-
-    def stop_thread(self, timeout: float = 2.0) -> None:
-        self._thread_stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout)
-            self._thread = None
 
 
 def wait_for_series(collector: Collector, name: Optional[str] = None,

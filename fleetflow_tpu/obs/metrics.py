@@ -292,7 +292,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict:
         """JSON-able dump: {name: {type, help, labels, values}} — the form
-        the CP `health.metrics` channel and bench.py artifacts embed."""
+        the CP `health.metrics` channel embeds."""
         with self._lock:
             metrics = [self._metrics[n] for n in sorted(self._metrics)]
         out: dict = {}

@@ -263,9 +263,9 @@ class FlightRecorder:
     Rotation: ``FLEET_TRACE_MAX_MB`` (unset/0 = unbounded) caps the file
     size with a keep-1 rollover — when the next line would cross the
     cap, the current file atomically becomes ``<path>.1`` (replacing any
-    previous generation) and a fresh file starts. The admission bench's
-    hours of micro-solve spans can no longer grow the recorder without
-    bound, and rotation happens BETWEEN lines so both generations stay
+    previous generation) and a fresh file starts. Hours of admission
+    micro-solve spans can no longer grow the recorder without bound, and
+    rotation happens BETWEEN lines so both generations stay
     well-formed JSONL; readers span the boundary via
     :func:`read_trace_files`."""
 

@@ -217,10 +217,10 @@ class TimeSeriesDB:
                         until: Optional[float] = None,
                         name: Optional[str] = None,
                         labels: Optional[dict] = None) -> list[dict]:
-        """Aggregates over an explicit [since, until] interval — the
-        bench's per-leg summary windows (aggregate() is anchored to NOW;
-        a leg that finished minutes ago needs absolute bounds). Series
-        with no samples in the interval are omitted."""
+        """Aggregates over an explicit [since, until] interval
+        (aggregate() is anchored to NOW; a window that closed minutes ago
+        needs absolute bounds). Series with no samples in the interval
+        are omitted."""
         out = []
         for s in self.match(name, labels):
             samples = s.samples(since=since, until=until)

@@ -8,7 +8,7 @@ back: a platform that fails to initialise raises out of `jax.devices()`.
 
   * Serving code (sched/tpu.py) calls `init_platform()` and runs on
     whatever it finds — TPU on a TPU host, CPU where JAX_PLATFORMS=cpu.
-  * Measurement entry points (chip_smoke.py, bench.py, scripts/tpu_tune.py,
+  * Measurement entry points (chip_smoke.py, scripts/tpu_tune.py,
     `python __graft_entry__.py`) pass `require_accelerator=True`: finding
     only the CPU raises `NoAcceleratorError` instead of producing CPU
     timings under a device metric's name.
@@ -257,8 +257,9 @@ def verify_compile_cache(log=None) -> bool:
 
 
 def compile_cache_info() -> dict:
-    """{'enabled', 'dir', 'entries'} for bench artifacts/metrics surfaces.
-    `entries` counts files currently in the cache directory (best effort)."""
+    """{'enabled', 'dir', 'entries'} for the smoke's report and the
+    metrics surfaces. `entries` counts files currently in the cache
+    directory (best effort)."""
     d = _compile_cache_dir
     entries = 0
     if d:

@@ -522,8 +522,8 @@ def _aggregate_fleets(registry, stages, loader, cache, content_hash):
     # lowering is the answer — a warm re-aggregation of an unchanged
     # registry costs a hash walk, not a lower. The key is pure content
     # (entry hashes + routes + a server-content digest), so it also keys
-    # a DISK tier next to the parse cache: a fresh process (CP restart,
-    # the bench's warm child) reuses the previous process's lowering.
+    # a DISK tier next to the parse cache: a fresh process (a CP restart)
+    # reuses the previous process's lowering.
     routes_sig = tuple(sorted(
         (f, r.stage, r.server)
         for f in registry.fleets for r in registry.routes_for_fleet(f)))

@@ -135,9 +135,9 @@ def prerepair_state_counted(prob: DeviceProblem, st: ChainState,
     """Fused churn pre-repair: relocate services stranded on invalid or
     ineligible nodes, one per `lax.while_loop` iteration, entirely on
     device. This replaces the host `repair.py` pre-pass on the warm path
-    (~27 ms of host numpy + a host->device seed upload at 10k x 1k,
-    BENCH_r05): the resident warm path never leaves the device between the
-    CP's churn delta and the anneal.
+    (a pass of host numpy + a host->device seed upload): the resident warm
+    path never leaves the device between the CP's churn delta and the
+    anneal.
 
     Each iteration picks the first not-yet-attempted stranded service and
     moves it to the least-utilized node that fits (capacity + conflicts +
@@ -514,9 +514,8 @@ def default_proposals_per_step(S: int) -> int:
     accelerator knee — below it a sweep costs the same fixed overhead,
     above it the sweep goes bandwidth-bound (and winner-per-target wastes
     the surplus). Hardware re-validation is pending TPU access; the CPU
-    path overrides to 64, where sweep cost is ~linear in width (measured
-    round 3, docs/guide/03-placement-and-the-tpu-solver.md tuning notes +
-    docs/profiles/)."""
+    path overrides to 64, where sweep cost is ~linear in width
+    (docs/guide/03-placement-and-the-tpu-solver.md tuning notes)."""
     return max(1, min(256, S // 2))
 
 
@@ -730,10 +729,9 @@ def anneal_adaptive_states(prob: DeviceProblem, init_assignments: jax.Array,
         (states, keys, best_assign, best_viol, best_soft,
          seen, accepted) = res[:7]
         # flight-deck row for this block: PURE observation of scores the
-        # sweeps already computed (no extra reduces — pinned by the
-        # admission bench's tail assert), written with mode="drop" so
-        # rows past the static buffer vanish instead of clamping onto
-        # the last slot. trace_blocks == 0 (static) skips everything:
+        # sweeps already computed (no extra reduces), written with
+        # mode="drop" so rows past the static buffer vanish instead of
+        # clamping onto the last slot. trace_blocks == 0 (static) skips everything:
         # the pre-telemetry program, byte for byte — the parity
         # reference.
         if trace_blocks:

@@ -123,8 +123,8 @@ class SolveResult:
     # for an exact-shape solve: {"orig_S", "padded_S", "pad_waste", "hit"}
     bucket: Optional[dict] = None
     # churn pre-repair ran as a fused on-device prologue inside the anneal
-    # dispatch (anneal.prerepair_state) instead of the host repair.py pass
-    # — the warm path then has no prerepair_ms timing at all
+    # dispatch (anneal.prerepair_state): true of every warm solve, false of
+    # a cold one
     fused_prerepair: bool = False
     # pod-scale sharded solves (solver/sharded.solve_sharded) report their
     # parallel-tempering config + replica-exchange outcome here:
@@ -202,11 +202,10 @@ def _refine(prob: DeviceProblem, seed_assignment: jax.Array, key: jax.Array,
     `fused_prerepair` runs the churn pre-repair as an on-device prologue
     (anneal.prerepair_state, bounded by `prerepair_moves`) before the chain
     fan-out: services stranded on dead/ineligible nodes are relocated
-    inside THIS dispatch, replacing the host repair.py pre-pass that cost
-    ~27 ms + a seed re-upload per warm reschedule (BENCH_r05 CPU). The
-    stickiness bonus is computed from the pre-repair seed (staying put is
-    rewarded at the PREVIOUS placement; forced moves stay free either
-    way)."""
+    inside THIS dispatch, so a warm reschedule pays no host repair pass
+    and no seed re-upload. The stickiness bonus is computed from the
+    pre-repair seed (staying put is rewarded at the PREVIOUS placement;
+    forced moves stay free either way)."""
     # named scopes are metadata: the profiler shows the device's ops under
     # the name solver/contracts.py registers, the program is the same
     scope = "refine.warm" if fused_prerepair else "refine.cold"
@@ -326,8 +325,8 @@ def solve(pt: ProblemTensors, **kw) -> SolveResult:
     When FLEET_PROFILE_DIR is set the whole solve is captured as a
     jax.profiler trace (obs.profile_trace).
 
-    Pod-scale routing: instances above the FLEET_SHARDED_MIN_CELLS
-    threshold (or any instance under FLEET_SHARDED=1) with >= 2 devices
+    Pod-scale routing: instances of sharded.SHARDED_MIN_CELLS cells and
+    more (or any instance under FLEET_SHARDED=1) with >= 2 devices
     visible solve through the mesh-sharded resident path
     (solver/sharded.solve_sharded — service-axis sharding + parallel
     tempering) instead of the single-chip pipeline; explicit staging
@@ -360,7 +359,6 @@ def _solve(pt: ProblemTensors, *,
            adaptive: bool = True,
            anneal_block: int = 1,
            warm_block: int = 1,
-           prerepair: Optional[bool] = None,
            proposals_per_step: Optional[int] = None,
            bucket: Optional[bool] = None,
            resident: Optional[ResidentProblem] = None,
@@ -477,56 +475,18 @@ def _solve(pt: ProblemTensors, *,
 
     with phase("solver.seed") as ph_seed:
         warm = init_assignment is not None or resident_warm
-        # Churn pre-repair mode: None -> FUSED into the anneal dispatch
-        # (anneal.prerepair_state — no host work, no prerepair_ms timing);
-        # True -> the legacy host repair.py pre-pass (kept for A/B and
-        # debugging); False -> none (the anneal's targeted proposals alone).
-        fused = warm and prerepair is None
         # a FACTORY, not a context instance: jax.transfer_guard is a one-shot
         # generator CM, and a sub-solve the gate rejects dispatches twice
         # (mini attempt, then the full fused path) — each under its own guard
         guard_ctx = (transfer_guard_ctx if resident_warm
                      else contextlib.nullcontext)
-        def _legacy_host_prepass(seed_np: np.ndarray) -> np.ndarray:
-            # the legacy host pre-repair (kept for A/B against the fused
-            # prologue): relocate services stranded on dead/ineligible nodes.
-            # Keep the result even when repair can't reach 0: it is never
-            # worse than its input (repair.py backstop), and a partially-
-            # fixed seed still saves the anneal sweeps. prerepair_ms is split
-            # out so a reschedule artifact can say whether host pre-repair or
-            # the device anneal ate the time (VERDICT r4 weak #1); the fused
-            # path has no such phase by construction.
-            with phase("solver.prerepair") as ph_pre:
-                rows = np.arange(pt.S)
-                stranded = ((~pt.node_valid[seed_np])
-                            | (~pt.eligible[rows, seed_np]))
-                if stranded.any():
-                    from .repair import repair as _host_repair
-                    seed_np = _host_repair(pt, seed_np, seed=seed).assignment
-            timings["prerepair_ms"] = ph_pre.ms
-            return seed_np
-
         if resident_warm:
             # seed already resident: the previous padded winner, phantoms
             # re-parked at delta time; nothing crosses the host boundary
             seed_assignment = resident.assignment
             t0 = min(t0, 0.1)  # warm start: refine, don't re-scramble
-            if prerepair is True:
-                # legacy host pre-pass requested (A/B): the seed deliberately
-                # round-trips the host — fetch the real rows, repair, re-upload
-                # (adopt_host counts the transfer)
-                # np.array, not asarray: device_get of the resident slot is a
-                # VIEW on the CPU backend and the slot is donated into the
-                # next merge dispatch — the host pre-pass must own its copy
-                seed_np = _legacy_host_prepass(np.array(
-                    jax.device_get(seed_assignment), dtype=np.int32,
-                    copy=True)[:pt.S])
-                resident.adopt_host(seed_np, pt.node_valid, warm=True)
-                seed_assignment = resident.assignment
         elif warm:
             seed_np = np.asarray(init_assignment, dtype=np.int32)
-            if prerepair is True:
-                seed_np = _legacy_host_prepass(seed_np)
             if bucketed:
                 seed_np = pad_assignment(seed_np, prob.S, pt.node_valid)
             seed_assignment = jnp.asarray(seed_np, dtype=jnp.int32)
@@ -598,9 +558,7 @@ def _solve(pt: ProblemTensors, *,
             # no block here: the refine dispatch queues behind the seed on-device
             # (device impls), so seed_ms is dispatch time only and the device
             # runs back-to-back; the native impl is synchronous host work.
-    # disjoint phases: the warm branch's host pre-repair is reported under
-    # prerepair_ms, not double-counted into seed_ms
-    timings["seed_ms"] = ph_seed.ms - timings.get("prerepair_ms", 0.0)
+    timings["seed_ms"] = ph_seed.ms
 
     if proposals_per_step is None:
         # derived from the PADDED row count: proposals_per_step is a static
@@ -628,16 +586,18 @@ def _solve(pt: ProblemTensors, *,
         # a new variant of the fused pipeline, which is exactly the event an
         # operator watching solve latency needs to see (a recompile can turn a
         # 100 ms reschedule into seconds — VERDICT r4 weak #1)
-        # fused pre-repair budget: a static bound the while_loop exits early
-        # from; derived from the PADDED rows so it cannot break bucket reuse
-        prerepair_moves = max(16, min(prob.S, 256)) if fused else 0
+        # a warm start's churn pre-repair is FUSED into the anneal dispatch
+        # (anneal.prerepair_state): no host work, no timing of its own. Its
+        # budget is a static bound the while_loop exits early from, derived
+        # from the PADDED rows so it cannot break bucket reuse
+        prerepair_moves = max(16, min(prob.S, 256)) if warm else 0
         # ---- churn-localized sub-solve plan (solver/subsolve.py) ------------
         # when the resident delta path knows the affected set and its
         # constraint closure is small, the anneal runs over a mini tier of
         # gathered rows instead of the full problem; the exact full-problem
         # gate below decides whether the localized result commits
         sub_plan = None
-        if resident_warm and fused and adaptive and mesh is None:
+        if resident_warm and adaptive and mesh is None:
             sub_plan = resident.take_active_plan()
         if binfo is not None:
             # hit = this process already ran the fused pipeline at these
@@ -648,8 +608,8 @@ def _solve(pt: ProblemTensors, *,
                  prob.coloc_ids.shape[1], chains, steps,
                  bool(warm and migration_weight > 0), adaptive,
                  min(warm_block, anneal_block) if warm else anneal_block,
-                 proposals_per_step, fused, prerepair_moves,
-                 bool(resident_warm and adaptive and fused),
+                 proposals_per_step, prerepair_moves,
+                 bool(resident_warm and adaptive),
                  prob.n_real is not None, trace_blocks,
                  # plane layout is part of the executable identity: a packed
                  # and a dense staging (or absent vs present preference) are
@@ -674,13 +634,12 @@ def _solve(pt: ProblemTensors, *,
             warm=bool(warm and migration_weight > 0), adaptive=adaptive,
             anneal_block=min(warm_block, anneal_block) if warm else anneal_block,
             proposals_per_step=proposals_per_step, sharding=sharding,
-            fused_prerepair=fused, prerepair_moves=prerepair_moves,
+            fused_prerepair=warm, prerepair_moves=prerepair_moves,
             # the resident delta path skips the 1-block soft polish when the
             # fused prologue already landed feasible: stickiness rejects
             # nearly all polish moves, so the sweep bought latency only. The
-            # host warm path (and the legacy-prepass A/B leg) keeps its
-            # 1-block polish (same results as r05).
-            skip_feasible_polish=bool(resident_warm and adaptive and fused),
+            # host warm path keeps its 1-block polish.
+            skip_feasible_polish=bool(resident_warm and adaptive),
             trace_blocks=trace_blocks)
         cache_before = _refine._cache_size()
         sub_info = None
@@ -873,7 +832,7 @@ def _solve(pt: ProblemTensors, *,
         bucket_hit=(binfo.hit or None) if binfo is not None else None,
         violations=int(stats["total"]), pre_repair=pre_repair,
         repaired=moves or None, warm=warm or None,
-        resident=resident_warm or None, fused=fused or None,
+        resident=resident_warm or None,
         sub=(f"{sub_info['rows']}/{sub_info['tier']}"
              f"({sub_info['outcome']})" if sub_info else None),
         **{k: f"{v:.1f}" for k, v in timings.items()}))
@@ -885,7 +844,7 @@ def _solve(pt: ProblemTensors, *,
         proposals_per_step=proposals_per_step,
         accepted_moves=accepted,
         bucket=binfo.to_dict() if binfo is not None else None,
-        fused_prerepair=fused,
+        fused_prerepair=warm,
         subsolve=sub_info,
         telemetry=telemetry,
     )

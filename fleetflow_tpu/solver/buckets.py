@@ -4,12 +4,12 @@ Every distinct (S, N, G, Gc, K, C) shape of a DeviceProblem is a distinct
 XLA program: `_refine` (solver/api.py) is jitted with those extents baked
 in as static/traced shapes, so a fleet drifting from 9,997 to 10,050
 services — the normal churn/reschedule path — recompiles the whole fused
-pipeline and pays the 4.3-5.5 s compile cliff for a 70 ms solve
-(BENCH_r05). This module rounds the churn-sensitive extents UP to a
+pipeline and pays a compile cliff of seconds for a solve of
+milliseconds. This module rounds the churn-sensitive extents UP to a
 geometric tier ladder so every fleet size inside a tier reuses ONE
 compiled executable:
 
-  S   (service rows)        -> next tier (x``growth`` steps from ``minimum``)
+  S   (service rows)        -> next tier (x1.25 steps from ``minimum``)
   K   (conflict-id columns) -> next multiple of ``width_multiple``
   C   (coloc-id columns)    -> next multiple of ``width_multiple``
   G   (conflict-id count)   -> next tier (static: sizes the (N, G) tables)
@@ -44,9 +44,9 @@ from topology/skew accounting. Bucketing therefore applies at
 ``max_skew > 0`` too (it was bypassed there before the mask existed).
 
 Config: `bucket_config()` reads the FLEET_BUCKET* environment once per
-call site; `FLEET_BUCKET=0` disables bucketing everywhere,
-`FLEET_BUCKET_GROWTH` (default 1.25) and `FLEET_BUCKET_MIN` (default 64)
-shape the tier ladder. docs/guide/11-performance.md covers tuning.
+call site; `FLEET_BUCKET=0` disables bucketing everywhere and
+`FLEET_BUCKET_MIN` (default 64) is the first tier of the ladder.
+docs/guide/11-performance.md covers tuning.
 """
 
 from __future__ import annotations
@@ -67,10 +67,15 @@ __all__ = ["BucketConfig", "BucketInfo", "bucket_config", "bucket_size",
            "soft_score_host", "stage_problem_tiers", "staging_arena_stats"]
 
 
+# geometric tier ratio of the S ladder
+BUCKET_GROWTH = 1.25
+# host staging arenas kept across restages; LRU beyond this many bytes
+STAGE_ARENA_BYTES = 512_000_000
+
+
 @dataclass(frozen=True)
 class BucketConfig:
     enabled: bool = True
-    growth: float = 1.25     # geometric tier ratio for S / G / Gc
     minimum: int = 64        # first S tier; G/Gc ladder starts at 16
     width_multiple: int = 4  # K / C column rounding
     align: int = 8           # every S tier is a multiple of this (lanes)
@@ -87,21 +92,16 @@ def bucket_config(default_enabled: bool = True) -> BucketConfig:
     """The process-wide bucketing knobs, read from the environment on each
     call (cheap; callers on hot paths hold the result)."""
     try:
-        growth = float(os.environ.get("FLEET_BUCKET_GROWTH", "1.25"))
-    except ValueError:
-        growth = 1.25
-    try:
         minimum = int(os.environ.get("FLEET_BUCKET_MIN", "64"))
     except ValueError:
         minimum = 64
     return BucketConfig(
         enabled=_env_flag("FLEET_BUCKET", default_enabled),
-        growth=max(growth, 1.01),
         minimum=max(minimum, 8),
     )
 
 
-def bucket_size(n: int, *, growth: float = 1.25, minimum: int = 64,
+def bucket_size(n: int, *, growth: float = BUCKET_GROWTH, minimum: int = 64,
                 align: int = 8) -> int:
     """Smallest tier >= n on the geometric ladder minimum, minimum*growth,
     minimum*growth^2, ... with every tier rounded up to a multiple of
@@ -118,7 +118,7 @@ def bucket_size(n: int, *, growth: float = 1.25, minimum: int = 64,
     return out
 
 
-def bucket_bounds(n: int, *, growth: float = 1.25, minimum: int = 64,
+def bucket_bounds(n: int, *, growth: float = BUCKET_GROWTH, minimum: int = 64,
                   align: int = 8) -> tuple[int, int]:
     """(previous tier, tier) around n: the tier n pads up to, and the
     largest smaller tier (0 below the ladder). `fleet lint` FF014 uses the
@@ -232,8 +232,7 @@ def pad_problem_tiers(prob, cfg: Optional[BucketConfig] = None):
     its tiers comes back unchanged (same object), so staged re-use across
     re-solves never re-pads."""
     cfg = cfg or bucket_config()
-    S_pad = bucket_size(prob.S, growth=cfg.growth, minimum=cfg.minimum,
-                        align=cfg.align)
+    S_pad = bucket_size(prob.S, minimum=cfg.minimum, align=cfg.align)
     K = prob.conflict_ids.shape[1]
     C = prob.coloc_ids.shape[1]
     K_pad = width_bucket(K, cfg.width_multiple)
@@ -296,9 +295,8 @@ def pad_assignment(assignment: np.ndarray, padded_S: int,
 
 # -- compile-free padded staging -------------------------------------------
 # pad_problem_tiers pads ON DEVICE: every plane pays a jnp.pad dispatch and
-# — in a fresh process — a shape-specific XLA compile, which is why the
-# cold_warm bench leg's stage_ms sat at ~667 ms while the actual bytes are
-# a ~100 ms memcpy. stage_problem_tiers instead builds the PADDED planes on
+# — in a fresh process — a shape-specific XLA compile, several times what
+# copying the actual bytes costs. stage_problem_tiers instead builds the PADDED planes on
 # the host, in per-tier arena buffers reused across restages (the phantom
 # region is written once per arena, not once per restage), and uploads
 # them: staging becomes pure memcpy + device_put, no XLA ops at all.
@@ -312,14 +310,6 @@ _DEV_CONSTS: OrderedDict[tuple, object] = OrderedDict()
 _DEV_CONST_CAP = 6                      # (S, N) planes; LRU beyond this
 
 
-def _arena_cap_bytes() -> int:
-    try:
-        return int(float(os.environ.get("FLEET_STAGE_ARENA_MB", "")
-                         or 512) * 1e6)
-    except ValueError:
-        return 512_000_000
-
-
 def _arena_take_locked(name: str, shape: tuple, dtype, fill,
                        rows_written: int) -> np.ndarray:
     """A host buffer of `shape` whose rows >= rows_written hold `fill`;
@@ -331,9 +321,9 @@ def _arena_take_locked(name: str, shape: tuple, dtype, fill,
     if ent is None:
         arr = np.full(shape, fill, dtype=dtype)
         ent = _ARENAS[key] = [arr, 0]
-        cap = _arena_cap_bytes()
         while len(_ARENAS) > 1 and \
-                sum(e[0].nbytes for e in _ARENAS.values()) > cap:
+                sum(e[0].nbytes for e in _ARENAS.values()) \
+                > STAGE_ARENA_BYTES:
             _ARENAS.popitem(last=False)
     else:
         _ARENAS.move_to_end(key)
@@ -410,8 +400,7 @@ def stage_problem_tiers(pt, cfg: Optional[BucketConfig] = None,
     Gc = int(pt.coloc_ids.max(initial=-1)) + 1
     T = int(pt.node_topology.max(initial=0)) + 1
     if cfg.enabled:
-        S_pad = bucket_size(S, growth=cfg.growth, minimum=cfg.minimum,
-                            align=cfg.align)
+        S_pad = bucket_size(S, minimum=cfg.minimum, align=cfg.align)
         K_pad = width_bucket(K, cfg.width_multiple)
         C_pad = width_bucket(C, cfg.width_multiple)
         G_pad = bucket_size(G, growth=2.0, minimum=16, align=4)

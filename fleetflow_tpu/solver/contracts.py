@@ -295,7 +295,7 @@ def _subsolve_cases() -> list[KernelCase]:
     # permissive gates: the audit instances sit far below the production
     # mini-tier ladder, and the contract pins kernel structure, not the
     # production closure heuristics
-    cfg = SubsolveConfig(enabled=True, frac=1.0, min_tier=8, max_tier=4096)
+    cfg = SubsolveConfig(enabled=True, frac=1.0, min_tier=8)
     out = []
     for S, N in AUDIT_TIERS:
         pt = _synthetic(S, N)

@@ -302,17 +302,16 @@ def partitioned_seed(pt, parts: int) -> np.ndarray:
     """Host seed for mega-scale sharded solves: service slices x disjoint
     round-robin node subsets, one full-capacity FFD per slice.
 
-    The exact host FFD is O(S*N) sequential work — 108.9 s at 100k x 10k
-    (docs/profiles/r5-xl-sharded.md), outweighing the sharded anneal it
-    feeds. This slices the NODE axis round-robin alongside a contiguous
-    service split: slice g FFDs its services onto its own nodes at full
-    capacity, cutting the work to O(S*N/parts) with a union feasible by
-    construction for both capacity and conflict groups (disjoint nodes
-    cannot share a port). The residue left for the anneal: services whose
-    eligible nodes all fall in other slices (best-effort in-slice, an
-    eligibility violation each) and packing fragmentation across node
-    subsets — the same repair contract as the batched device seed's
-    best-effort tail.
+    The exact host FFD is O(S*N) sequential work — minutes at 100k x 10k,
+    outweighing the sharded anneal it feeds. This slices the NODE axis
+    round-robin alongside a contiguous service split: slice g FFDs its
+    services onto its own nodes at full capacity, cutting the work to
+    O(S*N/parts) with a union feasible by construction for both capacity
+    and conflict groups (disjoint nodes cannot share a port). The residue
+    left for the anneal: services whose eligible nodes all fall in other
+    slices (best-effort in-slice, an eligibility violation each) and
+    packing fragmentation across node subsets — the same repair contract
+    as the batched device seed's best-effort tail.
 
     Returns (S,) int32. Uses the native C++ FFD per group when available,
     the pure-numpy host greedy otherwise.

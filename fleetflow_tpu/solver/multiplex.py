@@ -24,8 +24,8 @@ K is bucketed on a small power-of-two ladder (``mux_k``) so fleet-count
 drift never recompiles: a batch of 5 pads to 8 by replicating lane 0
 (padded lanes are discarded, counted on
 ``fleet_solver_mux_lanes_total{kind="pad"}``), and the executable
-identity is (tier statics, ladder K) — the bench leg pins zero
-recompiles across the whole tier x K grid after warm-up.
+identity is (tier statics, ladder K) — tests/test_multiplex.py pins zero
+recompiles across the ladder after warm-up.
 
 Lanes that cannot batch (singleton tier groups, host-warm stagings,
 sharded residents) fall through to the serial ``api._solve`` path with
@@ -156,7 +156,7 @@ def _mux_refine(prob: DeviceProblem, seed_assignment: jax.Array,
 
 
 def mux_cache_size() -> int:
-    """Compiled-variant count of the batched executable (the bench leg's
+    """Compiled-variant count of the batched executable (the tests'
     recompile watch, like api._refine._cache_size for the serial path)."""
     return _mux_refine._cache_size()
 

@@ -43,8 +43,8 @@ STRATEGY_CODES = {
 
 # -- packed problem planes ---------------------------------------------------
 # The two dense (S, N) planes dominate problem memory AND the anneal's
-# sweep bandwidth (~4.7 GiB at 100k x 10k; anneal_ms ~13 of 14.6 ms at
-# 10k x 1k was plane reads, BENCH_r07_dev). The packed layout attacks both:
+# sweep bandwidth (~4.7 GiB at 100k x 10k; at 10k x 1k most of a sweep is
+# plane reads). The packed layout attacks both:
 #
 #   eligible   bit-packed (S, ceil(N/32)) uint32 — one bit per node, 8x
 #              fewer bytes than the dense bool plane; the kernels unpack

@@ -5,10 +5,9 @@ structured deltas applied by a donated, jitted merge kernel.
 Before this module the warm path still rebuilt host state every burst:
 `sched/tpu.py` re-staged the padded DeviceProblem whenever capacity drifted
 (identity-keyed cache), `solver/api._solve` uploaded the previous assignment
-from host numpy and ran the churn pre-repair in host numpy (`prerepair_ms`
-~27 ms of the ~101 ms r05 CPU warm reschedule). The paper's thesis is that
-the placement hot loop lives on TPU; this closes the remaining host
-round-trips:
+from host numpy and ran the churn pre-repair in host numpy. The paper's
+thesis is that the placement hot loop lives on TPU; this closes the
+remaining host round-trips:
 
   ResidentProblem      owns the padded, bucketed DeviceProblem + the last
                        assignment as device buffers across bursts
@@ -80,7 +79,7 @@ def transfer_guard_ctx():
     """The context the resident warm path dispatches under.
     FLEET_TRANSFER_GUARD= unset/off/allow -> no guard; log -> jax logs every
     host transfer; disallow -> any host->device transfer raises (the proof
-    mode the resident tests and the bench burst leg run in)."""
+    mode the resident tests run in)."""
     mode = os.environ.get("FLEET_TRANSFER_GUARD", "").strip().lower()
     if mode in ("", "0", "off", "false", "allow"):
         return contextlib.nullcontext()
@@ -452,8 +451,8 @@ class ResidentProblem:
     def _expected_padded_S(self, pt) -> int:
         """The padded S a cold staging of `pt` would produce — the shape
         half of the bucket-identity gate."""
-        return bucket_size(pt.S, growth=self.cfg.growth,
-                           minimum=self.cfg.minimum, align=self.cfg.align)
+        return bucket_size(pt.S, minimum=self.cfg.minimum,
+                           align=self.cfg.align)
 
     def _staging_device(self):
         """Where cold_stage materializes the prepared problem. None = the

@@ -82,6 +82,17 @@ __all__ = ["anneal_sharded", "pad_problem", "shard_problem",
 SVC_AXIS = "svc"
 REPLICA_AXIS = "replica"
 
+# temperature ratio between neighboring tempering lanes: best of {1.3, 1.6,
+# 2.0, 3.0} on the partitioned-seed curve
+TEMPER_LADDER = 1.3
+# sweep-blocks between replica-exchange rounds
+TEMPER_EXCHANGE = 1
+# S*N at which a solve routes to the mesh: comfortably above the proven
+# single-chip 10k x 1k point
+SHARDED_MIN_CELLS = 50_000_000
+# sweep budget of a routed direct solve that names none
+SHARDED_STEPS = 64
+
 
 def tempering_mesh(replicas: int = 1, svc_shards: Optional[int] = None,
                    devices=None) -> Mesh:
@@ -208,7 +219,7 @@ def per_device_bytes(prob: DeviceProblem, *,
     best-ever). Per-device state is the same on every lane of a tempered
     mesh (each lane is one more set of devices, not more bytes per
     device); the exchange rounds ppermute transient double-buffers of the
-    same shapes on top. Without this the bench's per-device memory report
+    same shapes on top. Without this a per-device memory report
     undercounts — problem tensors alone are not what bounds the fleet
     shape on a chip."""
     import dataclasses
@@ -910,12 +921,12 @@ def _host_seed(pt, parts: int) -> np.ndarray:
 def solve_sharded(pt, *, resident: ShardedResident,
                   resident_warm: bool = False,
                   init_assignment=None,
-                  steps: int = 64, seed: int = 0,
+                  steps: int = SHARDED_STEPS, seed: int = 0,
                   t0: float = 1.0, t1: float = 1e-3,
                   adaptive: bool = True, block: int = 8,
                   proposals_per_step: Optional[int] = None,
-                  ladder: Optional[float] = None,
-                  exchange_every: Optional[int] = None,
+                  ladder: float = TEMPER_LADDER,
+                  exchange_every: int = TEMPER_EXCHANGE,
                   do_repair: bool = True,
                   overlap_host_work=None):
     """Pod-scale end-to-end solve through the mesh-resident sharded path:
@@ -927,9 +938,8 @@ def solve_sharded(pt, *, resident: ShardedResident,
     crosses the host boundary and the dispatch runs under
     FLEET_TRANSFER_GUARD=disallow when set, exactly like the single-chip
     resident path. Cold solves stage a host FFD seed. Tempering knobs:
-    `ladder` (temperature ratio between neighboring lanes,
-    FLEET_TEMPER_LADDER, default 1.3 — measured best of {1.3, 1.6, 2.0, 3.0} on the partitioned-seed curve) and `exchange_every` (sweep-blocks
-    between exchange rounds, FLEET_TEMPER_EXCHANGE, default 1)."""
+    `ladder` (temperature ratio between neighboring lanes) and
+    `exchange_every` (sweep-blocks between exchange rounds)."""
     import contextlib
 
     from .api import SolveResult
@@ -943,17 +953,6 @@ def solve_sharded(pt, *, resident: ShardedResident,
         prob = rp.prob
         D = mesh.shape[SVC_AXIS]
         n_rep = mesh.shape.get(REPLICA_AXIS, 1)
-        if ladder is None:
-            try:
-                ladder = float(os.environ.get("FLEET_TEMPER_LADDER") or "1.3")
-            except ValueError:
-                ladder = 1.3
-        if exchange_every is None:
-            try:
-                exchange_every = max(
-                    1, int(os.environ.get("FLEET_TEMPER_EXCHANGE") or "1"))
-            except ValueError:
-                exchange_every = 1
         warm = bool(resident_warm and rp.assignment is not None)
         if warm:
             timings["delta_stage_ms"] = rp.consume_delta_ms()
@@ -1111,39 +1110,19 @@ def solve_sharded(pt, *, resident: ShardedResident,
 def sharded_route(pt) -> Optional[Mesh]:
     """Decide whether `pt` takes the pod-scale sharded path, and on what
     mesh. `FLEET_SHARDED=0` disables, `=1` forces; otherwise instances
-    with S*N >= FLEET_SHARDED_MIN_CELLS (default 5e7 — comfortably above
-    the proven single-chip 10k x 1k point) route when >= 2 devices are
-    visible. FLEET_SHARDED_REPLICAS picks the tempering lanes (default 2
-    when the device count allows an even split, else 1); the remaining
-    devices shard the service axis."""
+    with S*N >= SHARDED_MIN_CELLS route when >= 2 devices are visible.
+    Two tempering lanes when the device count allows an even split, else
+    one; the remaining devices shard the service axis."""
     mode = os.environ.get("FLEET_SHARDED", "").strip().lower()
     if mode in ("0", "off", "false", "no"):
         return None
     force = mode in ("1", "on", "true", "yes", "force")
-    try:
-        thresh = int(os.environ.get("FLEET_SHARDED_MIN_CELLS")
-                     or str(50_000_000))
-    except ValueError:
-        thresh = 50_000_000
-    if not force and pt.S * pt.N < thresh:
+    if not force and pt.S * pt.N < SHARDED_MIN_CELLS:
         return None
     devs = jax.devices()
     if len(devs) < 2:
         return None
-    try:
-        want = int(os.environ.get("FLEET_SHARDED_REPLICAS") or "0")
-    except ValueError:
-        want = 0
-    if want <= 0:
-        replicas = 2 if len(devs) >= 4 else 1
-    else:
-        # an explicit replica count is honored up to the device count
-        # (replicas=len(devs) means pure tempering, one-device lanes)
-        replicas = min(want, len(devs))
-        if replicas != want:
-            log.warning("FLEET_SHARDED_REPLICAS=%d clamped to %d "
-                        "(only %d devices visible)", want, replicas,
-                        len(devs))
+    replicas = 2 if len(devs) >= 4 else 1
     return tempering_mesh(replicas, len(devs) // replicas, devices=devs)
 
 
@@ -1162,7 +1141,7 @@ def maybe_solve_sharded(pt, **kw):
     staging kwargs (prob/resident/mesh) and solver knobs the sharded path
     does not speak always stay put. The CP's TpuSolverScheduler routes
     itself (persistent per-stage ShardedResident slots); this hook covers
-    direct library/bench calls."""
+    direct library calls."""
     if any(kw.get(k) is not None for k in ("prob", "resident", "mesh")):
         return None
     if not set(kw) <= _ROUTED_KW:
@@ -1171,13 +1150,8 @@ def maybe_solve_sharded(pt, **kw):
     if mesh is None:
         return None
     rp = ShardedResident(pt, mesh=mesh)
-    try:
-        steps = kw.get("steps") or int(
-            os.environ.get("FLEET_SHARDED_STEPS") or "64")
-    except ValueError:
-        steps = 64
     return solve_sharded(
-        pt, resident=rp, steps=steps,
+        pt, resident=rp, steps=kw.get("steps") or SHARDED_STEPS,
         seed=kw.get("seed", 0),
         init_assignment=kw.get("init_assignment"),
         t0=kw.get("t0", 1.0), t1=kw.get("t1", 1e-3),

@@ -1,11 +1,10 @@
 """Active-set warm solves: churn-localized sub-problem annealing.
 
-The warm path's remaining tax (BENCH_r08) is sweep cost whenever churn
-actually needs annealing: a rolling-kill burst that moves 80 of 10k
-services pays 5 FULL-problem sweeps (133 ms), and admission micro-solves
-sweep all ~10.7k rows to place an 81-arrival batch (solve p99 218 ms vs
-p50 52 ms). Steady-state churn is sparse — the rows that can possibly
-move are the AFFECTED set (killed-node evictions, arrivals, demand and
+The warm path's remaining tax is sweep cost whenever churn actually
+needs annealing: a rolling-kill burst that moves 80 of 10k services pays
+FULL-problem sweeps, and admission micro-solves sweep all ~10.7k rows to
+place an 81-arrival batch. Steady-state churn is sparse — the rows that
+can possibly move are the AFFECTED set (killed-node evictions, arrivals, demand and
 eligibility drift) plus their constraint closure — so this module solves
 exactly that set:
 
@@ -43,8 +42,8 @@ above ``FLEET_SUBSOLVE_FRAC`` of the real rows (or past the tier
 ladder) fall back up front.
 
 Knobs: FLEET_SUBSOLVE=0 disables; FLEET_SUBSOLVE_FRAC (default 0.25) is
-the closure cap as a fraction of real rows; FLEET_SUBSOLVE_MIN /
-FLEET_SUBSOLVE_MAX (default 256 / 4096) bound the mini tier ladder.
+the closure cap as a fraction of real rows; FLEET_SUBSOLVE_MIN (default
+256) is the first mini tier, and the ladder ends at 4096 rows.
 Tuning + runbook: docs/guide/11-performance.md; metric catalog:
 docs/guide/10-observability.md.
 """
@@ -102,12 +101,15 @@ SUB_OUTCOMES = ("localized", "fallback_closure", "fallback_small",
                 "fallback_infeasible")
 
 
+# largest mini tier (beyond: full path)
+SUBSOLVE_MAX_TIER = 4096
+
+
 @dataclass(frozen=True)
 class SubsolveConfig:
     enabled: bool = True
     frac: float = 0.25       # closure cap as a fraction of real rows
     min_tier: int = 256      # first mini tier
-    max_tier: int = 4096     # largest mini tier (beyond: full path)
 
 
 def subsolve_config(default_enabled: bool = True) -> SubsolveConfig:
@@ -125,7 +127,6 @@ def subsolve_config(default_enabled: bool = True) -> SubsolveConfig:
         enabled=enabled,
         frac=min(max(_f("FLEET_SUBSOLVE_FRAC", 0.25), 0.0), 1.0),
         min_tier=max(int(_f("FLEET_SUBSOLVE_MIN", 256)), 8),
-        max_tier=max(int(_f("FLEET_SUBSOLVE_MAX", 4096)), 8),
     )
 
 
@@ -281,7 +282,8 @@ def plan_active(index: ActiveIndex, pt, mirror: np.ndarray, padded_S: int,
     k = int(rows.size)
     if k > max(cfg.frac * S, 1):
         return None, "fallback_closure"
-    tier = subsolve_tier(k, minimum=cfg.min_tier, maximum=cfg.max_tier)
+    tier = subsolve_tier(k, minimum=cfg.min_tier,
+                         maximum=SUBSOLVE_MAX_TIER)
     if tier == 0:
         return None, "fallback_closure"
     if tier >= S:

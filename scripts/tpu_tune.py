@@ -101,7 +101,7 @@ def main() -> None:
         run_cold(chains, block, props)
 
     # warm reschedule: kill the most-loaded node, re-solve from the cold
-    # reference (the bench's BASELINE-config-5 leg)
+    # reference (BASELINE.json config 5)
     victim = int(np.bincount(ref.assignment, minlength=N).argmax())
     valid = pt.node_valid.copy()
     valid[victim] = False

@@ -252,4 +252,4 @@ class TestConfig:
     def test_config_defaults(self):
         cfg = bucket_config()
         assert isinstance(cfg, BucketConfig)
-        assert cfg.enabled and cfg.growth > 1.0 and cfg.minimum >= 8
+        assert cfg.enabled and cfg.minimum >= 8

@@ -226,9 +226,6 @@ WANTED = {
                       {"solver.stage", "solver.seed",
                        "solver.dispatch.refine", "solver.fetch",
                        "solver.verify_repair"}),
-    "warm_host_prerepair": (BASE_KEYS | {"prerepair_ms"},
-                            {"solver.prerepair", "solver.dispatch.refine",
-                             "solver.fetch"}),
     "subsolve": (BASE_KEYS | {"delta_stage_ms", "subsolve_ms"},
                  {"solver.subsolve", "solver.dispatch.subsolve",
                   "solver.fetch", "solver.verify_repair"}),
@@ -237,10 +234,9 @@ WANTED = {
 
 @pytest.fixture(scope="module")
 def solves():
-    """One cold solve and three warm ones after a node kill each: the
-    resident full fused path, the localized sub-solve, and a host-seeded
-    warm solve with the legacy host pre-repair. Per path: (SolveResult,
-    the ring's spans over that solve)."""
+    """One cold solve and two warm ones after a node kill each: the
+    resident full fused path and the localized sub-solve. Per path:
+    (SolveResult, the ring's spans over that solve)."""
     from fleetflow_tpu.lower import synthetic_problem
     from fleetflow_tpu.solver import solve
     from fleetflow_tpu.solver.resident import ProblemDelta, ResidentProblem
@@ -279,13 +275,6 @@ def solves():
                     cur, prob=rp.prob, resident=rp, resident_warm=True,
                     seed=70 + step, bucket=True, **SOLVE_KW))
             pt = cur
-        prev = res.assignment
-        valid = valid.copy()
-        valid[int(np.bincount(prev[valid[prev]], minlength=pt.N).argmax())] \
-            = False
-        cur = dataclasses.replace(pt, node_valid=valid)
-        ringed("warm_host_prerepair", lambda: solve(
-            cur, seed=9, init_assignment=prev, prerepair=True, **SOLVE_KW))
         yield out
     finally:
         mp.undo()

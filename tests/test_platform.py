@@ -144,8 +144,7 @@ class TestCompileCachePlacement:
     def test_no_moving_cache_path(self):
         """A cache whose path moves never hits: no mkdtemp, pid or clock
         may feed a cache directory in the launchers or the bootstrap."""
-        for name in ("bench.py", "chip_smoke.py",
-                     "fleetflow_tpu/platform.py"):
+        for name in ("chip_smoke.py", "fleetflow_tpu/platform.py"):
             src = (REPO / name).read_text()
             assert "mkdtemp" not in src and "getpid" not in src, name
             for line in src.splitlines():

@@ -472,19 +472,6 @@ class TestFusedPrerepair:
         assert "prerepair_ms" not in res2.timings_ms
         assert not (res2.assignment == dead).any()
 
-    def test_legacy_host_prepass_still_available(self):
-        pt = synthetic_problem(100, 10, seed=3)
-        res = solve(pt, chains=2, steps=200, seed=3)
-        dead = int(np.bincount(res.assignment, minlength=pt.N).argmax())
-        valid = pt.node_valid.copy()
-        valid[dead] = False
-        pt2 = dataclasses.replace(pt, node_valid=valid)
-        res2 = solve(pt2, chains=2, steps=200, seed=4,
-                     init_assignment=res.assignment, prerepair=True)
-        assert res2.feasible
-        assert not res2.fused_prerepair
-        assert "prerepair_ms" in res2.timings_ms
-
 
 class TestZeroSweepTrustedStats:
     """ROADMAP item 2 shave: a resident warm dispatch that exits at
@@ -528,16 +515,13 @@ class TestZeroSweepTrustedStats:
 
 
 class TestResultOwnership:
-    """Regression for the api._solve legacy-prepass fetch site (the
-    PR 14 bug class): the resident-warm `prerepair=True` leg round-trips
-    the resident assignment slot through `jax.device_get`, which on the
-    CPU backend returns a zero-copy VIEW of the device buffer — and that
-    slot is donated into the next warm merge dispatch. The fix forces
-    `np.array(..., copy=True)` before the host pre-pass; this test holds
-    a result fetched on that leg bit-identical through later warm
-    dispatches."""
+    """The PR 14 bug class: on the CPU backend `jax.device_get` of the
+    resident assignment slot returns a zero-copy VIEW of the device
+    buffer, and that slot is donated into the next warm merge dispatch.
+    A resident-warm `solve` must hand back a host-owned copy; this test
+    holds one bit-identical through later warm dispatches."""
 
-    def test_prepass_result_survives_later_warm_dispatches(self):
+    def test_warm_result_survives_later_warm_dispatches(self):
         rng = np.random.default_rng(17)
         pt = synthetic_problem(73, 12, seed=17, port_fraction=0.3,
                                volume_fraction=0.2)
@@ -547,8 +531,7 @@ class TestResultOwnership:
         pt, delta = _churn_step(pt, rng)
         rp.apply_delta(pt, delta)
         res = solve(pt, prob=rp.prob, resident=rp, resident_warm=True,
-                    seed=18, steps=16, bucket=True, prerepair=True)
-        assert "prerepair_ms" in res.timings_ms   # the leg under test ran
+                    seed=18, steps=16, bucket=True)
         kept = res.assignment
         # ownership: the result's base must be a host-owned copy, never
         # a wrapper over the resident device slot
