@@ -449,15 +449,25 @@ class PlacementService:
     @staticmethod
     def _demand_by_node(pt: ProblemTensors,
                         placement: Placement) -> dict[str, np.ndarray]:
-        out: dict[str, np.ndarray] = {}
-        for i, node in enumerate(placement.raw):
-            dem = pt.demand[i]
-            if not dem.any():
-                continue    # zero-demand rows (admission tombstones)
-                            # must not materialize per-node entries
-            slug = pt.node_names[int(node)]
-            out[slug] = out.get(slug, 0) + dem.astype(np.float64)
-        return out
+        """Server slug -> (R,) float64 demand of `placement`'s rows on it,
+        in one array pass. What the row loop it replaces gave, and callers
+        lean on: a node enters at its first live row (_apply_allocation
+        writes the store in this order), rows add up in row order (so the
+        sums are the loop's to the last bit), and a node that carries
+        only zero-demand rows (admission tombstones) has no entry. The
+        values are rows of one array: consumers build new arrays from
+        them and never write in place."""
+        raw = np.asarray(placement.raw)
+        demand = np.asarray(pt.demand)
+        live = demand.any(axis=1)
+        nodes, first, slot = np.unique(raw[live], return_index=True,
+                                       return_inverse=True)
+        acc = np.zeros((nodes.shape[0], demand.shape[1]), dtype=np.float64)
+        np.add.at(acc, slot, demand[live].astype(np.float64))
+        order = np.argsort(first)
+        names = pt.node_names
+        return dict(zip([names[j] for j in nodes[order].tolist()],
+                        acc[order]))
 
     @staticmethod
     def _held_keys(pt: ProblemTensors,

@@ -18,6 +18,11 @@ services on 8 servers, host scheduler, no device):
   * the replication sink is handed one put per changed server and the
     placement record, and a second Store fed the stream ends with the
     primary's `allocated` on every server
+
+And, since _demand_by_node became one array pass: its keys, their order
+and its float64 sums are the row loop's (kept below as `_row_loop`), on
+bare arrays and through a first commitment, a churn hold and a retained
+commit of a 300-row stage.
 """
 
 from __future__ import annotations
@@ -25,7 +30,9 @@ from __future__ import annotations
 import dataclasses
 import json
 from collections import Counter
+from types import SimpleNamespace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -42,37 +49,38 @@ STAGES = {"live": range(0, 30), "canary": range(30, 40)}
 SCENARIOS = ["kill_one", "kill_then_revive", "no_previous", "second_stage"]
 
 
-def _flow():
-    slugs = [f"n{i}" for i in range(N_SERVERS)]
+def _flow(n_servers=N_SERVERS, n_services=40, stages=STAGES):
+    slugs = [f"n{i}" for i in range(n_servers)]
     servers = "\n".join(
         f'server "{s}" {{ capacity {{ cpu 32; memory 65536; disk 99999 }} }}'
         for s in slugs)
     services = "\n".join(
         f'service "s{i}" {{ image "x"; resources {{ cpu {(1, 2, 0.5)[i % 3]}; '
         f'memory {(64, 128, 256, 512)[i % 4]}; disk {1 + i % 5} }} }}'
-        for i in range(40))
-    stages = "\n".join(
+        for i in range(n_services))
+    stage_nodes = "\n".join(
         f'stage "{name}" {{\n'
         + "\n".join(f'    service "s{i}"' for i in rows)
         + "\n    servers " + " ".join(f'"{s}"' for s in slugs) + "\n}"
-        for name, rows in STAGES.items())
-    return parse_kdl_string(f'project "p"\n{servers}\n{services}\n{stages}\n')
+        for name, rows in stages.items())
+    return parse_kdl_string(
+        f'project "p"\n{servers}\n{services}\n{stage_nodes}\n')
 
 
 class _Cp:
     """A store with a replication sink attached from its first write, and
     a PlacementService on the host scheduler."""
 
-    def __init__(self):
+    def __init__(self, n_servers=N_SERVERS, flow=None):
         self.store = Store()
         self.stream: list[tuple[int, str]] = []
         self.store.replication_sink = self.stream.extend
-        for i in range(N_SERVERS):
+        for i in range(n_servers):
             self.store.create("servers", Server(
                 slug=f"n{i}", status="online", tenant="default",
                 capacity=ServerCapacity(cpu=32, memory=65536, disk=99999)))
         self.svc = PlacementService(self.store, use_tpu=False)
-        self.flow = _flow()
+        self.flow = flow if flow is not None else _flow()
         self.victim: str | None = None     # the server _run killed
 
     def allocated(self) -> dict[str, tuple[float, float, float]]:
@@ -311,3 +319,143 @@ def test_replication_stream_reproduces_allocated(scenario):
         for slug, d in r.demand_by_node.items():
             assert promoted._committed[key].demand_by_node[slug] \
                 == pytest.approx(np.asarray(d, dtype=np.float64))
+
+
+# --------------------------------------------------------------------------
+# _demand_by_node: one array pass, held to the row loop it replaced
+# --------------------------------------------------------------------------
+
+def _row_loop(pt, placement) -> dict[str, np.ndarray]:
+    """PlacementService._demand_by_node as it was before it became one
+    array pass: the reference for keys, their order and the sums."""
+    out: dict[str, np.ndarray] = {}
+    for i, node in enumerate(placement.raw):
+        dem = pt.demand[i]
+        if not dem.any():
+            continue
+        slug = pt.node_names[int(node)]
+        out[slug] = out.get(slug, 0) + dem.astype(np.float64)
+    return out
+
+
+def _case(S, N, R=3, *, seed=0, tombstones=(), raw=None, as_raw=np.asarray):
+    rng = np.random.default_rng(seed)
+    demand = (rng.random((S, R)) * (4.0, 8192.0, 500.0, 7.0, 0.1)[:R]
+              ).astype(np.float32)
+    if raw is None:
+        raw = rng.integers(0, N, size=S)
+    raw = np.asarray(raw, dtype=np.int32)
+    if tombstones:      # every row on these nodes departs, and a tenth
+        demand[np.isin(raw, tombstones) | (rng.random(S) < 0.1)] = 0.0
+    pt = SimpleNamespace(demand=demand,
+                         node_names=[f"n{j}" for j in range(N)])
+    return pt, SimpleNamespace(raw=as_raw(raw))
+
+
+DEMAND_CASES = {
+    # the benchmark's nc stage: 9,660 rows x 3 resources over 1,000 nodes
+    "nc_size": lambda: _case(9660, 1000, seed=1),
+    # nodes 3 and 17 carry tombstones only: neither may have an entry
+    "tombstones": lambda: _case(600, 40, seed=2, tombstones=(3, 17)),
+    "every_row_a_tombstone": lambda: _case(5, 3, tombstones=(0, 1, 2)),
+    # rd's size; node 1 is empty, node 2 enters the dict before node 0
+    "four_rows_one_empty_node": lambda: _case(4, 3, raw=[2, 0, 2, 0]),
+    "no_rows": lambda: _case(0, 3),
+    "raw_numpy_int32": lambda: _case(200, 16, seed=3),
+    "raw_device_int32": lambda: _case(200, 16, seed=3, as_raw=jnp.asarray),
+    "raw_python_list": lambda: _case(200, 16, seed=3,
+                                     as_raw=lambda a: a.tolist()),
+    # R is the demand's second axis, not a literal 3
+    "five_resources": lambda: _case(300, 20, R=5, seed=4),
+    "one_resource": lambda: _case(300, 20, R=1, seed=5),
+}
+
+
+def _assert_same_demand(got, want):
+    assert list(got) == list(want)          # same nodes, in the same order
+    for slug, d in want.items():
+        assert np.array_equal(got[slug], d), slug
+
+
+@pytest.mark.parametrize("case", DEMAND_CASES)
+def test_demand_by_node_is_the_row_loops(case):
+    pt, placement = DEMAND_CASES[case]()
+    want = _row_loop(pt, placement)
+    got = PlacementService._demand_by_node(pt, placement)
+    _assert_same_demand(got, want)
+    for d in got.values():
+        assert d.dtype == np.float64 and d.shape == pt.demand.shape[1:]
+    if case == "tombstones":
+        assert want and not {"n3", "n17"} & set(got)
+    if case == "four_rows_one_empty_node":
+        assert list(got) == ["n2", "n0"]
+    if case in ("no_rows", "every_row_a_tombstone"):
+        assert got == {}
+
+
+BIG_SERVERS, BIG_SERVICES = 24, 300
+
+
+def _committed_json(cp: _Cp) -> list[str]:
+    """The placements table as it is journaled, less what differs between
+    two stores by construction (ids, clocks). Key order is kept."""
+    out = []
+    for rec in cp.store.list("placements"):
+        d = rec.to_dict()
+        for k in ("id", "created_at", "updated_at"):
+            d.pop(k, None)
+        out.append(json.dumps(d))
+    return out
+
+
+def _server_puts(stream) -> list[str]:
+    entries = [json.loads(line) for _seq, line in stream]
+    return [e["r"]["slug"] for e in entries
+            if e["t"] == "servers" and e["op"] == "put"]
+
+
+def test_a_300_row_stage_keeps_the_row_loops_book():
+    """First commitment, kill, node_events, commit_retained — on two
+    services, one of them computing demand by node with the row loop:
+    the journal's server writes come in the same order, the churn hold
+    reserves the same delta, and the store and the persisted record end
+    bit for bit the same."""
+    flow = _flow(BIG_SERVERS, BIG_SERVICES, {"live": range(BIG_SERVICES)})
+    cp, ref = _Cp(BIG_SERVERS, flow), _Cp(BIG_SERVERS, flow)
+    ref.svc._demand_by_node = _row_loop
+    firsts = []
+    for side in (cp, ref):
+        mark = len(side.stream)         # past the servers' own creation
+        placement, rid = side.svc.solve_stage(side.flow, "live")
+        assert placement.feasible and side.svc.commit(rid)
+        firsts.append(_server_puts(side.stream[mark:]))
+    first = firsts[0]
+    assert first == firsts[1]
+    # a first commitment writes each server once, at its first live row
+    pt, placement = cp.svc.retained("p/live")
+    assert first == list(dict.fromkeys(
+        pt.node_names[int(j)] for j in placement.raw))
+    assert len(first) > 8 and pt.S >= BIG_SERVICES
+
+    victim = cp.busiest("p/live")
+    assert victim == ref.busiest("p/live")
+    holds = []
+    for side in (cp, ref):
+        moved = dict(side.svc.node_events([(victim, False)]))
+        assert moved["p/live"].feasible
+        churn = [r for r in side.svc._reservations.values() if r.churn]
+        assert len(churn) == 1
+        holds.append(churn[0].demand_by_node)
+    assert holds[0]
+    _assert_same_demand(*holds)
+
+    mark = len(cp.stream), len(ref.stream)
+    assert cp.svc.commit_retained("p/live")
+    assert ref.svc.commit_retained("p/live")
+    assert (_server_puts(cp.stream[mark[0]:])
+            == _server_puts(ref.stream[mark[1]:]) != [])
+    assert cp.allocated() == ref.allocated()        # exact, not approx
+    assert cp.allocated()[victim] == (0.0, 0.0, 0.0)
+    assert _committed_json(cp) == _committed_json(ref) != []
+    _assert_same_demand(cp.svc._committed["p/live"].demand_by_node,
+                        ref.svc._committed["p/live"].demand_by_node)
