@@ -262,6 +262,11 @@ class Service:
     # committed and reserved placements, cp/placement.py). A label without
     # an entry separates declarers inside one stage only.
     anti_affinity_stages: dict[str, list[str]] = field(default_factory=dict)
+    # scheduling priority (`priority 10`): a stage whose rows ALL rank
+    # strictly above a committed row of another stage may evict it when it
+    # fits nowhere otherwise (cp/placement.py). 0, the default, evicts
+    # nothing.
+    priority: int = 0
     replicas: int = 1
 
     _resources_set: bool = field(default=False, repr=False, compare=False)
@@ -323,6 +328,7 @@ class Service:
             anti_affinity=_merge_vec(self.anti_affinity, other.anti_affinity),
             anti_affinity_stages=_merge_map(self.anti_affinity_stages,
                                             other.anti_affinity_stages),
+            priority=other.priority or self.priority,
             replicas=other.replicas if other._replicas_set else self.replicas,
             _resources_set=self._resources_set or other._resources_set,
             _replicas_set=self._replicas_set or other._replicas_set,

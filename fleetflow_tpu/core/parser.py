@@ -408,6 +408,8 @@ def parse_service(node: KdlNode, source: Optional[str] = None) -> Service:
                           if s.strip()]
                 for label in labels:
                     svc.anti_affinity_stages[label] = stages
+        elif n == "priority":
+            svc.priority = int(c.arg(0, 0))
         elif n == "replicas":
             svc.replicas = int(c.arg(0, 1))
             svc._replicas_set = True

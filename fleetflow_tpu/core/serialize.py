@@ -187,6 +187,7 @@ def service_to_dict(s: Service) -> dict:
     _put(d, "colocate_with", s.colocate_with, [])
     _put(d, "anti_affinity", s.anti_affinity, [])
     _put(d, "anti_affinity_stages", s.anti_affinity_stages, {})
+    _put(d, "priority", s.priority, 0)
     if s._replicas_set or s.replicas != 1:
         # _replicas_set tracks an explicit config declaration, but a
         # programmatically built Flow (tests, chaos harness, API users)
@@ -221,6 +222,7 @@ def service_from_dict(d: dict) -> Service:
         colocate_with=d.get("colocate_with", []),
         anti_affinity=d.get("anti_affinity", []),
         anti_affinity_stages=d.get("anti_affinity_stages", {}),
+        priority=d.get("priority", 0),
         replicas=d.get("replicas", 1),
         _resources_set="resources" in d,
         _replicas_set="replicas" in d,

@@ -24,6 +24,8 @@ batcher in front of that warm solve path:
               problem (tombstoned departures, row-reusing arrivals), and
               ONE micro-solve rides the resident delta path through
               `PlacementService.admit_batch`, committed as ONE reservation.
+              An arrival never preempts, whatever its `priority`: the
+              micro-solve sees live capacity only (admit_batch's docstring).
   pressure()  the autoscaler feedback signal (cp/autoscaler.py): sustained
               queue age or infeasible-parked arrivals mean the SOLVER is
               the bottleneck or the fleet is full — provision nodes; a

@@ -955,6 +955,17 @@ async def _execute_deploy(state: "AppState", req: DeployRequest,
             if not placement.feasible:
                 raise ValueError(
                     f"placement infeasible: {placement.violations}")
+            victims = state.placement.victims(rid) if rid else []
+            if victims:
+                # a deploy stops and starts the containers of ITS stage on
+                # the agents; the victims are another stage's, and nothing
+                # here would stop them: refused outright, with the book as
+                # it was. placement.solve + placement.commit preempt.
+                state.placement.release(rid)
+                raise ValueError(
+                    f"placement needs {len(victims)} evictions from "
+                    f"{sorted({v['stage'] for v in victims})}: "
+                    f"deploy.execute does not preempt")
             # batched shard-parallel fan-out (cp/shards.py): the deploy
             # engine hands the registry the whole per-node command set
             # and each shard lane pipelines its slice
@@ -1019,7 +1030,11 @@ def _placement(state: "AppState"):
                     "violations": placement.violations,
                     "source": placement.source,
                     "solve_ms": placement.solve_ms,
-                    "reservation": rid}
+                    "reservation": rid,
+                    # rows of other stages' commitments that the commit of
+                    # this reservation evicts (cp/placement.py)
+                    "victims": (state.placement.victims(rid)
+                                if rid else [])}
         if method == "node_event":
             slug, online = _require(p, "slug", "online")
             moved = await asyncio.get_running_loop().run_in_executor(
@@ -1039,7 +1054,10 @@ def _placement(state: "AppState"):
                 {"stage": key, "assignment": pl.assignment,
                  "feasible": pl.feasible} for key, pl in moved]}
         if method == "commit":
-            return {"ok": state.placement.commit(p.get("reservation", ""))}
+            rid = p.get("reservation", "")
+            ok = state.placement.commit(rid)
+            return {"ok": ok, "evicted": (len(state.placement.victims(rid))
+                                          if ok else 0)}
         if method == "release":
             return {"ok": state.placement.release(p.get("reservation", ""))}
         if method == "explain":

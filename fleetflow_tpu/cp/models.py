@@ -302,6 +302,20 @@ class PlacementRecord(Record):
     # there while the record stands
     held_keys: dict[str, list[str]] = field(default_factory=dict)
 
+    def to_dict(self) -> dict:
+        """What `asdict` gives, key for key and in its order, without its
+        walk in Python over every row: the fields hold plain JSON values
+        (a 20,000-row assignment, a demand list a server), so one level of
+        copying is the same dict. A record is written whole whenever its
+        stage commits, is evicted from or gets rows back."""
+        return {"id": self.id, "created_at": self.created_at,
+                "updated_at": self.updated_at, "stage_key": self.stage_key,
+                "assignment": dict(self.assignment),
+                "demand_by_node": {k: list(v) for k, v
+                                   in self.demand_by_node.items()},
+                "held_keys": {k: list(v) for k, v
+                              in self.held_keys.items()}}
+
 
 # --------------------------------------------------------------------------
 # Deployments (model.rs:639)

@@ -21,6 +21,12 @@ beside the demand they book, which conflict keys their rows hold on which
 server (`Reservation.held_keys`; the keys are lower/tensors.py's), and every
 lowering against live inventory bars the stage's rows from the servers on
 which another stage holds one of their keys.
+
+Priority reaches across stages too: a commitment keeps a row-level view of
+itself (`Reservation.rows`), so that a stage whose rows all rank above some
+of them can be lowered with what those rows hold counted as capacity, and a
+commit of it evicts the fewest of them that make room (`PlacementService`'s
+docstring has the rules).
 """
 
 from __future__ import annotations
@@ -33,7 +39,8 @@ from typing import Optional
 import numpy as np
 
 from ..core.model import Flow, ResourceSpec, ServerLabels, ServerResource
-from ..lower.tensors import ProblemTensors, bar_held, lower_stage
+from ..lower.tensors import (ProblemTensors, bar_held, lower_stage,
+                             with_preemptible)
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
@@ -57,7 +64,79 @@ _M_HELD_KEYS = REGISTRY.counter(
     "Server x conflict-key pairs held by other stages' committed and "
     "reserved placements, as handed to a lowering")
 
+_M_PREEMPTIBLE_SERVERS = REGISTRY.counter(
+    "fleet_placement_preemptible_servers_total",
+    "Servers on which committed rows of lower priority hold capacity, as "
+    "handed to a lowering of a stage that may evict them")
+
+_M_VICTIMS = REGISTRY.counter(
+    "fleet_placement_victims_total",
+    "Committed rows evicted by the commit of a stage of higher priority")
+
+# a server is over its capacity only beyond this relative slack: the
+# solver's own (solver/repair.py), so that what it calls feasible fits here
+_CAP_RTOL = 1e-6
+
 __all__ = ["PlacementService", "Reservation"]
+
+
+@dataclass
+class _Rows:
+    """A placement row by row, for priority: which server each row is on,
+    what it asks and how it ranks. All of it is the lowered problem's and
+    the placement's own arrays, shared and never written; only `live` and
+    `evicted` are this view's."""
+    names: list[str]                # ProblemTensors.service_names
+    nodes: list[str]                # ProblemTensors.node_names
+    node_of: np.ndarray             # (S,) index into `nodes`
+    demand: np.ndarray              # (S, R) f32
+    priority: Optional[np.ndarray]  # (S,) i32, or None: every row ranks 0
+    holds: dict[str, list[int]]     # conflict key -> rows (lower/tensors.py)
+    floor: int                      # no row ranks below this
+    # (S,) bool, the rows the commitment still books (a row of no demand
+    # is an admission tombstone); built when priority first asks
+    live: Optional[np.ndarray] = None
+    # rows a higher stage's commit evicted, until `reinstate` or the next
+    # commit of the stage
+    evicted: Optional[np.ndarray] = None
+
+    @classmethod
+    def of(cls, pt: ProblemTensors, placement: Placement) -> "_Rows":
+        return cls(names=pt.service_names, nodes=pt.node_names,
+                   node_of=np.asarray(placement.raw), demand=pt.demand,
+                   priority=pt.priority, holds=pt.holds,
+                   floor=(0 if pt.priority is None
+                          else int(pt.priority.min())))
+
+    def below(self, p: int, claimed=()) -> np.ndarray:
+        """(S,) bool: live rows ranking strictly below `p`, less the rows
+        in the index arrays of `claimed`."""
+        if self.live is None:
+            self.live = np.asarray(self.demand).any(axis=1)
+        m = (self.live.copy() if self.priority is None
+             else self.live & (self.priority < p))
+        for idx in claimed:
+            m[idx] = False
+        return m
+
+    def by_node(self, rows) -> np.ndarray:
+        """(len(nodes), R) f64: the demand of `rows` (a mask or an index
+        array), summed by server."""
+        at = self.node_of[rows]
+        dem = np.asarray(self.demand)[rows].astype(np.float64)
+        return np.stack([np.bincount(at, weights=dem[:, k],
+                                     minlength=len(self.nodes))
+                         for k in range(dem.shape[1])], axis=1)
+
+    def onto(self, nodes: list[str]) -> Optional[np.ndarray]:
+        """Index of each of this view's servers in `nodes` (-1 where it is
+        not there); None when the two lists are the same, which two stages
+        over one pool give."""
+        if self.nodes is nodes or self.nodes == nodes:
+            return None
+        at = {n: j for j, n in enumerate(nodes)}
+        return np.fromiter((at.get(n, -1) for n in self.nodes),
+                           dtype=np.int64, count=len(self.nodes))
 
 
 @dataclass
@@ -76,6 +155,17 @@ class Reservation:
     # (lower/tensors.py). It lives and dies with the reservation: commit
     # moves it to the committed book, release and supersession drop it.
     held_keys: dict[str, list[str]] = field(default_factory=dict)
+    # row-level view, where the problem it was solved from is at hand (not
+    # after a reload from the store: such a commitment is preemptible only
+    # once `rehydrate` has lowered it again)
+    rows: Optional[_Rows] = field(default=None, repr=False)
+    # stage key -> {service: server} of that stage's commitment that the
+    # commit of this reservation evicts (nothing leaves before it);
+    # `victim_rows` says the same as (id of that commitment, indices into
+    # its rows)
+    victims: dict[str, dict[str, str]] = field(default_factory=dict)
+    victim_rows: dict[str, tuple[str, np.ndarray]] = field(
+        default_factory=dict, repr=False)
 
 
 def _alloc_vector(s: Server) -> np.ndarray:
@@ -109,7 +199,43 @@ class PlacementService:
     `placement.solve`, `deploy.execute`), on `rehydrate`, and on the churn
     re-solve of `node_events`; `admit_batch` does not yet bar its arrivals
     (its docstring). A stage alone on its servers takes the same path with
-    nothing held."""
+    nothing held.
+
+    Priority and preemption (`Service.priority`, default 0). A stage that
+    fits nowhere may evict committed rows of other stages. What is kept:
+
+    1. A row is evicted only by a stage whose rows ALL rank strictly above
+       it, and only a committed row is ever a victim: an open reservation
+       and a churn hold are not, nor a row another open reservation has
+       already claimed. A batch of mixed priorities preempts as its lowest
+       row (kube-scheduler decides pod by pod; this is the sound half).
+    2. After the commit, per server, the cpu / memory / disk of everything
+       that remains plus the arrivals is within capacity, counted over
+       every stage the CP has placed.
+    3. No needless victim per server: none on a server that took no
+       arrival, and on one that did, putting any one victim back breaks
+       capacity (all lower rows out, then reprieved highest priority
+       first while the arrivals still fit: kube-scheduler's rule). A stage
+       that fits without eviction evicts nothing and takes the path it
+       took before priorities existed.
+    4. The eviction is part of the acknowledged write: when `commit`
+       returns True the victim stage's `placements` record has lost
+       exactly the victims, the arriving stage's holds its assignment,
+       every touched server's `allocated` is the sum of what remains, and
+       all of it went through the store (journaled, replicated). `release`
+       of the reservation leaves the book untouched. A commit whose
+       victims' stage was committed anew since the solve returns False.
+    5. Held conflict keys still bar: a key held by a preemptible row is
+       not preempted for (kube-scheduler would evict the holder). A
+       victim's keys are released with it.
+
+    `solve_stage` (so `placement.solve`) and `commit` hold these;
+    `deploy.execute` refuses a placement that needs victims, `admit_batch`
+    and the churn re-solve of `node_events` never preempt. A commitment
+    reloaded from the store is no victim until `rehydrate` has lowered its
+    stage again. Victims stay in their stage's retained problem as
+    tombstones (no demand, masked from every view), so a later churn
+    re-solve does not resurrect them; `reinstate` puts them back."""
 
     def __init__(self, store: Store, *, use_tpu: bool = False,
                  chains=None, steps: int = 128):
@@ -159,7 +285,7 @@ class PlacementService:
             return
         attrs = dict(
             assignment=dict(r.assignment),
-            demand_by_node={slug: [float(x) for x in np.asarray(d)]
+            demand_by_node={slug: np.asarray(d, dtype=np.float64).tolist()
                             for slug, d in r.demand_by_node.items()},
             held_keys={k: list(v) for k, v in r.held_keys.items()})
         if rec is None:
@@ -175,13 +301,21 @@ class PlacementService:
     def _inventory(self, tenant: str,
                    slugs: Optional[list[str]] = None,
                    exclude_demand: Optional[dict[str, np.ndarray]] = None,
-                   ) -> tuple[list[ServerResource], np.ndarray]:
+                   preemptor: Optional[tuple[str, int]] = None,
+                   ) -> tuple[list[ServerResource], np.ndarray,
+                              Optional[np.ndarray]]:
         """Live nodes + validity mask, with reserved+committed demand
         subtracted from capacity.  `exclude_demand` (slug -> (R,)) is
         demand attributed to the CALLING stage itself (e.g. its own churn
         hold) — excluded BEFORE the zero-clamp, so a deficit against a
         shrunken node cannot turn into phantom free capacity the way a
-        post-clamp add-back would."""
+        post-clamp add-back would.
+
+        `preemptor` (stage key, the priority of its lowest row) asks, as
+        the third value, for what committed rows of lower priority hold
+        on each node beside that: (N, R), what the node's capacity would
+        gain were they left out, before the clamp likewise; None where no
+        row ranks lower."""
         # a tenant sees its own servers plus the shared "default" pool;
         # "default" solves never touch tenant-dedicated capacity
         servers = self.store.list(
@@ -190,18 +324,73 @@ class PlacementService:
         if not servers:
             raise ValueError(f"no servers registered for tenant {tenant!r}")
         reserved = self._reserved_by_node()
+        pre = None
+        if preemptor is not None:
+            with phase("cp.solve_stage.preemptible") as ph:
+                pre = self._preemptible_by_node(
+                    *preemptor, [s.slug for s in servers])
+                if pre is not None:
+                    holding = int(pre.any(axis=1).sum())
+                    _M_PREEMPTIBLE_SERVERS.inc(holding)
+                    ph.set(servers=holding)
         nodes, valid = [], []
-        for s in servers:
+        # free capacity before the clamp, kept only for `pre`
+        unclamped = None if pre is None else np.empty_like(pre)
+        for i, s in enumerate(servers):
             res = _server_to_resource(s)
             alloc = _alloc_vector(s) + reserved.get(s.slug, 0)
             if exclude_demand:
                 alloc = alloc - exclude_demand.get(s.slug, 0)
-            cap = np.maximum(np.array(res.capacity.as_tuple()) - alloc, 0.0)
+            free = np.array(res.capacity.as_tuple()) - alloc
+            if unclamped is not None:
+                unclamped[i] = free
+            cap = np.maximum(free, 0.0)
             res.capacity = ResourceSpec(cpu=float(cap[0]), memory=float(cap[1]),
                                         disk=float(cap[2]))
             nodes.append(res)
             valid.append(s.schedulable)
-        return nodes, np.array(valid, dtype=bool)
+        if pre is not None:
+            # what each node would gain: the deficit of a shrunken node is
+            # taken off it as it is off the node's own capacity
+            pre = (np.maximum(unclamped + pre, 0.0)
+                   - np.maximum(unclamped, 0.0))
+        return nodes, np.array(valid, dtype=bool), pre
+
+    def _lower_ranking(self, key: str, p: int
+                       ) -> list[tuple[Reservation, np.ndarray]]:
+        """The commitments of stages other than `key` that have rows a
+        stage of priority `p` may evict, each with the (S,) mask of those
+        rows: committed, live, ranking strictly below `p`, and not yet
+        claimed as a victim by an open reservation. One truth test a
+        commitment where no row ranks below `p`. Caller holds the lock."""
+        out = []
+        for c in self._committed.values():
+            if c.stage_key == key or c.rows is None or c.rows.floor >= p:
+                continue
+            claimed = [v[1] for r in self._reservations.values()
+                       if (v := r.victim_rows.get(c.stage_key))
+                       and v[0] == c.id]
+            mask = c.rows.below(p, claimed)
+            if mask.any():
+                out.append((c, mask))
+        return out
+
+    def _preemptible_by_node(self, key: str, p: int,
+                             slugs: list[str]) -> Optional[np.ndarray]:
+        """(len(slugs), R) f64: what rows that stage `key`, of priority
+        `p`, may evict hold on each server — an array pass over each
+        commitment that has such rows. None where none has."""
+        pre = None
+        for c, mask in self._lower_ranking(key, p):
+            held = c.rows.by_node(mask)
+            at = c.rows.onto(slugs)
+            if pre is None:
+                pre = np.zeros((len(slugs), held.shape[1]))
+            if at is None:
+                pre += held
+            else:
+                np.add.at(pre, at[at >= 0], held[at >= 0])
+        return pre
 
     def _reserved_by_node(self) -> dict[str, np.ndarray]:
         out: dict[str, np.ndarray] = {}
@@ -277,8 +466,21 @@ class PlacementService:
                     if r.churn and r.stage_key == key:
                         for slug, d in r.demand_by_node.items():
                             own_churn[slug] = own_churn.get(slug, 0) + d
-                nodes, valid = self._inventory(tenant, stage.servers or None,
-                                               exclude_demand=own_churn)
+                # only a stage that ranks above some committed row of
+                # another pays for its own priority: its lowest row's
+                preemptor = None
+                floor = min((c.rows.floor for c in self._committed.values()
+                             if c.rows is not None and c.stage_key != key),
+                            default=None)
+                if floor is not None:
+                    p = min((s.priority
+                             for s in stage.resolved_services(flow)),
+                            default=0)
+                    if p > floor:
+                        preemptor = (key, p)
+                nodes, valid, preemptible = self._inventory(
+                    tenant, stage.servers or None,
+                    exclude_demand=own_churn, preemptor=preemptor)
                 # Config-declared labels back-fill: agents register slug +
                 # capacity only, so live store records usually carry NO labels,
                 # and a blank label passes every gate (_server_matches treats
@@ -302,25 +504,117 @@ class PlacementService:
             with phase("cp.solve_stage.lower"):
                 pt = lower_stage(flow, stage_name, nodes=nodes, held=held)
                 pt.node_valid &= valid
+                if preemptible is not None and not self._fits_free(pt):
+                    # a row that fits on no server as it is: the stage
+                    # fits nowhere, whatever the solver would say
+                    pt = with_preemptible(pt, preemptible)
             with phase("cp.solve_stage.solve"):
-                prev = self._last.get(key)
-                if self.use_tpu:
-                    warm = (prev is not None
-                            and prev[0].S == pt.S and prev[0].N == pt.N)
-                    placement = self._sched_tpu.place(pt, warm_start=warm,
-                                                      stage=key)
-                    if not placement.feasible and pt.relax_order:
-                        placement, _ = place_with_fallback(
-                            self._sched_tpu, pt, initial=placement,
-                            place_kwargs={"stage": key})
-                else:
-                    placement, _ = place_with_fallback(self._sched_host, pt)
+                placement = self._solve_lowered(key, pt)
+                if (not placement.feasible and preemptible is not None
+                        and pt.preemptible is None):
+                    # it does not fit in what is free: with what lower
+                    # ranks hold, then
+                    pt = with_preemptible(pt, preemptible)
+                    placement = self._solve_lowered(key, pt)
+            victims = None
+            if pt.preemptible is not None and placement.feasible:
+                with phase("cp.solve_stage.victims") as ph:
+                    victims = self._select_victims(key, pt, placement,
+                                                   preemptor[1])
+                    ph.set(victims=sum(len(idx) for _c, idx in victims))
             with phase("cp.solve_stage.reserve"):
                 self._last[key] = (pt, placement)
                 rid = None
                 if reserve and placement.feasible:
-                    rid = self._reserve(key, pt, placement)
+                    rid = self._reserve(key, pt, placement, victims)
         return placement, rid
+
+    def _solve_lowered(self, key: str, pt: ProblemTensors) -> Placement:
+        """The solve of `solve_stage`: the device annealer, warm where the
+        stage's last problem had this shape, or the host scheduler, each
+        with the declared relaxation ladder behind it."""
+        if not self.use_tpu:
+            return place_with_fallback(self._sched_host, pt)[0]
+        prev = self._last.get(key)
+        warm = (prev is not None
+                and prev[0].S == pt.S and prev[0].N == pt.N)
+        placement = self._sched_tpu.place(pt, warm_start=warm, stage=key)
+        if not placement.feasible and pt.relax_order:
+            placement, _ = place_with_fallback(
+                self._sched_tpu, pt, initial=placement,
+                place_kwargs={"stage": key})
+        return placement
+
+    @staticmethod
+    def _fits_free(pt: ProblemTensors) -> bool:
+        """Whether every row of `pt` fits, alone, on some valid server:
+        necessary for the stage to fit as lowered, and cheap (the distinct
+        demand rows against the servers)."""
+        shapes = np.unique(pt.demand, axis=0)
+        room = pt.capacity[pt.node_valid] * (1 + _CAP_RTOL)
+        return bool((shapes[:, None, :] <= room[None]).all(axis=2)
+                    .any(axis=1).all())
+
+    def _select_victims(self, key: str, pt: ProblemTensors,
+                        placement: Placement, p: int
+                        ) -> list[tuple[Reservation, np.ndarray]]:
+        """Which committed rows the placement of stage `key` (priority
+        `p`, lowered with `pt.preemptible`) has to evict: [(commitment,
+        indices into its rows)]. Per server whose arrivals overflow what
+        is truly free there: every lower-ranking row is a candidate, and
+        candidates are reprieved, highest priority first, while the
+        arrivals and those already reprieved still fit (kube-scheduler's
+        rule), so that putting any one victim back breaks capacity. The
+        servers are worked together, one candidate of each a round: array
+        passes, as many as the fullest server has candidates."""
+        room = np.asarray(pt.capacity, dtype=np.float64)
+        load = np.zeros_like(room)
+        np.add.at(load, np.asarray(placement.raw),
+                  np.asarray(pt.demand, dtype=np.float64))
+        free = room - pt.preemptible
+        over = (load > free * (1 + _CAP_RTOL)).any(axis=1)
+        if not over.any():
+            return []
+        found, node, prio, dem = [], [], [], []
+        for c, mask in self._lower_ranking(key, p):
+            at = c.rows.onto(pt.node_names)
+            on = c.rows.node_of if at is None else at[c.rows.node_of]
+            mask &= (on >= 0) & over[on]
+            rows = np.flatnonzero(mask)
+            if not rows.size:
+                continue
+            found.append((c, rows))
+            node.append(on[rows])
+            prio.append(np.zeros(rows.size, dtype=np.int64)
+                        if c.rows.priority is None
+                        else c.rows.priority[rows].astype(np.int64))
+            dem.append(np.asarray(c.rows.demand)[rows].astype(np.float64))
+        if not found:
+            return []
+        node, prio, dem = map(np.concatenate, (node, prio, dem))
+        # by server, highest priority first, then in the book's order
+        order = np.lexsort((np.arange(node.size), -prio, node))
+        sorted_node = node[order]
+        first = np.flatnonzero(np.r_[True, sorted_node[1:]
+                                     != sorted_node[:-1]])
+        rank = np.arange(node.size) - np.repeat(
+            first, np.diff(np.r_[first, node.size]))
+        kept = np.zeros_like(room)
+        evict = np.zeros(node.size, dtype=bool)
+        for k in range(int(rank.max()) + 1):
+            sel = order[rank == k]          # one candidate of each server
+            n, d = node[sel], dem[sel]
+            fits = (load[n] + kept[n] + d
+                    <= room[n] * (1 + _CAP_RTOL)).all(axis=1)
+            kept[n[fits]] += d[fits]
+            evict[sel[~fits]] = True
+        out, lo = [], 0
+        for c, rows in found:
+            idx = rows[evict[lo:lo + rows.size]]
+            lo += rows.size
+            if idx.size:
+                out.append((c, idx))
+        return out
 
     def rehydrate(self, stage_key: str, flow: Flow,
                   tenant: str = "default") -> bool:
@@ -347,7 +641,7 @@ class PlacementService:
             # from inventory like solve_stage excludes its churn hold,
             # or the adopted placement double-counts itself
             exclude = dict(committed.demand_by_node) if committed else None
-            nodes, valid = self._inventory(
+            nodes, valid, _ = self._inventory(
                 tenant, flow.stage(stage_name).servers or None,
                 exclude_demand=exclude)
             pt = lower_stage(flow, stage_name, nodes=nodes,
@@ -367,10 +661,14 @@ class PlacementService:
             # the re-solve that moves the stage off the dead node
             pt.node_valid = pt.node_valid.copy()
             pt.node_valid[np.unique(raw)] = True
-            self._last[stage_key] = (pt, Placement(
+            adopted = Placement(
                 assignment=dict(rec.assignment),
                 levels=level_schedule(pt), feasible=True,
-                source="rehydrated", raw=raw))
+                source="rehydrated", raw=raw)
+            self._last[stage_key] = (pt, adopted)
+            if committed is not None and committed.rows is None:
+                # lowered again, the reloaded commitment knows its rows
+                committed.rows = _Rows.of(pt, adopted)
         log.info("placement rehydrated %s", kv(stage=stage_key,
                                                rows=pt.S))
         return True
@@ -400,7 +698,13 @@ class PlacementService:
         held for it — a row is not barred from a server on which another
         stage holds its key (streamed arrivals carry no ports, volumes or
         anti-affinity; the rows the stage was opened with can). The
-        reservation does record what the stage's own rows hold."""
+        reservation does record what the stage's own rows hold.
+
+        An arrival through admission does NOT preempt either: capacity
+        here is live capacity, what lower-ranking committed rows hold is
+        never added to it, and no victim is selected, whatever the rows'
+        priority (tests/test_preemption.py has the `xfail`). The stage's
+        committed rows can be another stage's victims."""
         with self._lock:
             server_map = {s.slug: s for s in self.store.list("servers")}
             valid = np.array(
@@ -491,16 +795,37 @@ class PlacementService:
             if r.churn and r.stage_key == key:
                 del self._reservations[rid]
 
-    def _reserve(self, key: str, pt: ProblemTensors,
-                 placement: Placement) -> str:
+    def _reserve(self, key: str, pt: ProblemTensors, placement: Placement,
+                 victims: Optional[list] = None) -> str:
+        """`victims` is `_select_victims`'s answer: the reservation claims
+        them, and its commit evicts them."""
         self._drop_churn(key)
         rid = f"rsv_{next(self._ids)}"
-        self._reservations[rid] = Reservation(
+        r = self._reservations[rid] = Reservation(
             id=rid, stage_key=key,
             demand_by_node=self._demand_by_node(pt, placement),
             assignment=dict(placement.assignment),
-            held_keys=self._held_keys(pt, placement))
+            held_keys=self._held_keys(pt, placement),
+            rows=_Rows.of(pt, placement))
+        for c, idx in victims or ():
+            r.victim_rows[c.stage_key] = (c.id, idx)
+            r.victims[c.stage_key] = {
+                c.rows.names[i]: c.rows.nodes[j]
+                for i, j in zip(idx.tolist(), c.rows.node_of[idx].tolist())}
         return rid
+
+    def victims(self, rid: str) -> list[dict]:
+        """[{stage, service, server}]: the rows the commit of reservation
+        `rid` evicts, or evicted if it is committed already. Empty for a
+        placement that fits without eviction, and for an id nobody has."""
+        with self._lock:
+            r = self._reservations.get(rid) or next(
+                (c for c in self._committed.values() if c.id == rid), None)
+            if r is None:
+                return []
+            return [{"stage": vkey, "service": name, "server": slug}
+                    for vkey, on in r.victims.items()
+                    for name, slug in on.items()]
 
     def _write_allocation(self, slug: str, d) -> int:
         """Add the cpu/memory/disk vector `d` to one server's `allocated`,
@@ -563,12 +888,23 @@ class PlacementService:
         return written
 
     def _supersede_allocation(self, prev: Optional[Reservation],
-                              r: Reservation) -> None:
+                              r: Reservation,
+                              returned: Optional[dict] = None) -> None:
         """The `cp.commit.apply_allocation` phase of both commit paths:
         book `r` on the servers in place of the stage's previous
-        commitment, if it has one. The phase's `records` field is the
+        commitment, if it has one. `returned` (slug -> (R,)) is what the
+        victims of `r` gave up (`_evict`): it is returned in the same
+        pass, by difference, so a server that loses victims and takes
+        arrivals is written once. The phase's `records` field is the
         number of server records written."""
         with phase("cp.commit.apply_allocation") as ph:
+            if returned:
+                gone = dict(returned)
+                if prev is not None:
+                    for slug, d in prev.demand_by_node.items():
+                        gone[slug] = gone.get(slug, 0) + d
+                prev = Reservation(id="", stage_key=r.stage_key,
+                                   demand_by_node=gone, assignment={})
             if prev is None:
                 written = self._apply_allocation(r, +1.0)
             else:
@@ -584,8 +920,14 @@ class PlacementService:
             r = self._reservations.pop(rid, None)
             if r is None or r.committed:
                 return False
+            if any(getattr(self._committed.get(vkey), "id", None) != cid
+                   for vkey, (cid, _idx) in r.victim_rows.items()):
+                # a victim's stage was committed anew since the solve: the
+                # rows this placement counted on are not there to evict
+                return False
             prev = self._committed.pop(r.stage_key, None)
-            self._supersede_allocation(prev, r)
+            returned = self._evict(r) if r.victim_rows else None
+            self._supersede_allocation(prev, r, returned)
             r.committed = True
             self._committed[r.stage_key] = r
             self._drop_churn(r.stage_key)   # commitment reflects reality now
@@ -593,9 +935,134 @@ class PlacementService:
                 self._persist_committed(r.stage_key)
             return True
 
+    def _evict(self, r: Reservation) -> dict[str, np.ndarray]:
+        """The `cp.commit.evict` phase: take the victims of `r` out of
+        their stages' commitments (assignment, demand by server, held
+        keys), tombstone them in those stages' retained problems and
+        persist those placement records. Returns slug -> (R,), what the
+        victims booked: the caller returns it to the servers."""
+        returned: dict[str, np.ndarray] = {}
+        with phase("cp.commit.evict") as ph:
+            for vkey, (_cid, idx) in r.victim_rows.items():
+                c = self._committed[vkey]
+                rows = c.rows
+                rows.live[idx] = False
+                rows.evicted = (idx if rows.evicted is None
+                                else np.concatenate([rows.evicted, idx]))
+                gone = rows.by_node(idx)
+                left = np.bincount(rows.node_of[rows.live],
+                                   minlength=len(rows.nodes))
+                for j in np.unique(rows.node_of[idx]).tolist():
+                    slug = rows.nodes[j]
+                    returned[slug] = returned.get(slug, 0) + gone[j]
+                    if left[j]:
+                        c.demand_by_node[slug] = np.maximum(
+                            c.demand_by_node[slug] - gone[j], 0.0)
+                    else:
+                        del c.demand_by_node[slug]
+                for name in r.victims[vkey]:
+                    del c.assignment[name]
+                c.held_keys = self._live_held_keys(rows)
+                self._tombstone(vkey, rows, idx, True)
+                self._persist_committed(vkey)
+            n = sum(map(len, r.victims.values()))
+            _M_VICTIMS.inc(n)
+            ph.set(victims=n, stages=len(r.victim_rows))
+        return returned
+
+    @staticmethod
+    def _live_held_keys(rows: _Rows) -> dict[str, list[str]]:
+        """`_held_keys` over the rows a commitment still books."""
+        out = {}
+        for k, held_by in rows.holds.items():
+            at = rows.node_of[[i for i in held_by if rows.live[i]]]
+            if at.size:
+                out[k] = sorted({rows.nodes[j] for j in at.tolist()})
+        return out
+
+    def _tombstone(self, key: str, rows: _Rows, idx: np.ndarray,
+                   gone: bool) -> None:
+        """Rows `idx` of stage `key`'s commitment leave (`gone`) or come
+        back to its retained problem, where that is still the problem the
+        commitment was solved from: gone, a row asks nothing and is masked
+        from every view, so a churn re-solve neither books nor shows it;
+        back, it asks what it asked, on the server it was on."""
+        entry = self._last.get(key)
+        if entry is None or entry[0].service_names is not rows.names:
+            return
+        pt, placement = entry
+        names = frozenset(rows.names[i] for i in idx.tolist())
+        demand = np.array(pt.demand)
+        if gone:
+            demand[idx] = 0.0
+            self._masked[key] = self._masked.get(key, frozenset()) | names
+            placement = self._apply_mask(key, placement)
+        else:
+            demand[idx] = np.asarray(rows.demand)[idx]
+            self._masked[key] = self._masked.get(key, frozenset()) - names
+            raw = np.array(placement.raw)
+            raw[idx] = rows.node_of[idx]
+            placement = _dc_replace(placement, raw=raw, assignment={
+                **placement.assignment,
+                **{rows.names[i]: rows.nodes[j] for i, j
+                   in zip(idx.tolist(), rows.node_of[idx].tolist())}})
+        self._last[key] = (_dc_replace(pt, demand=demand), placement)
+
+    def reinstate(self, stage_key: str) -> int:
+        """Put the rows that higher stages' commits evicted from stage
+        `stage_key` back where they were — an operator rolling back a
+        batch that preempted: `release_stage` of the batch, then this.
+        By difference, like a commit: each server that gets rows back is
+        written once, the placement record is persisted whole. Returns
+        the rows reinstated; 0, with the book untouched, where there are
+        none, where the stage was committed anew since (its old victims
+        are then history), or where they no longer fit: capacity taken,
+        a server gone, or a conflict key of theirs held by another stage
+        on their server."""
+        with phase("cp.reinstate", stage=stage_key), self._lock:
+            c = self._committed.get(stage_key)
+            rows = c.rows if c is not None else None
+            if rows is None or rows.evicted is None:
+                return 0
+            idx = rows.evicted
+            back = rows.by_node(idx)
+            at = np.unique(rows.node_of[idx]).tolist()
+            reserved = self._reserved_by_node()
+            for j in at:
+                s = self.store.server_by_slug(rows.nodes[j])
+                if s is None:
+                    return 0
+                cap = np.array([s.capacity.cpu, s.capacity.memory,
+                                s.capacity.disk])
+                booked = _alloc_vector(s) + reserved.get(s.slug, 0) + back[j]
+                if (booked > cap * (1 + _CAP_RTOL)).any():
+                    return 0
+            if rows.holds:
+                coming = set(idx.tolist())
+                others = self._held_by_others(stage_key)
+                for k, held_by in rows.holds.items():
+                    mine = {rows.nodes[rows.node_of[i]]
+                            for i in held_by if i in coming}
+                    if mine & set(others.get(k, ())):
+                        return 0
+            for j in at:
+                slug = rows.nodes[j]
+                self._write_allocation(slug, back[j])
+                c.demand_by_node[slug] = (
+                    np.asarray(c.demand_by_node.get(slug, 0.0)) + back[j])
+            for i, j in zip(idx.tolist(), rows.node_of[idx].tolist()):
+                c.assignment[rows.names[i]] = rows.nodes[j]
+            rows.live[idx] = True
+            rows.evicted = None
+            c.held_keys = self._live_held_keys(rows)
+            self._tombstone(stage_key, rows, idx, False)
+            self._persist_committed(stage_key)
+            return int(idx.size)
+
     def release(self, rid: str, *, undo_commit: bool = False) -> bool:
         """Deploy failed or stage torn down: drop the reservation; with
-        `undo_commit`, also return the stage's committed capacity."""
+        `undo_commit`, also return the stage's committed capacity. The
+        victims of a dropped reservation were never touched."""
         with self._lock:
             r = self._reservations.pop(rid, None)
             if r is not None:
@@ -638,7 +1105,8 @@ class PlacementService:
                     id=f"rsv_{next(self._ids)}", stage_key=stage_key,
                     demand_by_node=self._demand_by_node(pt, placement),
                     assignment=dict(placement.assignment), committed=True,
-                    held_keys=self._held_keys(pt, placement))
+                    held_keys=self._held_keys(pt, placement),
+                    rows=_Rows.of(pt, placement))
             prev = self._committed.pop(stage_key, None)
             self._supersede_allocation(prev, r)
             self._committed[stage_key] = r
@@ -677,7 +1145,8 @@ class PlacementService:
             "in_flight": [
                 {"id": r.id, "stage": r.stage_key, "churn": r.churn,
                  "demand_by_node": dem(r.demand_by_node),
-                 "held_keys": r.held_keys}
+                 "held_keys": r.held_keys,
+                 **({"victims": r.victims} if r.victims else {})}
                 for r in self._reservations.values()],
             "committed": [
                 {"id": r.id, "stage": key,

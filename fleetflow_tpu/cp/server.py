@@ -8,6 +8,7 @@ CpServerHandle supports graceful shutdown (server.rs CpServerHandle).
 
 from __future__ import annotations
 
+import gc
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -200,6 +201,30 @@ def _default_backend_factory():
     return MockBackend(auto_pull=True)
 
 
+# full collections a CP runs per hundred collections of the middle generation
+# (CPython's default is ten)
+_OLDEST_GENERATION_EVERY = 100
+
+
+def settle_collector() -> None:
+    """A CP is a long-lived process whose heap is mostly state it keeps:
+    what it imported, the store's records, the committed book, retained
+    problems. CPython's defaults run a full collection about every 70,000
+    container allocations — one or two `placement.solve` + `commit` of
+    1,000 rows — and a full collection walks all of that: 50-100 ms with
+    5,000 server records and 20,000 committed rows, in about every second
+    request (PERF.md §6, PR 33; §7 had the two modes of op time since PR
+    30). So what is alive when the server starts (modules, JAX, a loaded
+    store) is moved out of the collector's sight for good (`gc.freeze`:
+    it is garbage only when the process ends), and the oldest generation
+    is looked at ten times less often. The young generations, which take
+    a request's own garbage, run as they did."""
+    gc.collect()
+    gc.freeze()
+    young, middle, _oldest = gc.get_threshold()
+    gc.set_threshold(young, middle, _OLDEST_GENERATION_EVERY)
+
+
 async def start(config: ServerConfig, *,
                 backend_factory: Optional[Callable] = None,
                 server_provider_factory: Optional[Callable] = None,
@@ -302,6 +327,7 @@ async def start(config: ServerConfig, *,
     register_all(server, state)
 
     host, port = await server.start(config.host, config.port)
+    settle_collector()
     log.info("listening %s", kv(
         host=host, port=port, name=config.name,
         role=state.replication_role,

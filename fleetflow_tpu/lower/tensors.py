@@ -38,6 +38,16 @@ every S in R, and is barred by L>T and by L@S for every S in R: two
 declarers on one server collide when either reaches the other's stage.
 Ports and volumes are facts about the host: held key = barring key.
 
+Priority. A stage may be lowered with `preemptible`, (N, R): what committed
+rows of other stages that rank strictly below every row of this one hold on
+each server (cp/placement.py gathers it, for a stage that does not fit in
+what is free). `capacity` grows by it (`with_preemptible`), so the solver
+may land a row on a server that is full of such rows; what using it costs
+goes into the soft plane `preferred` as the share of a server's preemptible
+capacity the row would have to take, so the annealer leans to the servers
+that need fewer evictions. Which rows are evicted is decided after the
+solve, by the caller; the solver never sees a victim.
+
 Replicas are expanded at lowering time: `service "w" { replicas 3 }` becomes
 rows w#0, w#1, w#2 sharing demand/ports/volumes; replica host-port conflicts
 make replicas of a port-publishing service mutually anti-affine exactly like
@@ -57,7 +67,8 @@ from ..core.model import (ServiceType, Flow, PlacementPolicy, PlacementStrategy,
                           ResourceSpec, ServerResource, Service)
 from ..obs.metrics import REGISTRY
 
-__all__ = ["ProblemTensors", "lower_stage", "bar_held", "dependency_depths",
+__all__ = ["ProblemTensors", "lower_stage", "bar_held", "with_preemptible",
+           "dependency_depths",
            "LOCAL_NODE_NAME", "local_node", "synthetic_problem"]
 
 # metric catalog: docs/guide/10-observability.md
@@ -110,6 +121,13 @@ class ProblemTensors:
     holds: dict[str, list[int]] = field(default_factory=dict)
     barred_by: dict[str, list[int]] = field(default_factory=dict)
     held: dict[str, list[str]] = field(default_factory=dict)
+    # Priority (module docstring); neither is read by the solver.
+    #   priority     (S,) i32 per row, or None: every row ranks 0
+    #   preemptible  (N, R) f32, the part of `capacity` that lower-ranking
+    #                committed rows of other stages hold, or None: none of
+    #                it is
+    priority: Optional[np.ndarray] = None
+    preemptible: Optional[np.ndarray] = None
 
     @property
     def S(self) -> int:
@@ -280,10 +298,49 @@ def bar_held(eligible: np.ndarray, barred_by: dict[str, list[int]],
     return cleared
 
 
+def preemption_cost(demand: np.ndarray, free: np.ndarray,
+                    preemptible: np.ndarray) -> Optional[np.ndarray]:
+    """(S, N) f32 in [0, 1]: the share of server n's preemptible capacity
+    that row s alone would take there beyond what is `free`, over the
+    resource that takes most (0 where the row fits in what is free). None
+    when every cell reads the same, which a deployment of one pod shape
+    on servers filled alike does: a constant plane moves no choice.
+    Computed over the distinct demand rows, and in steps of 1/256 so that
+    the last bit of a server's book does not make a plane."""
+    shapes, row_shape = np.unique(demand, axis=0, return_inverse=True)
+    over = np.maximum(shapes[:, None, :].astype(np.float64)
+                      - free[None].astype(np.float64), 0.0)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        share = np.where(over > 0.0, over / preemptible[None], 0.0)
+    cost = np.rint(np.clip(share.max(axis=2), 0.0, 1.0) * 256.0) / 256.0
+    if (cost == cost.flat[0]).all():
+        return None
+    return cost.astype(np.float32)[row_shape.reshape(-1)]
+
+
+def with_preemptible(pt: ProblemTensors,
+                     preemptible: np.ndarray) -> ProblemTensors:
+    """`pt`, lowered against what is free, with `preemptible` ((N, R), in
+    the order of its nodes) added to its capacity, recorded on it and
+    priced into `preferred` (module docstring, Priority). `pt` itself,
+    where nothing is preemptible."""
+    preemptible = np.asarray(preemptible, dtype=np.float32)
+    if not preemptible.any():
+        return pt
+    cost = preemption_cost(pt.demand, pt.capacity, preemptible)
+    preferred = pt.preferred
+    if cost is not None:
+        preferred = -cost if preferred is None else preferred - cost
+    return dataclasses.replace(
+        pt, capacity=pt.capacity + preemptible, preemptible=preemptible,
+        preferred=preferred)
+
+
 def lower_stage(flow: Flow, stage_name: str,
                 nodes: Optional[list[ServerResource]] = None,
                 local: bool = False,
                 held: Optional[dict[str, list[str]]] = None,
+                preemptible: Optional[np.ndarray] = None,
                 ) -> ProblemTensors:
     """Lower one stage of a Flow into ProblemTensors.
 
@@ -291,6 +348,12 @@ def lower_stage(flow: Flow, stage_name: str,
     the keys of the module docstring; the stage's rows are barred from
     those servers through `eligible`. Empty or None lowers the stage as
     if it were alone.
+
+    `preemptible` ((N, R), in the order of `nodes`) is what lower-ranking
+    committed rows hold on each node beside what `nodes` say is free
+    (module docstring, Priority): capacity grows by it
+    (`with_preemptible`). None or all zero lowers the same tensors as
+    without the argument.
 
     Node set: explicit `nodes` arg > stage.servers > all flow.servers > a
     single implicit "local" node with generous capacity (the `fleet up local`
@@ -562,6 +625,11 @@ def lower_stage(flow: Flow, stage_name: str,
     # plane, which ProblemTensors represents as preferred=None)
     preferred = (np.broadcast_to(node_pref, (S, N)).copy()
                  if node_pref.any() else None)
+    # one truth test a service; a stage of default priorities carries None
+    priority = (np.repeat(np.fromiter((s.priority for s in services),
+                                      dtype=np.int32, count=len(services)),
+                          reps_arr)
+                if any(s.priority for s in services) else None)
     held = held or {}
     if held:
         bar_held(eligible, barred_by, [n.name for n in nodes], held)
@@ -632,9 +700,10 @@ def lower_stage(flow: Flow, stage_name: str,
         holds=holds,
         barred_by=barred_by,
         held=held,
+        priority=priority,
     )
     pt.validate()
-    return pt
+    return pt if preemptible is None else with_preemptible(pt, preemptible)
 
 
 # --------------------------------------------------------------------------
