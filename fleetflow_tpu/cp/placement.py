@@ -38,8 +38,8 @@ from typing import Optional
 
 import numpy as np
 
-from ..core.model import Flow, ResourceSpec, ServerLabels, ServerResource
-from ..lower.tensors import (ProblemTensors, bar_held, lower_stage,
+from ..core.model import Flow, ServerLabels
+from ..lower.tensors import (Node, ProblemTensors, bar_held, lower_stage,
                              with_preemptible)
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
@@ -168,25 +168,52 @@ class Reservation:
         default_factory=dict, repr=False)
 
 
+def _booked_columns(servers: list[Server]) -> tuple[np.ndarray, np.ndarray]:
+    """((N, R) capacity, (N, R) committed+reserved demand) as the server
+    records state them, float64, in the records' order — the ONE
+    definition of 'how much of this node is spoken for' (admission
+    inventory and churn capacity refresh alike): one pass that gathers
+    the records' numbers, one array, no numpy call per server."""
+    cols = np.array(
+        [(c.cpu, c.memory, c.disk, a.cpu, a.memory, a.disk,
+          a.reserved_cpu, a.reserved_memory, a.reserved_disk)
+         for c, a in [(s.capacity, s.allocated) for s in servers]],
+        dtype=np.float64).reshape(len(servers), 9)
+    return cols[:, 0:3], cols[:, 3:6] + cols[:, 6:9]
+
+
 def _alloc_vector(s: Server) -> np.ndarray:
-    """(R,) committed+reserved demand recorded on a server record — the ONE
-    definition of 'how much of this node is spoken for' (used by admission
-    inventory and churn capacity refresh alike)."""
-    return np.array([s.allocated.cpu + s.allocated.reserved_cpu,
-                     s.allocated.memory + s.allocated.reserved_memory,
-                     s.allocated.disk + s.allocated.reserved_disk],
-                    dtype=np.float64)
+    """(R,) committed+reserved demand recorded on one server record: its
+    row of `_booked_columns`."""
+    return _booked_columns([s])[1][0]
 
 
-def _server_to_resource(s: Server) -> ServerResource:
-    return ServerResource(
-        name=s.slug,
-        capacity=ResourceSpec(cpu=s.capacity.cpu, memory=s.capacity.memory,
-                              disk=s.capacity.disk),
-        labels=ServerLabels(tier=s.labels.tier, region=s.labels.region,
-                            clazz=s.labels.clazz, arch=s.labels.arch,
-                            extra=dict(s.labels.extra)),
-    )
+def _by_slug(slugs: list[str], by_slug: dict[str, np.ndarray]) -> np.ndarray:
+    """(len(slugs), R) float64: `by_slug`'s (R,) vector in the row of each
+    slug that has one, zero elsewhere (a slug `by_slug` names and `slugs`
+    lacks is dropped; one that `slugs` lists twice gets it twice)."""
+    out = np.zeros((len(slugs), 3))
+    if by_slug:
+        hit = [i for i, slug in enumerate(slugs) if slug in by_slug]
+        if hit:
+            out[hit] = np.array([by_slug[slugs[i]] for i in hit])
+    return out
+
+
+# the labels of every node whose record carries none: read, never written
+_UNLABELLED = ServerLabels()
+
+
+def _node(s: Server) -> Node:
+    """The node `lower_stage` sees for a server record: its name and its
+    own copy of the labels (`_UNLABELLED`, shared, where it has none)."""
+    lb = s.labels
+    if (lb.tier is None and lb.region is None and lb.clazz is None
+            and lb.arch is None and not lb.extra):
+        return Node(s.slug, _UNLABELLED)
+    return Node(s.slug, ServerLabels(
+        tier=lb.tier, region=lb.region, clazz=lb.clazz, arch=lb.arch,
+        extra=dict(lb.extra)))
 
 
 class PlacementService:
@@ -302,59 +329,51 @@ class PlacementService:
                    slugs: Optional[list[str]] = None,
                    exclude_demand: Optional[dict[str, np.ndarray]] = None,
                    preemptor: Optional[tuple[str, int]] = None,
-                   ) -> tuple[list[ServerResource], np.ndarray,
+                   ) -> tuple[list[Node], np.ndarray, np.ndarray,
                               Optional[np.ndarray]]:
-        """Live nodes + validity mask, with reserved+committed demand
-        subtracted from capacity.  `exclude_demand` (slug -> (R,)) is
+        """Live nodes, what is free on each ((N, R) float64: capacity with
+        reserved+committed demand subtracted, clamped at zero) and the
+        validity mask.  `exclude_demand` (slug -> (R,)) is
         demand attributed to the CALLING stage itself (e.g. its own churn
         hold) — excluded BEFORE the zero-clamp, so a deficit against a
         shrunken node cannot turn into phantom free capacity the way a
         post-clamp add-back would.
 
         `preemptor` (stage key, the priority of its lowest row) asks, as
-        the third value, for what committed rows of lower priority hold
+        the last value, for what committed rows of lower priority hold
         on each node beside that: (N, R), what the node's capacity would
         gain were they left out, before the clamp likewise; None where no
         row ranks lower."""
         # a tenant sees its own servers plus the shared "default" pool;
         # "default" solves never touch tenant-dedicated capacity
+        wanted = frozenset(slugs) if slugs else None
         servers = self.store.list(
             "servers", lambda s: s.tenant in (tenant, "default")
-            and (not slugs or s.slug in slugs))
+            and (wanted is None or s.slug in wanted))
         if not servers:
             raise ValueError(f"no servers registered for tenant {tenant!r}")
-        reserved = self._reserved_by_node()
+        names = [s.slug for s in servers]
         pre = None
         if preemptor is not None:
             with phase("cp.solve_stage.preemptible") as ph:
-                pre = self._preemptible_by_node(
-                    *preemptor, [s.slug for s in servers])
+                pre = self._preemptible_by_node(*preemptor, names)
                 if pre is not None:
                     holding = int(pre.any(axis=1).sum())
                     _M_PREEMPTIBLE_SERVERS.inc(holding)
                     ph.set(servers=holding)
-        nodes, valid = [], []
-        # free capacity before the clamp, kept only for `pre`
-        unclamped = None if pre is None else np.empty_like(pre)
-        for i, s in enumerate(servers):
-            res = _server_to_resource(s)
-            alloc = _alloc_vector(s) + reserved.get(s.slug, 0)
-            if exclude_demand:
-                alloc = alloc - exclude_demand.get(s.slug, 0)
-            free = np.array(res.capacity.as_tuple()) - alloc
-            if unclamped is not None:
-                unclamped[i] = free
-            cap = np.maximum(free, 0.0)
-            res.capacity = ResourceSpec(cpu=float(cap[0]), memory=float(cap[1]),
-                                        disk=float(cap[2]))
-            nodes.append(res)
-            valid.append(s.schedulable)
+        capacity, booked = _booked_columns(servers)
+        # free capacity before the clamp: what the caller calls its own
+        # comes off what is spoken for first, so a deficit on a shrunken
+        # node stays a deficit
+        free = capacity - ((booked + _by_slug(names, self._reserved_by_node()))
+                           - _by_slug(names, exclude_demand or {}))
+        clamped = np.maximum(free, 0.0)
+        valid = np.array([s.schedulable for s in servers], dtype=bool)
         if pre is not None:
             # what each node would gain: the deficit of a shrunken node is
             # taken off it as it is off the node's own capacity
-            pre = (np.maximum(unclamped + pre, 0.0)
-                   - np.maximum(unclamped, 0.0))
-        return nodes, np.array(valid, dtype=bool), pre
+            pre = np.maximum(free + pre, 0.0) - clamped
+        return [_node(s) for s in servers], clamped, valid, pre
 
     def _lower_ranking(self, key: str, p: int
                        ) -> list[tuple[Reservation, np.ndarray]]:
@@ -478,7 +497,7 @@ class PlacementService:
                             default=0)
                     if p > floor:
                         preemptor = (key, p)
-                nodes, valid, preemptible = self._inventory(
+                nodes, free, valid, preemptible = self._inventory(
                     tenant, stage.servers or None,
                     exclude_demand=own_churn, preemptor=preemptor)
                 # Config-declared labels back-fill: agents register slug +
@@ -489,7 +508,8 @@ class PlacementService:
                 # (found by the full-stack smoke: api landed on the standard
                 # node).  Fill per FIELD: only fields the server API has not
                 # set inherit the flow's declaration; API-set fields win.
-                for n in nodes:
+                # A flow that declares no server has nothing to fill.
+                for n in (nodes if flow.servers else ()):
                     decl = flow.servers.get(n.name)
                     if decl is None:
                         continue
@@ -502,7 +522,8 @@ class PlacementService:
                         extra={**d.extra, **got.extra})
                 held = self._held_for_lowering(key)
             with phase("cp.solve_stage.lower"):
-                pt = lower_stage(flow, stage_name, nodes=nodes, held=held)
+                pt = lower_stage(flow, stage_name, nodes=nodes, held=held,
+                                 capacity=free)
                 pt.node_valid &= valid
                 if preemptible is not None and not self._fits_free(pt):
                     # a row that fits on no server as it is: the stage
@@ -641,11 +662,12 @@ class PlacementService:
             # from inventory like solve_stage excludes its churn hold,
             # or the adopted placement double-counts itself
             exclude = dict(committed.demand_by_node) if committed else None
-            nodes, valid, _ = self._inventory(
+            nodes, free, valid, _ = self._inventory(
                 tenant, flow.stage(stage_name).servers or None,
                 exclude_demand=exclude)
             pt = lower_stage(flow, stage_name, nodes=nodes,
-                             held=self._held_for_lowering(stage_key))
+                             held=self._held_for_lowering(stage_key),
+                             capacity=free)
             pt.node_valid &= valid
             node_idx = {n: i for i, n in enumerate(pt.node_names)}
             raw = np.zeros(pt.S, dtype=np.int64)
@@ -1226,11 +1248,10 @@ class PlacementService:
         committed allocation plus any of its own IN-FLIGHT reservations
         (a churn re-solve racing the stage's deploy window must not
         double-count the stage against itself)."""
-        out: dict[str, np.ndarray] = {}
         c = self._committed.get(key)
-        if c is not None:
-            for slug, d in c.demand_by_node.items():
-                out[slug] = out.get(slug, 0) + d
+        # the commitment's own vectors, shared: read, never written
+        out: dict[str, np.ndarray] = (dict(c.demand_by_node)
+                                      if c is not None else {})
         for r in self._reservations.values():
             if r.stage_key == key and not r.committed:
                 for slug, d in r.demand_by_node.items():
@@ -1255,24 +1276,20 @@ class PlacementService:
         when the caller already holds one.  Returns pt unchanged (same
         object, so device stagings keyed on identity stay warm) when
         nothing moved; otherwise a copy with fresh capacity."""
-        own = self._stage_demand(key)
-        reserved = self._reserved_by_node()
-        other = [snap for okey, snap in (overrides or {}).items()
-                 if okey != key]
+        get = (server_map.get if server_map is not None
+               else self.store.server_by_slug)
+        records = [get(slug) for slug in pt.node_names]
+        at = [j for j, s in enumerate(records) if s is not None]
+        names = [pt.node_names[j] for j in at]
+        capacity, booked = _booked_columns([records[j] for j in at])
+        alloc = (booked + _by_slug(names, self._reserved_by_node())
+                 - _by_slug(names, self._stage_demand(key)))
+        for okey, (old_dem, new_dem) in (overrides or {}).items():
+            if okey != key:
+                alloc = (alloc - _by_slug(names, old_dem)
+                         + _by_slug(names, new_dem))
         cap = pt.capacity.copy()
-        for j, slug in enumerate(pt.node_names):
-            s = (server_map.get(slug) if server_map is not None
-                 else self.store.server_by_slug(slug))
-            if s is None:
-                continue
-            alloc = (_alloc_vector(s) + reserved.get(slug, 0)
-                     - own.get(slug, 0))
-            for old_dem, new_dem in other:
-                alloc = (alloc - old_dem.get(slug, 0)
-                         + new_dem.get(slug, 0))
-            raw = np.array([s.capacity.cpu, s.capacity.memory,
-                            s.capacity.disk], dtype=np.float64)
-            cap[j] = np.maximum(raw - alloc, 0.0)
+        cap[at] = np.maximum(capacity - alloc, 0.0)
         if np.array_equal(cap, pt.capacity):
             return pt
         return _dc_replace(pt, capacity=cap)

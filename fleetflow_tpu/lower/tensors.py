@@ -58,17 +58,17 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Sequence, Union
 
 import numpy as np
 
 from ..core.errors import SolverError
 from ..core.model import (ServiceType, Flow, PlacementPolicy, PlacementStrategy,
-                          ResourceSpec, ServerResource, Service)
+                          ResourceSpec, ServerLabels, ServerResource, Service)
 from ..obs.metrics import REGISTRY
 
-__all__ = ["ProblemTensors", "lower_stage", "bar_held", "with_preemptible",
-           "dependency_depths",
+__all__ = ["ProblemTensors", "Node", "lower_stage", "bar_held",
+           "with_preemptible", "dependency_depths",
            "LOCAL_NODE_NAME", "local_node", "synthetic_problem"]
 
 # metric catalog: docs/guide/10-observability.md
@@ -236,8 +236,21 @@ def _pad_ids(groups: list[list[int]], pad_to_multiple: int = 1) -> np.ndarray:
     return out
 
 
+class Node:
+    """What `lower_stage` reads of a node whose capacity it is handed as an
+    array: its name and its labels. cp/placement.py builds one a
+    registered server for every solve against live inventory, where a
+    `ServerResource` (a declaration: provider, plan, ssh keys, DNS) is ten
+    container objects to carry these two."""
+    __slots__ = ("name", "labels")
+
+    def __init__(self, name: str, labels: ServerLabels):
+        self.name = name
+        self.labels = labels
+
+
 def _server_matches(policy: Optional[PlacementPolicy],
-                    server: ServerResource) -> bool:
+                    server: Union[ServerResource, Node]) -> bool:
     if policy is None:
         return True
     labels = server.labels.as_dict()
@@ -250,7 +263,7 @@ def _server_matches(policy: Optional[PlacementPolicy],
 
 
 def _preference_row(policy: Optional[PlacementPolicy],
-                    server: ServerResource) -> float:
+                    server: Union[ServerResource, Node]) -> float:
     if policy is None or not policy.preferred_labels:
         return 0.0
     labels = server.labels.as_dict()
@@ -337,10 +350,11 @@ def with_preemptible(pt: ProblemTensors,
 
 
 def lower_stage(flow: Flow, stage_name: str,
-                nodes: Optional[list[ServerResource]] = None,
+                nodes: Optional[Sequence[Union[ServerResource, Node]]] = None,
                 local: bool = False,
                 held: Optional[dict[str, list[str]]] = None,
                 preemptible: Optional[np.ndarray] = None,
+                capacity: Optional[np.ndarray] = None,
                 ) -> ProblemTensors:
     """Lower one stage of a Flow into ProblemTensors.
 
@@ -354,6 +368,11 @@ def lower_stage(flow: Flow, stage_name: str,
     (module docstring, Priority): capacity grows by it
     (`with_preemptible`). None or all zero lowers the same tensors as
     without the argument.
+
+    `capacity` ((N, R), in the order of `nodes`) is what is free on each
+    node, for a caller that has it as an array (cp/placement.py: capacity
+    less what is spoken for); it takes the place of each node's own
+    `capacity`, which is then not read. None reads the nodes'.
 
     Node set: explicit `nodes` arg > stage.servers > all flow.servers > a
     single implicit "local" node with generous capacity (the `fleet up local`
@@ -437,7 +456,9 @@ def lower_stage(flow: Flow, stage_name: str,
     base_demand = np.array([s.resources.as_tuple() for s in services],
                            dtype=np.float32).reshape(len(services), _R)
     demand = np.repeat(base_demand, reps_arr, axis=0)
-    capacity = np.array([n.capacity.as_tuple() for n in nodes], dtype=np.float32)
+    if capacity is None:
+        capacity = [n.capacity.as_tuple() for n in nodes]
+    capacity = np.array(capacity, dtype=np.float32).reshape(N, _R)
 
     # ---- dependency DAG over expanded rows ---------------------------------
     # edge endpoints are COLLECTED in python (dict lookups) but written to
