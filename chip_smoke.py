@@ -15,7 +15,10 @@ through the objects the product itself builds, at the BASELINE.json sizes:
   admit   AdmissionController on that stage: arrivals + departures drained
           to empty through admit_batch
   pod     >= 2 devices only: a stage that routes to the mesh by its own size
-          (100,000 x 1,000), then two warm reschedules
+          (100,000 x 1,000), then two warm reschedules — through the
+          scheduler alone. The measured one is the benchmark's cell
+          `pod100kx1k.node-churn-moved`: the same size through the CP,
+          every op timed and checked (PERF.md §4-5)
 
 FLEET_TRANSFER_GUARD=disallow is set for the whole run. Every phase is
 checked by the host oracle (solver/repair.verify) and by the counters that

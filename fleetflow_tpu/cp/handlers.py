@@ -25,12 +25,18 @@ from .models import (BuildJob, BuildStatus, CostEntry, Deployment,
                      DeploymentStatus, DnsRecord, ObservedContainer, Project,
                      Server, ServerCapacity, Tenant, TenantUser,
                      VolumeRecord, VolumeSnapshot, WorkerPool, now_ts)
-from .protocol import Connection, ProtocolServer
+from .protocol import Connection, ProtocolServer, Reply
 
 if TYPE_CHECKING:
     from .server import AppState
 
-__all__ = ["register_all", "check_all_servers", "dns_sync"]
+__all__ = ["register_all", "check_all_servers", "dns_sync",
+           "NODE_EVENTS_REPLY_FORMS"]
+
+# what `placement.node_events` / `node_event` answer, by the request's
+# "reply": each re-solved stage's whole assignment (the default), or only
+# the rows the burst moved (docs/guide/03)
+NODE_EVENTS_REPLY_FORMS = ("assignment", "moved")
 
 _log = get_logger("cp.deploy")
 
@@ -43,6 +49,10 @@ _M_REQUEST_S = REGISTRY.histogram(
 _M_REQUEST_ERRORS = REGISTRY.counter(
     "fleet_cp_request_errors_total",
     "Channel RPC handlers that raised, by channel", labels=("channel",))
+_M_REPLY_ROWS = REGISTRY.counter(
+    "fleet_placement_reply_rows_total",
+    "Rows carried by placement.node_event(s) replies, by reply form",
+    labels=("form",))
 
 
 def check_all_servers(state: "AppState") -> dict:
@@ -1014,6 +1024,34 @@ async def _execute_deploy(state: "AppState", req: DeployRequest,
 # placement channel (TPU solver surface — no reference analog)
 # --------------------------------------------------------------------------
 
+async def _churn_reply(state: "AppState", p: dict,
+                       events: list[tuple[str, bool]]) -> Reply:
+    """Run one churn burst and answer it in the form the request names
+    (NODE_EVENTS_REPLY_FORMS). The client chooses: a reply that carries
+    every row outgrows a frame with the stage, one that carries what
+    moved grows with the burst."""
+    form = p.get("reply", NODE_EVENTS_REPLY_FORMS[0])
+    if form not in NODE_EVENTS_REPLY_FORMS:
+        raise ValueError(f"unknown reply form {form!r}; one of "
+                         f"{list(NODE_EVENTS_REPLY_FORMS)}")
+    diff = form == "moved"
+    out = await asyncio.get_running_loop().run_in_executor(
+        None, lambda: state.placement.node_events(events, diff=diff))
+    if diff:
+        rescheduled = [{"stage": key, "feasible": pl.feasible,
+                        "rows": len(pl.assignment), "moved": moved}
+                       for key, pl, moved in out]
+        carried = sum(len(r["moved"]) for r in rescheduled)
+    else:
+        rescheduled = [{"stage": key, "assignment": pl.assignment,
+                        "feasible": pl.feasible} for key, pl in out]
+        carried = sum(len(r["assignment"]) for r in rescheduled)
+    _M_REPLY_ROWS.inc(carried, form=form)
+    other = "assignment" if diff else "moved"
+    return Reply({"rescheduled": rescheduled},
+                 if_too_large=f'the other form is "reply": "{other}"')
+
+
 def _placement(state: "AppState"):
     async def handle(conn: Connection, method: str, p: dict) -> dict:
         if method == "solve":
@@ -1037,22 +1075,13 @@ def _placement(state: "AppState"):
                                 if rid else [])}
         if method == "node_event":
             slug, online = _require(p, "slug", "online")
-            moved = await asyncio.get_running_loop().run_in_executor(
-                None, lambda: state.placement.node_event(
-                    slug, online=bool(online)))
-            return {"rescheduled": [
-                {"stage": key, "assignment": pl.assignment,
-                 "feasible": pl.feasible} for key, pl in moved]}
+            return await _churn_reply(state, p, [(slug, bool(online))])
         if method == "node_events":
             # coalesced burst: [{"slug": ..., "online": bool}, ...] -> ONE
             # warm re-solve per affected stage against the final mask
             (raw,) = _require(p, "events")
-            events = [(e["slug"], bool(e["online"])) for e in raw]
-            moved = await asyncio.get_running_loop().run_in_executor(
-                None, lambda: state.placement.node_events(events))
-            return {"rescheduled": [
-                {"stage": key, "assignment": pl.assignment,
-                 "feasible": pl.feasible} for key, pl in moved]}
+            return await _churn_reply(
+                state, p, [(e["slug"], bool(e["online"])) for e in raw])
         if method == "commit":
             rid = p.get("reservation", "")
             ok = state.placement.commit(rid)

@@ -200,6 +200,21 @@ def _by_slug(slugs: list[str], by_slug: dict[str, np.ndarray]) -> np.ndarray:
     return out
 
 
+def _moved_rows(pt: ProblemTensors, before: Placement,
+                after: Placement) -> dict[str, str]:
+    """{row: server} of `after` for the rows whose server differs from
+    `before`'s — two placements of the one problem `pt`: one pass over the
+    raw assignments, and names only for what moved. A row `after`'s public
+    assignment lacks (a streaming tombstone) is not reported."""
+    new = np.asarray(after.raw)
+    moved = {pt.service_names[i]: pt.node_names[new[i]]
+             for i in np.flatnonzero(new != np.asarray(before.raw)).tolist()}
+    if len(after.assignment) != len(new):
+        moved = {row: node for row, node in moved.items()
+                 if row in after.assignment}
+    return moved
+
+
 # the labels of every node whose record carries none: read, never written
 _UNLABELLED = ServerLabels()
 
@@ -1317,26 +1332,33 @@ class PlacementService:
             k: pt.held.get(k, []) + held.get(k, [])
             for k in pt.held.keys() | held.keys()})
 
-    def node_event(self, slug: str, *, online: bool) -> list[tuple[str, Placement]]:
+    def node_event(self, slug: str, *, online: bool,
+                   diff: bool = False) -> list[tuple]:
         """Churn: flip the node's validity and warm-start re-solve every
-        stage that had services there. Returns [(stage_key, new placement)].
-        Device masks update as a small delta; the solver's migration
-        stickiness keeps unaffected services in place."""
-        return self.node_events([(slug, online)])
+        stage that had services there. Returns [(stage_key, new placement)]
+        (`diff`: as in node_events). Device masks update as a small delta;
+        the solver's migration stickiness keeps unaffected services in
+        place."""
+        return self.node_events([(slug, online)], diff=diff)
 
-    def node_events(self, events: list[tuple[str, bool]]
-                    ) -> list[tuple[str, Placement]]:
+    def node_events(self, events: list[tuple[str, bool]], *,
+                    diff: bool = False) -> list[tuple]:
         """Coalesced churn (VERDICT r3 item 5): apply EVERY validity flip
         of a burst first, then warm re-solve each affected stage ONCE
         against the final mask — a 3-dead-1-revived burst costs one
         re-solve per stage, not four, and the solver sees the true final
         world instead of three intermediate ones (sequential re-solves can
-        bounce services onto a node that the next event kills)."""
-        with phase("cp.node_events", events=len(events)):
-            return self._node_events(events)
+        bounce services onto a node that the next event kills).
 
-    def _node_events(self, events: list[tuple[str, bool]]
-                     ) -> list[tuple[str, Placement]]:
+        With `diff` each entry is (stage_key, new placement, moved): the
+        rows whose server differs from the retained placement the burst
+        started from, as {row: server} — what a client that holds that
+        placement needs to hold the new one."""
+        with phase("cp.node_events", events=len(events)):
+            return self._node_events(events, diff)
+
+    def _node_events(self, events: list[tuple[str, bool]],
+                     diff: bool) -> list[tuple]:
         with phase("cp.node_events.mark"):
             for slug, online in events:
                 s = self.store.server_by_slug(slug)
@@ -1344,7 +1366,7 @@ class PlacementService:
                     self.store.update(
                         "servers", s.id,
                         status="online" if online else "offline")
-        moved: list[tuple[str, Placement]] = []
+        moved: list[tuple] = []
         # stages re-solved earlier in THIS burst -> (stage-demand snapshot,
         # new per-node demand), so later re-solves see them at their new
         # homes instead of their stale store records (double-booking the
@@ -1468,5 +1490,11 @@ class PlacementService:
                         # new_dem, cancelling the reservation they also see
                         # in _reserved_by_node
                         overrides[key] = (self._stage_demand(key), new_dem)
-                moved.append((key, new))
+                if diff:
+                    with phase("cp.node_events.diff", stage=key) as ph_diff:
+                        changed = _moved_rows(pt, placement, new)
+                        ph_diff.set(rows=len(changed))
+                    moved.append((key, new, changed))
+                else:
+                    moved.append((key, new))
         return moved

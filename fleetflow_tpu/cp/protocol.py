@@ -35,13 +35,22 @@ from ..obs import get_logger, kv, phase
 log = get_logger("cp.protocol")
 
 __all__ = ["Connection", "ProtocolServer", "ProtocolClient", "RpcError",
-           "MAX_FRAME"]
+           "Reply", "MAX_FRAME"]
 
 MAX_FRAME = 1 << 20
 
 
 class RpcError(ControlPlaneError):
     pass
+
+
+class Reply(dict):
+    """A handler's payload that can outgrow a frame: should it, the peer
+    is sent `if_too_large` with the error (what to ask for instead)."""
+
+    def __init__(self, payload: dict, *, if_too_large: str):
+        super().__init__(payload)
+        self.if_too_large = if_too_large
 
 
 async def read_frame(reader: asyncio.StreamReader) -> Optional[dict]:
@@ -107,9 +116,12 @@ class Connection:
         return task
 
     async def _send(self, msg: dict) -> None:
+        await self._write(encode_frame(msg))
+
+    async def _write(self, frame: bytes) -> None:
         if self._closed:
             raise RpcError("connection closed")
-        self.writer.write(encode_frame(msg))
+        self.writer.write(frame)
         await self.writer.drain()
 
     async def request(self, channel: str, method: str, payload: dict | None = None,
@@ -172,7 +184,17 @@ class Connection:
             except Exception as e:  # handler errors become remote RpcErrors
                 resp["error"] = f"{type(e).__name__}: {e}"
         try:
-            await self._send(resp)
+            frame = encode_frame(resp)
+        except RpcError as e:
+            # a reply over MAX_FRAME: the peer hears why now, as an error
+            # reply, and does not wait out its request's timeout
+            advice = getattr(resp.get("payload"), "if_too_large", "")
+            frame = encode_frame({
+                "type": "response", "id": msg.get("id"),
+                "error": f"RpcError: reply to {channel}.{method}: {e}"
+                         + (f"; {advice}" if advice else "")})
+        try:
+            await self._write(frame)
         except (RpcError, ConnectionResetError):
             pass
 

@@ -82,6 +82,17 @@ __all__ = ["anneal_sharded", "pad_problem", "shard_problem",
 SVC_AXIS = "svc"
 REPLICA_AXIS = "replica"
 
+# named scopes of the shard_map body, as a profiler trace and the lowered
+# text show them (docs/guide/10): the parts a sweep of the single-chip
+# annealer has too, plus the two that exist only across chips
+SCOPE = "fleet/sharded."
+SCOPES = ("propose",    # draw M moves a shard, price and accept them
+          "winner",     # one move a service, then one a target node (pmin)
+          "reduce",     # the applied deltas summed over the svc axis (psum)
+          "score",      # violations + soft of the state, best-ever kept
+          "exchange",   # replica lanes trade whole states (ppermute)
+          "exit")       # the adaptive exit's predicate, pmin over lanes
+
 # temperature ratio between neighboring tempering lanes: best of {1.3, 1.6,
 # 2.0, 3.0} on the partitioned-seed curve
 TEMPER_LADDER = 1.3
@@ -440,6 +451,33 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
              best_assign, best_viol, best_soft) = carry
             temp = t0 * decay ** i.astype(jnp.float32) * lad_f
             key = jax.random.fold_in(key, i)
+            with jax.named_scope(SCOPE + "propose"):
+                s_idx, b_idx, a_idx, accept = propose(
+                    assign, load, used, coloc, topo, key, temp)
+            with jax.named_scope(SCOPE + "winner"):
+                applied = elect(s_idx, b_idx, accept)
+            with jax.named_scope(SCOPE + "reduce"):
+                assign, load, used, coloc, topo = apply_moves(
+                    assign, load, used, coloc, topo, s_idx, a_idx, b_idx,
+                    applied)
+            with jax.named_scope(SCOPE + "score"):
+                # Best-ever tracking, lexicographic (violations, soft) —
+                # the same monotonicity contract as the single-device
+                # anneal: a sweep budget that ENDS on an uphill Metropolis
+                # state must not discard a better state it walked through.
+                # Both scalars are replicated (psums), so the update is
+                # identical on every shard.
+                vt = viol_total(assign, load, used, topo)
+                sf = soft_here(assign, load, coloc)
+                better = ((vt < best_viol)
+                          | ((vt == best_viol) & (sf < best_soft)))
+                best_viol = jnp.where(better, vt, best_viol)
+                best_soft = jnp.where(better, sf, best_soft)
+                best_assign = jnp.where(better, assign, best_assign)
+            return (assign, load, used, coloc, topo, key,
+                    best_assign, best_viol, best_soft), None
+
+        def propose(assign, load, used, coloc, topo, key, temp):
             kk = jax.random.fold_in(key, me)   # decorrelate shards
             if has_rep:
                 kk = jax.random.fold_in(kk, rep)   # ...and replica lanes
@@ -466,7 +504,9 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
             accept = ((delta < 0)
                       | (u < jnp.exp(-delta / jnp.maximum(temp, 1e-8)))) \
                 & (a_idx != b_idx)
+            return s_idx, b_idx, a_idx, accept
 
+        def elect(s_idx, b_idx, accept):
             order = jnp.arange(M, dtype=jnp.int32)
             winner = jnp.full((S_loc,), M, dtype=jnp.int32).at[s_idx].min(
                 jnp.where(accept, order, M))
@@ -477,8 +517,10 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
             rank = jnp.where(cand, order + M * me, M * D)
             node_best = jnp.full((N,), M * D, jnp.int32).at[b_idx].min(rank)
             node_best = jax.lax.pmin(node_best, SVC_AXIS)
-            applied = cand & (node_best[b_idx] == rank)
+            return cand & (node_best[b_idx] == rank)
 
+        def apply_moves(assign, load, used, coloc, topo, s_idx, a_idx, b_idx,
+                        applied):
             w = applied.astype(jnp.float32)
             wi = applied.astype(jnp.int32)
             d = demand[s_idx]
@@ -516,21 +558,7 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
             tgt = jnp.where(applied, s_idx, S_loc)
             assign = jnp.zeros((S_loc + 1,), jnp.int32).at[:S_loc].set(
                 assign).at[tgt].set(b_idx.astype(jnp.int32))[:S_loc]
-
-            # Best-ever tracking, lexicographic (violations, soft) — the
-            # same monotonicity contract as the single-device anneal: a
-            # sweep budget that ENDS on an uphill Metropolis state must
-            # not discard a better state it walked through. Both scalars
-            # are replicated (psums), so the update is identical on every
-            # shard.
-            vt = viol_total(assign, load, used, topo)
-            sf = soft_here(assign, load, coloc)
-            better = (vt < best_viol) | ((vt == best_viol) & (sf < best_soft))
-            best_viol = jnp.where(better, vt, best_viol)
-            best_soft = jnp.where(better, sf, best_soft)
-            best_assign = jnp.where(better, assign, best_assign)
-            return (assign, load, used, coloc, topo, key,
-                    best_assign, best_viol, best_soft), None
+            return assign, load, used, coloc, topo
 
         def exchange(assign, load, used, coloc, topo, key, b):
             """One replica-exchange round at block boundary `b` (even/odd
@@ -665,28 +693,31 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
                             best_assign, best_viol, best_soft), offsets)
                 if n_rep > 1:
                     ops = (assign, load, used, coloc, topo)
-                    if exchange_every == 1:
-                        out = exchange(*ops, key, b)
-                    else:
-                        # skip the WHOLE round (energy psum + both
-                        # full-state ppermutes) on off blocks — the gate
-                        # is replica-uniform (computed from the carried
-                        # block index), so every lane takes the same
-                        # branch and the collectives stay collective
-                        out = jax.lax.cond(
-                            (b % exchange_every) == (exchange_every - 1),
-                            lambda o: exchange(*o, key, b),
-                            lambda o: o + (zero_i, zero_i), ops)
+                    with jax.named_scope(SCOPE + "exchange"):
+                        if exchange_every == 1:
+                            out = exchange(*ops, key, b)
+                        else:
+                            # skip the WHOLE round (energy psum + both
+                            # full-state ppermutes) on off blocks — the
+                            # gate is replica-uniform (computed from the
+                            # carried block index), so every lane takes
+                            # the same branch and the collectives stay
+                            # collective
+                            out = jax.lax.cond(
+                                (b % exchange_every) == (exchange_every - 1),
+                                lambda o: exchange(*o, key, b),
+                                lambda o: o + (zero_i, zero_i), ops)
                     (assign, load, used, coloc, topo, d_att, d_acc) = out
                     att = att + d_att
                     acc = acc + d_acc
-                g_viol = jax.lax.pmin(best_viol, REPLICA_AXIS)
-                # the lexicographic leader ACROSS lanes (one extra scalar
-                # pmin per block): what the flight deck shows as "the
-                # ladder's best so far"
-                g_soft = jax.lax.pmin(
-                    jnp.where(best_viol == g_viol, best_soft, jnp.inf),
-                    REPLICA_AXIS)
+                with jax.named_scope(SCOPE + "exit"):
+                    g_viol = jax.lax.pmin(best_viol, REPLICA_AXIS)
+                    # the lexicographic leader ACROSS lanes (one extra
+                    # scalar pmin per block): what the flight deck shows
+                    # as "the ladder's best so far"
+                    g_soft = jax.lax.pmin(
+                        jnp.where(best_viol == g_viol, best_soft, jnp.inf),
+                        REPLICA_AXIS)
                 telem = trace_row(
                     telem, b,
                     jnp.minimum((b + 1) * block, steps).astype(jnp.float32),
@@ -898,8 +929,14 @@ def _host_seed(pt, parts: int) -> np.ndarray:
     """Cold host seed for the sharded path: native FFD when the library is
     built (partitioned past the r5 crossover where whole-instance FFD
     dominates), else one minimal pass through the single-chip pipeline."""
-    from ..native.lib import available_nobuild
-    if available_nobuild():
+    from ..native.lib import available, available_nobuild
+    # at the size that routes here by itself the pure-host greedy below is
+    # S x N Python steps — minutes at 100,000 x 1,000 — and a cold seed that
+    # finds no library built (a fresh checkout) is better off paying the
+    # few seconds of its `make` once; below it no solve waits for a
+    # compiler
+    if (available() if pt.S * pt.N >= SHARDED_MIN_CELLS
+            else available_nobuild()):
         if pt.S * pt.N >= 1_000_000:
             from .greedy import partitioned_seed
             return partitioned_seed(pt, max(parts, 1))
