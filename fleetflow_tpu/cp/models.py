@@ -16,8 +16,8 @@ from __future__ import annotations
 import enum
 import time
 import uuid
-from dataclasses import asdict, dataclass, field, fields
-from typing import Optional
+from dataclasses import asdict, dataclass, field, fields, is_dataclass
+from typing import Iterable, Optional
 
 from ..core.model import PlacementPolicy, ResourceSpec  # noqa: F401  (re-export)
 
@@ -30,6 +30,19 @@ __all__ = [
     "BuildStatus", "BuildJob", "CostEntry", "DnsRecord", "ParkedWork",
     "PlacementRecord",
 ]
+
+
+_ATOMS = frozenset({float, int, str, bool, type(None)})
+
+
+def _plain(v) -> dict:
+    """`asdict(v)` of a nested dataclass; one whose values are all atoms
+    (a server's `allocated`, rendered a thousand times a commit) is its
+    own `vars`, without asdict's walk."""
+    d = vars(v)
+    if all(type(x) in _ATOMS for x in d.values()):
+        return dict(d)
+    return asdict(v)
 
 
 def now_ts() -> float:
@@ -55,6 +68,22 @@ class Record:
         for k, v in list(d.items()):
             if isinstance(v, enum.Enum):
                 d[k] = v.value
+        return d
+
+    def fields_dict(self, names: Iterable[str]) -> dict:
+        """`to_dict()` cut to the fields `names`, each rendered as
+        `to_dict` renders it, without rendering the others: what a
+        partial update journals (Store.update_many). The values are the
+        record's own where they are plain — for serialising at once, not
+        for keeping."""
+        d = {}
+        for k in names:
+            v = getattr(self, k)
+            if is_dataclass(v):
+                v = _plain(v)
+            elif isinstance(v, enum.Enum):
+                v = v.value
+            d[k] = v
         return d
 
     @classmethod
@@ -213,7 +242,13 @@ class Server(Record):
     pool: Optional[str] = None
 
     def to_dict(self) -> dict:
-        d = super().to_dict()
+        return self._wire_labels(super().to_dict())
+
+    def fields_dict(self, names: Iterable[str]) -> dict:
+        return self._wire_labels(super().fields_dict(names))
+
+    @staticmethod
+    def _wire_labels(d: dict) -> dict:
         # wire parity with the reference model.rs ("class", a Rust keyword
         # there and a Python keyword here — stored as clazz on both sides)
         lbl = d.get("labels") or {}

@@ -864,32 +864,36 @@ class PlacementService:
                     for vkey, on in r.victims.items()
                     for name, slug in on.items()]
 
-    def _write_allocation(self, slug: str, d) -> int:
-        """Add the cpu/memory/disk vector `d` to one server's `allocated`,
-        clamped at 0, through Store.update (journaled and handed to the
-        replication sink). Returns the server records written: 1, or 0
-        for a slug the store no longer has."""
-        s = self.store.server_by_slug(slug)
-        if s is None:
-            return 0
-        self.store.update("servers", s.id, allocated=type(s.allocated)(
-            cpu=max(s.allocated.cpu + float(d[0]), 0.0),
-            memory=max(s.allocated.memory + float(d[1]), 0.0),
-            disk=max(s.allocated.disk + float(d[2]), 0.0),
-            reserved_cpu=s.allocated.reserved_cpu,
-            reserved_memory=s.allocated.reserved_memory,
-            reserved_disk=s.allocated.reserved_disk,
-        ))
-        return 1
+    def _write_allocations(self, slugs, vectors) -> int:
+        """Add row i of `vectors` ((n, 3): cpu, memory, disk) to server
+        `slugs[i]`'s `allocated`, clamped at 0, in ONE Store.update_many:
+        the new `allocated` of every server goes to the journal and to
+        the replication sink in one `upd` entry, under the store lock,
+        before this returns. Returns the server records written; a slug
+        the store no longer has is skipped."""
+        changes = {}
+        vectors = np.asarray(vectors, dtype=np.float64).tolist()
+        for slug, (cpu, memory, disk) in zip(slugs, vectors):
+            s = self.store.server_by_slug(slug)
+            if s is None:
+                continue
+            a = s.allocated
+            changes[s.id] = {"allocated": type(a)(
+                cpu=max(a.cpu + cpu, 0.0),
+                memory=max(a.memory + memory, 0.0),
+                disk=max(a.disk + disk, 0.0),
+                reserved_cpu=a.reserved_cpu,
+                reserved_memory=a.reserved_memory,
+                reserved_disk=a.reserved_disk,
+            )}
+        return self.store.update_many("servers", changes)
 
     def _apply_allocation(self, r: Reservation, sign: float) -> int:
         """Add (`sign` +1) or return (-1) the whole of `r` on every node
         that carries demand of it. Returns the server records written."""
-        written = 0
-        for slug, dem in r.demand_by_node.items():
-            written += self._write_allocation(
-                slug, sign * np.asarray(dem, dtype=np.float64))
-        return written
+        slugs = list(r.demand_by_node)
+        return self._write_allocations(
+            slugs, sign * _by_slug(slugs, r.demand_by_node))
 
     def _apply_allocation_delta(self, prev: Reservation,
                                 new: Reservation) -> int:
@@ -899,30 +903,27 @@ class PlacementService:
         micro-solve commit per drain tick, cp/admission.py) and
         commit_retained() (the reconverger's commit after churn). Such a
         commit moves a batch's or one dead server's worth of nodes, and
-        a lookup, a write and a serialized journal entry for every server
-        of a 10k-service stage cost more than the solve.
+        a lookup, a write and a journaled record for every server of a
+        10k-service stage cost more than the solve.
 
         Every server's `allocated` ends where apply(prev, -1) +
         apply(new, +1) would leave it, to floating-point rounding (the
         clamp at 0 acts on the same quantity wherever the book is
         consistent: the server still holds what `prev` booked on it), and
-        every changed record goes through Store.update. A record whose
-        value does not change is not rewritten — the same state, with one
-        visible consequence: a server the commit does not touch does not
-        have `updated_at` bumped by it. cp/autoscaler.py ages an OFFLINE
-        server by max(last_heartbeat, updated_at): a dead server is
-        written once, by the commit that moves its rows away, then left
-        alone, which is what the reaper's clock wants."""
-        zero = np.zeros(3)
-        written = 0
-        for slug in set(prev.demand_by_node) | set(new.demand_by_node):
-            d = (np.asarray(new.demand_by_node.get(slug, zero),
-                            dtype=np.float64)
-                 - np.asarray(prev.demand_by_node.get(slug, zero),
-                              dtype=np.float64))
-            if d.any():
-                written += self._write_allocation(slug, d)
-        return written
+        every changed record goes through Store.update_many, in one
+        journal entry. A record whose value does not change is not
+        rewritten — the same state, with one visible consequence: a
+        server the commit does not touch does not have `updated_at`
+        bumped by it. cp/autoscaler.py ages an OFFLINE server by
+        max(last_heartbeat, updated_at): a dead server is written once,
+        by the commit that moves its rows away, then left alone, which
+        is what the reaper's clock wants."""
+        slugs = list(set(prev.demand_by_node) | set(new.demand_by_node))
+        d = (_by_slug(slugs, new.demand_by_node)
+             - _by_slug(slugs, prev.demand_by_node))
+        changed = np.flatnonzero(d.any(axis=1))
+        return self._write_allocations(
+            [slugs[i] for i in changed.tolist()], d[changed])
 
     def _supersede_allocation(self, prev: Optional[Reservation],
                               r: Reservation,
@@ -1082,9 +1083,9 @@ class PlacementService:
                             for i in held_by if i in coming}
                     if mine & set(others.get(k, ())):
                         return 0
+            self._write_allocations([rows.nodes[j] for j in at], back[at])
             for j in at:
                 slug = rows.nodes[j]
-                self._write_allocation(slug, back[j])
                 c.demand_by_node[slug] = (
                     np.asarray(c.demand_by_node.get(slug, 0.0)) + back[j])
             for i, j in zip(idx.tolist(), rows.node_of[idx].tolist()):
@@ -1125,7 +1126,7 @@ class PlacementService:
         that took its rows, not every server of the stage twice. When it
         returns, every server's `allocated` is what subtract-then-add
         would have left (to rounding), each changed record went through
-        Store.update — journaled, replicated — and the placement record
+        Store.update_many — journaled, replicated — and the placement record
         is persisted whole, so a standby or a restart reloads the same
         book: the op is committed before it is acknowledged. Servers the
         commit does not touch keep their `updated_at` (what reads it:

@@ -297,13 +297,15 @@ def test_replication_stream_reproduces_allocated(scenario):
         assert do()
         changed = _changed_nodes(before, _stage_book(cp.svc, key))
         entries = [json.loads(line) for _seq, line in cp.stream[mark:]]
-        assert all(e["op"] == "put" for e in entries)
-        by_table = Counter(e["t"] for e in entries)
-        assert by_table == {"servers": len(changed), "placements": 1}
-        assert ({e["r"]["slug"] for e in entries if e["t"] == "servers"}
-                == changed)
-        placement_rec = next(e["r"] for e in entries
-                             if e["t"] == "placements")
+        # the changed servers' new `allocated` in one `upd` entry, then
+        # the placement record, whole
+        assert [(e["op"], e["t"]) for e in entries] == [
+            ("upd", "servers"), ("put", "placements")]
+        assert all(fields.keys() == {"allocated"}
+                   for fields in entries[0]["u"].values())
+        written = _server_writes(cp.store, cp.stream[mark:])
+        assert len(written) == len(changed) and set(written) == changed
+        placement_rec = entries[1]["r"]
         assert placement_rec["stage_key"] == key
         assert (set(placement_rec["demand_by_node"])
                 == set(_stage_book(cp.svc, key)))
@@ -408,10 +410,19 @@ def _committed_json(cp: _Cp) -> list[str]:
     return out
 
 
-def _server_puts(stream) -> list[str]:
-    entries = [json.loads(line) for _seq, line in stream]
-    return [e["r"]["slug"] for e in entries
-            if e["t"] == "servers" and e["op"] == "put"]
+def _server_writes(store, stream) -> list[str]:
+    """The slug of every server record written in `stream`, in the order
+    the journal holds them: a `put`'s record, each id of an `upd` entry."""
+    out = []
+    for _seq, line in stream:
+        e = json.loads(line)
+        if e["t"] != "servers":
+            continue
+        if e["op"] == "put":
+            out.append(e["r"]["slug"])
+        elif e["op"] == "upd":
+            out.extend(store.get("servers", rid).slug for rid in e["u"])
+    return out
 
 
 def test_a_300_row_stage_keeps_the_row_loops_book():
@@ -428,7 +439,7 @@ def test_a_300_row_stage_keeps_the_row_loops_book():
         mark = len(side.stream)         # past the servers' own creation
         placement, rid = side.svc.solve_stage(side.flow, "live")
         assert placement.feasible and side.svc.commit(rid)
-        firsts.append(_server_puts(side.stream[mark:]))
+        firsts.append(_server_writes(side.store, side.stream[mark:]))
     first = firsts[0]
     assert first == firsts[1]
     # a first commitment writes each server once, at its first live row
@@ -452,8 +463,8 @@ def test_a_300_row_stage_keeps_the_row_loops_book():
     mark = len(cp.stream), len(ref.stream)
     assert cp.svc.commit_retained("p/live")
     assert ref.svc.commit_retained("p/live")
-    assert (_server_puts(cp.stream[mark[0]:])
-            == _server_puts(ref.stream[mark[1]:]) != [])
+    assert (_server_writes(cp.store, cp.stream[mark[0]:])
+            == _server_writes(ref.store, ref.stream[mark[1]:]) != [])
     assert cp.allocated() == ref.allocated()        # exact, not approx
     assert cp.allocated()[victim] == (0.0, 0.0, 0.0)
     assert _committed_json(cp) == _committed_json(ref) != []

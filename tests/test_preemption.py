@@ -18,6 +18,7 @@ from __future__ import annotations
 import asyncio
 import copy
 import dataclasses
+import json
 
 import numpy as np
 import pytest
@@ -177,10 +178,13 @@ def test_commit_is_read_back_from_the_store(use_tpu):
         left = sum(1 for s in c.record(INIT).assignment.values()
                    if s == slug)
         assert np.isclose(dem[0], 0.9 * left, rtol=1e-5)
-    # one journaled write a touched server, and both placement records
-    written = [e for _seq, e in c.stream[mark:]]
-    assert sum('"servers"' in e for e in written) == len(touched)
-    assert sum('"placements"' in e for e in written) == 2
+    # one journaled write a touched server — each once in the commit's
+    # `upd` entries — and both placement records
+    written = [json.loads(e) for _seq, e in c.stream[mark:]]
+    servers = [c.store.get("servers", rid).slug for e in written
+               if e["t"] == "servers" for rid in e["u"]]
+    assert sorted(servers) == sorted(touched)
+    assert sum(e["t"] == "placements" for e in written) == 2
     found = ref.check(c.model, {INIT: c.init,
                                 MEASURED: placement.assignment}, victims)
     assert found["total"] == 0, found
