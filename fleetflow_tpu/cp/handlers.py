@@ -17,7 +17,7 @@ from typing import TYPE_CHECKING
 from ..core.serialize import flow_from_dict
 from ..obs import get_logger, phase, span
 from ..obs.metrics import REGISTRY
-from ..obs.trace import new_trace_id, use_trace
+from ..obs.trace import bound, current_trace_id, new_trace_id, use_trace
 from ..runtime.engine import DeployEngine, DeployRequest
 from .agent_registry import BUILD_TIMEOUT, DEPLOY_TIMEOUT
 from .log_router import LogEntry, topic_for
@@ -440,8 +440,8 @@ def _server(state: "AppState"):
             if ok and method == "shutdown":
                 db.update("servers", s.id, status="offline")
                 await loop.run_in_executor(
-                    None, lambda: state.placement.node_event(slug,
-                                                             online=False))
+                    None, bound(state.placement.node_event, slug,
+                                online=False))
             return {"ok": bool(ok), "instance": match.id}
         if method == "check_all":
             return check_all_servers(state)
@@ -507,7 +507,7 @@ def _server(state: "AppState"):
             # warm re-solve of affected stages runs off-loop (the JAX solve
             # would otherwise block every heartbeat/RPC for its duration)
             await loop.run_in_executor(
-                None, lambda: state.placement.node_event(slug, online=False))
+                None, bound(state.placement.node_event, slug, online=False))
             return {"ok": True}
         if method == "pool.create":
             (name,) = _require(p, "name")
@@ -753,8 +753,8 @@ def _deploy(state: "AppState"):
                 flow = flow_from_dict(p["flow"])
                 key = f"{flow.name}/{stage}"
                 await loop.run_in_executor(
-                    None, lambda: adm.attach(
-                        flow, stage, tenant=p.get("tenant", "default")))
+                    None, bound(adm.attach, flow, stage,
+                                tenant=p.get("tenant", "default")))
                 stage = key
             return await loop.run_in_executor(
                 None, lambda: adm.submit(
@@ -905,11 +905,11 @@ async def execute_deploy(state: "AppState", req: DeployRequest,
     the request, so redeploy can replay it), solve placement, fan out to
     every connected stage agent (or run CP-locally), finish the record.
 
-    The whole path runs inside ONE trace: minted here (or adopted from the
-    CLI's request), carried to every agent via DeployRequest.trace_id, so
-    the CP span, each agent's engine spans, and all their log lines share
-    a trace_id end to end."""
-    req.trace_id = req.trace_id or new_trace_id()
+    The whole path runs inside ONE trace: the request's own id (the
+    CLI's), else the one its frame carried, else minted here; carried to
+    every agent via DeployRequest.trace_id, so the CP span, each agent's
+    engine spans, and all their log lines share a trace_id end to end."""
+    req.trace_id = req.trace_id or current_trace_id() or new_trace_id()
     with use_trace(req.trace_id):
         with span(_log, "deploy.execute", project=req.flow.name,
                   stage=req.stage_name, tenant=tenant_name) as sp:
@@ -959,9 +959,9 @@ async def _execute_deploy(state: "AppState", req: DeployRequest,
             # (handlers/deploy.rs:386-398); the placement solve makes
             # per-node slices explicit, so we send each agent its own.
             placement, rid = await asyncio.get_running_loop(
-                ).run_in_executor(None, lambda: state.placement
-                                  .solve_stage(req.flow, req.stage_name,
-                                               tenant=tenant.name))
+                ).run_in_executor(None, bound(
+                    state.placement.solve_stage, req.flow, req.stage_name,
+                    tenant=tenant.name))
             if not placement.feasible:
                 raise ValueError(
                     f"placement infeasible: {placement.violations}")
@@ -1004,7 +1004,7 @@ async def _execute_deploy(state: "AppState", req: DeployRequest,
             engine = DeployEngine(state.backend_factory(),
                                   sleep=state.deploy_sleep)
             res = await asyncio.get_running_loop().run_in_executor(
-                None, lambda: engine.execute(req))
+                None, bound(engine.execute, req))
             if not res.ok:
                 raise ValueError(f"failed services: {res.failed}")
             log = f"deployed {len(res.deployed)} containers locally"
@@ -1036,7 +1036,7 @@ async def _churn_reply(state: "AppState", p: dict,
                          f"{list(NODE_EVENTS_REPLY_FORMS)}")
     diff = form == "moved"
     out = await asyncio.get_running_loop().run_in_executor(
-        None, lambda: state.placement.node_events(events, diff=diff))
+        None, bound(state.placement.node_events, events, diff=diff))
     if diff:
         rescheduled = [{"stage": key, "feasible": pl.feasible,
                         "rows": len(pl.assignment), "moved": moved}
@@ -1058,10 +1058,12 @@ def _placement(state: "AppState"):
             flow = flow_from_dict(p["flow"])
             # executor: a fleet-scale solve must not stall heartbeats and
             # command_result traffic on the loop (PlacementService locks
-            # with threading.Lock, so it is thread-safe)
+            # with threading.Lock, so it is thread-safe); `bound` carries
+            # the handler's phase and trace id into the pool thread
             placement, rid = await asyncio.get_running_loop().run_in_executor(
-                None, lambda: state.placement.solve_stage(
-                    flow, p["stage"], tenant=p.get("tenant", "default"),
+                None, bound(
+                    state.placement.solve_stage, flow, p["stage"],
+                    tenant=p.get("tenant", "default"),
                     reserve=p.get("reserve", False)))
             return {"assignment": placement.assignment,
                     "feasible": placement.feasible,
@@ -1096,8 +1098,8 @@ def _placement(state: "AppState"):
             stage, service = _require(p, "stage", "service")
             try:
                 return await asyncio.get_running_loop().run_in_executor(
-                    None, lambda: state.placement.explain(
-                        stage, service, top_k=int(p.get("top_k", 5))))
+                    None, bound(state.placement.explain, stage, service,
+                                top_k=int(p.get("top_k", 5))))
             except KeyError as e:
                 raise ValueError(str(e)) from None
         if method == "reservations":
@@ -1105,7 +1107,7 @@ def _placement(state: "AppState"):
             # a fleet-scale solve can hold for its full duration — same
             # off-loop rule as solve/node_events above
             return await asyncio.get_running_loop().run_in_executor(
-                None, state.placement.reservations_snapshot)
+                None, bound(state.placement.reservations_snapshot))
         raise ValueError(f"unknown method placement.{method}")
     return handle
 

@@ -31,6 +31,7 @@ docstring has the rules).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 from dataclasses import dataclass, field, replace as _dc_replace
@@ -435,6 +436,19 @@ class PlacementService:
                 out[node] = out.get(node, 0) + dem
         return out
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """`self._lock`, with the wait for it written as the phase
+        `cp.wait.placement_lock`: entered after the caller's own phase
+        (`with phase("cp.commit"), self._locked():`), so the wait is that
+        phase's child and not its self time."""
+        with phase("cp.wait.placement_lock"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def _held_by_others(self, key: str) -> dict[str, list[str]]:
         """Conflict key -> servers on which a stage OTHER than `key` holds
         it, over the committed book and every open reservation (churn
@@ -485,7 +499,7 @@ class PlacementService:
         a reservation. Returns (placement, reservation_id)."""
         stage = flow.stage(stage_name)
         key = f"{flow.name}/{stage_name}"
-        with phase("cp.solve_stage", stage=key), self._lock:
+        with phase("cp.solve_stage", stage=key), self._locked():
             with phase("cp.solve_stage.inventory"):
                 # a full re-lower rebuilds the stage from the flow, which the
                 # admission controller keeps tombstone-free
@@ -954,7 +968,7 @@ class PlacementService:
         (2-phase step 2, model.rs:421-427). A redeploy of the same stage
         SUPERSEDES its previous commit — the old containers were stopped and
         replaced, so their allocation is returned first."""
-        with phase("cp.commit"), self._lock:
+        with phase("cp.commit"), self._locked():
             r = self._reservations.pop(rid, None)
             if r is None or r.committed:
                 return False
@@ -1057,7 +1071,7 @@ class PlacementService:
         are then history), or where they no longer fit: capacity taken,
         a server gone, or a conflict key of theirs held by another stage
         on their server."""
-        with phase("cp.reinstate", stage=stage_key), self._lock:
+        with phase("cp.reinstate", stage=stage_key), self._locked():
             c = self._committed.get(stage_key)
             rows = c.rows if c is not None else None
             if rows is None or rows.evicted is None:
@@ -1131,7 +1145,7 @@ class PlacementService:
         book: the op is committed before it is acknowledged. Servers the
         commit does not touch keep their `updated_at` (what reads it:
         _apply_allocation_delta)."""
-        with phase("cp.commit_retained", stage=stage_key), self._lock:
+        with phase("cp.commit_retained", stage=stage_key), self._locked():
             entry = self._last.get(stage_key)
             if entry is None:
                 return False
@@ -1156,7 +1170,7 @@ class PlacementService:
     def release_stage(self, stage_key: str) -> bool:
         """Stage torn down (`fleet down` on a remote stage): return its
         committed capacity."""
-        with self._lock:
+        with self._locked():
             self._drop_churn(stage_key)
             c = self._committed.pop(stage_key, None)
             if c is None:
@@ -1373,7 +1387,7 @@ class PlacementService:
         # homes instead of their stale store records (double-booking the
         # survivor node)
         overrides: dict[str, tuple] = {}
-        with self._lock:
+        with self._locked():
             with phase("cp.node_events.mark"):
                 server_map = {s.slug: s for s in self.store.list("servers")}
             for key, (pt, placement) in list(self._last.items()):

@@ -11,9 +11,14 @@ wrapped in TLS from cp/cert.py:
               "channels":[...]}            client -> server, once
   welcome  = {"type":"welcome","server":str}
   request  = {"type":"request","id":int,"channel":str,"method":str,
-              "payload":{}}
+              "payload":{},"trace":str,"span":str}
   response = {"type":"response","id":int,"payload":{},"error":str|None}
   event    = {"type":"event","channel":str,"method":str,"payload":{}}
+
+`trace` is the sender's trace id (obs.trace) and `span` the phase that sent
+the request, as `<process token>:<id>`: the receiver serves the request
+under that trace and, where the token is its own process's, as a child of
+that phase. Both are optional: a frame without them is served all the same.
 
 Requests flow BOTH ways on a connection (the agent channel is duplex: the
 CP sends commands to agents, handlers/agent.rs:129-159), so both endpoints
@@ -26,11 +31,13 @@ import asyncio
 import itertools
 import json
 import ssl
+import time
 from dataclasses import dataclass, field
 from typing import Any, Awaitable, Callable, Optional
 
 from ..core.errors import ControlPlaneError
-from ..obs import get_logger, kv, phase
+from ..obs import get_logger, kv, phase, use_trace
+from ..obs.trace import record_interval, wire_span
 
 log = get_logger("cp.protocol")
 
@@ -53,7 +60,12 @@ class Reply(dict):
         self.if_too_large = if_too_large
 
 
-async def read_frame(reader: asyncio.StreamReader) -> Optional[dict]:
+async def read_frame(reader: asyncio.StreamReader,
+                     whose: Optional[Callable[[dict], tuple]] = None,
+                     ) -> Optional[dict]:
+    """The next frame, decoded; None at a disconnect. `whose(frame)` is
+    the (trace, span) the decode is filed under (Connection._whose): a
+    decode learns whose it is from the frame it decodes."""
     try:
         header = await reader.readexactly(4)
     except (asyncio.IncompleteReadError, ConnectionResetError):
@@ -66,8 +78,11 @@ async def read_frame(reader: asyncio.StreamReader) -> Optional[dict]:
     except (asyncio.IncompleteReadError, ConnectionResetError):
         return None
     # the decode only: the awaits above are the peer's time, not the codec's
-    with phase("protocol.decode", bytes=size):
-        return json.loads(body)
+    with phase("protocol.decode", bytes=size) as ph:
+        msg = json.loads(body)
+        if whose is not None:
+            ph.adopt(*whose(msg))
+        return msg
 
 
 def encode_frame(msg: dict) -> bytes:
@@ -99,6 +114,8 @@ class Connection:
     event_handlers: dict[str, EventHandler] = field(default_factory=dict)
     _ids: itertools.count = field(default_factory=lambda: itertools.count(1))
     _pending: dict[int, asyncio.Future] = field(default_factory=dict)
+    # request id -> (trace, span) of the phase waiting for its reply
+    _callers: dict[int, tuple] = field(default_factory=dict)
     _tasks: set = field(default_factory=set)   # strong refs: loop holds weak
     _closed: bool = False
     on_close: Optional[Callable[["Connection"], Awaitable[None]]] = None
@@ -126,19 +143,27 @@ class Connection:
 
     async def request(self, channel: str, method: str, payload: dict | None = None,
                       timeout: float = 60.0) -> dict:
-        """Id-correlated request; raises RpcError on remote error/timeout."""
+        """Id-correlated request; raises RpcError on remote error/timeout.
+        Phase `protocol.request`, send to reply in hand, under the active
+        trace (a new one where none is): the frame carries both."""
         mid = next(self._ids)
         fut: asyncio.Future = asyncio.get_running_loop().create_future()
         self._pending[mid] = fut
         try:
-            await self._send({"type": "request", "id": mid, "channel": channel,
-                              "method": method, "payload": payload or {}})
-            return await asyncio.wait_for(fut, timeout)
+            with use_trace() as trace, phase("protocol.request",
+                                             channel=channel, method=method):
+                caller = self._callers[mid] = (trace, wire_span())
+                await self._send({
+                    "type": "request", "id": mid, "channel": channel,
+                    "method": method, "payload": payload or {},
+                    "trace": caller[0], "span": caller[1]})
+                return await asyncio.wait_for(fut, timeout)
         except asyncio.TimeoutError:
             raise RpcError(
                 f"request {channel}.{method} timed out after {timeout}s") from None
         finally:
             self._pending.pop(mid, None)
+            self._callers.pop(mid, None)
 
     async def send_event(self, channel: str, method: str,
                          payload: dict | None = None) -> None:
@@ -146,12 +171,22 @@ class Connection:
         await self._send({"type": "event", "channel": channel,
                           "method": method, "payload": payload or {}})
 
+    def _whose(self, msg: dict) -> tuple:
+        """(trace, span) a frame's decode belongs to: a request's own, a
+        response's caller's; (None, None) for anything else."""
+        if isinstance(msg, dict):
+            if msg.get("type") == "request":
+                return msg.get("trace"), msg.get("span")
+            if msg.get("type") == "response":
+                return self._callers.get(msg.get("id"), (None, None))
+        return None, None
+
     async def run(self) -> None:
         """Dispatch loop: route responses to futures, requests to channel
         handlers, events to event handlers. Returns on disconnect."""
         try:
             while True:
-                msg = await read_frame(self.reader)
+                msg = await read_frame(self.reader, self._whose)
                 if msg is None:
                     break
                 t = msg.get("type")
@@ -163,7 +198,7 @@ class Connection:
                         else:
                             fut.set_result(msg.get("payload", {}))
                 elif t == "request":
-                    self._spawn(self._dispatch(msg))
+                    self._spawn(self._dispatch(msg, time.perf_counter()))
                 elif t == "event":
                     handler = self.event_handlers.get(msg.get("channel", ""))
                     if handler is not None:
@@ -172,8 +207,20 @@ class Connection:
         finally:
             await self.close()
 
-    async def _dispatch(self, msg: dict) -> None:
+    async def _dispatch(self, msg: dict, decoded: float) -> None:
+        """Serve one request under the trace and the caller's phase its
+        frame carried: `protocol.wait.dispatch` is the loop's queue, from
+        the frame decoded to this task running; `protocol.serve` the
+        handler, the reply's encoding and its write."""
         channel, method = msg.get("channel", ""), msg.get("method", "")
+        trace, span = msg.get("trace"), msg.get("span")
+        with use_trace(trace if isinstance(trace, str) else None,
+                       span if isinstance(span, str) else None):
+            record_interval("protocol.wait.dispatch", decoded)
+            with phase("protocol.serve", channel=channel, method=method):
+                await self._serve(msg, channel, method)
+
+    async def _serve(self, msg: dict, channel: str, method: str) -> None:
         handler = self.handlers.get(channel)
         resp: dict = {"type": "response", "id": msg.get("id")}
         if handler is None:

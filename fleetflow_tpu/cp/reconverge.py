@@ -52,7 +52,7 @@ from ..core.errors import AgentCommandError, AgentUnreachable
 from ..obs import get_logger, kv, span
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
-from ..obs.trace import new_trace_id, use_trace
+from ..obs.trace import bound, new_trace_id, use_trace
 from ..runtime.engine import DeployRequest
 from .agent_registry import DEPLOY_TIMEOUT
 from .failure_detector import FailureDetector, LeaseEvent
@@ -331,7 +331,7 @@ class Reconverger:
                     moved = await asyncio.get_running_loop(
                         ).run_in_executor(
                             None,
-                            lambda: self.state.placement.node_events(burst))
+                            bound(self.state.placement.node_events, burst))
                 except Exception:
                     # the verdicts are NOT consumed: requeue so the next
                     # step retries them (placement.node_events already
@@ -584,8 +584,8 @@ class Reconverger:
             # reserve=False: commit_retained books the capacity when the
             # redelivery lands, same as the node_events churn path
             await asyncio.get_running_loop().run_in_executor(
-                None, lambda: solve(req.flow, req.stage_name,
-                                    tenant=tenant, reserve=False))
+                None, bound(solve, req.flow, req.stage_name,
+                            tenant=tenant, reserve=False))
         self.stats["rebuilt_solves"] += 1
         log.info("retained placement rebuilt from template %s",
                  kv(stage=key))

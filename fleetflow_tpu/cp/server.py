@@ -28,6 +28,7 @@ from .replication import (ReplicationConfig, Replicator, StandbyReplica,
 from .shards import ShardTable, shards_from_env
 from .store import Store
 from ..obs import get_logger, kv
+from ..obs.trace import watch_collector
 
 log = get_logger("cp.server")
 
@@ -218,11 +219,15 @@ def settle_collector() -> None:
     store) is moved out of the collector's sight for good (`gc.freeze`:
     it is garbage only when the process ends), and the oldest generation
     is looked at ten times less often. The young generations, which take
-    a request's own garbage, run as they did."""
+    a request's own garbage, run as they did. What the collector then
+    costs is counted from here on (`fleet_gc_collections_total`,
+    `fleet_gc_pause_ms_total`; a full collection is the phase
+    `runtime.gc`: obs.trace.watch_collector)."""
     gc.collect()
     gc.freeze()
     young, middle, _oldest = gc.get_threshold()
     gc.set_threshold(young, middle, _OLDEST_GENERATION_EVERY)
+    watch_collector()
 
 
 async def start(config: ServerConfig, *,

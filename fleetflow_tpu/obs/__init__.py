@@ -17,11 +17,13 @@ the aggregation layer the reference leaves to its operators:
   begin/end/fail JSONL events in the flight recorder.
 - `phase("cp.commit.persist", records=n)` is the span's light half and
   the one timing primitive (obs.trace.Phase; `span` is built on it): no
-  ids, no log line, a few microseconds, always on. Every phase lands in
+  log line, a few microseconds, always on. Every phase lands in
   the profiler's trace as `fleet/<name>` (same clock as the device
-  trace), in a bounded in-memory ring (`obs.trace.spans_between`), in
+  trace), in a bounded in-memory ring with its id, its parent's and the
+  trace id (`obs.trace.spans_between`, `tree_between`), in
   the `fleet_phase_ms{phase}` histogram, and in the flight recorder
-  under its enclosing span.
+  under its trace. A callable handed to a thread pool goes through
+  `obs.trace.bound`, which carries the caller's phase and trace along.
 - `obs.metrics.REGISTRY` is the process-wide metrics registry
   (Counter/Gauge/Histogram, Prometheus text exposition at the daemon's
   `GET /metrics`).

@@ -71,7 +71,9 @@ def _fmt(v: float) -> str:
 
 class _Metric:
     """Base: a named family with a fixed label-name tuple and per-label-value
-    children. All mutation goes through one lock per family."""
+    children. All mutation goes through one lock per family — re-entrant:
+    a collection can interrupt a thread that holds it, and the collector's
+    callback (obs.trace.watch_collector) counts into the registry."""
 
     kind = "untyped"
 
@@ -79,7 +81,7 @@ class _Metric:
         self.name = name
         self.help = help
         self.labelnames = tuple(labelnames)
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._children: dict[tuple, object] = {}
         if not self.labelnames:
             # eager zero sample: the exposition surface must not depend on

@@ -9,27 +9,37 @@ fail event with durations, so a single `fleet deploy` can be replayed as a
 timeline afterwards (`fleet events --trace-file`).
 
 Contextvars propagate through async/await but NOT into
-`loop.run_in_executor` threads; code that hops threads re-enters the trace
-explicitly from the id it carried (`with use_trace(req.trace_id): ...`),
-which is exactly what DeployEngine.execute does.
+`loop.run_in_executor` threads: a callable handed to a pool goes through
+`bound()`, which carries the caller's context (trace id, open phase) into
+the pool thread. Across machines the id is carried (`DeployRequest.trace_id`,
+the `trace` key of a request frame) and re-entered with `use_trace`.
 
 `Phase` is the one primitive every span goes through, `obs.span` included.
 It is always on and has no switch: on exit it has written the span (a) into
 the profiler's trace as `fleet/<name>` (`jax.profiler.TraceAnnotation`, the
 clock the device trace shares; only where `jax` is already imported and a
 profiler session is collecting), (b) into a
-bounded in-memory ring on `time.perf_counter()` (`spans_between`), (c) into
-the `fleet_phase_ms{phase}` histogram, and (d) into the flight recorder
-where `FLEET_TRACE_FILE` is set and an `obs.span` encloses it (the span is
-its `parent`). A phase mints no ids and logs nothing; it
-costs a few microseconds, so it belongs around a step of a request and
-never inside a loop over servers, rows or records (count those instead).
+bounded in-memory ring on `time.perf_counter()` (`spans_between`,
+`tree_between`), (c) into the `fleet_phase_ms{phase}` histogram, and (d)
+into the flight recorder where `FLEET_TRACE_FILE` is set (looked up when
+the trace is entered) and a trace id is active (an enclosing `obs.span` is
+its `parent`). A phase has a
+process-wide integer id and knows the phase that was open in its context
+when it opened (its parent; 0 at a root) and the active trace id: the ring
+holds the span tree, and a layer's self time is read from it. A phase logs
+nothing; it costs a few microseconds, so it belongs around a step of a
+request and never inside a loop over servers, rows or records (count those
+instead). Time a request spends waiting, not working, is written as a
+finished interval by whoever waited (`record_interval`).
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
+import gc
+import itertools
 import json
 import os
 import sys
@@ -45,12 +55,25 @@ __all__ = ["new_trace_id", "new_span_id", "current_trace_id",
            "current_span_id", "use_trace", "FlightRecorder",
            "flight_recorder", "record_span_event", "read_trace_file",
            "read_trace_files", "Phase", "SpanRing", "SpansDropped",
-           "spans_between", "RING_CAPACITY", "PROFILER_PREFIX"]
+           "spans_between", "tree_between", "RING_CAPACITY",
+           "PROFILER_PREFIX", "PROCESS_TOKEN", "bound",
+           "record_interval", "wire_span", "watch_collector"]
 
 _trace_id: contextvars.ContextVar[str] = contextvars.ContextVar(
     "fleet_trace_id", default="")
 _span_id: contextvars.ContextVar[str] = contextvars.ContextVar(
     "fleet_span_id", default="")
+# the id of the phase open in this context; 0 outside any
+_phase_id: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "fleet_phase_id", default=0)
+_phase_ids = itertools.count(1)
+# names this process in the `span` key of a request frame: a phase id means
+# something only to the process whose counter minted it
+PROCESS_TOKEN = uuid.uuid4().hex[:8]
+# whether FLEET_TRACE_FILE was set when a trace was last entered: a phase
+# under a trace asks this, not the environment (a lookup that misses costs
+# a microsecond, and a request opens tens of phases)
+_recording = False
 
 
 def new_trace_id() -> str:
@@ -71,17 +94,56 @@ def current_span_id() -> str:
 
 
 @contextlib.contextmanager
-def use_trace(trace_id: Optional[str] = None) -> Iterator[str]:
+def use_trace(trace_id: Optional[str] = None,
+              span: Optional[str] = None) -> Iterator[str]:
     """Enter a trace context: adopt `trace_id`, keep the already-active
     trace when none is given, or mint a fresh id. Restores the previous
     context on exit, so nested/sequential operations cannot leak ids into
-    each other."""
+    each other. `span` is what a request frame carried (`wire_span` on the
+    sending side): where it names a phase of this process, that phase is
+    the parent of what opens here, as if the caller's context had come
+    along."""
+    global _recording
+    _recording = bool(os.environ.get("FLEET_TRACE_FILE"))
     tid = trace_id or _trace_id.get() or new_trace_id()
     token = _trace_id.set(tid)
+    parent = _local_phase(span) if span else 0
+    adopted = _phase_id.set(parent) if parent else None
     try:
         yield tid
     finally:
+        if adopted is not None:
+            _phase_id.reset(adopted)
         _trace_id.reset(token)
+
+
+def wire_span() -> str:
+    """The phase open in this context as a request frame carries it:
+    `<process token>:<id>`."""
+    return f"{PROCESS_TOKEN}:{_phase_id.get()}"
+
+
+def _local_phase(span) -> int:
+    """The id in a frame's `span` where this process minted it, else 0."""
+    token, _, pid = str(span).partition(":")
+    return int(pid) if token == PROCESS_TOKEN and pid.isdigit() else 0
+
+
+def bound(fn, /, *args, **kwargs):
+    """`fn(*args, **kwargs)` as a zero-argument callable for a pool
+    (`loop.run_in_executor(None, bound(fn, ...))`), run in a copy of the
+    caller's context: the phases it opens in the pool thread have the
+    caller's open phase as parent and the caller's trace id. The time
+    between this call and the pool thread picking the callable up is
+    written as `cp.wait.executor`."""
+    ctx = contextvars.copy_context()
+    asked = time.perf_counter()
+
+    def run():
+        record_interval("cp.wait.executor", asked)
+        return fn(*args, **kwargs)
+
+    return functools.partial(ctx.run, run)
 
 
 @contextlib.contextmanager
@@ -122,30 +184,37 @@ class SpansDropped(RuntimeError):
 
 
 class SpanRing:
-    """The last `capacity` finished spans as `(name, t0, t1, thread id)` on
-    `time.perf_counter()`, in the order they ended. Appends come from any
-    thread (`deque.append` is atomic); readers take a copy."""
+    """The last `capacity` finished spans as `(name, t0, t1, thread id, id,
+    parent id, trace id)` on `time.perf_counter()`, in the order they
+    ended. Appends come from any thread (`deque.append` is atomic); readers
+    take a copy."""
 
     def __init__(self, capacity: int = RING_CAPACITY):
         self._spans: deque = deque(maxlen=capacity)
         self._evicted_until = 0.0    # latest end of an overwritten span
 
-    def append(self, name: str, t0: float, t1: float, tid: int) -> None:
+    def append(self, name: str, t0: float, t1: float, tid: int,
+               pid: int = 0, parent: int = 0, trace: str = "") -> None:
         spans = self._spans
         if len(spans) == spans.maxlen:
             self._evicted_until = max(self._evicted_until, spans[0][2])
             _count_dropped()
-        spans.append((name, t0, t1, tid))
+        spans.append((name, t0, t1, tid, pid, parent, trace))
 
-    def between(self, t0: float, t1: float) -> list[tuple]:
-        """The spans that lie wholly inside [t0, t1]. Raises SpansDropped
-        when a span that ended after t0 has been overwritten: a sum over
-        the window would then be short without saying so."""
+    def tree_between(self, t0: float, t1: float) -> list[tuple]:
+        """The records of the spans that lie wholly inside [t0, t1]. Raises
+        SpansDropped when a span that ended after t0 has been overwritten:
+        a sum over the window would then be short without saying so."""
         if self._evicted_until > t0:
             raise SpansDropped(
                 f"the span ring ({self._spans.maxlen} spans) overwrote "
                 f"spans that ended after t0={t0:.6f}")
         return [s for s in list(self._spans) if s[1] >= t0 and s[2] <= t1]
+
+    def between(self, t0: float, t1: float) -> list[tuple]:
+        """`tree_between` as `(name, t0, t1, thread id)`: what a reader that
+        sums by name needs."""
+        return [s[:4] for s in self.tree_between(t0, t1)]
 
 
 RING = SpanRing()
@@ -155,6 +224,14 @@ def spans_between(t0: float, t1: float) -> list[tuple]:
     """`RING.between`: what the program did between two readings of
     `time.perf_counter()`, for a benchmark reader or a debugger."""
     return RING.between(t0, t1)
+
+
+def tree_between(t0: float, t1: float) -> list[tuple]:
+    """`RING.tree_between`: the same spans with who caused each and whose
+    it is — `(name, t0, t1, thread id, id, parent id, trace id)`. A span's
+    self time is its duration less the union of its children's intervals,
+    whatever thread they ran on."""
+    return RING.tree_between(t0, t1)
 
 
 _annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
@@ -181,17 +258,24 @@ def _bind_observe(name: str):
 class Phase:
     """Context manager around one step of a request: `with
     phase("cp.commit.persist", records=n) as ph: ...`; afterwards `ph.ms`
-    is its wall time. See the module docstring for where it is written."""
+    is its wall time, `ph.id` its id and `ph.parent` the id of the phase
+    that was open in this context when it opened (0: none). See the module
+    docstring for where it is written."""
 
-    __slots__ = ("name", "fields", "t0", "t1", "_ann", "_owner")
+    __slots__ = ("name", "fields", "t0", "t1", "id", "parent", "_ann",
+                 "_token", "_trace", "_owner")
 
     def __init__(self, name: str, /, **fields):
         self.name = name
         self.fields = fields
         self.t0 = self.t1 = 0.0
+        self.id = self.parent = 0
         self._ann = None
+        self._token = None
+        self._trace = ""        # adopt(): the trace it learned in its body
         # obs.span owns its phase: (logger, trace id, span id, parent span
-        # id, the dict of fields collected in the body)
+        # id, the dict of fields collected in the body): the recorder's
+        # `span` and `parent` keys and the log lines keep the hex ids
         self._owner: Optional[tuple] = None
 
     @property
@@ -204,7 +288,19 @@ class Phase:
         if self._ann is not None:
             self._ann.set_metadata(**fields)
 
+    def adopt(self, trace, span) -> None:
+        """For a phase that learns in its body whose it is — a frame's
+        decode, from the frame: file it under `trace`, as a child of the
+        phase a request frame's `span` names (`wire_span`), where this
+        process minted it. Anything else leaves it as it opened."""
+        if trace and isinstance(trace, str):
+            self._trace = trace
+        self.parent = _local_phase(span) or self.parent
+
     def __enter__(self) -> "Phase":
+        self.id = pid = next(_phase_ids)
+        self.parent = _phase_id.get()
+        self._token = _phase_id.set(pid)
         # an annotation only while a profiler session is collecting them:
         # the profiler's own switch, read in tens of nanoseconds
         ann = _annotation or _profiler_annotation()
@@ -220,29 +316,114 @@ class Phase:
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
-        name = self.name
-        RING.append(name, t0, t1, threading.get_ident())
+        try:
+            _phase_id.reset(self._token)
+        except ValueError:
+            # closed in another context than it opened in (a generator
+            # finalised elsewhere): that context never saw this phase
+            pass
+        name, trace = self.name, self._trace or _trace_id.get()
+        RING.append(name, t0, t1, threading.get_ident(), self.id,
+                    self.parent, trace)
         (_observe.get(name) or _bind_observe(name))((t1 - t0) * 1e3)
-        # the recorder files a phase under its enclosing span; outside any
-        # span there is nothing to hang it on (and no environment read)
-        if _span_id.get() and os.environ.get("FLEET_TRACE_FILE"):
-            self._record(exc)
+        # the recorder files a phase under its trace; outside any there is
+        # nothing to hang it on
+        if trace and _recording:
+            self._record(exc, trace)
         return False
 
-    def _record(self, exc) -> None:
+    def _record(self, exc, trace: str) -> None:
         """The flight recorder's `end` (or `fail`) event. A bare phase has
-        no id of its own: its `parent` is the enclosing obs.span."""
+        no span id of its own: its `parent` is the enclosing obs.span,
+        where there is one; `id` and `parent_id` are the tree's."""
         fields = self.fields
         if self._owner is not None:
             logger, trace, span, parent, extra = self._owner
             fields = {**fields, **extra}
         else:
-            logger, trace, span, parent = (
-                PHASE_LOGGER, _trace_id.get(), "", _span_id.get())
+            logger, span, parent = PHASE_LOGGER, "", _span_id.get()
         record_span_event(
             "end" if exc is None else "fail", self.name, logger,
             trace=trace, span=span, parent=parent, duration_ms=self.ms,
-            error=None if exc is None else str(exc), fields=fields or None)
+            error=None if exc is None else str(exc), fields=fields or None,
+            phase_ids=(self.id, self.parent))
+
+
+def record_interval(name: str, t0: float, t1: Optional[float] = None) -> None:
+    """A finished interval `[t0, t1]` (`t1`: now) on `time.perf_counter()`,
+    written by whoever waited through it — for a queue, a pool thread, a
+    collection — as a phase of the ring, with the phase open in this
+    context as parent and the active trace id, and of the histogram. An
+    interval that began in another thread, or is over, cannot be an
+    annotation of the profiler's trace, and the flight recorder is not
+    written: a collection can interrupt the recorder's own write."""
+    if t1 is None:
+        t1 = time.perf_counter()
+    RING.append(name, t0, t1, threading.get_ident(), next(_phase_ids),
+                _phase_id.get(), _trace_id.get())
+    (_observe.get(name) or _bind_observe(name))((t1 - t0) * 1e3)
+
+
+# --------------------------------------------------------------------------
+# the collector: every collection counted, a full one a phase
+# --------------------------------------------------------------------------
+
+_M_GC_COLLECTIONS = REGISTRY.counter(
+    "fleet_gc_collections_total",
+    "Collections of CPython's cyclic collector since the process settled "
+    "it (cp.server.settle_collector), by generation", labels=("generation",))
+_M_GC_PAUSE_MS = REGISTRY.counter(
+    "fleet_gc_pause_ms_total",
+    "Wall milliseconds the interpreter spent inside collections, by "
+    "generation: every thread waits through them", labels=("generation",))
+
+
+class _CollectorWatch:
+    """A `gc.callbacks` entry. The interpreter runs one collection at a
+    time, in whatever thread crossed the threshold, so one start time is
+    enough. Young collections are counted, not spanned (a solve of 1,000
+    rows runs about a hundred); a collection of the oldest generation is
+    also the phase `runtime.gc`, child of whatever phase it interrupted.
+    It may interrupt a thread inside a metric family's lock, which is why
+    that lock is re-entrant (obs/metrics.py)."""
+
+    GENERATIONS = 3
+
+    def __init__(self):
+        self._t0 = 0.0
+        self._ann = None
+        gens = [str(g) for g in range(self.GENERATIONS)]
+        self._count = [_M_GC_COLLECTIONS.bind(generation=g) for g in gens]
+        self._pause = [_M_GC_PAUSE_MS.bind(generation=g) for g in gens]
+
+    def __call__(self, when: str, info: dict) -> None:
+        gen = info["generation"]
+        full = gen == self.GENERATIONS - 1
+        if when == "start":
+            ann = (_annotation or _profiler_annotation()) if full else None
+            if ann is not None and ann.is_enabled():
+                self._ann = ann(PROFILER_PREFIX + "runtime.gc")
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        t0, t1 = self._t0, time.perf_counter()
+        self._count[gen]()
+        self._pause[gen]((t1 - t0) * 1e3)
+        if full:
+            if self._ann is not None:
+                self._ann.set_metadata(collected=info["collected"])
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            record_interval("runtime.gc", t0, t1)
+
+
+_COLLECTOR_WATCH = _CollectorWatch()
+
+
+def watch_collector() -> None:
+    """Install the process's one collector watch; again is a no-op."""
+    if _COLLECTOR_WATCH not in gc.callbacks:
+        gc.callbacks.append(_COLLECTOR_WATCH)
 
 
 # --------------------------------------------------------------------------
@@ -255,7 +436,10 @@ class FlightRecorder:
         {"ts": ..., "kind": "begin"|"end"|"fail"|"telemetry",
          "name": ..., "logger": ..., "trace": ..., "span": ...,
          "parent": ..., "duration_ms": ...?, "error": ...?,
-         "fields": {...}?}
+         "fields": {...}?, "id": ...?, "parent_id": ...?}
+
+    (`id` / `parent_id`: a finished phase's place in its process's span
+    tree; `span` / `parent` are obs.span's hex ids.)
 
     Thread-safe (one lock around write+flush); line-buffered so a crashed
     process leaves at most one torn final line, which readers skip.
@@ -340,9 +524,11 @@ def record_span_event(kind: str, name: str, logger: str, *,
                       trace: str, span: str, parent: str = "",
                       duration_ms: Optional[float] = None,
                       error: Optional[str] = None,
-                      fields: Optional[dict] = None) -> None:
+                      fields: Optional[dict] = None,
+                      phase_ids: Optional[tuple[int, int]] = None) -> None:
     """Write one span event if the flight recorder is active; no-op (and
-    near-free: one env lookup) otherwise."""
+    near-free: one env lookup) otherwise. `phase_ids` is a finished
+    phase's (id, parent id) in the process's span tree."""
     rec = flight_recorder()
     if rec is None:
         return
@@ -356,6 +542,8 @@ def record_span_event(kind: str, name: str, logger: str, *,
         event["error"] = error
     if fields:
         event["fields"] = fields
+    if phase_ids is not None:
+        event["id"], event["parent_id"] = phase_ids
     rec.record(event)
 
 

@@ -41,14 +41,13 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
-import time
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import Any, Optional
 
 import numpy as np
 
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 from ..obs.metrics import MS_BUCKETS, REGISTRY
 from .buckets import bucket_config, bucket_size
 
@@ -416,35 +415,34 @@ class ResidentProblem:
         `delta_stage_ms` timing). The caller has already checked
         `compatible`; node_valid/capacity always re-upload from `pt` (a few
         KB — the (S, N) problem planes are what never move)."""
-        t0 = time.perf_counter()
-        self._note_churn(pt, delta)
-        uploads, n_real, has_demand, has_eligible = self.merge_inputs(
-            pt, delta)
-        valid, cap = self._staged_fp
-        # ONE donated merge dispatch
-        try:
-            self.prob, self.assignment = self._merge()(
-                self.prob, self.assignment, *uploads, n_real,
-                has_demand=has_demand, has_eligible=has_eligible)
-        except Exception:
-            # a failed merge leaves donated buffers in an unknown state:
-            # the only safe recovery is a full cold restage
-            log.warning("delta merge failed; cold restaging %s",
-                        kv(S=pt.S, N=pt.N))
-            self.cold_stage(pt)
-            raise
-        self.pt = pt
-        self._valid_fp = valid.copy()
-        self._cap_fp = cap.copy()
-        if self._mirror is not None:
-            # replay the merge kernel's deterministic phantom re-park so
-            # the mirror stays an exact host copy of the device assignment
-            self._mirror[self.n_real:] = int(np.argmax(valid))
-        ms = (time.perf_counter() - t0) * 1e3
-        self._delta_ms += ms
-        _M_DELTA_MS.observe(ms)
+        with phase("sched.stage.delta") as ph:
+            self._note_churn(pt, delta)
+            uploads, n_real, has_demand, has_eligible = self.merge_inputs(
+                pt, delta)
+            valid, cap = self._staged_fp
+            # ONE donated merge dispatch
+            try:
+                self.prob, self.assignment = self._merge()(
+                    self.prob, self.assignment, *uploads, n_real,
+                    has_demand=has_demand, has_eligible=has_eligible)
+            except Exception:
+                # a failed merge leaves donated buffers in an unknown state:
+                # the only safe recovery is a full cold restage
+                log.warning("delta merge failed; cold restaging %s",
+                            kv(S=pt.S, N=pt.N))
+                self.cold_stage(pt)
+                raise
+            self.pt = pt
+            self._valid_fp = valid.copy()
+            self._cap_fp = cap.copy()
+            if self._mirror is not None:
+                # replay the merge kernel's deterministic phantom re-park so
+                # the mirror stays an exact host copy of the device assignment
+                self._mirror[self.n_real:] = int(np.argmax(valid))
+        self._delta_ms += ph.ms
+        _M_DELTA_MS.observe(ph.ms)
         _M_REUSE.inc(outcome="delta")
-        return ms
+        return ph.ms
 
     # -- staging hooks (overridden by solver/sharded.ShardedResident) ------
 

@@ -776,9 +776,16 @@ class TestSelfHealE2E:
             heal_key = heals[0][1]["idempotency_key"]
             assert heal_key.startswith("heal-healdemo/main-")
 
-            # heal landed in deployment history with its placement
-            heal_deps = [d for d in handle.state.store.list("deployments")
-                         if d.log.startswith("self-heal")]
+            # heal landed in deployment history with its placement (the
+            # container runs on the survivor before the CP has the ack and
+            # writes the record: wait for the record, not for a fixed time)
+            while time.monotonic() < deadline:
+                heal_deps = [d for d in
+                             handle.state.store.list("deployments")
+                             if d.log.startswith("self-heal")]
+                if heal_deps:
+                    break
+                await asyncio.sleep(0.02)
             assert heal_deps and heal_deps[-1].placement == {
                 "web": survivor}
 
