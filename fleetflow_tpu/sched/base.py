@@ -10,7 +10,8 @@ import numpy as np
 from ..lower.tensors import ProblemTensors
 from ..obs.metrics import REGISTRY
 
-__all__ = ["Placement", "Scheduler", "level_schedule", "record_placement"]
+__all__ = ["Placement", "Scheduler", "assignment_names", "level_schedule",
+           "record_placement"]
 
 # one catalog entry per scheduler backend: host-greedy, native-ffd,
 # partitioned, tpu-anneal, relaxation sources — whatever `source` says
@@ -38,19 +39,38 @@ def level_schedule(pt: ProblemTensors) -> list[list[str]]:
     """Dependency level buckets in start order: all services at depth d can
     start concurrently once depth d-1 is ready (exact Kahn levels from
     lower.tensors.dependency_depths — the vectorizable replacement for the
-    reference's sequential ordering, engine.rs:67-85)."""
-    depth = np.asarray(pt.dep_depth)
-    levels: list[list[str]] = []
-    for d in range(int(depth.max()) + 1 if depth.size else 0):
-        levels.append([pt.service_names[i] for i in np.flatnonzero(depth == d)])
-    return levels
+    reference's sequential ordering, engine.rs:67-85). One stable sort of
+    the depths, sliced at each depth's cumulative count: rows in ascending
+    index within a level, an empty list for a depth no row has."""
+    depth = np.asarray(pt.dep_depth, dtype=np.intp)
+    if not depth.size:
+        return []
+    order = np.argsort(depth, kind="stable")
+    names = np.array(pt.service_names, dtype=object)[order].tolist()
+    ends = np.cumsum(np.bincount(depth)).tolist()
+    return [names[a:b] for a, b in zip([0] + ends, ends)]
+
+
+def assignment_names(pt: ProblemTensors, raw: np.ndarray) -> dict[str, str]:
+    """The solver's array as names: service row -> node name, in row order
+    (a repeated row name keeps its last row's node). `raw` may be longer
+    than `pt.S` (a bucketed, padded result) and of any dtype that holds
+    whole numbers, an empty float array included. A plain dict of str:
+    replies and the journal carry it, and callers copy it with `dict(...)`."""
+    nodes = np.array(pt.node_names, dtype=object)
+    rows = np.asarray(raw)[:pt.S].astype(np.intp, copy=False)
+    return dict(zip(pt.service_names, nodes[rows].tolist(), strict=True))
 
 
 @dataclass
 class Placement:
     """A solved placement: where each service row runs and in what order."""
     assignment: dict[str, str]       # service row name -> node name
-    levels: list[list[str]]          # start-order level buckets
+    # start-order level buckets. Read-only: TpuSolverScheduler hands the
+    # placements of one stage the SAME lists (the schedule kept with the
+    # stage's slot), so an edit in place would reach every later placement
+    # of that stage; build new lists, as `node_levels` and `services_on` do
+    levels: list[list[str]]
     feasible: bool
     violations: int = 0
     soft: float = 0.0
@@ -77,8 +97,7 @@ def assemble_placement(pt: ProblemTensors, assignment: np.ndarray,
                        solve_ms: float) -> Placement:
     """Shared Placement assembly for greedy backends (host + native)."""
     placement = Placement(
-        assignment={pt.service_names[i]: pt.node_names[int(assignment[i])]
-                    for i in range(pt.S)},
+        assignment=assignment_names(pt, assignment),
         levels=level_schedule(pt),
         feasible=violations == 0,
         violations=violations,

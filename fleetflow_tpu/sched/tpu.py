@@ -47,7 +47,8 @@ from typing import Any, Optional
 
 import numpy as np
 
-from .base import Placement, level_schedule, record_placement
+from .base import (Placement, assignment_names, level_schedule,
+                   record_placement)
 from ..lower.tensors import ProblemTensors
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
@@ -63,6 +64,12 @@ _M_EVICTIONS = REGISTRY.counter(
 _M_READMITS = REGISTRY.counter(
     "fleet_sched_slot_readmissions_total",
     "Evicted stages re-admitted warm from their host snapshot")
+_M_LEVELS = REGISTRY.counter(
+    "fleet_sched_level_schedules_total",
+    "Level schedules a placement was given, by outcome: kept (the stage's "
+    "slot had one built from the very same dep_depth and service_names "
+    "objects) or built",
+    labels=("outcome",))
 _M_RES_BYTES = REGISTRY.gauge(
     "fleet_sched_resident_bytes",
     "Device bytes held by resident stage slots (packed-plane accounting)")
@@ -93,6 +100,11 @@ class _StageSlot:
     key: Optional[str] = None                      # CP stage key, when the caller has one
     nbytes: int = 0                                # device footprint at admission
     last_used: float = 0.0                         # monotonic stamp for LRU + status
+    # (dep_depth, service_names, levels) of the last level schedule built:
+    # reused while a problem brings the same two OBJECTS (a churn re-solve
+    # replaces capacity and keeps the graph); shared by the stage's
+    # placements and read-only
+    schedule: Optional[tuple] = None
 
 
 @dataclass
@@ -395,7 +407,9 @@ class TpuSolverScheduler:
                      overlap_host_work=overlap_host_work)
 
     def _finalize(self, pt: ProblemTensors, res, slot, ms: float,
-                  stage: Optional[str]) -> Placement:
+                  stage: Optional[str], ph) -> Placement:
+        """`ph` is the open `sched.finalize` phase: its `levels` says
+        "built" if any schedule under it was, else "kept"."""
         slot.last_assignment = res.assignment
         slot.last_used = time.monotonic()
         sub = getattr(res, "subsolve", None)
@@ -405,10 +419,19 @@ class TpuSolverScheduler:
             log.debug("active-set %s", kv(
                 stage=stage, rows=sub["rows"], tier=sub["tier"],
                 outcome=sub["outcome"], ms=sub["ms"]))
+        kept = slot.schedule
+        if (kept is not None and kept[0] is pt.dep_depth
+                and kept[1] is pt.service_names):
+            levels, outcome = kept[2], "kept"
+        else:
+            levels, outcome = level_schedule(pt), "built"
+            slot.schedule = (pt.dep_depth, pt.service_names, levels)
+        _M_LEVELS.inc(outcome=outcome)
+        if ph.fields.get("levels") != "built":
+            ph.set(levels=outcome)
         placement = Placement(
-            assignment={pt.service_names[i]: pt.node_names[int(res.assignment[i])]
-                        for i in range(pt.S)},
-            levels=level_schedule(pt),
+            assignment=assignment_names(pt, res.assignment),
+            levels=levels,
             feasible=res.feasible,
             violations=res.violations,
             soft=res.soft,
@@ -459,8 +482,8 @@ class TpuSolverScheduler:
                                       overlap_host_work=overlap_host_work)
             # solve_ms: staging + dispatch + the fetch of the result
             ms = (ph_solve.t1 - ph.t0) * 1e3
-            with phase("sched.finalize", rows=pt.S):
-                return self._finalize(pt, res, slot, ms, stage)
+            with phase("sched.finalize", rows=pt.S) as ph_fin:
+                return self._finalize(pt, res, slot, ms, stage, ph_fin)
 
     def place_many(self, requests: list[dict]) -> list[Placement]:
         """Batched placement across stages — the tenant multiplexer
@@ -514,8 +537,8 @@ class TpuSolverScheduler:
                     results[i] = self._solve_one(pt, slot, resident_warm,
                                                  sh_mesh, init)
             ms = (ph_solve.t1 - ph.t0) * 1e3
-            with phase("sched.finalize"):
-                return [self._finalize(pt, res, slot, ms, stg)
+            with phase("sched.finalize") as ph_fin:
+                return [self._finalize(pt, res, slot, ms, stg, ph_fin)
                         for (pt, slot, _rw, _mesh, stg, _w), res
                         in zip(staged, results)]
 
