@@ -876,9 +876,10 @@ async def execute_down(state: "AppState", req: DeployRequest,
         ok = not errors
         if ok:
             if not req.target_services:
-                # full-stage teardown: capacity back, every service marked
+                # full-stage teardown: capacity back, the stage's retained
+                # problem and solver slot dropped, every service marked
                 state.placement.release_stage(
-                    f"{req.flow.name}/{req.stage_name}")
+                    f"{req.flow.name}/{req.stage_name}", forget=True)
                 marked = stage_cfg.services
             else:
                 # targeted: no capacity release (the stage still runs),

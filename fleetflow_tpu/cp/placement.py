@@ -216,20 +216,12 @@ def _moved_rows(pt: ProblemTensors, before: Placement,
     return moved
 
 
-# the labels of every node whose record carries none: read, never written
-_UNLABELLED = ServerLabels()
-
-
 def _node(s: Server) -> Node:
-    """The node `lower_stage` sees for a server record: its name and its
-    own copy of the labels (`_UNLABELLED`, shared, where it has none)."""
-    lb = s.labels
-    if (lb.tier is None and lb.region is None and lb.clazz is None
-            and lb.arch is None and not lb.extra):
-        return Node(s.slug, _UNLABELLED)
-    return Node(s.slug, ServerLabels(
-        tier=lb.tier, region=lb.region, clazz=lb.clazz, arch=lb.arch,
-        extra=dict(lb.extra)))
+    """The node `lower_stage` sees for a server record: its name and the
+    record's own labels, shared and only ever read. Nothing writes into a
+    node's labels: `solve_stage`'s back-fill gives the node a new object, a
+    store update gives the record one."""
+    return Node(s.slug, s.labels)
 
 
 class PlacementService:
@@ -552,8 +544,7 @@ class PlacementService:
                 held = self._held_for_lowering(key)
             with phase("cp.solve_stage.lower"):
                 pt = lower_stage(flow, stage_name, nodes=nodes, held=held,
-                                 capacity=free)
-                pt.node_valid &= valid
+                                 capacity=free, valid=valid)
                 if preemptible is not None and not self._fits_free(pt):
                     # a row that fits on no server as it is: the stage
                     # fits nowhere, whatever the solver would say
@@ -696,8 +687,7 @@ class PlacementService:
                 exclude_demand=exclude)
             pt = lower_stage(flow, stage_name, nodes=nodes,
                              held=self._held_for_lowering(stage_key),
-                             capacity=free)
-            pt.node_valid &= valid
+                             capacity=free, valid=valid)
             node_idx = {n: i for i, n in enumerate(pt.node_names)}
             raw = np.zeros(pt.S, dtype=np.int64)
             for i, row in enumerate(pt.service_names):
@@ -1167,11 +1157,19 @@ class PlacementService:
                 self._persist_committed(stage_key)
             return True
 
-    def release_stage(self, stage_key: str) -> bool:
+    def release_stage(self, stage_key: str, *, forget: bool = False) -> bool:
         """Stage torn down (`fleet down` on a remote stage): return its
-        committed capacity."""
+        committed capacity. With `forget` the stage's retained problem and
+        placement and its solver slot go too: what ran there is gone, so
+        node churn no longer re-solves it, `snapshot` no longer shows it,
+        and a later solve under the same key is a first solve (cold: the
+        rows it would warm-start from no longer exist)."""
         with self._locked():
             self._drop_churn(stage_key)
+            if forget:
+                self._last.pop(stage_key, None)
+                self._masked.pop(stage_key, None)
+                self._sched_tpu.forget(stage_key)
             c = self._committed.pop(stage_key, None)
             if c is None:
                 return False

@@ -39,6 +39,11 @@ Codes are stable (never renumber; retire by leaving a gap):
                   solver/problem.py) exceed the configured device budget
                   (FLEET_LINT_DEVICE_BUDGET_MB) — surfaced at lint time,
                   before a staging OOM does it the hard way
+  FF017  warning  spread constraint that will not do what it says: its
+                  topology key is carried by none (the solve is refused)
+                  or only some (the others take no row) of the stage's
+                  servers, or the stage's fallback lists `spread`, so an
+                  infeasible spread is answered by dropping the bound
 
 Rules are pure functions over a :class:`LintContext`; `scope` says what
 they iterate ("flow" once, "stage" per stage) and `structural=True` marks
@@ -659,3 +664,53 @@ def check_plane_memory(r: Rule, ctx: LintContext, stage: Stage):
              "packed (S, ·) planes divide by mesh width), or raise "
              "FLEET_LINT_DEVICE_BUDGET_MB if the device is larger "
              "(docs/guide/11-performance.md)")
+
+
+@rule("FF017", "spread-constraint", Severity.WARNING, "stage")
+def check_spread_constraint(r: Rule, ctx: LintContext, stage: Stage):
+    """A `placement { spread ... }` whose topology key the stage's servers
+    do not all carry, or whose bound the stage's own fallback drops: says
+    what will happen at solve time (lower/tensors.py, "Spread";
+    sched/fallback.py)."""
+    policy = stage.placement
+    spread = policy.spread_constraint if policy else None
+    if spread is None or spread.max_skew <= 0:
+        return
+    from ..lower.tensors import SPREAD_RELAX_CLASSES, _server_matches
+
+    fallback = policy.fallback_policy
+    if fallback is not None and any(w in SPREAD_RELAX_CLASSES
+                                    for w in fallback.relax_order):
+        yield ctx.diag(
+            r, f"stage spreads with max_skew {spread.max_skew} and its "
+               f"fallback lists `spread`: a solve that cannot keep the "
+               f"bound is answered with the bound dropped, and only the "
+               f"reply's source (`+relaxed:spread`) says so",
+            loc=stage.loc, stage=stage,
+            hint="leave `spread` out of the fallback's order to have an "
+                 "infeasible spread refused instead")
+    nodes, is_local = ctx.stage_nodes(stage)
+    if is_local or spread.topology_key == "node":
+        return      # local lowering drops the constraint; "node" needs no label
+    candidates = [n for n in nodes if _server_matches(policy, n)]
+    keyless = [n.name for n in candidates
+               if spread.topology_key not in n.labels.as_dict()]
+    if not candidates or not keyless:
+        return
+    if len(keyless) == len(candidates):
+        yield ctx.diag(
+            r, f"stage spreads over {spread.topology_key!r} and none of "
+               f"its {len(candidates)} candidate server(s) carries that "
+               f"label: no service has an eligible server and the solve "
+               f"is refused at lowering",
+            loc=stage.loc, stage=stage,
+            hint=f"label the servers (`labels {{ {spread.topology_key} "
+                 f"\"...\" }}`) or spread over \"node\"")
+    else:
+        shown = ", ".join(keyless[:3]) + (", ..." if len(keyless) > 3 else "")
+        yield ctx.diag(
+            r, f"stage spreads over {spread.topology_key!r} and "
+               f"{len(keyless)} of its {len(candidates)} candidate "
+               f"server(s) lack that label ({shown}): they take no service "
+               f"of this stage and count as no domain",
+            loc=stage.loc, stage=stage)

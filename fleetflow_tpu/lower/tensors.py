@@ -48,6 +48,17 @@ capacity the row would have to take, so the annealer leans to the servers
 that need fewer evictions. Which rows are evicted is decided after the
 solve, by the caller; the solver never sees a victim.
 
+Spread. A stage's `placement { spread topology_key=K max_skew=M }` is the
+PodTopologySpread constraint with DoNotSchedule over all of the stage's
+rows: the counts of the stage's rows per topology domain differ by at most
+M. A domain is a distinct value of label K among the servers the stage may
+use (the policy admits them, and they are up when the caller says which
+are: `valid`); `"node"` makes each such server its own domain. A server
+that lacks K takes no row of the stage (its `eligible` column is cleared,
+`topology_keyless` remembers which) and is no domain, as the source reads
+it; it used to be a domain of its own, which pinned the emptiest domain at
+0. Rows that other stages hold are not counted.
+
 Replicas are expanded at lowering time: `service "w" { replicas 3 }` becomes
 rows w#0, w#1, w#2 sharing demand/ports/volumes; replica host-port conflicts
 make replicas of a port-publishing service mutually anti-affine exactly like
@@ -65,6 +76,7 @@ import numpy as np
 from ..core.errors import SolverError
 from ..core.model import (ServiceType, Flow, PlacementPolicy, PlacementStrategy,
                           ResourceSpec, ServerLabels, ServerResource, Service)
+from ..obs import phase
 from ..obs.metrics import REGISTRY
 
 __all__ = ["ProblemTensors", "Node", "lower_stage", "bar_held",
@@ -128,6 +140,12 @@ class ProblemTensors:
     #                it is
     priority: Optional[np.ndarray] = None
     preemptible: Optional[np.ndarray] = None
+    # Spread (module docstring): (N,) bool, the nodes that lack the spread
+    # constraint's topology key, already cleared from `eligible`; None
+    # where the stage spreads over nothing or every node carries the key.
+    # Not read by the solver: the relax ladder keeps these nodes barred
+    # while the constraint stands (sched/fallback.py).
+    topology_keyless: Optional[np.ndarray] = None
 
     @property
     def S(self) -> int:
@@ -349,12 +367,50 @@ def with_preemptible(pt: ProblemTensors,
         preferred=preferred)
 
 
+def _topology_domains(nodes, key: str, usable: np.ndarray
+                      ) -> tuple[np.ndarray, np.ndarray]:
+    """Labels -> `node_topology` for a spread constraint over `key`:
+    ((N,) i32 domain per node, (N,) bool nodes that lack the key).
+
+    A domain is a distinct value of `key` over the `usable` nodes (those
+    the stage's policy admits and that are up): a zone with no such node is
+    no domain, so it cannot pin the emptiest domain's count at 0. `"node"`
+    makes every usable node a domain of its own and needs no label. A node
+    that is not usable, or lacks the key, carries domain 0 as a filler: no
+    row can lie there, so it counts toward nothing."""
+    N = len(nodes)
+    if key == "node":
+        keyless = np.zeros(N, dtype=bool)
+        counted = usable
+        ids = np.cumsum(usable) - 1
+    else:
+        # `ServerLabels.as_dict()[key]` without a dict a node: a label
+        # field where it is set, else the free-form entry
+        field_of = {"tier": "tier", "region": "region", "arch": "arch",
+                    "class": "clazz"}.get(key)
+        values = [n.labels.extra.get(key) for n in nodes]
+        if field_of is not None:
+            values = [v if getattr(n.labels, field_of) is None
+                      else getattr(n.labels, field_of)
+                      for n, v in zip(nodes, values)]
+        keyless = np.fromiter((v is None for v in values), dtype=bool,
+                              count=N)
+        counted = usable & ~keyless
+        ids = np.zeros(N, dtype=np.int64)
+        if counted.any():
+            labels = np.array([v for v, c in zip(values, counted) if c],
+                              dtype=object)
+            ids[counted] = np.unique(labels, return_inverse=True)[1]
+    return np.where(counted, ids, 0).astype(np.int32), keyless
+
+
 def lower_stage(flow: Flow, stage_name: str,
                 nodes: Optional[Sequence[Union[ServerResource, Node]]] = None,
                 local: bool = False,
                 held: Optional[dict[str, list[str]]] = None,
                 preemptible: Optional[np.ndarray] = None,
                 capacity: Optional[np.ndarray] = None,
+                valid: Optional[np.ndarray] = None,
                 ) -> ProblemTensors:
     """Lower one stage of a Flow into ProblemTensors.
 
@@ -373,6 +429,10 @@ def lower_stage(flow: Flow, stage_name: str,
     node, for a caller that has it as an array (cp/placement.py: capacity
     less what is spoken for); it takes the place of each node's own
     `capacity`, which is then not read. None reads the nodes'.
+
+    `valid` ((N,) bool, in the order of `nodes`) says which nodes are up;
+    it becomes `node_valid`, and a node that is down is no topology domain
+    of a spread constraint (module docstring, Spread). None: all are up.
 
     Node set: explicit `nodes` arg > stage.servers > all flow.servers > a
     single implicit "local" node with generous capacity (the `fleet up local`
@@ -685,17 +745,28 @@ def lower_stage(flow: Flow, stage_name: str,
             raise SolverError(
                 f"services {bad} have no eligible node under the placement "
                 f"policy (declare a fallback{{}} to relax)")
-    node_valid = np.ones(N, dtype=bool)
+    node_valid = (np.ones(N, dtype=bool) if valid is None
+                  else np.array(valid, dtype=bool))
 
-    topo_key = (policy.spread_constraint.topology_key
-                if policy and policy.spread_constraint else None)
-    topo_ids: dict[str, int] = {}
-    node_topology = np.zeros(N, dtype=np.int32)
-    if topo_key and topo_key != "node":
-        for j, node in enumerate(nodes):
-            lbl = node.labels.as_dict().get(topo_key, f"__node_{j}")
-            node_topology[j] = topo_ids.setdefault(lbl, len(topo_ids))
+    spread = policy.spread_constraint if policy else None
+    if spread is not None and spread.max_skew > 0:
+        with phase("cp.solve_stage.lower.topology", nodes=N) as ph:
+            node_topology, keyless = _topology_domains(
+                nodes, spread.topology_key, node_ok & node_valid)
+            if keyless.any():
+                # the source's reading (PodTopologySpread): a node without
+                # the key takes no pod of the constraint
+                eligible[:, keyless] = False
+            ph.set(domains=int(node_topology.max(initial=-1)) + 1,
+                   keyless=int(keyless.sum()))
+        if not eligible.any(axis=1).all():
+            raise SolverError(
+                f"stage {stage_name!r} spreads over "
+                f"{spread.topology_key!r} and "
+                f"{int((~eligible.any(axis=1)).sum())} of its services are "
+                f"left no eligible server that carries the key")
     else:
+        keyless = None
         node_topology = np.arange(N, dtype=np.int32)
 
     pt = ProblemTensors(
@@ -713,8 +784,8 @@ def lower_stage(flow: Flow, stage_name: str,
         node_valid=node_valid,
         node_topology=node_topology,
         strategy=policy.strategy if policy else PlacementStrategy.SPREAD_ACROSS_POOL,
-        max_skew=(policy.spread_constraint.max_skew
-                  if policy and policy.spread_constraint else 0),
+        max_skew=spread.max_skew if spread is not None else 0,
+        topology_keyless=keyless,
         preferred=preferred,
         relax_order=relax_order,
         replica_of=replica_of,

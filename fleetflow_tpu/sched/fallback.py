@@ -26,10 +26,17 @@ from ..lower.tensors import (ELIGIBILITY_RELAX_CLASSES as _ELIG,
                              SPREAD_RELAX_CLASSES as _SPREAD,
                              ProblemTensors, bar_held)
 from ..obs import get_logger, kv
+from ..obs.metrics import REGISTRY
 
 __all__ = ["place_with_fallback", "relax_problem"]
 
 log = get_logger("sched")
+
+# metric catalog: docs/guide/10-observability.md
+_M_RELAXED = REGISTRY.counter(
+    "fleet_sched_relaxed_total",
+    "Rungs of a stage's fallback ladder taken: a constraint class relaxed "
+    "and the stage solved again", labels=("what",))
 
 
 def relax_problem(pt: ProblemTensors, what: str) -> Optional[ProblemTensors]:
@@ -48,6 +55,10 @@ def relax_problem(pt: ProblemTensors, what: str) -> Optional[ProblemTensors]:
         # stage's own conflicts: the relaxed plane keeps those bars
         eligible = np.ones_like(pt.eligible)
         bar_held(eligible, pt.barred_by, pt.node_names, pt.held)
+        if pt.max_skew > 0 and pt.topology_keyless is not None:
+            # while the spread constraint stands, so does its bar on the
+            # servers that lack its key
+            eligible[:, pt.topology_keyless] = False
         if np.array_equal(eligible, pt.eligible):
             return None
         return dataclasses.replace(pt, eligible=eligible)
@@ -80,6 +91,7 @@ def place_with_fallback(scheduler: Scheduler, pt: ProblemTensors, *,
             continue
         pt = pt2
         relaxed.append(what)
+        _M_RELAXED.inc(what=what)
         log.info("placement infeasible; relaxing %s",
                  kv(what=what, order=",".join(pt.relax_order)))
         placement = scheduler.place(pt, **kw)

@@ -2,11 +2,11 @@
 
 First-fit-decreasing over dependency-depth order, honoring every hard
 constraint the TPU solver enforces (eligibility, node validity, capacity,
-port/volume/anti-affinity exclusivity). This is the default backend for
-small instances and the fallback when no accelerator is present — the moral
-successor of the reference's host-side `order_by_dependencies`
-(engine.rs:67-85), upgraded from "partition into two buckets" to an actual
-constrained bin-packer.
+port/volume/anti-affinity exclusivity, the spread constraint). This is the
+default backend for small instances and the fallback when no accelerator is
+present — the moral successor of the reference's host-side
+`order_by_dependencies` (engine.rs:67-85), upgraded from "partition into two
+buckets" to an actual constrained bin-packer.
 
 Strategy scoring mirrors solver/kernels.py:
   spread_across_pool  pick the least-utilized eligible node
@@ -59,6 +59,11 @@ def greedy_host_place(pt: ProblemTensors) -> tuple[np.ndarray, int]:
     violations = 0
     valid = np.asarray(pt.node_valid, dtype=bool)
     eligible = np.asarray(pt.eligible, dtype=bool)
+    # spread constraint: rows per topology domain, and the source's own
+    # filter (PodTopologySpread, DoNotSchedule) on where a row may go
+    topo = np.asarray(pt.node_topology)
+    per_domain = (np.zeros(int(topo.max(initial=0)) + 1, dtype=np.int64)
+                  if pt.max_skew > 0 else None)
 
     for s in order:
         cands = np.flatnonzero(eligible[s] & valid)
@@ -73,6 +78,11 @@ def greedy_host_place(pt: ProblemTensors) -> tuple[np.ndarray, int]:
             cands = np.arange(N)
             inelig = True
         fits = []
+        if per_domain is not None:
+            open_domain = per_domain + 1 - per_domain.min() <= pt.max_skew
+            cands = cands[open_domain[topo[cands]]]
+            if cands.size == 0:       # no open domain has a usable node
+                cands = np.flatnonzero(valid)
         for n in cands:
             if np.any(load[n] + demand[s] > capacity[n]):
                 continue
@@ -101,7 +111,12 @@ def greedy_host_place(pt: ProblemTensors) -> tuple[np.ndarray, int]:
         assignment[s] = n
         load[n] += demand[s]
         occupied.update((n, k, g) for k, g in conflict_groups(s))
+        if per_domain is not None:
+            per_domain[topo[n]] += 1
 
+    if per_domain is not None:
+        violations += max(
+            int(per_domain.max() - per_domain.min()) - pt.max_skew, 0)
     return assignment, violations
 
 

@@ -224,6 +224,16 @@ class TpuSolverScheduler:
         _M_RES_BYTES.set(self._resident_bytes())
         _M_RES_SLOTS.set(len(self._residents))
 
+    def forget(self, stage: str) -> None:
+        """The stage is gone (torn down), not idle: its resident slot and
+        its eviction snapshot go with it, so a later solve under the same
+        key stages cold and seeds afresh, and the device memory is free
+        now instead of at the next eviction."""
+        self._residents = [s for s in self._residents if s.key != stage]
+        self._evicted.pop(stage, None)
+        _M_RES_BYTES.set(self._resident_bytes())
+        _M_RES_SLOTS.set(len(self._residents))
+
     def byte_drift(self) -> int:
         """Live device bytes minus the accounted admission-time bytes,
         summed over resident slots — the cross-check the profiling hook

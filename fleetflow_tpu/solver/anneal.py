@@ -309,6 +309,55 @@ def _skew_pen(prob: DeviceProblem, topo: jax.Array) -> jax.Array:
     return jnp.maximum(skew - prob.max_skew, 0.0) * W_SKEW
 
 
+def spread_window(prob: DeviceProblem, topo: jax.Array):
+    """The band ``[lo, hi]`` of width `max_skew` a sweep keeps every
+    domain's count inside (spread stages only: callers sit behind the
+    static ``prob.max_skew > 0``).
+
+    A state within the bound has slack ``max_skew - (max - min) >= 0``;
+    the band is laid over its counts with the slack split between the two
+    ends, so every count is inside it. A state over the bound gets the
+    band whose floor is the mean's floor: the balanced state lies in it,
+    every domain above it may only lose rows and every domain below it
+    only gain them, so the excess never grows and each such move is
+    downhill — where ``max - min`` is flat for every move that touches
+    neither the fullest nor the emptiest domain."""
+    k = jnp.int32(prob.max_skew)
+    lo, hi = topo.min(), topo.max()
+    slack = k - (hi - lo)
+    floor = jnp.where(slack >= 0, lo - slack // 2, topo.sum() // prob.T)
+    return floor, floor + k
+
+
+def _band_excess(count: jax.Array, window) -> jax.Array:
+    """How far one domain's count lies outside the band (i32, >= 0)."""
+    lo, hi = window
+    return jnp.maximum(count - hi, 0) + jnp.maximum(lo - count, 0)
+
+
+def _admit_spread(topo: jax.Array, window, ta: jax.Array, tb: jax.Array,
+                  crossing: jax.Array) -> jax.Array:
+    """Which of a step's domain-crossing moves may land together (M,) bool.
+
+    Each move was priced alone against the pre-step counts; a domain's
+    count is shared by all its nodes, so the winner-per-target-node rule
+    does not bound what a step does to it. Here a domain takes at most as
+    many entrants as it has room under the band's ceiling and loses at
+    most as many rows as it holds above the band's floor, lowest proposal
+    index first: whatever subset lands, every count that was inside the
+    band stays inside it, and one outside it only moves toward it. A
+    chain within the bound therefore stays within it through the whole
+    anneal, and what the moves do to `topo` together is what was priced."""
+    lo, hi = window
+    M = ta.shape[0]
+    earlier = jnp.tril(jnp.ones((M, M), bool), k=-1) & crossing[None, :]
+    rank_in = ((tb[:, None] == tb[None, :]) & earlier).sum(-1)
+    rank_out = ((ta[:, None] == ta[None, :]) & earlier).sum(-1)
+    room_in = jnp.maximum(hi - topo, 0)[tb]
+    room_out = jnp.maximum(topo - lo, 0)[ta]
+    return ~crossing | ((rank_in < room_in) & (rank_out < room_out))
+
+
 def _soft_rows(prob: DeviceProblem, load_rows: jax.Array,
                cap_rows: jax.Array) -> jax.Array:
     """Strategy soft term restricted to the touched node rows."""
@@ -327,7 +376,7 @@ def _move_delta_core(prob: DeviceProblem, *, capacity: jax.Array,
                      a: jax.Array, b: jax.Array, d: jax.Array,
                      ids: jax.Array, cids: jax.Array, elig_a: jax.Array,
                      elig_b: jax.Array, d_pref: jax.Array,
-                     r: jax.Array) -> jax.Array:
+                     r: jax.Array, window=None) -> jax.Array:
     """Annealing-cost delta of moving one service from node `a` to node `b`,
     shared term for term between the single-device sweep (_proposal_delta)
     and the service-axis sharded sweep (solver/sharded.py) — "a legal sweep
@@ -340,7 +389,11 @@ def _move_delta_core(prob: DeviceProblem, *, capacity: jax.Array,
     gathers against the replicated node state. `elig_a`/`elig_b` are the
     node_valid-masked eligibility bits of the two endpoints, `d_pref` the
     preference delta (including any warm-start stickiness), `r` the row's
-    topology weight (0 for bucket-padding phantoms)."""
+    topology weight (0 for bucket-padding phantoms). `window` (spread
+    stages on the single-device sweep) is the step's `spread_window`: the
+    skew term is then the two touched domains' distance from the band,
+    which `_admit_spread` makes exact for the moves applied together; the
+    sharded sweep passes none and prices ``max - min`` as before."""
     valid = (ids >= 0)
     safe = jnp.where(valid, ids, 0)
     cvalid = (cids >= 0)
@@ -368,8 +421,16 @@ def _move_delta_core(prob: DeviceProblem, *, capacity: jax.Array,
 
     # skew (phantom rows carry no topology weight)
     ta, tb = node_topology[a], node_topology[b]
-    topo2 = topo.at[ta].add(-r).at[tb].add(r)
-    d_skew = _skew_pen(prob, topo2) - _skew_pen(prob, topo)
+    if window is not None:
+        ca, cb = topo[ta], topo[tb]
+        moved = jnp.where(ta == tb, 0, r)
+        d_skew = (_band_excess(ca - moved, window)
+                  + _band_excess(cb + moved, window)
+                  - _band_excess(ca, window)
+                  - _band_excess(cb, window)).astype(jnp.float32) * W_SKEW
+    else:
+        topo2 = topo.at[ta].add(-r).at[tb].add(r)
+        d_skew = _skew_pen(prob, topo2) - _skew_pen(prob, topo)
 
     # -- soft deltas ---------------------------------------------------------
     soft_before = _soft_rows(prob, jnp.stack([load_a, load_b]),
@@ -385,7 +446,7 @@ def _move_delta_core(prob: DeviceProblem, *, capacity: jax.Array,
 
 
 def _proposal_delta(prob: DeviceProblem, state: ChainState,
-                    s: jax.Array, b: jax.Array) -> jax.Array:
+                    s: jax.Array, b: jax.Array, window=None) -> jax.Array:
     """Annealing-cost delta of moving service s to node b (no apply)."""
     a = state.assignment[s]
     elig_a = eligible_lookup(prob.eligible, s, a) & prob.node_valid[a]
@@ -411,7 +472,7 @@ def _proposal_delta(prob: DeviceProblem, state: ChainState,
         load=state.load, used=state.used, coloc=state.coloc, topo=state.topo,
         a=a, b=b, d=prob.demand[s], ids=prob.conflict_ids[s],
         cids=prob.coloc_ids[s], elig_a=elig_a, elig_b=elig_b,
-        d_pref=d_pref, r=r)
+        d_pref=d_pref, r=r, window=window)
 
 
 def _batched_step(prob: DeviceProblem, state: ChainState,
@@ -450,9 +511,27 @@ def _batched_step(prob: DeviceProblem, state: ChainState,
     half = M // 2
     s_idx = jnp.where(jnp.arange(M) < half, s_tgt, s_uni)
     b_idx = jax.random.randint(kb, (M,), 0, prob.N)
+    window = None
+    if prob.max_skew > 0:
+        # a spread stage: the band this step holds the counts to. While a
+        # domain sits above it, the targeted half lands on the valid nodes
+        # of domains with room under its ceiling (one inverse-CDF draw
+        # over the nodes), so the rows the band sheds are not left to find
+        # a small domain by chance
+        window = spread_window(prob, state.topo)
+        over_band = state.topo > window[1]                           # (T,)
+        room = ((state.topo < window[1])[prob.node_topology]
+                & prob.node_valid).astype(jnp.float32)
+        cdf = jnp.cumsum(room)
+        pick = jnp.searchsorted(
+            cdf, jax.random.uniform(jax.random.fold_in(kb, 1), (M,))
+            * cdf[-1], side="right")
+        aim = (jnp.arange(M) < half) & over_band.any() & (cdf[-1] > 0)
+        b_idx = jnp.where(aim, jnp.minimum(pick, prob.N - 1), b_idx)
     a_idx = state.assignment[s_idx]
 
-    delta = jax.vmap(lambda s, b: _proposal_delta(prob, state, s, b))(
+    delta = jax.vmap(
+        lambda s, b: _proposal_delta(prob, state, s, b, window))(
         s_idx, b_idx)
     u = jax.random.uniform(ka, (M,))
     accept = ((delta < 0) | (u < jnp.exp(-delta / jnp.maximum(temp, 1e-8)))) \
@@ -472,6 +551,13 @@ def _batched_step(prob: DeviceProblem, state: ChainState,
     tgt_winner = jnp.full((prob.N,), M, dtype=jnp.int32).at[b_idx].min(
         jnp.where(applied, order, M))
     applied = applied & (tgt_winner[b_idx] == order)
+    if prob.max_skew > 0:
+        ta, tb = prob.node_topology[a_idx], prob.node_topology[b_idx]
+        crossing = applied & (ta != tb)
+        if prob.n_real is not None:
+            crossing = crossing & (s_idx < prob.n_real)
+        applied = applied & _admit_spread(state.topo, window, ta, tb,
+                                          crossing)
     w = applied.astype(jnp.float32)
     wi = applied.astype(jnp.int32)
 

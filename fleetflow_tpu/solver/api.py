@@ -33,7 +33,7 @@ from .buckets import (bucket_config, pad_assignment, pad_problem_tiers,
                       record_bucket, soft_score_host, stage_problem_tiers,
                       _env_flag)
 from .greedy import greedy_place, greedy_place_batched, placement_order
-from .kernels import soft_score, violation_stats
+from .kernels import _skew_excess, soft_score, violation_stats
 from .problem import DeviceProblem, prepare_problem
 from .repair import RepairResult, repair, verify
 from .resident import ResidentProblem, transfer_guard_ctx
@@ -71,6 +71,18 @@ _M_BUCKET = REGISTRY.counter(
 _M_PAD_WASTE = REGISTRY.gauge(
     "fleet_solver_bucket_pad_waste_ratio",
     "Phantom fraction of the most recent bucketed solve's service rows")
+_M_SPREAD = REGISTRY.counter(
+    "fleet_solver_spread_solves_total",
+    "Solves of a stage that carries a spread constraint (max_skew > 0)")
+_M_SPREAD_EXCESS = REGISTRY.counter(
+    "fleet_solver_spread_excess_total",
+    "Excess of (max - min) rows per topology domain over max_skew, summed "
+    "over spread solves: after the seed, in the device's winner before "
+    "the host touches it, and in what is returned", labels=("at",))
+_M_SPREAD_REPAIR = REGISTRY.counter(
+    "fleet_solver_spread_repair_moves_total",
+    "Rows the host's repair moved in solves of spread stages (above 0 "
+    "the host finished the annealer's work)")
 _M_INFLIGHT = REGISTRY.gauge(
     "fleet_solver_dispatches_in_flight",
     "Solver anneal dispatches currently executing (full fused + "
@@ -317,6 +329,10 @@ def _refine(prob: DeviceProblem, seed_assignment: jax.Array, key: jax.Array,
             stats = violation_stats(prob, winner)
             soft = soft_score(prob, winner)
     telem = dict(telem, prerepair_moves=prerepair_applied)
+    if prob.max_skew > 0:
+        # what the seed (a warm start's, after the prologue) left the
+        # sweeps to do; rides the one fetch
+        telem["seed_skew"] = _skew_excess(prob, seed_assignment)
     return winner, stats, soft, sweeps_run, accepted, telem
 
 
@@ -493,7 +509,11 @@ def _solve(pt: ProblemTensors, *,
             t0 = min(t0, 0.1)  # warm start: refine, don't re-scramble
         else:
             if seed_impl is None:
-                if jax.default_backend() == "cpu":
+                if pt.max_skew > 0:
+                    # the host FFDs never read node_topology; the batched
+                    # seed deals a spread stage's rows to its domains
+                    seed_impl = "batched"
+                elif jax.default_backend() == "cpu":
                     # nobuild: auto-pick must never trigger a synchronous make
                     # inside the timed solve; explicit seed_impl="native" may
                     from ..native.lib import available_nobuild
@@ -823,6 +843,14 @@ def _solve(pt: ProblemTensors, *,
         _M_COMPILES.inc(compile_events)
     _M_VIOL.set(int(stats["total"]))
     _M_PRE_VIOL.set(pre_repair)
+    if pt.max_skew > 0:
+        _M_SPREAD.inc()
+        seed_skew = htelem.get("seed_skew")
+        if seed_skew is not None:
+            _M_SPREAD_EXCESS.inc(int(seed_skew), at="seed")
+        _M_SPREAD_EXCESS.inc(int(dstats["skew"]), at="device")
+        _M_SPREAD_EXCESS.inc(int(stats["skew"]), at="final")
+        _M_SPREAD_REPAIR.inc(moves)
     log.info("solve %s", kv(
         S=pt.S, N=prob.N, chains=chains, steps=steps,
         sweeps=int(sweeps_run),
@@ -832,6 +860,7 @@ def _solve(pt: ProblemTensors, *,
         bucket_hit=(binfo.hit or None) if binfo is not None else None,
         violations=int(stats["total"]), pre_repair=pre_repair,
         repaired=moves or None, warm=warm or None,
+        spread_domains=prob.T if pt.max_skew > 0 else None,
         resident=resident_warm or None,
         sub=(f"{sub_info['rows']}/{sub_info['tier']}"
              f"({sub_info['outcome']})" if sub_info else None),

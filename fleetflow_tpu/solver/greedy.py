@@ -35,7 +35,7 @@ import numpy as np
 from .problem import DeviceProblem, eligible_row, eligible_rows
 
 __all__ = ["greedy_place", "greedy_place_batched", "placement_order",
-           "partitioned_seed"]
+           "partitioned_seed", "deal_to_domains"]
 
 _NEG = -1e30
 
@@ -55,6 +55,40 @@ def placement_order(demand: np.ndarray, dep_depth: np.ndarray,
     return np.lexsort((dep_depth, -weight)).astype(np.int32)
 
 
+def _real_rows(prob: DeviceProblem, svc: jax.Array) -> jax.Array:
+    """Which of the rows `svc` count toward a spread constraint: every row
+    but the bucket-padding phantoms (rows >= n_real)."""
+    if prob.n_real is None:
+        return jnp.ones(svc.shape, bool)
+    return svc < prob.n_real
+
+
+def deal_to_domains(counts: jax.Array, m: jax.Array) -> jax.Array:
+    """How many of `m` new rows each topology domain takes so that the
+    counts stay as level as they can be: (T,) i32 summing to `m`.
+
+    Water-filling, in closed form over the sorted counts: the emptiest
+    domains are raised to one common level, the remainder goes one each to
+    the first of them in (count, id) order. The counts it ends in are those
+    that dealing the rows one at a time to the emptiest domain ends in
+    (which of several equal domains holds the odd row may differ), without
+    the `m` sequential steps: the spread never widens, and a spread wider
+    than one narrows by as much as `m` rows can."""
+    T = counts.shape[0]
+    order = jnp.argsort(counts, stable=True)
+    s = counts[order]
+    j = jnp.arange(1, T + 1, dtype=jnp.int32)
+    # rows it takes to raise the j emptiest domains to the j-th's count
+    need = j * s - jnp.cumsum(s)
+    n_fill = jnp.sum(need <= m).astype(jnp.int32)        # >= 1: need[0] = 0
+    spare = m - need[n_fill - 1]
+    level = s[n_fill - 1] + spare // n_fill
+    extra = spare % n_fill
+    pos = jnp.arange(T, dtype=jnp.int32)
+    filled = jnp.where(pos < n_fill, level + (pos < extra) - s, 0)
+    return jnp.zeros((T,), jnp.int32).at[order].set(filled.astype(jnp.int32))
+
+
 @partial(jax.jit, static_argnames=("best_effort",))
 def greedy_place(prob: DeviceProblem, order: jax.Array,
                  best_effort: bool = True) -> jax.Array:
@@ -62,8 +96,10 @@ def greedy_place(prob: DeviceProblem, order: jax.Array,
     R = prob.demand.shape[1]
     eps = 1e-6
 
+    spread = prob.max_skew > 0
+
     def step(carry, s):
-        load, used, assignment = carry
+        load, used, assignment, *topo = carry
         d = prob.demand[s]                      # (R,)
         ids = prob.conflict_ids[s]              # (K,)
         valid_ids = (ids >= 0)
@@ -74,6 +110,14 @@ def greedy_place(prob: DeviceProblem, order: jax.Array,
         fits = (new_load <= prob.capacity + eps).all(-1)
         elig_s = eligible_row(prob.eligible, s, prob.N)
         ok = fits & elig_s & prob.node_valid & ~conflict
+        if spread:
+            # the source's own filter (PodTopologySpread, DoNotSchedule): a
+            # domain may take the row while its count, with the row, stays
+            # within max_skew of the emptiest domain's
+            (topo,) = topo
+            real = _real_rows(prob, s)
+            open_dom = topo + 1 - topo.min() <= prob.max_skew
+            ok = ok & (open_dom[prob.node_topology] | ~real)
 
         u_after = new_load / jnp.maximum(prob.capacity, 1e-6)
         usq = (u_after * u_after).sum(-1)                              # (N,)
@@ -100,6 +144,10 @@ def greedy_place(prob: DeviceProblem, order: jax.Array,
         load = load.at[node].add(d)
         used = used.at[node, safe].add(valid_ids.astype(used.dtype))
         assignment = assignment.at[s].set(node.astype(jnp.int32))
+        if spread:
+            topo = topo.at[prob.node_topology[node]].add(
+                real.astype(jnp.int32))
+            return (load, used, assignment, topo), None
         return (load, used, assignment), None
 
     init = (
@@ -107,10 +155,12 @@ def greedy_place(prob: DeviceProblem, order: jax.Array,
         jnp.zeros((prob.N, prob.G), dtype=jnp.int32),
         jnp.full((prob.S,), -1, dtype=jnp.int32),
     )
+    if spread:
+        init = init + (jnp.zeros((prob.T,), jnp.int32),)
     # unroll: one fused device step per 8 services — the scan is dispatch-
     # bound at fleet scale (each step's math is tiny), so unrolling buys
     # ~40% wall-clock at 10k services
-    (_, _, assignment), _ = jax.lax.scan(step, init, order, unroll=8)
+    (_, _, assignment, *_), _ = jax.lax.scan(step, init, order, unroll=8)
     return assignment
 
 
@@ -219,10 +269,25 @@ def greedy_place_batched(prob: DeviceProblem, order: jax.Array,
     # and the pairwise gate rejects most of them every round)
     W = min(M, N)
 
+    spread = prob.max_skew > 0
+
     def step(carry, svc_raw):
-        load, used, assignment = carry
+        load, used, assignment, *topo = carry
         live0 = svc_raw >= 0
         svc = jnp.where(live0, svc_raw, 0)
+        in_domain = None
+        if spread:
+            # a spread stage: the batch's real rows are dealt to the
+            # domains so the counts stay level (deal_to_domains), each row
+            # then chooses among its own domain's nodes; which row takes
+            # which domain is free, the rows count alike
+            (topo,) = topo
+            real = live0 & _real_rows(prob, svc)
+            quota = deal_to_domains(topo, real.sum().astype(jnp.int32))
+            rank = jnp.cumsum(real.astype(jnp.int32)) - 1
+            dom = jnp.searchsorted(jnp.cumsum(quota), rank, side="right")
+            in_domain = ((prob.node_topology[None, :] == dom[:, None])
+                         | ~real[:, None])                   # (M, N)
 
         def choose(load, used, live):
             score, fits, overflow = _node_scores(prob, load, svc)
@@ -230,6 +295,8 @@ def greedy_place_batched(prob: DeviceProblem, order: jax.Array,
             elig_b = eligible_rows(prob.eligible, svc, prob.N)   # (M, N)
             hard_ok = (fits & elig_b & prob.node_valid[None]
                        & ~conflict)
+            if spread:
+                hard_ok = hard_ok & in_domain
             masked = jnp.where(hard_ok, score, _NEG)
             # Anti-herding ranks: a plain argmax sends every batch-mate to
             # the same node; the pairwise gate then admits only one node's
@@ -260,6 +327,10 @@ def greedy_place_batched(prob: DeviceProblem, order: jax.Array,
             best_ok = jnp.take_along_axis(topk, r_eff[:, None], 1)[:, 0]
             # fallback: least overflow / fewest conflicts among eligible
             fb_score = score - overflow * 1e3 - conflict * 1e3
+            if spread:
+                # a row its domain has no room for: off it, at a conflict's
+                # price, and the sweeps carry the skew back
+                fb_score = fb_score - (~in_domain) * 1e3
             fb_ok = elig_b & prob.node_valid[None]
             best_fb = jnp.argmax(jnp.where(fb_ok, fb_score, fb_score - 1e15),
                                  axis=-1)
@@ -286,6 +357,14 @@ def greedy_place_batched(prob: DeviceProblem, order: jax.Array,
         # the annealer repairs (FallbackPolicy relax-order in spirit)
         load, used, assignment = _commit(prob, load, used, assignment,
                                          svc, c_tail, rest)
+        if spread:
+            # where the rows landed, not where they were dealt: the next
+            # batch's deal levels out what a fallback left uneven
+            # (a second round's winners chose c2, which is c_tail)
+            landed = jnp.where(ok1, c1, c_tail)
+            topo = topo.at[prob.node_topology[landed]].add(
+                real.astype(jnp.int32))
+            return (load, used, assignment, topo), None
         return (load, used, assignment), None
 
     R = prob.demand.shape[1]
@@ -294,7 +373,9 @@ def greedy_place_batched(prob: DeviceProblem, order: jax.Array,
         jnp.zeros((N, prob.G), jnp.int32),
         jnp.full((S + 1,), -1, jnp.int32),   # +1 dump row
     )
-    (_, _, assignment), _ = jax.lax.scan(step, init, batches)
+    if spread:
+        init = init + (jnp.zeros((prob.T,), jnp.int32),)
+    (_, _, assignment, *_), _ = jax.lax.scan(step, init, batches)
     return assignment[:S]
 
 

@@ -4,8 +4,9 @@ The deterministic backstop behind the zero-violation contract: the device
 solver (greedy + annealing) lands feasible in practice, but the contract is
 exact, so any residual violations are repaired here with vectorized numpy —
 move each violating service to the best feasible node, smallest first, a
-bounded number of rounds. Also home to `verify()`, the numpy ground-truth
-violation accounting that tests use to cross-check the device kernels.
+bounded number of rounds, then level a spread stage's topology domains.
+Also home to `verify()`, the numpy ground-truth violation accounting that
+tests use to cross-check the device kernels.
 """
 
 from __future__ import annotations
@@ -80,6 +81,60 @@ class RepairResult:
     moves: int
     stats: dict
     feasible: bool
+    # of `moves`, those the spread pass made (fullest domain -> emptiest)
+    skew_moves: int = 0
+
+
+def _level_domains(pt: ProblemTensors, assignment: np.ndarray,
+                   ids: np.ndarray, G: int, budget: int) -> int:
+    """The spread pass: while the fullest topology domain holds more than
+    `max_skew` rows over the emptiest, take from the fullest a row that
+    fits on a node of the emptiest — eligible, valid, within capacity, no
+    shared conflict id — smallest first, onto the least-utilized such node.
+    Edits `assignment` in place and returns the moves made; stops where no
+    row of the fullest domain fits anywhere in the emptiest (capacity or
+    eligibility binds there: the instance is infeasible as spread)."""
+    N = pt.N
+    topo = np.asarray(pt.node_topology)
+    T = int(topo.max(initial=0)) + 1
+    demand = pt.demand.astype(np.float64)
+    cap = pt.capacity.astype(np.float64) * (1 + 1e-6)
+    load = np.zeros((N, demand.shape[1]), dtype=np.float64)
+    np.add.at(load, assignment, demand)
+    counts = (_group_counts(assignment, ids, N, G) if G > 0 else None)
+    per = np.bincount(topo[assignment], minlength=T)
+    size = demand.sum(axis=1)
+    moves = 0
+    while moves < budget:
+        full, empty = int(per.argmax()), int(per.argmin())
+        if per[full] - per[empty] <= pt.max_skew:
+            break
+        into = np.flatnonzero((topo == empty) & pt.node_valid)
+        rows = np.flatnonzero(topo[assignment] == full)
+        for s in rows[np.argsort(size[rows], kind="stable")]:
+            mine = ids[s][ids[s] >= 0]
+            ok = pt.eligible[s, into] & (
+                load[into] + demand[s] <= cap[into]).all(axis=1)
+            if mine.size:
+                ok &= (counts[np.ix_(into, mine)] == 0).all(axis=1)
+            if ok.any():
+                break
+        else:
+            break           # nothing of the fullest fits in the emptiest
+        cand = into[ok]
+        n = int(cand[np.argmin(
+            (load[cand] / np.maximum(cap[cand], 1e-6)).max(axis=1))])
+        a = int(assignment[s])
+        load[a] -= demand[s]
+        load[n] += demand[s]
+        if mine.size:
+            counts[a, mine] -= 1
+            counts[n, mine] += 1
+        assignment[s] = n
+        per[full] -= 1
+        per[empty] += 1
+        moves += 1
+    return moves
 
 
 def repair(pt: ProblemTensors, assignment: np.ndarray,
@@ -314,6 +369,13 @@ def repair(pt: ProblemTensors, assignment: np.ndarray,
         if not queue and not evicted_any and not detached.any():
             break
 
+    # the relocations above read no topology: what they and the device
+    # left uneven is levelled last, by moves that keep the rest feasible
+    skew_moves = 0
+    if pt.max_skew > 0:
+        skew_moves = _level_domains(pt, assignment, ids, G, budget=4 * S)
+        moves += skew_moves
+
     stats = verify(pt, assignment)
     # Ejection leaves un-replaced evictees at stale nodes when the budget
     # exhausts; never return something worse than the input.
@@ -321,5 +383,6 @@ def repair(pt: ProblemTensors, assignment: np.ndarray,
         in_stats = verify(pt, original)
         if in_stats["total"] < stats["total"]:
             assignment, stats, moves = original.copy(), in_stats, 0
+            skew_moves = 0
     return RepairResult(assignment=assignment, moves=moves, stats=stats,
-                        feasible=stats["total"] == 0)
+                        feasible=stats["total"] == 0, skew_moves=skew_moves)
