@@ -47,9 +47,13 @@ STRATEGY_CODES = {
 # plane reads). The packed layout attacks both:
 #
 #   eligible   bit-packed (S, ceil(N/32)) uint32 — one bit per node, 8x
-#              fewer bytes than the dense bool plane; the kernels unpack
-#              with a shift/mask at each gather site (cheap ALU vs.
-#              streamed bytes on both TPU and CPU)
+#              fewer bytes than the dense bool plane. Two kinds of read:
+#              a POINT (s, node) gathers its one word and shifts/masks it
+#              (eligible_lookup: S or proposals_per_step elements); a whole
+#              ROW gathers its W words once and unpacks all 32 bits of each
+#              with a broadcast shift (eligible_row/eligible_rows) — never
+#              one gather per (row, node) cell, which costs a TPU ~10 ns a
+#              cell (118 ms of a 2,000 x 5,000 seed, PERF.md section 6, PR 40)
 #   preferred  ABSENT from the pytree (None) when no service scores nodes,
 #              instead of a materialized 4*S*N zero plane every sweep then
 #              streams; `prob.preferred is None` is a static treedef fact,
@@ -102,9 +106,11 @@ def pack_bool_rows(mask: np.ndarray) -> np.ndarray:
 
 
 def eligible_lookup(eligible: jax.Array, s, node) -> jax.Array:
-    """eligible[s, node] as bool, for either plane layout: dense (S, N)
-    bool, or bit-packed (S, ceil(N/32)) uint32 unpacked with shift/mask at
-    the gather site. `s`/`node` broadcast like fancy indices."""
+    """eligible[s, node] as bool at POINTS, for either plane layout: dense
+    (S, N) bool, or bit-packed (S, ceil(N/32)) uint32 — each point gathers
+    its one word and shifts/masks it. `s`/`node` broadcast like fancy
+    indices. For S or proposals_per_step points; a whole row is read by
+    eligible_row/eligible_rows, which gather words, not cells."""
     if eligible.dtype != jnp.uint32:
         return eligible[s, node]
     node = jnp.asarray(node)
@@ -113,20 +119,29 @@ def eligible_lookup(eligible: jax.Array, s, node) -> jax.Array:
             & jnp.uint32(1)).astype(bool)
 
 
+def _unpack_words(words: jax.Array, N: int) -> jax.Array:
+    """(..., W) packed words -> (..., N) bool, pack_bool_rows's bit order
+    (bit j of word w is column 32*w + j): every word's 32 bits by one
+    broadcast shift, the pad bits of the last word cut off."""
+    bits = (words[..., None] >> jnp.arange(PLANE_PACK, dtype=jnp.uint32)
+            ) & jnp.uint32(1)
+    return bits.reshape(*words.shape[:-1], -1)[..., :N].astype(bool)
+
+
 def eligible_row(eligible: jax.Array, s, N: int) -> jax.Array:
-    """One service's full (N,) eligibility row (dense or unpacked)."""
+    """One service's full (N,) eligibility row: the dense plane's row, or
+    the packed plane's W words unpacked."""
     if eligible.dtype != jnp.uint32:
         return eligible[s]
-    cols = jnp.arange(N, dtype=jnp.int32)
-    return eligible_lookup(eligible, s, cols)
+    return _unpack_words(eligible[s], N)
 
 
 def eligible_rows(eligible: jax.Array, svc: jax.Array, N: int) -> jax.Array:
-    """(M, N) eligibility rows for a batch of services (dense or unpacked)."""
+    """(M, N) eligibility rows for a batch of services: a row gather either
+    way — M dense rows, or M rows of W packed words, unpacked."""
     if eligible.dtype != jnp.uint32:
         return eligible[svc]
-    cols = jnp.arange(N, dtype=jnp.int32)
-    return eligible_lookup(eligible, svc[:, None], cols[None, :])
+    return _unpack_words(eligible[svc], N)
 
 
 # metric catalog: docs/guide/10-observability.md
@@ -161,8 +176,9 @@ class DeviceProblem:
     capacity: jax.Array        # (N, R) f32
     conflict_ids: jax.Array    # (S, K) i32, -1 pad (ports ∪ volumes ∪ anti)
     coloc_ids: jax.Array       # (S, C) i32, -1 pad
-    # bit-packed (S, ceil(N/32)) uint32 (production layout; read through
-    # eligible_lookup/eligible_row) or dense (S, N) bool (FLEET_PACKED=0)
+    # bit-packed (S, ceil(N/32)) uint32 (production layout; points read
+    # through eligible_lookup, rows through eligible_row/eligible_rows) or
+    # dense (S, N) bool (FLEET_PACKED=0)
     eligible: jax.Array
     node_valid: jax.Array      # (N,) bool
     node_topology: jax.Array   # (N,) i32 in [0, T)
