@@ -76,9 +76,10 @@ import numpy as np
 
 from ..core.errors import ControlPlaneError
 from ..core.model import Flow, ResourceSpec, Service
-from ..obs import get_logger, kv
+from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
+from ..obs.trace import record_interval
 
 # the active-set dispatch vocabulary (solver/subsolve.py); read via the
 # registry so a host-path CP's status call never imports jax
@@ -94,7 +95,7 @@ def subsolve_outcomes() -> dict:
 log = get_logger("cp.admission")
 
 __all__ = ["AdmissionConfig", "AdmissionController", "AdmissionRejected",
-           "AdmissionRequest"]
+           "AdmissionRequest", "Waiter"]
 
 _M_DEPTH = REGISTRY.gauge(
     "fleet_admission_queue_depth",
@@ -141,6 +142,24 @@ _M_SOLVES = REGISTRY.counter(
     "fleet_admission_solves_total",
     "Admission micro-solves, by outcome",
     labels=("outcome",))
+_M_EVENTS = REGISTRY.counter(
+    "fleet_admission_events_total",
+    "Arrivals and departures folded into admission micro-solves, by kind "
+    "(what fleet_admission_batch_size observes, as a counter a ratio can "
+    "read)",
+    labels=("kind",))
+_M_MOVED = REGISTRY.counter(
+    "fleet_admission_moved_rows_total",
+    "Rows of a stream that were live before an admission micro-solve and "
+    "that its committed plan left on another server: a moved service is a "
+    "restarted container, and an arrival should move none")
+_M_WAKES = REGISTRY.counter(
+    "fleet_admission_wakes_total",
+    "Drain passes the background loop took, by what woke it: submit (a "
+    "submit found it asleep), backlog (the pass before left work), timer "
+    "(drain_interval_s ran out: a parked retry, an aged tail, work the "
+    "last pass could do nothing about)",
+    labels=("by",))
 _M_RATE = REGISTRY.gauge(
     "fleet_admission_placements_per_s",
     "Sustained admission throughput over the most recent drain window "
@@ -220,9 +239,38 @@ class AdmissionRequest:
     # depth (on_full="park" policy), quota (tenant hard cap). Drives the
     # retry policy: quota parks wait for tenant headroom, not capacity
     park_reason: Optional[str] = None
+    # where the committed plan put a placed arrival
+    server: Optional[str] = None
 
     TERMINAL = frozenset({"placed", "departed", "parked", "shed",
                           "cancelled"})
+
+    def verdict(self) -> dict:
+        """What a waiting caller is told of this request."""
+        out = {"id": self.id, "kind": self.kind, "name": self.name,
+               "state": self.state}
+        if self.state == "placed":
+            out["server"] = self.server
+        elif self.state == "parked":
+            out["reason"] = self.park_reason
+        return out
+
+
+class Waiter:
+    """The caller of one submit, waiting to be told what its requests
+    came to. `notify()` is called once, when the last of them is terminal
+    (placed | departed | parked | shed | cancelled) — from `submit` if
+    they all are by then, else from the thread that settles the last one,
+    which holds the controller's lock: it must only signal (set an event,
+    schedule a callback), never call back in. `submit` fills `requests`;
+    `AdmissionController.verdicts` reads them."""
+
+    __slots__ = ("notify", "requests", "pending")
+
+    def __init__(self, notify: Callable[[], None]):
+        self.notify = notify
+        self.requests: list[AdmissionRequest] = []
+        self.pending: set[str] = set()
 
 
 @dataclass
@@ -303,7 +351,7 @@ class AdmissionController:
         self.stats = {"admitted": 0, "departed": 0, "sheds": 0,
                       "parked": 0, "unparked": 0, "solves": 0,
                       "compactions": 0, "batches": 0, "quota_parked": 0,
-                      "restored": 0}
+                      "restored": 0, "moved_rows": 0}
         # wall-ms of the most recent drain pass, by phase (drain / fold /
         # solve / commit) — surfaced through deploy.admit_status so a
         # p99 solve tail can be attributed to a phase without a profiler
@@ -312,7 +360,19 @@ class AdmissionController:
         # first-class operator number — `fleet admit status` reports the
         # p50/p99 so a re-grown tail does not hide in an average
         self.solve_ms_samples: deque[float] = deque(maxlen=4096)
+        # request id -> the callers waiting on it (submit's `waiter`)
+        self._watches: dict[str, list[Waiter]] = {}
+        # verdicts written so far (`_settle`): what a drain pass's
+        # `progress` is read from
+        self._settled = 0
+        # perf_counter reading since which queued work has waited for a
+        # drain pass (None: nothing waits); the next step writes the
+        # interval as the phase cp.admission.wait.drain
+        self._waiting_since: Optional[float] = None
         self._task = None
+        # the background loop's event loop and its wake-up (run_loop)
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
+        self._wake: Optional[asyncio.Event] = None
         self._restore_parked()
 
     # ------------------------------------------------------------------
@@ -488,14 +548,17 @@ class AdmissionController:
         )
 
     def submit(self, tenant: str, arrivals=(), departures=(), *,
-               stage: Optional[str] = None) -> dict:
+               stage: Optional[str] = None,
+               waiter: Optional[Waiter] = None) -> dict:
         """Enqueue a batch of arrivals (Service or wire spec dicts) and
         departures (service names). Atomic: validates everything first,
         then enqueues everything — a bad entry rejects the whole submit
         with ValueError; backpressure rejects it with AdmissionRejected
-        (retryable). Returns {accepted, queued, stage}."""
+        (retryable). Returns {accepted, queued, stage}. A `waiter` is told
+        when every request this submit accepted is terminal (`Waiter`):
+        it is registered before any drain pass can see them."""
         now = self.clock()
-        with self._lock:
+        with phase("cp.admission.submit"), self._lock:
             stream = self._stream_for(stage)
             self._resync(stream)
             svcs: list[Service] = []
@@ -570,7 +633,7 @@ class AdmissionController:
             # full queue would turn transient backpressure into a stall
             # (deps are naturally bounded by the live set, so the
             # exemption cannot grow the queue without bound)
-            depth = sum(len(q) for q in self._queues.values())
+            depth = self._depth()
             incoming = len(svcs) + len(deps)
             if svcs and depth + incoming > self.cfg.max_queue:
                 if self.cfg.on_full == "park":
@@ -596,6 +659,11 @@ class AdmissionController:
                 result["quota_parked"] = len(ids)
             self._update_pressure(now)
             self._set_queue_gauges(now)
+            if waiter is not None:
+                self._watch(result["accepted"], waiter)
+            if self._waiting_since is None and self._has_work_locked():
+                self._waiting_since = time.perf_counter()
+            self._wake_loop()
             return result
 
     def _enqueue(self, stream: _Stream, tenant: str, svcs: list[Service],
@@ -725,61 +793,106 @@ class AdmissionController:
     # the drain pass
     # ------------------------------------------------------------------
 
+    def _depth(self) -> int:
+        return sum(len(q) for q in self._queues.values())
+
     def has_work(self) -> bool:
         with self._lock:
-            # parked arrivals whose capacity epoch moved are pending a
-            # retry — real work; parked-with-unchanged-epoch is not (no
-            # hot loop on a standing infeasibility)
-            return (any(self._queues.values())
-                    or (bool(self._parked)
-                        and self._park_epoch != self._capacity_epoch))
+            return self._has_work_locked()
+
+    def _has_work_locked(self) -> bool:
+        # parked arrivals whose capacity epoch moved are pending a
+        # retry — real work; parked-with-unchanged-epoch is not (no
+        # hot loop on a standing infeasibility)
+        return (any(self._queues.values())
+                or (bool(self._parked)
+                    and self._park_epoch != self._capacity_epoch))
 
     def step(self, now: Optional[float] = None) -> dict:
         """One drain pass: retry parked if capacity moved, shed the aged
         tail, pop one DRR batch, fold + micro-solve + commit per stage.
-        Returns a summary for callers that narrate (chaos runner, tests)."""
+        Returns a summary for callers that narrate (chaos runner, tests);
+        its `progress` says whether the pass wrote a verdict or left the
+        queues shorter — a pass that did neither (departures that cannot
+        be applied go back to the head of their queue) would do the same
+        again at once, so the drain loop leaves it to its timer. The pass
+        is the phase cp.admission.step, a root (it serves many requests),
+        with children .drain, .fold, .solve and .commit: the summary's
+        `phase_ms` and fleet_admission_solve_phase_ms are read from
+        them."""
         with self._lock:
-            now = self.clock() if now is None else now
-            t_drain = time.perf_counter()
+            if self._waiting_since is not None:
+                # what queued work waited for this pass
+                record_interval("cp.admission.wait.drain",
+                                self._waiting_since)
+                self._waiting_since = None
+            depth, settled = self._depth(), self._settled
+            with phase("cp.admission.step") as ph:
+                summary = self._step_locked(
+                    self.clock() if now is None else now)
+                ph.set(batch=summary["batch"])
+            summary["progress"] = (self._settled != settled
+                                   or self._depth() < depth)
+            if self._has_work_locked():
+                self._waiting_since = time.perf_counter()
+            return summary
+
+    def _step_locked(self, now: float) -> dict:
+        with phase("cp.admission.step.drain") as ph_drain:
             self._retry_parked()
             self._shed_aged(now)
             batch = self._next_batch()
-            drain_ms = (time.perf_counter() - t_drain) * 1e3
-            summary = {"batch": len(batch), "placed": [], "departed": [],
-                       "parked": [], "stages": [], "violations": 0,
-                       "solve_ms": 0.0, "shed": 0,
-                       "phase_ms": {"drain": drain_ms, "fold": 0.0,
-                                    "solve": 0.0, "commit": 0.0}}
-            if not batch:
-                self._update_pressure(now)
-                self._set_queue_gauges(now)
-                return summary
-            self.stats["batches"] += 1
-            _M_BATCH.observe(len(batch))
-            _M_BATCH_AGE.observe(now - min(r.submitted_at for r in batch))
-            by_stage: dict[str, list[AdmissionRequest]] = {}
-            for r in batch:
-                by_stage.setdefault(r.stage_key, []).append(r)
-            for key in sorted(by_stage):
-                stream = self._streams[key]
-                self._resync(stream)
-                out = self._micro_solve(stream, by_stage[key], now)
-                summary["placed"] += out["placed"]
-                summary["departed"] += out["departed"]
-                summary["parked"] += out["parked"]
-                summary["violations"] = max(summary["violations"],
-                                            out["violations"])
-                summary["solve_ms"] += out["solve_ms"]
-                for ph, ms in out.get("phase_ms", {}).items():
-                    summary["phase_ms"][ph] += ms
-                if out["placed"] or out["departed"]:
-                    summary["stages"].append(key)
-            for ph, ms in summary["phase_ms"].items():
-                _M_PHASE.observe(ms, phase=ph)
-                self.last_phase_ms[ph] = round(ms, 3)
+        summary = {"batch": len(batch), "placed": [], "departed": [],
+                   "parked": [], "stages": [], "violations": 0,
+                   "solve_ms": 0.0, "shed": 0,
+                   "phase_ms": {"drain": ph_drain.ms, "fold": 0.0,
+                                "solve": 0.0, "commit": 0.0}}
+        if not batch:
             self._update_pressure(now)
             self._set_queue_gauges(now)
             return summary
+        self.stats["batches"] += 1
+        _M_BATCH.observe(len(batch))
+        arrivals = sum(1 for r in batch if r.kind == "arrival")
+        _M_EVENTS.inc(arrivals, kind="arrival")
+        _M_EVENTS.inc(len(batch) - arrivals, kind="departure")
+        _M_BATCH_AGE.observe(now - min(r.submitted_at for r in batch))
+        by_stage: dict[str, list[AdmissionRequest]] = {}
+        for r in batch:
+            by_stage.setdefault(r.stage_key, []).append(r)
+        for key in sorted(by_stage):
+            stream = self._streams[key]
+            self._resync(stream)
+            out = self._micro_solve(stream, by_stage[key], now)
+            summary["placed"] += out["placed"]
+            summary["departed"] += out["departed"]
+            summary["parked"] += out["parked"]
+            summary["violations"] = max(summary["violations"],
+                                        out["violations"])
+            summary["solve_ms"] += out["solve_ms"]
+            for ph, ms in out.get("phase_ms", {}).items():
+                summary["phase_ms"][ph] += ms
+            if out["placed"] or out["departed"]:
+                summary["stages"].append(key)
+        for ph, ms in summary["phase_ms"].items():
+            _M_PHASE.observe(ms, phase=ph)
+            self.last_phase_ms[ph] = round(ms, 3)
+        self._update_pressure(now)
+        self._set_queue_gauges(now)
+        return summary
+
+    def _settle(self, r: AdmissionRequest, state: str,
+                now: Optional[float] = None) -> None:
+        """`r` turns terminal: the one place a verdict is written, so the
+        callers waiting on it (`Waiter`) hear of it."""
+        r.state = state
+        if now is not None:
+            r.done_at = now
+        self._settled += 1
+        for w in self._watches.pop(r.id, ()):
+            w.pending.discard(r.id)
+            if not w.pending:
+                w.notify()
 
     def _shed_aged(self, now: float) -> None:
         """Age watermark: a queued request older than shed_age_s is shed
@@ -797,7 +910,7 @@ class AdmissionController:
                 # them — shedding them on requeue would betray that
                 if (r.kind == "arrival" and r.park_reason != "quota"
                         and now - r.submitted_at > self.cfg.shed_age_s):
-                    r.state, r.done_at = "shed", now
+                    self._settle(r, "shed", now)
                     _M_SHEDS.inc(reason="age")
                     self.stats["sheds"] += 1
                 else:
@@ -1006,10 +1119,11 @@ class AdmissionController:
                             minimum=cfg.minimum, align=cfg.align)
         return grown != cur
 
-    def _compact(self, stream: _Stream) -> None:
+    def _compact(self, stream: _Stream) -> np.ndarray:
         """Drop the reclaimable tombstone rows (exactly the free list:
         every tombstoned-but-not-reused row) from the streaming problem.
-        The next solve cold-stages (new shapes) — amortized and counted."""
+        The next solve cold-stages (new shapes) — amortized and counted.
+        Returns the rows kept, in their new order."""
         pt = stream.pt
         drop = set(stream.free_rows)
         keep = np.asarray([i for i in range(pt.S) if i not in drop],
@@ -1034,6 +1148,7 @@ class AdmissionController:
         self.stats["compactions"] += 1
         log.info("admission stream compacted %s",
                  kv(stage=stream.key, dropped=len(drop), rows=len(keep)))
+        return keep
 
     def _micro_solve(self, stream: _Stream, events: list[AdmissionRequest],
                      now: float) -> dict:
@@ -1058,9 +1173,9 @@ class AdmissionController:
                     None)
                 if parked is not None:
                     self._parked.remove(parked)
-                    parked.state, parked.done_at = "cancelled", now
+                    self._settle(parked, "cancelled", now)
                     self._unjournal_park(parked)
-                    r.state, r.done_at = "departed", now
+                    self._settle(r, "departed", now)
                     out["departed"].append(r.name)
                 elif any(q2.name == r.name and q2.kind == "arrival"
                          for q in self._queues.values() for q2 in q):
@@ -1071,34 +1186,40 @@ class AdmissionController:
                     # target is gone (already departed, shed, or never
                     # existed): the goal state holds — terminal, not a
                     # forever-spinning requeue
-                    r.state, r.done_at = "cancelled", now
+                    self._settle(r, "cancelled", now)
                 continue
             kept.append(r)
         events = kept
         if not events:
             return out
-        t_fold = time.perf_counter()
-        n_app = sum(1 for r in events if r.kind == "arrival")
-        if self._should_compact(stream, max(n_app - len(stream.free_rows),
-                                            0)):
-            self._compact(stream)
-        folded = self._fold(stream, events)
-        out["phase_ms"]["fold"] += (time.perf_counter() - t_fold) * 1e3
-        pt2, delta, plan = folded
+        # where the stream's rows stand before this micro-solve, by row of
+        # the problem the events fold into: what _commit_plan counts the
+        # moved rows against
+        standing = self._standing_rows(stream)
+        with phase("cp.admission.step.fold") as ph_fold:
+            n_app = sum(1 for r in events if r.kind == "arrival")
+            if self._should_compact(
+                    stream, max(n_app - len(stream.free_rows), 0)):
+                kept_rows = self._compact(stream)
+                if standing is not None:
+                    standing = standing[kept_rows]
+            pt2, delta, plan = self._fold(stream, events)
+        out["phase_ms"]["fold"] += ph_fold.ms
         if plan is None:
             return out
         for r in plan["cancelled"]:
-            r.state = "cancelled" if r.kind == "arrival" else "departed"
-            r.done_at = now
+            self._settle(r, "cancelled" if r.kind == "arrival"
+                         else "departed", now)
         if not plan["events"]:
             return out
+        plan["standing"] = standing
 
-        t0 = time.perf_counter()
         masked = (stream.tombstones
                   | {name for _row, name in plan["tomb_rows"]})
-        placement, rid, pt_used = self.placement.admit_batch(
-            stream.key, pt2, delta, tenant=stream.tenant, masked=masked)
-        wall_ms = (time.perf_counter() - t0) * 1e3
+        with phase("cp.admission.step.solve", stage=stream.key) as ph_solve:
+            placement, rid, pt_used = self.placement.admit_batch(
+                stream.key, pt2, delta, tenant=stream.tenant, masked=masked)
+        wall_ms = ph_solve.ms
         out["solve_ms"] = wall_ms
         out["phase_ms"]["solve"] += wall_ms
         # ONE sample per micro-solve: the p50/p99 surface measures the
@@ -1109,12 +1230,12 @@ class AdmissionController:
         self.stats["solves"] += 1
 
         if placement.feasible and rid:
-            t_commit = time.perf_counter()
-            self.placement.commit(rid)
-            _M_SOLVES.inc(outcome="committed")
-            self._commit_plan(stream, pt_used, plan, now, out)
-            out["phase_ms"]["commit"] += \
-                (time.perf_counter() - t_commit) * 1e3
+            with phase("cp.admission.step.commit") as ph_commit:
+                self.placement.commit(rid)
+                _M_SOLVES.inc(outcome="committed")
+                self._commit_plan(stream, pt_used, plan, now, out,
+                                  placement)
+            out["phase_ms"]["commit"] += ph_commit.ms
             if wall_ms > 0:
                 _M_RATE.set(len(out["placed"]) / (wall_ms / 1e3))
             return out
@@ -1125,9 +1246,9 @@ class AdmissionController:
         arrivals = [r for r in plan["events"] if r.kind == "arrival"]
         departures = [r for r in plan["events"] if r.kind == "departure"]
         for r in arrivals:
-            r.state = "parked"
             self._parked.append(r)
             self._journal_park(r, "capacity")
+            self._settle(r, "parked")
         if arrivals:
             _M_PARKED.inc(len(arrivals))
             self.stats["parked"] += len(arrivals)
@@ -1137,27 +1258,28 @@ class AdmissionController:
         out["parked"] = [r.name for r in arrivals]
         if departures:
             # strictly capacity-freeing — re-fold without the arrivals
-            t_fold = time.perf_counter()
-            pt3, delta3, plan3 = self._fold(stream, departures)
-            out["phase_ms"]["fold"] += (time.perf_counter() - t_fold) * 1e3
+            with phase("cp.admission.step.fold") as ph_fold:
+                pt3, delta3, plan3 = self._fold(stream, departures)
+            out["phase_ms"]["fold"] += ph_fold.ms
             if plan3 is not None and plan3["events"]:
+                plan3["standing"] = standing
                 masked3 = (stream.tombstones
                            | {n for _row, n in plan3["tomb_rows"]})
-                t_solve = time.perf_counter()
-                placement3, rid3, pt_used3 = self.placement.admit_batch(
-                    stream.key, pt3, delta3, tenant=stream.tenant,
-                    masked=masked3)
-                solve3_ms = (time.perf_counter() - t_solve) * 1e3
-                out["phase_ms"]["solve"] += solve3_ms
-                self.solve_ms_samples.append(solve3_ms)
-                slo_observe("admission_solve_ms", solve3_ms)
+                with phase("cp.admission.step.solve",
+                           stage=stream.key) as ph_solve:
+                    placement3, rid3, pt_used3 = self.placement.admit_batch(
+                        stream.key, pt3, delta3, tenant=stream.tenant,
+                        masked=masked3)
+                out["phase_ms"]["solve"] += ph_solve.ms
+                self.solve_ms_samples.append(ph_solve.ms)
+                slo_observe("admission_solve_ms", ph_solve.ms)
                 if placement3.feasible and rid3:
-                    t_commit = time.perf_counter()
-                    self.placement.commit(rid3)
-                    _M_SOLVES.inc(outcome="committed")
-                    self._commit_plan(stream, pt_used3, plan3, now, out)
-                    out["phase_ms"]["commit"] += \
-                        (time.perf_counter() - t_commit) * 1e3
+                    with phase("cp.admission.step.commit") as ph_commit:
+                        self.placement.commit(rid3)
+                        _M_SOLVES.inc(outcome="committed")
+                        self._commit_plan(stream, pt_used3, plan3, now, out,
+                                          placement3)
+                    out["phase_ms"]["commit"] += ph_commit.ms
                     return out
                 if rid3:
                     self.placement.release(rid3)
@@ -1167,11 +1289,41 @@ class AdmissionController:
                     self._queues[r.tenant].appendleft(r)
         return out
 
+    def _standing_rows(self, stream: _Stream) -> Optional[np.ndarray]:
+        """Node index by row of the stage's standing placement, where it
+        is a placement of the very problem the stream folds from (after
+        `_resync` it is, unless no solve has left a raw assignment)."""
+        entry = self.placement.retained(stream.key)
+        if entry is None or entry[0] is not stream.pt:
+            return None
+        raw = entry[1].raw
+        return None if raw is None else np.asarray(raw)[:stream.pt.S]
+
+    def _count_moved(self, plan: dict, placement) -> int:
+        """Rows that were live before the micro-solve, are live after it
+        under the same name, and that `placement` puts on another server:
+        every row of the standing placement but the tombstones, the rows
+        this batch vacated and the rows it handed to an arrival."""
+        standing = plan.get("standing")
+        if standing is None or placement.raw is None:
+            return 0
+        stay = np.ones(standing.shape[0], dtype=bool)
+        for rows in (plan["free"], [row for row, _n in plan["tomb_rows"]],
+                     [row for row, _r, _old in plan["reuse"]]):
+            stay[np.asarray(rows, dtype=np.intp)] = False
+        now_on = np.asarray(placement.raw)[:standing.shape[0]]
+        return int(np.count_nonzero(now_on[stay] != standing[stay]))
+
     def _commit_plan(self, stream: _Stream, pt_used, plan: dict,
-                     now: float, out: dict) -> None:
+                     now: float, out: dict, placement) -> None:
         """The micro-solve committed: apply the row plan to the stream
         book and the flow (so redeploys/teardowns see streamed truth),
-        mark the requests terminal, record waits."""
+        mark the requests terminal with the server `placement` names,
+        record waits, count the rows it moved."""
+        moved = self._count_moved(plan, placement)
+        if moved:
+            _M_MOVED.inc(moved)
+            self.stats["moved_rows"] += moved
         stream.pt = pt_used
         stage = stream.flow.stage(stream.stage_name)
         freed_capacity = False
@@ -1197,7 +1349,8 @@ class AdmissionController:
             stream.row_of[r.name] = stream.pt.S - len(plan["appended"]) + j
         for r in plan["events"]:
             if r.kind == "arrival":
-                r.state, r.done_at = "placed", now
+                r.server = placement.assignment.get(r.name)
+                self._settle(r, "placed", now)
                 stream.streamed[r.name] = r.seq
                 stream.owner[r.name] = r.tenant
                 stream.flow.services[r.name] = r.service
@@ -1218,7 +1371,7 @@ class AdmissionController:
                     slo_observe("admission_wait_s", now - r.submitted_at)
                 out["placed"].append(r.name)
             else:
-                r.state, r.done_at = "departed", now
+                self._settle(r, "departed", now)
                 self.stats["departed"] += 1
                 out["departed"].append(r.name)
         if freed_capacity:
@@ -1272,6 +1425,30 @@ class AdmissionController:
         the last submit/step's snapshot — the feedback must not block on
         a drain pass's solver wall time."""
         return dict(self._pressure_snapshot)
+
+    # ------------------------------------------------------------------
+    # verdicts: a caller is told, it does not poll
+    # ------------------------------------------------------------------
+
+    def _watch(self, ids: list[str], waiter: Waiter) -> None:
+        """`waiter` waits for the requests `ids` that a submit has just
+        made (under the lock the submit holds)."""
+        waiter.requests = [self.requests[i] for i in ids]
+        waiter.pending = {r.id for r in waiter.requests
+                          if r.state not in AdmissionRequest.TERMINAL}
+        if not waiter.pending:
+            waiter.notify()
+            return
+        for i in waiter.pending:
+            self._watches.setdefault(i, []).append(waiter)
+
+    def verdicts(self, waiter: Waiter) -> list[dict]:
+        """What each request of `waiter`'s submit has come to, in the
+        order of its `accepted`: `AdmissionRequest.verdict` — `state`,
+        for `placed` the `server` the committed plan names, for `parked`
+        the `reason`. A request still `queued` is answered as that."""
+        with self._lock:
+            return [r.verdict() for r in waiter.requests]
 
     def live_names(self, stage_key: str) -> list[str]:
         """Currently-live streamed services of a stage (the chaos
@@ -1396,14 +1573,52 @@ class AdmissionController:
     # ------------------------------------------------------------------
 
     async def run_loop(self) -> None:
+        """Drain while there is work, one pass after another with the
+        event loop served in between (a pass runs in the executor); sleep
+        when there is none, until a submit wakes the loop or
+        `drain_interval_s` runs out — the timer is for what has no event
+        to wake on (a parked retry after capacity moved elsewhere, an
+        aged tail), for backing off after a pass that failed, and for
+        work a pass could do nothing about (`step`'s `progress`): no hot
+        loop on a standing infeasibility."""
+        loop = self._loop = asyncio.get_running_loop()
+        wake = self._wake = asyncio.Event()
+        by = "timer"
         while True:
+            # cleared BEFORE the queue is looked at: a submit that lands
+            # after the last look sets it again, so none is slept through
+            wake.clear()
             try:
-                if self.has_work():
-                    await asyncio.get_running_loop().run_in_executor(
-                        None, self.step)
+                while self.has_work():
+                    _M_WAKES.inc(by=by)
+                    summary = await loop.run_in_executor(None, self.step)
+                    if not summary["progress"]:
+                        # a standing infeasibility: the next pass would
+                        # find what this one found. The timer retries it
+                        # (or a submit, which may bring what it needs)
+                        break
+                    by = "backlog"
             except Exception:
                 log.exception("admission drain pass failed")
-            await asyncio.sleep(self.cfg.drain_interval_s)
+                await asyncio.sleep(self.cfg.drain_interval_s)
+                by = "timer"
+                continue
+            try:
+                await asyncio.wait_for(wake.wait(),
+                                       self.cfg.drain_interval_s)
+                by = "submit"
+            except asyncio.TimeoutError:
+                by = "timer"
+
+    def _wake_loop(self) -> None:
+        """A submit queued work: end the drain loop's sleep. Nothing to
+        do for a controller whose passes are driven by hand (chaos, tests,
+        a bench calling step())."""
+        if self._loop is not None:
+            try:
+                self._loop.call_soon_threadsafe(self._wake.set)
+            except RuntimeError:
+                pass        # the loop is closed: the CP is stopping
 
     def spawn(self) -> None:
         self._task = asyncio.ensure_future(self.run_loop())
@@ -1412,3 +1627,4 @@ class AdmissionController:
         if self._task is not None:
             self._task.cancel()
             self._task = None
+        self._loop = None

@@ -682,6 +682,41 @@ def _dns(state: "AppState"):
 # deploy channel (handlers/deploy.rs)
 # --------------------------------------------------------------------------
 
+class _Told:
+    """A caller of `deploy.submit` that waits (`wait`) for its requests'
+    verdicts: `waiter` is what the admission controller is given, and
+    tells — from its drain thread — when the last one is terminal."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop):
+        from .admission import Waiter
+        self._loop = loop
+        self._all = asyncio.Event()
+        self.waiter = Waiter(self._notify)
+
+    def _notify(self) -> None:
+        try:
+            self._loop.call_soon_threadsafe(self._all.set)
+        except RuntimeError:
+            pass            # the loop is closed: nobody waits any more
+
+    async def verdicts(self, adm, timeout: float) -> dict:
+        """`{verdicts, pending}`: one verdict a request, in the order of
+        the submit's `accepted` (cp/admission.py
+        `AdmissionRequest.verdict`), after all are terminal or `timeout`
+        seconds, whichever is first; `pending` counts the ones still
+        queued then."""
+        with phase("cp.admission.wait.verdict",
+                   requests=len(self.waiter.requests)):
+            try:
+                await asyncio.wait_for(self._all.wait(), timeout)
+            except asyncio.TimeoutError:
+                pass
+        out = await self._loop.run_in_executor(
+            None, bound(adm.verdicts, self.waiter))
+        return {"verdicts": out,
+                "pending": sum(v["state"] == "queued" for v in out)}
+
+
 def _deploy(state: "AppState"):
     async def handle(conn: Connection, method: str, p: dict) -> dict:
         db = state.store
@@ -756,12 +791,18 @@ def _deploy(state: "AppState"):
                     None, bound(adm.attach, flow, stage,
                                 tenant=p.get("tenant", "default")))
                 stage = key
-            return await loop.run_in_executor(
-                None, lambda: adm.submit(
-                    p.get("tenant", "default"),
-                    arrivals=p.get("arrivals") or (),
-                    departures=p.get("departures") or (),
-                    stage=stage))
+            # `wait` (seconds) holds the reply until every request of this
+            # submit has its verdict: the caller is told, it does not poll
+            told = _Told(loop) if p.get("wait") else None
+            result = await loop.run_in_executor(
+                None, bound(adm.submit, p.get("tenant", "default"),
+                            arrivals=p.get("arrivals") or (),
+                            departures=p.get("departures") or (),
+                            stage=stage,
+                            waiter=told.waiter if told else None))
+            if told is not None:
+                result.update(await told.verdicts(adm, float(p["wait"])))
+            return result
         if method == "admit_status":
             adm = getattr(state, "admission", None)
             if adm is None:

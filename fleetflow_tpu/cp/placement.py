@@ -747,15 +747,16 @@ class PlacementService:
         priority (tests/test_preemption.py has the `xfail`). The stage's
         committed rows can be another stage's victims."""
         with self._lock:
-            server_map = {s.slug: s for s in self.store.list("servers")}
-            valid = np.array(
-                [bool(server_map[slug].schedulable)
-                 if slug in server_map else bool(pt.node_valid[j])
-                 for j, slug in enumerate(pt.node_names)], dtype=bool)
-            if not np.array_equal(valid, pt.node_valid):
-                pt = _dc_replace(pt, node_valid=valid)
-            pt = self._refresh_capacity(pt, stage_key,
-                                        server_map=server_map)
+            with phase("cp.admit_batch.refresh", stage=stage_key):
+                server_map = {s.slug: s for s in self.store.list("servers")}
+                valid = np.array(
+                    [bool(server_map[slug].schedulable)
+                     if slug in server_map else bool(pt.node_valid[j])
+                     for j, slug in enumerate(pt.node_names)], dtype=bool)
+                if not np.array_equal(valid, pt.node_valid):
+                    pt = _dc_replace(pt, node_valid=valid)
+                pt = self._refresh_capacity(pt, stage_key,
+                                            server_map=server_map)
             if delta is not None:
                 # the delta always re-ships the small planes; keep them
                 # coherent with the refreshed candidate
@@ -784,6 +785,13 @@ class PlacementService:
                     place_kwargs=({"stage": stage_key}
                                   if sched is self._sched_tpu else None))
             if not new.feasible:
+                standing = self._last.get(stage_key)
+                if (self.use_tpu and standing is not None
+                        and standing[1].raw is not None):
+                    # the candidate is dropped: the scheduler's resident
+                    # state goes back to what stands
+                    self._sched_tpu.restore(stage_key, standing[0],
+                                            standing[1].raw)
                 return self._apply_mask(stage_key, new), None, pt
             self._masked[stage_key] = frozenset(masked or ())
             new = self._apply_mask(stage_key, new)

@@ -234,6 +234,34 @@ class TpuSolverScheduler:
         _M_RES_BYTES.set(self._resident_bytes())
         _M_RES_SLOTS.set(len(self._residents))
 
+    def restore(self, stage: str, pt: ProblemTensors,
+                raw: np.ndarray) -> None:
+        """The caller did not adopt the last solve of `stage` (a candidate
+        that came back infeasible: cp/placement.py admit_batch) and the
+        stage stands as `pt` placed by `raw`: make THAT the slot's
+        resident state again, so the next delta is one against what
+        stands and its solve the resident warm one. Without it the slot
+        would hold the dropped candidate, the next delta would not fit
+        it, and the stage would be solved again from the seed — every
+        running service free to move. One cold staging, no solve. A
+        stage on the mesh is forgotten instead (it stages cold next
+        time, as before)."""
+        from ..solver.resident import ResidentProblem
+
+        slot = next((s for s in self._residents if s.key == stage), None)
+        if slot is None:
+            return
+        if slot.resident.mesh is not None:
+            self.forget(stage)
+            return
+        resident = ResidentProblem(pt, bucket=self._bucket_enabled(pt))
+        resident.adopt_host(raw, pt.node_valid, warm=False)
+        resident.note_host_assignment(feasible=True)
+        slot.resident = resident
+        slot.last_assignment = np.asarray(raw)
+        slot.nbytes = int(resident.device_nbytes())
+        _M_RES_BYTES.set(self._resident_bytes())
+
     def byte_drift(self) -> int:
         """Live device bytes minus the accounted admission-time bytes,
         summed over resident slots — the cross-check the profiling hook
@@ -353,11 +381,17 @@ class TpuSolverScheduler:
                              or old.service_names == pt.service_names)):
                     slot = self._residents.pop(i)
                     break
+        outgrown = None
         if warm and slot is not None and slot.resident.assignment is not None:
             # this stage HAD resident state but the delta contract broke:
             # problem tensors will cross the host boundary (the
             # transfer-guard event)
             slot.resident.record_warm_fallback()
+            if (mesh is None and slot.resident.mesh is None
+                    and slot.resident.grown_by(pt, delta)):
+                # plain arrivals pushed the stage past its padded tier:
+                # the new staging inherits the old one's placement below
+                outgrown = slot.resident
         if mesh is not None:
             from ..solver.sharded import ShardedResident
             resident = ShardedResident(pt, mesh=mesh,
@@ -391,6 +425,12 @@ class TpuSolverScheduler:
             _M_READMITS.inc()
             log.debug("slot-readmit %s", kv(stage=stage_key,
                                             evictions=rec.evictions))
+        elif outgrown is not None:
+            resident.inherit(outgrown, delta)
+            resident_warm = True
+            log.debug("slot-outgrown %s", kv(stage=stage_key,
+                                             rows=pt.S,
+                                             tier=resident.prob.S))
         self._admit(slot)
         return slot, resident_warm
 

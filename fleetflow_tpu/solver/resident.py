@@ -279,7 +279,7 @@ class ResidentProblem:
         return same
 
     def _arrivals_compatible(self, pt, delta: Optional[ProblemDelta],
-                             old) -> bool:
+                             old, same_tier: bool = True) -> bool:
         """Can a GROWN pt (arrivals appended since the resident staging)
         still ride the delta path? Yes when the new rows activate phantom
         rows already on device: the fleet stays inside the padded tier,
@@ -287,10 +287,12 @@ class ResidentProblem:
         n_real, and the appended rows bring no new hard-constraint ids
         (the padded id planes already read -1 there). Anything richer —
         a crossed tier, an arrival with ports/volumes/anti-affinity, a
-        preference plane — cold-stages."""
+        preference plane — cold-stages. `same_tier=False` asks the same
+        of the rows and leaves the tier out (`grown_by`)."""
         if delta is None or delta.n_real != pt.S or pt.S <= old.S:
             return False
-        if not self.bucket or self._expected_padded_S(pt) != self.prob.S:
+        if same_tier and (not self.bucket or
+                          self._expected_padded_S(pt) != self.prob.S):
             return False
         if delta.demand_rows is None or delta.eligible_rows is None:
             return False
@@ -308,6 +310,35 @@ class ResidentProblem:
                     or (a[old.S:] != -1).any()):
                 return False
         return True
+
+    def grown_by(self, pt, delta: Optional[ProblemDelta]) -> bool:
+        """Is `pt` this staging's problem with plain arrivals appended —
+        what `_arrivals_compatible` admits to the delta path, but past
+        the padded tier, so that it has to stage anew? Then the new
+        staging can `inherit` this one's placement."""
+        old = self.pt
+        return (old is not None and self._mirror is not None
+                and pt.N == old.N and pt.strategy == old.strategy
+                and pt.max_skew == old.max_skew
+                and self._arrivals_compatible(pt, delta, old,
+                                              same_tier=False))
+
+    def inherit(self, old: "ResidentProblem",
+                delta: ProblemDelta) -> None:
+        """This fresh staging of a problem that `old.grown_by` takes
+        `old`'s place as if its tier had had room: `old`'s committed
+        assignment for the rows it had, the appended rows parked where
+        the merge kernel parks phantoms, and the delta's rows pending for
+        the active-set planner. The solve that follows is the resident
+        warm one — localized to the arrivals where their closure is
+        small, sticky otherwise — so a stage that outgrows its tier keeps
+        its incumbents where they run."""
+        self.adopt_host(old._mirror[:old.pt.S], self.pt.node_valid,
+                        warm=False)
+        self._mirror_feasible = old._mirror_feasible
+        # capacity that shrank since `old` was solved puts its rows in the
+        # active set, as on the delta path
+        self._note_churn(self.pt, delta, since=old._cap_fp)
 
     def merge_inputs(self, pt, delta: Optional[ProblemDelta] = None):
         """Stage the per-burst merge-kernel inputs for `delta`: returns
@@ -376,13 +407,15 @@ class ResidentProblem:
         self._staged_fp = (valid, cap)
         return uploads, n_real, has_demand, has_eligible
 
-    def _note_churn(self, pt, delta: Optional[ProblemDelta]) -> None:
+    def _note_churn(self, pt, delta: Optional[ProblemDelta],
+                    since: Optional[np.ndarray] = None) -> None:
         """Accumulate the row set this delta touches for the active-set
         planner (solver/subsolve.py) — called BEFORE the fingerprints
         roll over so capacity shrink is measured against the staging the
-        mirror assignment was solved on. Node kills need no bookkeeping
-        here: stranded rows are recomputed from the post-delta tensors at
-        plan time."""
+        mirror assignment was solved on (`since`, where that was another
+        staging's: `inherit`). Node kills need no bookkeeping here:
+        stranded rows are recomputed from the post-delta tensors at plan
+        time."""
         if not self.supports_subsolve or self._mirror is None:
             return    # nothing to localize against (no previous solve)
         rows = [np.empty(0, dtype=np.int64)]
@@ -398,8 +431,9 @@ class ResidentProblem:
         new_cap = np.asarray(
             delta.capacity if delta is not None and
             delta.capacity is not None else pt.capacity, dtype=np.float32)
-        if self._cap_fp is not None and new_cap.shape == self._cap_fp.shape:
-            shrunk = (new_cap < self._cap_fp - 1e-6).any(axis=1)
+        since = self._cap_fp if since is None else since
+        if since is not None and new_cap.shape == since.shape:
+            shrunk = (new_cap < since - 1e-6).any(axis=1)
             if shrunk.any():
                 n = min(self.n_real, self._mirror.shape[0])
                 rows.append(np.nonzero(shrunk[self._mirror[:n]])[0])
