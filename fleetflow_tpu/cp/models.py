@@ -266,7 +266,9 @@ class Server(Record):
 
     @property
     def schedulable(self) -> bool:
-        return (self.scheduling_state == SchedulingState.SCHEDULABLE.value
+        # a str enum's member equals its value: no `.value` descriptor on
+        # a property that the store reads for every server it patches
+        return (self.scheduling_state == SchedulingState.SCHEDULABLE
                 and self.status == "online")
 
 

@@ -48,7 +48,7 @@ from ..obs.slo import observe as slo_observe
 from ..sched import (HostGreedyScheduler, Placement, TpuSolverScheduler,
                      level_schedule, place_with_fallback)
 from .models import PlacementRecord, Server
-from .store import Store
+from .store import ServerColumns, Store, booked_columns as _booked_columns
 
 log = get_logger("cp.placement")
 
@@ -169,20 +169,6 @@ class Reservation:
         default_factory=dict, repr=False)
 
 
-def _booked_columns(servers: list[Server]) -> tuple[np.ndarray, np.ndarray]:
-    """((N, R) capacity, (N, R) committed+reserved demand) as the server
-    records state them, float64, in the records' order — the ONE
-    definition of 'how much of this node is spoken for' (admission
-    inventory and churn capacity refresh alike): one pass that gathers
-    the records' numbers, one array, no numpy call per server."""
-    cols = np.array(
-        [(c.cpu, c.memory, c.disk, a.cpu, a.memory, a.disk,
-          a.reserved_cpu, a.reserved_memory, a.reserved_disk)
-         for c, a in [(s.capacity, s.allocated) for s in servers]],
-        dtype=np.float64).reshape(len(servers), 9)
-    return cols[:, 0:3], cols[:, 3:6] + cols[:, 6:9]
-
-
 def _alloc_vector(s: Server) -> np.ndarray:
     """(R,) committed+reserved demand recorded on one server record: its
     row of `_booked_columns`."""
@@ -283,6 +269,9 @@ class PlacementService:
         self._committed: dict[str, Reservation] = {}      # stage_key -> last
         self._ids = itertools.count(1)
         self._last: dict[str, tuple[ProblemTensors, Placement]] = {}
+        # stage key -> (the node names of its problem, ServerColumns.members,
+        # the row of each name there): _server_rows
+        self._node_rows: dict[str, tuple[list[str], int, np.ndarray]] = {}
         # streaming-admission tombstones (cp/admission.py): rows kept in
         # the problem at zero demand so the padded shape tier survives a
         # departure, but masked OUT of every public assignment view —
@@ -354,29 +343,37 @@ class PlacementService:
         row ranks lower."""
         # a tenant sees its own servers plus the shared "default" pool;
         # "default" solves never touch tenant-dedicated capacity
-        wanted = frozenset(slugs) if slugs else None
-        servers = self.store.list(
-            "servers", lambda s: s.tenant in (tenant, "default")
-            and (wanted is None or s.slug in wanted))
-        if not servers:
+        view = self.store.server_columns()
+        mine = view.tenant == "default"
+        if isinstance(tenant, str):   # a request's is whatever JSON gave
+            mine |= view.tenant == tenant
+        if slugs:
+            named = np.zeros(len(view), dtype=bool)
+            named[view.holders(slugs)] = True
+            mine &= named
+        # the records in the order the store lists them: the solver
+        # breaks its ties by a node's place in this order
+        at = view.order[mine[view.order]]
+        if not at.size:
             raise ValueError(f"no servers registered for tenant {tenant!r}")
-        names = [s.slug for s in servers]
+        servers = [view.records[i] for i in at.tolist()]
         pre = None
         if preemptor is not None:
             with phase("cp.solve_stage.preemptible") as ph:
-                pre = self._preemptible_by_node(*preemptor, names)
+                pre = self._preemptible_by_node(
+                    *preemptor, [s.slug for s in servers])
                 if pre is not None:
                     holding = int(pre.any(axis=1).sum())
                     _M_PREEMPTIBLE_SERVERS.inc(holding)
                     ph.set(servers=holding)
-        capacity, booked = _booked_columns(servers)
         # free capacity before the clamp: what the caller calls its own
         # comes off what is spoken for first, so a deficit on a shrunken
         # node stays a deficit
-        free = capacity - ((booked + _by_slug(names, self._reserved_by_node()))
-                           - _by_slug(names, exclude_demand or {}))
+        free = (view.capacity - (
+            (view.booked + view.scatter(self._reserved_by_node()))
+            - view.scatter(exclude_demand or {})))[at]
         clamped = np.maximum(free, 0.0)
-        valid = np.array([s.schedulable for s in servers], dtype=bool)
+        valid = view.schedulable[at]
         if pre is not None:
             # what each node would gain: the deficit of a shrunken node is
             # taken off it as it is off the node's own capacity
@@ -748,15 +745,14 @@ class PlacementService:
         committed rows can be another stage's victims."""
         with self._lock:
             with phase("cp.admit_batch.refresh", stage=stage_key):
-                server_map = {s.slug: s for s in self.store.list("servers")}
-                valid = np.array(
-                    [bool(server_map[slug].schedulable)
-                     if slug in server_map else bool(pt.node_valid[j])
-                     for j, slug in enumerate(pt.node_names)], dtype=bool)
+                view, row = self._server_rows(stage_key, pt)
+                # a node no server carries keeps the bit it has
+                known = row >= 0
+                valid = np.array(pt.node_valid, dtype=bool)
+                valid[known] = view.schedulable[row[known]]
                 if not np.array_equal(valid, pt.node_valid):
                     pt = _dc_replace(pt, node_valid=valid)
-                pt = self._refresh_capacity(pt, stage_key,
-                                            server_map=server_map)
+                pt = self._refresh_capacity(pt, stage_key, on=(view, row))
             if delta is not None:
                 # the delta always re-ships the small planes; keep them
                 # coherent with the refreshed candidate
@@ -1176,6 +1172,7 @@ class PlacementService:
             self._drop_churn(stage_key)
             if forget:
                 self._last.pop(stage_key, None)
+                self._node_rows.pop(stage_key, None)
                 self._masked.pop(stage_key, None)
                 self._sched_tpu.forget(stage_key)
             c = self._committed.pop(stage_key, None)
@@ -1294,9 +1291,24 @@ class PlacementService:
                     out[slug] = out.get(slug, 0) + d
         return out
 
+    def _server_rows(self, key: str, pt: ProblemTensors
+                     ) -> tuple[ServerColumns, np.ndarray]:
+        """The servers' columns as the store states them now, and the row
+        there of each node of stage `key`'s problem `pt` (-1: no server
+        carries the name). The rows are kept with the stage: they stand
+        while the problem's names are the same list and no server entered,
+        left or was renamed. Caller holds the lock."""
+        view = self.store.server_columns()
+        kept = self._node_rows.get(key)
+        if (kept is None or kept[0] is not pt.node_names
+                or kept[1] != view.members):
+            kept = self._node_rows[key] = (
+                pt.node_names, view.members, view.rows(pt.node_names))
+        return view, kept[2]
+
     def _refresh_capacity(self, pt: ProblemTensors, key: str,
                           overrides: Optional[dict[str, tuple]] = None,
-                          server_map: Optional[dict[str, Server]] = None,
+                          on: Optional[tuple[ServerColumns, np.ndarray]] = None,
                           ) -> ProblemTensors:
         """Live per-node capacity for a churn re-solve of stage `key`:
         raw capacity minus committed allocations and in-flight
@@ -1308,24 +1320,21 @@ class PlacementService:
         their store records still cite the pre-burst nodes, so without the
         substitution two stages displaced by one burst would each see the
         other at its old (dead) node and double-book the survivor.
-        `server_map` (slug -> Server) avoids a per-node linear store scan
-        when the caller already holds one.  Returns pt unchanged (same
-        object, so device stagings keyed on identity stay warm) when
-        nothing moved; otherwise a copy with fresh capacity."""
-        get = (server_map.get if server_map is not None
-               else self.store.server_by_slug)
-        records = [get(slug) for slug in pt.node_names]
-        at = [j for j, s in enumerate(records) if s is not None]
-        names = [pt.node_names[j] for j in at]
-        capacity, booked = _booked_columns([records[j] for j in at])
-        alloc = (booked + _by_slug(names, self._reserved_by_node())
-                 - _by_slug(names, self._stage_demand(key)))
+        `on` is `_server_rows(key, pt)` where the caller has read it
+        already. Returns pt unchanged (same object, so device stagings
+        keyed on identity stay warm) when nothing moved; otherwise a copy
+        with fresh capacity."""
+        view, row = on or self._server_rows(key, pt)
+        # every server's, then the rows of this problem's nodes
+        alloc = (view.booked + view.scatter(self._reserved_by_node())
+                 - view.scatter(self._stage_demand(key)))
         for okey, (old_dem, new_dem) in (overrides or {}).items():
             if okey != key:
-                alloc = (alloc - _by_slug(names, old_dem)
-                         + _by_slug(names, new_dem))
+                alloc = (alloc - view.scatter(old_dem)
+                         + view.scatter(new_dem))
+        known = np.flatnonzero(row >= 0)
         cap = pt.capacity.copy()
-        cap[at] = np.maximum(capacity - alloc, 0.0)
+        cap[known] = np.maximum(view.capacity - alloc, 0.0)[row[known]]
         if np.array_equal(cap, pt.capacity):
             return pt
         return _dc_replace(pt, capacity=cap)
@@ -1394,8 +1403,6 @@ class PlacementService:
         # survivor node)
         overrides: dict[str, tuple] = {}
         with self._locked():
-            with phase("cp.node_events.mark"):
-                server_map = {s.slug: s for s in self.store.list("servers")}
             for key, (pt, placement) in list(self._last.items()):
                 needs_resolve = False
                 flipped = False
@@ -1430,8 +1437,7 @@ class PlacementService:
                 # services are the ones being re-placed) and substituting
                 # burst-mates' already-re-solved positions.
                 with phase("cp.node_events.refresh_capacity", stage=key):
-                    pt = self._refresh_capacity(pt, key, overrides,
-                                                server_map)
+                    pt = self._refresh_capacity(pt, key, overrides)
                     pt = self._rebar(pt, key)
                 degraded = False
                 with phase("cp.node_events.solve", stage=key) as ph_solve:

@@ -45,6 +45,12 @@ kept under the same lock wherever a record enters, leaves or is replaced
 — create, update, delete, a replicated or replayed journal entry, a loaded
 snapshot — so `server_by_slug` reads one row, and a miss is authoritative.
 
+Columns: what placement reads of every server at every solve — capacity,
+what is booked, whether it is schedulable, and who is where in the table
+— is kept as arrays that outlive the solve (`server_columns`,
+`ServerColumns`). A write of a server record marks it; the next read
+re-reads the marked records into their rows, and nothing else.
+
 Replication (docs/guide/13-cp-replication.md): every journal entry —
 including the batched/coalesced paths — carries a monotonic sequence
 number (`"q"`) and the store's fencing epoch (`"e"`), and is handed to an
@@ -60,13 +66,17 @@ ex-primary's entries are refusable forever after a failover.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import operator
 import os
 import threading
 from dataclasses import fields
 from pathlib import Path
-from typing import Callable, Optional, TypeVar
+from types import MappingProxyType
+from typing import Callable, Mapping, Optional, TypeVar
+
+import numpy as np
 
 from .models import (Alert, BuildJob, CostEntry, Deployment, DeploymentStatus,
                      DnsRecord, ObservedContainer, ParkedArrival, ParkedWork,
@@ -76,7 +86,8 @@ from .models import (Alert, BuildJob, CostEntry, Deployment, DeploymentStatus,
 from ..core.errors import ControlPlaneError
 from ..obs.metrics import REGISTRY
 
-__all__ = ["Store", "ReplicationGap", "ReplicationFenced"]
+__all__ = ["Store", "ServerColumns", "booked_columns", "ReplicationGap",
+           "ReplicationFenced"]
 
 
 class ReplicationGap(ControlPlaneError):
@@ -114,6 +125,19 @@ _M_JOURNAL_ENTRIES = REGISTRY.counter(
     "once whether it went to the local journal, the replication sink or "
     "both; beside fleet_store_ops_total it says how many records an entry "
     "carries")
+# unlabelled on purpose: a ratio of two of them is read child by child
+_M_COLUMNS_READS = REGISTRY.counter(
+    "fleet_store_server_columns_reads_total",
+    "Reads of the servers' columns (Store.server_columns): one an "
+    "inventory, a capacity refresh or an admission micro-solve")
+_M_COLUMNS_ROWS = REGISTRY.counter(
+    "fleet_store_server_columns_rows_total",
+    "Server records re-read into the columns: the records written since "
+    "the last read (a patch), or the whole table (a rebuild)")
+_M_COLUMNS_REBUILDS = REGISTRY.counter(
+    "fleet_store_server_columns_rebuilds_total",
+    "Reads that rebuilt the servers' columns whole: the first, and one "
+    "after a server entered, left, or changed slug, tenant or created_at")
 _M_HEARTBEATS = REGISTRY.counter(
     "fleet_heartbeats_total", "Agent heartbeats recorded")
 _M_COMPACTIONS = REGISTRY.counter(
@@ -164,12 +188,158 @@ _LOOKUPS_INDEX = {t: _M_LOOKUPS.bind(table=t, path="index")
                   for t in _INDEXED}
 _count_journal_bytes = _M_JOURNAL_BYTES.bind()
 _count_journal_entries = _M_JOURNAL_ENTRIES.bind()
+_count_columns_reads = _M_COLUMNS_READS.bind()
+_count_columns_rows = _M_COLUMNS_ROWS.bind()
+_count_columns_rebuilds = _M_COLUMNS_REBUILDS.bind()
 
 # The most a journal line may hold where the store can split it (an `upd`
 # entry of several records): replication.SNAPSHOT_CHUNK, a quarter of
 # protocol.MAX_FRAME — a `replication` `append` event ships what one sink
 # call handed over, JSON-escaped once more.
 JOURNAL_LINE_MAX = 256 * 1024
+
+
+def booked_columns(servers: list[Server]) -> tuple[np.ndarray, np.ndarray]:
+    """((N, R) capacity, (N, R) committed+reserved demand) as the server
+    records state them, float64, in the records' order — the ONE
+    definition of 'how much of this node is spoken for' (the columns the
+    store keeps, and so admission inventory and churn capacity refresh
+    alike): one pass that gathers the records' numbers, one array, no
+    numpy call per server."""
+    cols = np.array(
+        [(c.cpu, c.memory, c.disk, a.cpu, a.memory, a.disk,
+          a.reserved_cpu, a.reserved_memory, a.reserved_disk)
+         for c, a in [(s.capacity, s.allocated) for s in servers]],
+        dtype=np.float64).reshape(len(servers), 9)
+    return cols[:, 0:3], cols[:, 3:6] + cols[:, 6:9]
+
+
+# a write of one of these moves who is where in the columns, not a row
+_MEMBERSHIP = frozenset(("slug", "tenant", "created_at"))
+
+
+class ServerColumns:
+    """What the `servers` table states, column by column, in table order:
+    one value of `Store.server_columns`, never written again — a later
+    read hands out another. Row i is record `ids[i]`.
+
+    `capacity` and `booked` are `booked_columns` of the records and
+    `schedulable` their `Server.schedulable`, to the bit. `row_of` is
+    slug -> row, the first in table order where two records carry one
+    slug (the record `server_by_slug` returns); `also` has the later
+    ones. `order` lists the rows as `Store.list` orders the records:
+    ascending `created_at`, stable over table order. `members` moves when
+    a record enters, leaves, or changes slug, tenant or `created_at` —
+    what is keyed on rows is good for as long as it stands — and
+    `version` with any write of a server."""
+    __slots__ = ("ids", "slugs", "records", "id_rows", "row_of", "also",
+                 "tenant", "created_at", "order", "capacity", "booked",
+                 "schedulable", "members", "version")
+
+    ids: tuple[str, ...]
+    slugs: tuple[str, ...]
+    records: tuple[Server, ...]
+    id_rows: Mapping[str, int]
+    row_of: Mapping[str, int]
+    also: Mapping[str, tuple[int, ...]]
+    tenant: np.ndarray          # (N,) object
+    created_at: np.ndarray      # (N,) float64
+    order: np.ndarray           # (N,) int64
+    capacity: np.ndarray        # (N, 3) float64
+    booked: np.ndarray          # (N, 3) float64
+    schedulable: np.ndarray     # (N,) bool
+    members: int
+    version: int
+
+    @classmethod
+    def of(cls, table: dict[str, Server], members: int,
+           version: int) -> "ServerColumns":
+        self = cls()
+        self.ids = tuple(table)
+        self.records = records = tuple(table.values())
+        self.slugs = tuple([s.slug for s in records])
+        self.id_rows = MappingProxyType(
+            dict(zip(self.ids, range(len(records)))))
+        row_of: dict[str, int] = {}
+        also: dict[str, tuple[int, ...]] = {}
+        for i, slug in enumerate(self.slugs):
+            if row_of.setdefault(slug, i) != i:
+                also[slug] = also.get(slug, ()) + (i,)
+        self.row_of = MappingProxyType(row_of)
+        self.also = MappingProxyType(also)
+        tenant = np.empty(len(records), dtype=object)
+        tenant[:] = [s.tenant for s in records]
+        self.tenant = tenant
+        self.created_at = np.array([s.created_at for s in records],
+                                   dtype=np.float64)
+        self.order = np.argsort(self.created_at, kind="stable")
+        capacity, self.booked = booked_columns(records)
+        self.capacity = np.ascontiguousarray(capacity)
+        self.schedulable = np.array([s.schedulable for s in records],
+                                    dtype=bool)
+        self.members, self.version = members, version
+        return self._sealed()
+
+    def patched(self, table: dict[str, Server], ids,
+                version: int) -> "ServerColumns":
+        """These columns with the rows of the records `ids` of `table` —
+        members of them, whose slug, tenant and `created_at` stand — read
+        again, in one array assignment a column."""
+        new = ServerColumns()
+        for name in ServerColumns.__slots__:
+            setattr(new, name, getattr(self, name))
+        at = list(map(self.id_rows.__getitem__, ids))
+        records = list(map(table.__getitem__, ids))
+        capacity, booked = booked_columns(records)
+        for name, rows in (("capacity", capacity), ("booked", booked),
+                           ("schedulable", [s.schedulable for s in records])):
+            column = getattr(self, name).copy()
+            column[at] = rows
+            setattr(new, name, column)
+        new.version = version
+        return new._sealed()
+
+    def _sealed(self) -> "ServerColumns":
+        for name in ("tenant", "created_at", "order", "capacity", "booked",
+                     "schedulable"):
+            getattr(self, name).flags.writeable = False
+        return self
+
+    def __len__(self) -> int:
+        return len(self.ids)
+
+    def rows(self, slugs) -> np.ndarray:
+        """(len(slugs),) int64: the row of each slug, -1 for one no
+        server carries."""
+        return np.fromiter(map(self.row_of.get, slugs, itertools.repeat(-1)),
+                           dtype=np.int64, count=len(slugs))
+
+    def holders(self, slugs) -> list[int]:
+        """The rows of every record that carries one of `slugs`."""
+        rows = [i for i in map(self.row_of.get, slugs) if i is not None]
+        if self.also:
+            rows.extend(i for slug in self.also.keys() & set(slugs)
+                        for i in self.also[slug])
+        return rows
+
+    def scatter(self, by_slug: Mapping[str, np.ndarray]) -> np.ndarray:
+        """(N, R) float64: `by_slug`'s (R,) vector in the row of every
+        record that carries its slug, zero elsewhere; a slug no record
+        carries is dropped. One pass over `by_slug`'s own keys."""
+        out = np.zeros((len(self.ids), 3))
+        if not by_slug:
+            return out
+        at = list(map(self.row_of.get, by_slug))
+        vectors = list(by_slug.values())
+        if None in at:
+            known = [k for k, i in enumerate(at) if i is not None]
+            at = [at[k] for k in known]
+            vectors = [vectors[k] for k in known]
+        if at:
+            out[at] = np.array(vectors)
+        for slug in self.also.keys() & by_slug.keys() if self.also else ():
+            out[list(self.also[slug])] = by_slug[slug]
+        return out
 
 
 class Store:
@@ -190,6 +360,15 @@ class Store:
         # returns the first, as the scan did)
         self._index: dict[str, dict[object, list[str]]] = {
             t: {} for t in _INDEXED}
+        # the servers' columns as the last read left them (None: to be
+        # built), the ids of the records written since, and whether one
+        # of those writes moved who is where; `members` and `version`
+        # count on through a view that is dropped
+        self._columns: Optional[ServerColumns] = None
+        self._columns_dirty: set[str] = set()
+        self._columns_moved = False
+        self._columns_members = 0
+        self._columns_version = 0
         self._path = Path(path) if path else None
         self._journal_path = (self._path.with_name(self._path.name + ".journal")
                               if self._path else None)
@@ -372,6 +551,10 @@ class Store:
             self._index_drop(table, getattr(rec, field), rec.id)
         for k, v in changes.items():
             setattr(rec, k, v)
+        if table == "servers":
+            self._columns_dirty.add(rec.id)
+            if not _MEMBERSHIP.isdisjoint(changes):
+                self._columns_moved = True
 
     def _index_add(self, table: str, key: object, rec_id: str) -> None:
         # caller holds the lock; the record is in its table already (its
@@ -401,6 +584,9 @@ class Store:
         hash(key)   # an unhashable key raises before the table changes
         old = rows.get(rec.id)
         rows[rec.id] = rec
+        if table == "servers":
+            # entered, or replaced by an object that may say anything
+            self._columns_moved = True
         if old is None:
             self._index_add(table, key, rec.id)
         elif getattr(old, field) != key:
@@ -416,6 +602,8 @@ class Store:
         field = _INDEXED.get(table)
         if field is not None:
             self._index_drop(table, getattr(rec, field), rec_id)
+        if table == "servers":
+            self._columns_moved = True
         return True
 
     def _reindex(self) -> None:
@@ -426,6 +614,7 @@ class Store:
             for rid, rec in self._tables[table].items():
                 index.setdefault(getattr(rec, field), []).append(rid)
             self._index[table] = index
+        self._columns = None
 
     # ------------------------------------------------------------------
     # domain queries (the named fns of db.rs)
@@ -495,6 +684,36 @@ class Store:
     # servers ----------------------------------------------------------
     def server_by_slug(self, slug: str) -> Optional[Server]:
         return self._lookup("servers", slug)  # type: ignore[return-value]
+
+    def server_columns(self) -> ServerColumns:
+        """The servers' columns as the records state them now. Every path
+        that writes a server record marks it (`_set_fields`, `_put`,
+        `_pop`; a table loaded whole drops the columns), and this read
+        brings the columns up to date from the marked records alone: a
+        patch of their rows, or a rebuild where a write moved who is
+        where. Read after the write, so a record a replicated `upd` set
+        from plain values has been coerced by then. The value handed out
+        is not written again, and its arrays refuse a write."""
+        with self._lock:
+            view, dirty = self._columns, self._columns_dirty
+            if view is None or self._columns_moved:
+                self._columns_members += 1
+                self._columns_version += 1
+                view = ServerColumns.of(
+                    self._tables["servers"], self._columns_members,
+                    self._columns_version)
+                _count_columns_rebuilds()
+                _count_columns_rows(len(view))
+            elif dirty:
+                self._columns_version += 1
+                view = view.patched(self._tables["servers"], dirty,
+                                    self._columns_version)
+                _count_columns_rows(len(dirty))
+            self._columns = view
+            self._columns_moved = False
+            dirty.clear()
+        _count_columns_reads()
+        return view
 
     def register_server(self, slug: str, tenant: str = "default",
                         **attrs) -> Server:

@@ -12,6 +12,11 @@ shrank below what is allocated on them (a deficit must stay a deficit until
 the clamp), a tenant's own servers beside the default pool, one slug
 registered twice, labelled and unlabelled records, offline and cordoned
 ones, commitments with rows of several priorities.
+
+Since PR 42 the passes read the store's columns (`Store.server_columns`)
+and no record: the loops below still walk the records, so they are what
+the columns are held to — in a fresh world, and in one whose servers are
+written between two calls.
 """
 
 from __future__ import annotations
@@ -25,12 +30,14 @@ from fleetflow_tpu.core.model import (Flow, PlacementPolicy, ResourceSpec,
                                       ServerLabels, ServerResource, Service,
                                       Stage)
 from fleetflow_tpu.cp import placement as placement_mod
+from fleetflow_tpu.cp import store as store_mod
 from fleetflow_tpu.cp.models import (Server, ServerAllocated, ServerCapacity,
                                      ServerLabelsRec)
 from fleetflow_tpu.cp.placement import (PlacementService, Reservation, _Rows,
                                         _alloc_vector, _booked_columns)
 from fleetflow_tpu.cp.store import Store
 from fleetflow_tpu.lower.tensors import Node, lower_stage
+from fleetflow_tpu.obs.metrics import REGISTRY
 
 SEEDS = [3, 2_147_483_659, 77]
 OWN = "p/own"
@@ -302,12 +309,15 @@ def test_alloc_vector_is_its_row_of_the_columns(seed):
 
 
 REFRESH_CASES = {
-    # (node names of the retained problem, overrides, hand a server_map)
+    # (node names of the retained problem, overrides, read the columns
+    # first and hand them over, as admit_batch does)
     "every-node-known": lambda w: (w.slugs[::2], None, True),
     "a-node-without-record": lambda w: (
         ["ghost"] + w.slugs[5:25] + ["gone"], None, True),
     "no-node-known": lambda w: (["ghost", "gone"], None, True),
     "store-lookups": lambda w: (w.slugs[::-1] + ["ghost"], None, False),
+    # n1 is registered twice: both names read the first record's numbers
+    "a-name-twice": lambda w: (["n1", "n4", "n1"], None, False),
     "burst-mate-overrides": lambda w: (
         list(w.rng.permutation(w.slugs)) + ["ghost"],
         {"p/mate": (_demand(w.rng, w.slugs, 8), _demand(w.rng, w.slugs, 8)),
@@ -322,12 +332,13 @@ REFRESH_CASES = {
 @pytest.mark.parametrize("case", REFRESH_CASES)
 def test_refresh_capacity_is_the_loop(case, seed):
     world = _World(seed)
-    names, overrides, mapped = REFRESH_CASES[case](world)
+    names, overrides, handed = REFRESH_CASES[case](world)
     pt = world.problem([str(g) for g in names])
-    server_map = ({s.slug: s for s in world.store.list("servers")}
-                  if mapped else None)
-    want = _ref_refresh_capacity(world.svc, pt, OWN, overrides, server_map)
-    got = world.svc._refresh_capacity(pt, OWN, overrides, server_map)
+    # the loop looks every name up in the store: the record that
+    # server_by_slug returns, the first in table order
+    want = _ref_refresh_capacity(world.svc, pt, OWN, overrides)
+    on = world.svc._server_rows(OWN, pt) if handed else None
+    got = world.svc._refresh_capacity(pt, OWN, overrides, on)
     assert got.capacity.dtype == pt.capacity.dtype == np.float32
     _same(got.capacity, want)
     if case == "no-node-known":
@@ -336,8 +347,174 @@ def test_refresh_capacity_is_the_loop(case, seed):
         assert got is not pt and got.node_names is pt.node_names
         # nothing moved since: the very object, so that a device staging
         # keyed on identity stays warm
-        assert world.svc._refresh_capacity(got, OWN, overrides,
-                                           server_map) is got
+        assert world.svc._refresh_capacity(got, OWN, overrides) is got
+
+
+# --------------------------------------------------------------------------
+# servers written between two calls: the columns follow the records
+# --------------------------------------------------------------------------
+
+def _a_commit(world):
+    """What a commit does: one update_many of the servers it books."""
+    slugs = [str(g) for g in world.rng.choice(world.slugs, 9, replace=False)]
+    assert world.svc._write_allocations(
+        slugs + ["gone"], np.stack([_vec(world.rng, 0.2) for _ in range(10)])
+    ) == 9
+
+
+def _a_return(world):
+    """A release: allocations come off, clamped at zero."""
+    world.svc._write_allocations(world.slugs[:7], -np.stack(
+        [_vec(world.rng, 9.0) for _ in range(7)]))
+
+
+def _status_flips(world):
+    world.store.bulk_server_status({"n0": "offline", "n2": "online",
+                                    "n3": "offline", "whole": "offline"})
+    world.store.update("servers", world.store.server_by_slug("n5").id,
+                       scheduling_state="cordoned")
+    world.store.heartbeat("n7")
+
+
+def _a_server_shrinks(world):
+    world.store.update("servers", world.store.server_by_slug("n4").id,
+                       capacity=ServerCapacity(0.5, 64.0, 1.0))
+
+
+def _a_new_server(world):
+    world.store.create("servers", Server(
+        slug="fresh", tenant="default", status="online",
+        capacity=ServerCapacity(64.0, 1e5, 1e6),
+        allocated=ServerAllocated(cpu=1.0)))
+
+
+def _standing(world, *slugs) -> Server:
+    """The first of `slugs` that still has a record (a write is applied
+    more than once in a test)."""
+    return next(s for s in map(world.store.server_by_slug, slugs)
+                if s is not None)
+
+
+def _a_server_deleted(world):
+    """Under a retained stage: its node keeps its name and loses its
+    record."""
+    world.store.delete("servers", _standing(world, "n6", "n10", "n12").id)
+
+
+def _the_first_of_two_deleted(world):
+    """n1 is registered twice: the name reads the second record, then
+    none."""
+    world.store.delete("servers", _standing(world, "n1", "n14", "n16").id)
+
+
+def _a_server_renamed(world):
+    rec = _standing(world, "n8", "n18", "n20")
+    world.store.update("servers", rec.id, slug=rec.slug + "-renamed")
+
+
+def _a_reservation_opens(world):
+    """Nothing written in the store: the book alone moved."""
+    world.svc._reservations["r9"] = Reservation(
+        "r9", "p/z", _demand(world.rng, world.slugs, 12), {})
+
+
+WRITES = {
+    "a-commit": _a_commit,
+    "a-return": _a_return,
+    "status-flips": _status_flips,
+    "a-server-shrinks": _a_server_shrinks,
+    "a-new-server": _a_new_server,
+    "a-server-deleted": _a_server_deleted,
+    "the-first-of-two-deleted": _the_first_of_two_deleted,
+    "a-server-renamed": _a_server_renamed,
+    "a-reservation-opens": _a_reservation_opens,
+}
+
+
+def _inventory_agrees(world, **kw):
+    want_nodes, want_valid, want_pre = _ref_inventory(world.svc, **kw)
+    nodes, free, valid, pre = world.svc._inventory(**kw)
+    assert [n.name for n in nodes] == [n.name for n in want_nodes]
+    assert [n.labels for n in nodes] == [n.labels for n in want_nodes]
+    _same(free, np.array([n.capacity.as_tuple() for n in want_nodes],
+                         dtype=np.float64))
+    _same(valid, want_valid)
+    _same(pre, want_pre)
+
+
+@pytest.mark.parametrize("seed", SEEDS[:2])
+@pytest.mark.parametrize("write", WRITES)
+def test_inventory_is_the_loop_after_servers_were_written(write, seed):
+    world = _World(seed)
+    kw = dict(tenant="acme", exclude_demand=world.hold, preemptor=(OWN, 3))
+    _inventory_agrees(world, **kw)
+    for step in (write, "a-commit", write):
+        WRITES[step](world)
+        _inventory_agrees(world, **kw)
+
+
+def _ref_admit_refresh(svc: PlacementService, pt, key):
+    """`admit_batch`'s preamble as it walked the records: each node's
+    validity from its record, the bit it has where no server carries the
+    name, then the capacity loop."""
+    valid = np.array(
+        [bool(s.schedulable)
+         if (s := svc.store.server_by_slug(slug)) is not None
+         else bool(pt.node_valid[j])
+         for j, slug in enumerate(pt.node_names)], dtype=bool)
+    return valid, _ref_refresh_capacity(svc, pt, key)
+
+
+@pytest.mark.parametrize("seed", SEEDS[:2])
+@pytest.mark.parametrize("write", WRITES)
+def test_admit_batch_sees_the_loops_world_after_servers_were_written(
+        write, seed):
+    """A retained stage admitted to twice, servers written in between: the
+    problem each micro-solve is handed carries the validity and the
+    capacity the loops read off the records at that moment."""
+    world = _World(seed)
+    names = [str(g) for g in world.slugs[2:30:2]] + ["ghost", "n1"]
+    pt = world.problem(names)
+    pt.node_valid = np.array(world.rng.random(len(names)) < 0.5)
+    for step in (None, write, write):
+        if step is not None:
+            WRITES[step](world)
+        want_valid, want_cap = _ref_admit_refresh(world.svc, pt, OWN)
+        _placement, _rid, used = world.svc.admit_batch(OWN, pt)
+        _same(used.node_valid, want_valid)
+        _same(used.capacity, want_cap)
+        assert used.node_names is pt.node_names
+        # the candidate of the next micro-solve shares what did not move
+        pt = used
+    # nothing written, no reservation opened since the refresh that
+    # `used` came from: the same object, for the resident delta path
+    world.svc._reservations.clear()
+    settled = world.svc._refresh_capacity(used, OWN)
+    assert world.svc._refresh_capacity(settled, OWN) is settled
+
+
+@pytest.mark.parametrize("seed", SEEDS[:2])
+@pytest.mark.parametrize("write", WRITES)
+def test_refresh_capacity_is_the_loop_after_servers_were_written(write, seed):
+    """The churn path's refresh, with a burst-mate's overrides, keeps the
+    rows of a stage's nodes with the stage: they are renewed when a
+    server enters, leaves or is renamed, and not otherwise."""
+    world = _World(seed)
+    names = [str(g) for g in world.rng.permutation(world.slugs)] + ["ghost"]
+    pt = world.problem(names)
+    overrides = {"p/mate": (_demand(world.rng, world.slugs, 8),
+                            _demand(world.rng, world.slugs, 8))}
+    for step in (None, write, "status-flips", write):
+        if step is not None:
+            WRITES[step](world)
+        want = _ref_refresh_capacity(world.svc, pt, OWN, overrides)
+        kept = world.svc._node_rows.get(OWN)
+        members = world.store.server_columns().members
+        got = world.svc._refresh_capacity(pt, OWN, overrides)
+        _same(got.capacity, want)
+        if kept is not None:
+            renewed = world.svc._node_rows[OWN][2] is not kept[2]
+            assert renewed == (members != kept[1])
 
 
 # --------------------------------------------------------------------------
@@ -436,7 +613,14 @@ class _CountingNumpy:
         return counted
 
 
+def _columns_rows() -> float:
+    return REGISTRY.get("fleet_store_server_columns_rows_total").value()
+
+
 def _counted_inventory(monkeypatch, n: int) -> tuple[int, dict[str, int]]:
+    """numpy calls (placement.py's and store.py's) and objects built by
+    one inventory over `n` servers whose columns stand, 50 of them
+    written since the last read."""
     store = Store()
     for j in range(n):
         store.create("servers", Server(
@@ -449,6 +633,11 @@ def _counted_inventory(monkeypatch, n: int) -> tuple[int, dict[str, int]]:
     svc._reservations["r"] = Reservation(
         "r", "p/a", {f"n{j}": np.ones(3) for j in range(0, n, 7)}, {})
     hold = {f"n{j}": np.ones(3) for j in range(0, n, 11)}
+    flow = _tiered_flow()
+    r0 = _columns_rows()
+    svc._inventory("default", exclude_demand=hold)
+    assert _columns_rows() - r0 == n            # the first read builds
+    svc._write_allocations([f"n{j}" for j in range(50)], np.ones((50, 3)))
     built = {}
     for cls in (ResourceSpec, ServerResource, ServerLabels, Node):
         def init(self, *a, _cls=cls, _init=cls.__init__, **kw):
@@ -457,16 +646,29 @@ def _counted_inventory(monkeypatch, n: int) -> tuple[int, dict[str, int]]:
         monkeypatch.setattr(cls, "__init__", init)
     counting = _CountingNumpy()
     monkeypatch.setattr(placement_mod, "np", counting)
+    monkeypatch.setattr(store_mod, "np", counting)
+    r0 = _columns_rows()
     nodes, free, valid, _pre = svc._inventory("default", exclude_demand=hold)
     assert len(nodes) == n and free.shape == (n, 3) and valid.all()
-    return counting.calls, built
+    assert free[:51, 0].tolist() == [3.0 - 0.5 * (j % 3) - (j % 7 == 0)
+                                     + (j % 11 == 0) for j in range(50)] + [
+                                         4.0 - 0.5 * (50 % 3)]
+    # the walk: the 50 records written, not the table
+    assert _columns_rows() - r0 == 50
+    calls, r0 = counting.calls, _columns_rows()
+    svc._inventory("default", exclude_demand=hold)
+    pt = lower_stage(flow, "own", nodes=nodes, capacity=free)
+    assert svc._refresh_capacity(pt, "p/own") is not pt
+    assert _columns_rows() - r0 == 0    # nothing written: no record read
+    return calls, built
 
 
 def test_inventory_cost_is_a_pass_not_a_loop(monkeypatch):
     calls_small, _built = _counted_inventory(monkeypatch, 200)
     calls, built = _counted_inventory(monkeypatch, 2000)
-    # as many numpy calls for 2,000 servers as for 200, and few
-    assert calls == calls_small <= 16
+    # as many numpy calls for 2,000 servers as for 200, and few: the
+    # inventory's own, and the patch of the columns it reads
+    assert calls == calls_small <= 24
     # one object a server: a node shares its record's labels (PR 39; a
     # copy for each labelled record before: 5,000 a solve in a zoned pool)
-    assert built == {"Node": 2000}
+    assert built == {"Node": 2000 * 2}
