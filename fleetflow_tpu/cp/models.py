@@ -341,8 +341,9 @@ class PlacementRecord(Record):
         """What `asdict` gives, key for key and in its order, without its
         walk in Python over every row: the fields hold plain JSON values
         (a 20,000-row assignment, a demand list a server), so one level of
-        copying is the same dict. A record is written whole whenever its
-        stage commits, is evicted from or gets rows back."""
+        copying is the same dict. A record is written whole at its stage's
+        first commit; later commits patch its dicts' keys in place
+        (`Store.update_keys`), so a snapshot copies what it renders."""
         return {"id": self.id, "created_at": self.created_at,
                 "updated_at": self.updated_at, "stage_key": self.stage_key,
                 "assignment": dict(self.assignment),

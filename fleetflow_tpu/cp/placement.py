@@ -74,6 +74,19 @@ _M_VICTIMS = REGISTRY.counter(
     "fleet_placement_victims_total",
     "Committed rows evicted by the commit of a stage of higher priority")
 
+_M_RECORD_WRITES = REGISTRY.counter(
+    "fleet_placement_record_writes_total",
+    "Writes of a stage's placement record, by form: whole (one put of the "
+    "record) or diff (one mrg entry of the keys that changed)",
+    labels=("form",))
+_M_RECORD_KEYS = REGISTRY.counter(
+    "fleet_placement_record_keys_total",
+    "Keys of placement records journaled — rows, servers' demand and held "
+    "conflict keys, set or dropped; a whole write counts every key")
+_count_whole_write = _M_RECORD_WRITES.bind(form="whole")
+_count_diff_write = _M_RECORD_WRITES.bind(form="diff")
+_count_record_keys = _M_RECORD_KEYS.bind()
+
 # a server is over its capacity only beyond this relative slack: the
 # solver's own (solver/repair.py), so that what it calls feasible fits here
 _CAP_RTOL = 1e-6
@@ -202,6 +215,87 @@ def _moved_rows(pt: ProblemTensors, before: Placement,
     return moved
 
 
+def _demand_changed(prev: dict[str, np.ndarray], new: dict[str, np.ndarray]
+                    ) -> tuple[list[str], np.ndarray]:
+    """The slugs whose (R,) vector differs from `prev` to `new` (a slug one
+    of them lacks counts as zero there), and `new - prev` in their rows."""
+    slugs = list(set(prev) | set(new))
+    d = _by_slug(slugs, new) - _by_slug(slugs, prev)
+    changed = np.flatnonzero(d.any(axis=1))
+    return [slugs[i] for i in changed.tolist()], d[changed]
+
+
+def _row_changes(prev: "Reservation", r: "Reservation"
+                 ) -> tuple[dict[str, str], list[str]]:
+    """The rows of `r.assignment` that differ from `prev.assignment`, as
+    {row: server} to set and [row] to drop. Where the two are placements of
+    one problem's rows (the same `names` object, the same servers) and both
+    show every row, it is one comparison of the two `node_of` arrays and
+    names only for what moved; otherwise a dict difference."""
+    a, b = prev.rows, r.rows
+    if (a is not None and b is not None and a.names is b.names
+            and len(prev.assignment) == len(r.assignment) == len(b.names)
+            and (a.nodes is b.nodes or a.nodes == b.nodes)):
+        n = len(b.names)
+        moved = np.flatnonzero(a.node_of[:n] != b.node_of[:n])
+        return {b.names[i]: b.nodes[j] for i, j
+                in zip(moved.tolist(), b.node_of[moved].tolist())}, []
+    old, new = prev.assignment, r.assignment
+    return ({row: node for row, node in new.items() if old.get(row) != node},
+            [row for row in old if row not in new])
+
+
+@dataclass
+class _RecordChange:
+    """What a stage's commitment changed since its placement record was
+    written from `basis`, as the writer knows it: rows to set and to drop,
+    the servers whose demand may differ (each looked up in the commitment
+    as it is now: set where it books something there, dropped where not)
+    and the held keys the record holds."""
+    basis: "Reservation"
+    rows_set: dict[str, str]
+    rows_drop: list[str]
+    slugs: list[str]
+    held: dict[str, list[str]]
+
+    @classmethod
+    def superseding(cls, prev: Optional["Reservation"], r: "Reservation",
+                    slugs: Optional[list[str]] = None
+                    ) -> Optional["_RecordChange"]:
+        """`r` in place of `prev`; `slugs` are the servers whose demand the
+        supersession found changed (`_demand_changed`), where it ran."""
+        if prev is None:
+            return None
+        if slugs is None:
+            slugs, _ = _demand_changed(prev.demand_by_node, r.demand_by_node)
+        # a slug that enters or leaves at a zero vector changes no sum
+        slugs = list(set(slugs).union(
+            prev.demand_by_node.keys() ^ r.demand_by_node.keys()))
+        return cls(prev, *_row_changes(prev, r), slugs, prev.held_keys)
+
+    def patch(self, r: "Reservation") -> tuple[dict, dict, int]:
+        """`Store.update_keys`'s `set_keys` and `drop_keys` that take the
+        record from `basis` to `r`, and how many keys they name."""
+        dem_set, dem_drop = {}, []
+        for slug in self.slugs:
+            d = r.demand_by_node.get(slug)
+            if d is None:
+                dem_drop.append(slug)
+            else:
+                dem_set[slug] = np.asarray(d, dtype=np.float64).tolist()
+        held_set = {k: list(v) for k, v in r.held_keys.items()
+                    if self.held.get(k) != v}
+        held_drop = [k for k in self.held if k not in r.held_keys]
+        set_keys = {"assignment": self.rows_set, "demand_by_node": dem_set,
+                    "held_keys": held_set}
+        drop_keys = {"assignment": self.rows_drop,
+                     "demand_by_node": dem_drop, "held_keys": held_drop}
+        n = sum(map(len, set_keys.values())) + sum(map(len,
+                                                       drop_keys.values()))
+        return ({k: v for k, v in set_keys.items() if v},
+                {k: v for k, v in drop_keys.items() if v}, n)
+
+
 def _node(s: Server) -> Node:
     """The node `lower_stage` sees for a server record: its name and the
     record's own labels, shared and only ever read. Nothing writes into a
@@ -278,6 +372,10 @@ class PlacementService:
         # a departed service must never look placed to invariants,
         # dashboards, or deploy fan-out
         self._masked: dict[str, frozenset] = {}
+        # stage key -> (the placement record this service last wrote, the
+        # commitment it wrote it from): what a commit's record is written
+        # by difference against (_persist_committed)
+        self._recorded: dict[str, tuple[PlacementRecord, Reservation]] = {}
         # the committed book explains servers.allocated: rebuild it from
         # the store's placements table so a restarted (or promoted
         # standby, docs/guide/13-cp-replication.md) CP's next commit
@@ -297,26 +395,51 @@ class PlacementService:
                 assignment=dict(rec.assignment), committed=True,
                 held_keys={k: list(v) for k, v in rec.held_keys.items()})
 
-    def _persist_committed(self, key: str) -> None:
+    def _persist_committed(self, key: str,
+                           change: Optional[_RecordChange] = None) -> None:
         """Mirror the stage's committed reservation into the store (one
-        row per stage, journaled and replicated). Caller holds the lock."""
+        row per stage, journaled and replicated). Caller holds the lock.
+
+        By difference where it can be: where the store's record is the one
+        this service last wrote, from `change.basis`, only the keys that
+        `change` names are journaled (`Store.update_keys`, one `mrg`
+        entry). Whole (a `put`) where there is no record yet, where this
+        service did not write it from that basis (a restart, a promoted
+        standby), or where the difference names more than half of the
+        record's keys: a put is no dearer then. Either way the record
+        equals the commitment, as dicts."""
         r = self._committed.get(key)
         rec = self.store.find_one("placements",
                                   lambda p: p.stage_key == key)
+        last = self._recorded.pop(key, None)
         if r is None:
             if rec is not None:
                 self.store.delete("placements", rec.id)
             return
+        size = len(r.assignment) + len(r.demand_by_node) + len(r.held_keys)
+        if (change is not None and rec is not None and last is not None
+                and last[0] is rec and last[1] is change.basis):
+            set_keys, drop_keys, n = change.patch(r)
+            if 2 * n <= size:
+                self.store.update_keys("placements", rec.id,
+                                       set_keys=set_keys, drop_keys=drop_keys)
+                self._recorded[key] = (rec, r)
+                _count_diff_write()
+                _count_record_keys(n)
+                return
         attrs = dict(
             assignment=dict(r.assignment),
             demand_by_node={slug: np.asarray(d, dtype=np.float64).tolist()
                             for slug, d in r.demand_by_node.items()},
             held_keys={k: list(v) for k, v in r.held_keys.items()})
         if rec is None:
-            self.store.create("placements",
-                              PlacementRecord(stage_key=key, **attrs))
+            rec = self.store.create("placements",
+                                    PlacementRecord(stage_key=key, **attrs))
         else:
             self.store.update("placements", rec.id, **attrs)
+        self._recorded[key] = (rec, r)
+        _count_whole_write()
+        _count_record_keys(size)
 
     # ------------------------------------------------------------------
     # inventory lowering
@@ -904,9 +1027,10 @@ class PlacementService:
             slugs, sign * _by_slug(slugs, r.demand_by_node))
 
     def _apply_allocation_delta(self, prev: Reservation,
-                                new: Reservation) -> int:
+                                new: Reservation) -> tuple[int, list[str]]:
         """Supersede `prev` by `new` touching only the nodes whose demand
-        actually CHANGED; returns the server records written. BOTH commit
+        actually CHANGED; returns the server records written and the slugs
+        of those nodes (`_demand_changed`). BOTH commit
         paths supersede this way: commit() (a redeploy; a streaming
         micro-solve commit per drain tick, cp/admission.py) and
         commit_retained() (the reconverger's commit after churn). Such a
@@ -926,23 +1050,23 @@ class PlacementService:
         max(last_heartbeat, updated_at): a dead server is written once,
         by the commit that moves its rows away, then left alone, which
         is what the reaper's clock wants."""
-        slugs = list(set(prev.demand_by_node) | set(new.demand_by_node))
-        d = (_by_slug(slugs, new.demand_by_node)
-             - _by_slug(slugs, prev.demand_by_node))
-        changed = np.flatnonzero(d.any(axis=1))
-        return self._write_allocations(
-            [slugs[i] for i in changed.tolist()], d[changed])
+        slugs, d = _demand_changed(prev.demand_by_node, new.demand_by_node)
+        return self._write_allocations(slugs, d), slugs
 
     def _supersede_allocation(self, prev: Optional[Reservation],
                               r: Reservation,
-                              returned: Optional[dict] = None) -> None:
+                              returned: Optional[dict] = None
+                              ) -> Optional[list[str]]:
         """The `cp.commit.apply_allocation` phase of both commit paths:
         book `r` on the servers in place of the stage's previous
         commitment, if it has one. `returned` (slug -> (R,)) is what the
         victims of `r` gave up (`_evict`): it is returned in the same
         pass, by difference, so a server that loses victims and takes
         arrivals is written once. The phase's `records` field is the
-        number of server records written."""
+        number of server records written. Returns the slugs on which the
+        stage's own demand changed, where the pass found them (a
+        supersession without victims), for the placement record's
+        difference; None otherwise."""
         with phase("cp.commit.apply_allocation") as ph:
             if returned:
                 gone = dict(returned)
@@ -951,11 +1075,13 @@ class PlacementService:
                         gone[slug] = gone.get(slug, 0) + d
                 prev = Reservation(id="", stage_key=r.stage_key,
                                    demand_by_node=gone, assignment={})
+            changed = None
             if prev is None:
                 written = self._apply_allocation(r, +1.0)
             else:
-                written = self._apply_allocation_delta(prev, r)
+                written, changed = self._apply_allocation_delta(prev, r)
             ph.set(records=written)
+        return None if returned else changed
 
     def commit(self, rid: str) -> bool:
         """Deploy succeeded: move reserved -> committed on the servers
@@ -973,24 +1099,27 @@ class PlacementService:
                 return False
             prev = self._committed.pop(r.stage_key, None)
             returned = self._evict(r) if r.victim_rows else None
-            self._supersede_allocation(prev, r, returned)
+            changed = self._supersede_allocation(prev, r, returned)
             r.committed = True
             self._committed[r.stage_key] = r
             self._drop_churn(r.stage_key)   # commitment reflects reality now
             with phase("cp.commit.persist"):
-                self._persist_committed(r.stage_key)
+                self._persist_committed(r.stage_key, _RecordChange.superseding(
+                    prev, r, changed))
             return True
 
     def _evict(self, r: Reservation) -> dict[str, np.ndarray]:
         """The `cp.commit.evict` phase: take the victims of `r` out of
         their stages' commitments (assignment, demand by server, held
         keys), tombstone them in those stages' retained problems and
-        persist those placement records. Returns slug -> (R,), what the
-        victims booked: the caller returns it to the servers."""
+        persist those placement records, by difference: the victims
+        dropped, the servers they were on written. Returns slug -> (R,),
+        what the victims booked: the caller returns it to the servers."""
         returned: dict[str, np.ndarray] = {}
         with phase("cp.commit.evict") as ph:
             for vkey, (_cid, idx) in r.victim_rows.items():
                 c = self._committed[vkey]
+                held = c.held_keys
                 rows = c.rows
                 rows.live[idx] = False
                 rows.evicted = (idx if rows.evicted is None
@@ -998,8 +1127,9 @@ class PlacementService:
                 gone = rows.by_node(idx)
                 left = np.bincount(rows.node_of[rows.live],
                                    minlength=len(rows.nodes))
-                for j in np.unique(rows.node_of[idx]).tolist():
-                    slug = rows.nodes[j]
+                at = np.unique(rows.node_of[idx]).tolist()
+                slugs = [rows.nodes[j] for j in at]
+                for j, slug in zip(at, slugs):
                     returned[slug] = returned.get(slug, 0) + gone[j]
                     if left[j]:
                         c.demand_by_node[slug] = np.maximum(
@@ -1010,7 +1140,8 @@ class PlacementService:
                     del c.assignment[name]
                 c.held_keys = self._live_held_keys(rows)
                 self._tombstone(vkey, rows, idx, True)
-                self._persist_committed(vkey)
+                self._persist_committed(vkey, _RecordChange(
+                    c, {}, list(r.victims[vkey]), slugs, held))
             n = sum(map(len, r.victims.values()))
             _M_VICTIMS.inc(n)
             ph.set(victims=n, stages=len(r.victim_rows))
@@ -1059,7 +1190,8 @@ class PlacementService:
         `stage_key` back where they were — an operator rolling back a
         batch that preempted: `release_stage` of the batch, then this.
         By difference, like a commit: each server that gets rows back is
-        written once, the placement record is persisted whole. Returns
+        written once, and the placement record gets the rows and those
+        servers back. Returns
         the rows reinstated; 0, with the book untouched, where there are
         none, where the stage was committed anew since (its old victims
         are then history), or where they no longer fit: capacity taken,
@@ -1091,18 +1223,21 @@ class PlacementService:
                             for i in held_by if i in coming}
                     if mine & set(others.get(k, ())):
                         return 0
-            self._write_allocations([rows.nodes[j] for j in at], back[at])
-            for j in at:
-                slug = rows.nodes[j]
+            slugs = [rows.nodes[j] for j in at]
+            self._write_allocations(slugs, back[at])
+            for j, slug in zip(at, slugs):
                 c.demand_by_node[slug] = (
                     np.asarray(c.demand_by_node.get(slug, 0.0)) + back[j])
-            for i, j in zip(idx.tolist(), rows.node_of[idx].tolist()):
-                c.assignment[rows.names[i]] = rows.nodes[j]
+            returning = {rows.names[i]: rows.nodes[j] for i, j
+                         in zip(idx.tolist(), rows.node_of[idx].tolist())}
+            c.assignment.update(returning)
+            held = c.held_keys
             rows.live[idx] = True
             rows.evicted = None
             c.held_keys = self._live_held_keys(rows)
             self._tombstone(stage_key, rows, idx, False)
-            self._persist_committed(stage_key)
+            self._persist_committed(stage_key, _RecordChange(
+                c, returning, [], slugs, held))
             return int(idx.size)
 
     def release(self, rid: str, *, undo_commit: bool = False) -> bool:
@@ -1135,7 +1270,8 @@ class PlacementService:
         returns, every server's `allocated` is what subtract-then-add
         would have left (to rounding), each changed record went through
         Store.update_many — journaled, replicated — and the placement record
-        is persisted whole, so a standby or a restart reloads the same
+        is persisted (by difference: the rows that moved, the servers
+        whose demand changed), so a standby or a restart reloads the same
         book: the op is committed before it is acknowledged. Servers the
         commit does not touch keep their `updated_at` (what reads it:
         _apply_allocation_delta)."""
@@ -1154,11 +1290,12 @@ class PlacementService:
                     held_keys=self._held_keys(pt, placement),
                     rows=_Rows.of(pt, placement))
             prev = self._committed.pop(stage_key, None)
-            self._supersede_allocation(prev, r)
+            changed = self._supersede_allocation(prev, r)
             self._committed[stage_key] = r
             self._drop_churn(stage_key)
             with phase("cp.commit.persist"):
-                self._persist_committed(stage_key)
+                self._persist_committed(stage_key, _RecordChange.superseding(
+                    prev, r, changed))
             return True
 
     def release_stage(self, stage_key: str, *, forget: bool = False) -> bool:

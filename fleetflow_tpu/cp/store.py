@@ -14,8 +14,12 @@ database): every create/update/delete appends ONE journal line
 `update_many` — a partial update of many records of one table, which is
 how a commit books a placement on its servers — appends one `upd` line
 for the lot: `{"op": "upd", "t": table, "at": updated_at, "u": {id:
-{field: value}}}`, the changed fields only, O(change) not O(records). A
-promotion appends `{"op": "epoch"}`. An `upd` line stays under
+{field: value}}}`, the changed fields only, O(change) not O(records).
+`update_keys` — a patch of the keys of one record's dict-valued fields,
+which is how a commit rewrites a stage's placement record — appends one
+`mrg` line: `{"op": "mrg", "t": table, "id": id, "at": updated_at,
+"set": {field: {key: value}}, "drop": {field: [key]}}`, O(keys changed)
+not O(record). A promotion appends `{"op": "epoch"}`. An `upd` line stays under
 JOURNAL_LINE_MAX (a larger batch is cut into several entries, each with
 its own sequence number), so the replication stream can frame it.
 When the journal passes `journal_max_bytes` or `journal_max_entries` the
@@ -24,7 +28,8 @@ Recovery loads the snapshot and replays the journal; replaying a journal
 that was already folded into the snapshot (crash between snapshot rename
 and truncate) is idempotent — puts overwrite with identical rows, an
 `upd` carries the ABSOLUTE new values of its fields (never a delta) and
-sets them again, deletes of absent rows are no-ops. A torn final line
+sets them again, an `mrg` the absolute value of each key it sets and
+drops only what is there, deletes of absent rows are no-ops. A torn final line
 (crash mid-append) is detected and dropped — a torn `upd` whole, so a
 batch's writes are all or nothing. Writes are flushed to the OS on every
 append;
@@ -342,6 +347,19 @@ class ServerColumns:
         return out
 
 
+def _merge_keys(rec: Record, set_keys: Mapping[str, Mapping],
+                drop_keys: Mapping[str, list]) -> None:
+    """An `mrg` entry's patch of `rec`'s dict-valued fields, in place:
+    drops first (a missing key is skipped), then sets. Applied twice it
+    leaves what it left once."""
+    for name, keys in drop_keys.items():
+        held = getattr(rec, name)
+        for k in keys:
+            held.pop(k, None)
+    for name, values in set_keys.items():
+        getattr(rec, name).update(values)
+
+
 class Store:
     def __init__(self, path: Optional[str] = None, *,
                  journal_max_bytes: int = 4 * 1024 * 1024,
@@ -485,6 +503,33 @@ class Store:
             if changed:
                 self._log_upd(table, now, changed)
             return written
+
+    def update_keys(self, table: str, rec_id: str, *,
+                    set_keys: Optional[Mapping[str, Mapping]] = None,
+                    drop_keys: Optional[Mapping[str, list]] = None,
+                    ) -> Optional[Record]:
+        """Patch dict-valued fields of one record in place: `drop_keys` is
+        field -> [key] (a key the field lacks is skipped), then `set_keys`
+        field -> {key: value}, the ABSOLUTE new value of each key. Journaled
+        as ONE `mrg` entry of just those keys, handed to the journal and the
+        sink before this returns, where `update` journals the record whole:
+        a commit that moves 340 rows of a 100,000-row placement record
+        writes 340 rows. Values are kept by the record as given: the caller
+        hands over objects it does not write again. Returns the record; None
+        where the table lacks `rec_id`."""
+        set_keys = set_keys or {}
+        drop_keys = drop_keys or {}
+        with self._lock:
+            rec = self._tables[table].get(rec_id)
+            if rec is None:
+                return None
+            rec.updated_at = self._clock()
+            _merge_keys(rec, set_keys, drop_keys)
+            self._emit({"op": "mrg", "t": table, "id": rec_id,
+                        "at": rec.updated_at, "set": set_keys,
+                        "drop": drop_keys})
+            self._notify("put", table, rec)
+            return rec
 
     def delete(self, table: str, rec_id: str) -> bool:
         with self._lock:
@@ -1092,6 +1137,18 @@ class Store:
                 rec.updated_at = entry["at"]
                 if notify:
                     self._notify("put", table, rec)
+        elif op == "mrg":
+            rec = self._tables[table].get(entry.get("id"))
+            if rec is None:
+                return
+            known = {f.name for f in fields(cls)}
+            _merge_keys(rec, {k: v for k, v in entry["set"].items()
+                              if k in known},
+                        {k: v for k, v in entry["drop"].items()
+                         if k in known})
+            rec.updated_at = entry["at"]
+            if notify:
+                self._notify("put", table, rec)
         elif op == "del":
             rid = entry.get("id")
             if self._pop(table, rid) and notify:

@@ -15,9 +15,10 @@ services on 8 servers, host scheduler, no device):
   * a commit writes exactly the server records whose demand changed:
     fleet_store_ops_total{table="servers",op="put"} and the `records`
     field of the cp.commit.apply_allocation phase both say so
-  * the replication sink is handed one put per changed server and the
-    placement record, and a second Store fed the stream ends with the
-    primary's `allocated` on every server
+  * the replication sink is handed one `upd` of the changed servers and
+    the placement record (whole on a first commit and where over half of
+    it changed, else the keys that did: PR 43), and a second Store fed
+    the stream ends with the primary's `allocated` on every server
 
 And, since _demand_by_node became one array pass: its keys, their order
 and its float64 sums are the row loop's (kept below as `_row_loop`), on
@@ -46,7 +47,8 @@ from fleetflow_tpu.obs.metrics import REGISTRY
 
 N_SERVERS = 8
 STAGES = {"live": range(0, 30), "canary": range(30, 40)}
-SCENARIOS = ["kill_one", "kill_then_revive", "no_previous", "second_stage"]
+SCENARIOS = ["kill_one", "kill_then_revive", "no_previous", "second_stage",
+             "move_one_server"]
 
 
 def _flow(n_servers=N_SERVERS, n_services=40, stages=STAGES):
@@ -141,12 +143,36 @@ def _run(cp: _Cp, scenario: str, commit_retained):
         yield "p/live", (lambda: commit_retained(svc, "p/live"))
         return
     deploy("live")
+    if scenario == "move_one_server":
+        # what a sticky re-solve (the annealer's) does where the host
+        # scheduler re-places the stage: one server's rows move, the
+        # others stay
+        _move_one_server(cp, "p/live")
+        yield "p/live", (lambda: commit_retained(svc, "p/live"))
+        return
     if scenario == "second_stage":
         deploy("canary")
     cp.victim = cp.busiest("p/live")
     yield from churn(cp.victim, False)
     if scenario == "kill_then_revive":
         yield from churn(cp.victim, True)
+
+
+def _move_one_server(cp: _Cp, key: str) -> tuple[str, str]:
+    """Retain a placement of `key` that moves the rows of its busiest
+    server to the next server; returns (that server, the next)."""
+    pt, placement = cp.svc.retained(key)
+    src = pt.node_names.index(cp.busiest(key))
+    dst = (src + 1) % len(pt.node_names)
+    raw = np.where(np.asarray(placement.raw) == src, dst,
+                   np.asarray(placement.raw))
+    moved = dataclasses.replace(
+        placement, raw=raw,
+        assignment={name: pt.node_names[int(j)]
+                    for name, j in zip(pt.service_names, raw)})
+    with cp.svc._lock:
+        cp.svc._last[key] = (pt, moved)
+    return pt.node_names[src], pt.node_names[dst]
 
 
 def _changed_nodes(before: dict, after: dict) -> set[str]:
@@ -259,33 +285,37 @@ def test_a_commit_leaves_untouched_servers_alone():
     cp.store._clock = lambda: float(next(ticks))
     placement, rid = cp.svc.solve_stage(cp.flow, "live")
     assert placement.feasible and cp.svc.commit(rid)
-    pt, placement = cp.svc.retained("p/live")
-    src = pt.node_names.index(cp.busiest("p/live"))
-    dst = (src + 1) % N_SERVERS
-    raw = np.where(np.asarray(placement.raw) == src, dst,
-                   np.asarray(placement.raw))
-    moved = dataclasses.replace(
-        placement, raw=raw,
-        assignment={name: pt.node_names[int(j)]
-                    for name, j in zip(pt.service_names, raw)})
-    with cp.svc._lock:
-        cp.svc._last["p/live"] = (pt, moved)
+    src, dst = _move_one_server(cp, "p/live")
     stamps = {s.slug: s.updated_at for s in cp.store.list("servers")}
     want = dict(cp.allocated())
     puts = REGISTRY.get("fleet_store_ops_total")
     n0 = puts.value(table="servers", op="put")
     assert cp.svc.commit_retained("p/live")
     assert puts.value(table="servers", op="put") - n0 == 2
-    touched = {pt.node_names[src], pt.node_names[dst]}
+    touched = {src, dst}
     for s in cp.store.list("servers"):
         assert (s.updated_at != stamps[s.slug]) == (s.slug in touched)
     got = cp.allocated()
-    assert got[pt.node_names[src]] == (0.0, 0.0, 0.0)
-    assert got[pt.node_names[dst]] == pytest.approx(tuple(
-        a + b for a, b in zip(want[pt.node_names[src]],
-                              want[pt.node_names[dst]])), abs=1e-9)
+    assert got[src] == (0.0, 0.0, 0.0)
+    assert got[dst] == pytest.approx(tuple(
+        a + b for a, b in zip(want[src], want[dst])), abs=1e-9)
     for slug in set(want) - touched:
         assert got[slug] == want[slug]
+
+
+def _record_fields(rec) -> dict:
+    return {"assignment": dict(rec.assignment),
+            "demand_by_node": dict(rec.demand_by_node),
+            "held_keys": dict(rec.held_keys)}
+
+
+def _keys_changed(was: dict, now: dict) -> int:
+    """Keys of a placement record's fields set or dropped from `was` to
+    `now`."""
+    return sum(len(now[f].keys() ^ was[f].keys())
+               + sum(now[f][k] != was[f][k]
+                     for k in now[f].keys() & was[f].keys())
+               for f in now)
 
 
 @pytest.mark.parametrize("scenario", SCENARIOS)
@@ -293,22 +323,32 @@ def test_replication_stream_reproduces_allocated(scenario):
     cp = _Cp()
     for key, do in _run(cp, scenario, PlacementService.commit_retained):
         before = _stage_book(cp.svc, key)
+        rec = cp.store.find_one("placements", lambda p: p.stage_key == key)
+        was = _record_fields(rec) if rec is not None else None
         mark = len(cp.stream)
         assert do()
         changed = _changed_nodes(before, _stage_book(cp.svc, key))
         entries = [json.loads(line) for _seq, line in cp.stream[mark:]]
         # the changed servers' new `allocated` in one `upd` entry, then
-        # the placement record, whole
+        # the placement record: whole (`put`) on the stage's first commit
+        # and where over half of its keys changed, else the keys that did
+        # (`mrg`, PR 43)
+        rec = cp.store.find_one("placements", lambda p: p.stage_key == key)
+        now = _record_fields(rec)
+        form = ("put" if was is None
+                or 2 * _keys_changed(was, now) > sum(map(len, now.values()))
+                else "mrg")
         assert [(e["op"], e["t"]) for e in entries] == [
-            ("upd", "servers"), ("put", "placements")]
+            ("upd", "servers"), (form, "placements")]
         assert all(fields.keys() == {"allocated"}
                    for fields in entries[0]["u"].values())
         written = _server_writes(cp.store, cp.stream[mark:])
         assert len(written) == len(changed) and set(written) == changed
-        placement_rec = entries[1]["r"]
-        assert placement_rec["stage_key"] == key
-        assert (set(placement_rec["demand_by_node"])
-                == set(_stage_book(cp.svc, key)))
+        if form == "put":
+            assert entries[1]["r"]["stage_key"] == key
+        else:
+            assert entries[1]["id"] == rec.id
+        assert set(rec.demand_by_node) == set(_stage_book(cp.svc, key))
     standby = Store()
     assert standby.apply_replicated(cp.stream) == len(cp.stream)
     got = {s.slug: (s.allocated.cpu, s.allocated.memory, s.allocated.disk)
