@@ -32,10 +32,6 @@ it through `adopt_host` instead of cold-staging: the readmitted warm
 solve runs the exact resident-warm executable, bit-identical to a
 never-evicted slot (pinned by the eviction property test). Occupancy is
 rendered by `fleet solve slots` from `slots_status()`.
-
-`place_many` is the tenant-multiplexer entry (solver/multiplex.py):
-same-tier resident-warm stages batch into ONE vmapped dispatch; the
-rest fall through to the serial path with identical results.
 """
 
 from __future__ import annotations
@@ -459,7 +455,7 @@ class TpuSolverScheduler:
     def _finalize(self, pt: ProblemTensors, res, slot, ms: float,
                   stage: Optional[str], ph) -> Placement:
         """`ph` is the open `sched.finalize` phase: its `levels` says
-        "built" if any schedule under it was, else "kept"."""
+        whether the level schedule was "built" or "kept"."""
         slot.last_assignment = res.assignment
         slot.last_used = time.monotonic()
         sub = getattr(res, "subsolve", None)
@@ -477,8 +473,7 @@ class TpuSolverScheduler:
             levels, outcome = level_schedule(pt), "built"
             slot.schedule = (pt.dep_depth, pt.service_names, levels)
         _M_LEVELS.inc(outcome=outcome)
-        if ph.fields.get("levels") != "built":
-            ph.set(levels=outcome)
+        ph.set(levels=outcome)
         placement = Placement(
             assignment=assignment_names(pt, res.assignment),
             levels=levels,
@@ -534,63 +529,6 @@ class TpuSolverScheduler:
             ms = (ph_solve.t1 - ph.t0) * 1e3
             with phase("sched.finalize", rows=pt.S) as ph_fin:
                 return self._finalize(pt, res, slot, ms, stage, ph_fin)
-
-    def place_many(self, requests: list[dict]) -> list[Placement]:
-        """Batched placement across stages — the tenant multiplexer
-        entry. Each request is a dict with keys `pt` (required), `delta`,
-        `warm_start`, `stage`. Every request stages through the slot
-        manager first; the resident-warm single-chip stages then batch
-        same-tier into ONE vmapped dispatch (solver/multiplex.py), the
-        rest solve serially. Results come back in request order, each
-        identical to what a solo `place()` would have produced (parity is
-        property-pinned)."""
-        self._platform()
-        from ..solver.multiplex import MuxEntry, solve_multiplexed
-        from ..solver.sharded import sharded_route
-
-        with phase("sched.place", requests=len(requests)) as ph:
-            staged = []
-            with phase("sched.stage"):
-                for req in requests:
-                    pt = req["pt"]
-                    warm = bool(req.get("warm_start"))
-                    sh_mesh = sharded_route(pt) if self.mesh is None else None
-                    slot, resident_warm = self._stage(
-                        pt, req.get("delta"), warm, req.get("stage"),
-                        mesh=sh_mesh)
-                    staged.append((pt, slot, resident_warm, sh_mesh,
-                                   req.get("stage"), warm))
-
-            results: list = [None] * len(staged)
-            with phase("sched.solve") as ph_solve:
-                mux_idx = [i for i, (_, slot, rw, mesh, _, _w)
-                           in enumerate(staged)
-                           if rw and mesh is None and slot.resident.mesh is None]
-                if len(mux_idx) >= 2:
-                    entries = [MuxEntry(pt=staged[i][0],
-                                        resident=staged[i][1].resident,
-                                        seed=self.seed, stage=staged[i][4])
-                               for i in mux_idx]
-                    mres = solve_multiplexed(entries, chains=self.chains,
-                                             steps=self.steps)
-                    for i, r in zip(mux_idx, mres):
-                        results[i] = r
-                for i, (pt, slot, resident_warm, sh_mesh, _stg,
-                        warm) in enumerate(staged):
-                    if results[i] is not None:
-                        continue
-                    init = None
-                    if (warm and not resident_warm
-                            and slot.last_assignment is not None
-                            and slot.last_assignment.shape[0] == pt.S):
-                        init = slot.last_assignment
-                    results[i] = self._solve_one(pt, slot, resident_warm,
-                                                 sh_mesh, init)
-            ms = (ph_solve.t1 - ph.t0) * 1e3
-            with phase("sched.finalize") as ph_fin:
-                return [self._finalize(pt, res, slot, ms, stg, ph_fin)
-                        for (pt, slot, _rw, _mesh, stg, _w), res
-                        in zip(staged, results)]
 
     def reschedule(self, pt: ProblemTensors, *, delta=None,
                    overlap_host_work=None,

@@ -5,7 +5,7 @@ order_by_dependencies + per-service Docker loop) with greedy seeding +
 mesh-sharded simulated annealing over dense constraint tensors.
 """
 
-from .anneal import anneal, chain_states_from_assignment, prerepair_state
+from .anneal import chain_states_from_assignment, prerepair_state
 from .buckets import (BucketConfig, BucketInfo, bucket_config, bucket_size,
                       pad_problem_tiers, soft_score_host,
                       stage_problem_tiers, staging_arena_stats,

@@ -15,6 +15,10 @@ Chain ranking and adaptive-exit checks read the carried state (cheap, exact
 by construction); the WINNER's final stats are re-derived from scratch with
 kernels.violation_stats so float32 drift in the carried load can never flip
 the feasibility gate.
+
+There is one anneal loop, `anneal_adaptive_states`: sweep blocks inside a
+lax.while_loop that exits on device once any chain has seen a feasible
+state, returning each chain's best-ever state.
 """
 
 from __future__ import annotations
@@ -29,12 +33,11 @@ import jax.numpy as jnp
 from .kernels import real_row_weights
 from .problem import DeviceProblem, eligible_lookup, eligible_row
 
-__all__ = ["anneal", "anneal_adaptive", "anneal_states",
-           "anneal_adaptive_states", "chain_states_from_assignment",
+__all__ = ["anneal_adaptive_states",
+           "chain_states_from_assignment",
            "prerepair_state", "prerepair_state_counted",
            "state_violation_stats", "state_soft_score",
-           "ChainState", "TRACE_COLS", "solve_trace_blocks",
-           "empty_trace"]
+           "ChainState", "TRACE_COLS", "solve_trace_blocks"]
 
 W_CAP = 1e3     # per-unit overflow mass (normalized units)
 W_CONF = 1e4    # per conflicting co-placement
@@ -63,18 +66,6 @@ def solve_trace_blocks(default: int = 16) -> int:
     except ValueError:
         v = default
     return max(0, min(v, 512))
-
-
-def empty_trace(trace_blocks: int):
-    """The telemetry pytree at its zero value — the treedef every
-    returning path (adaptive, fixed-budget, 0-sweep exit) must share so
-    the telemetry can never fork an executable's output signature."""
-    return {
-        "blocks": jnp.zeros((trace_blocks, len(TRACE_COLS)), jnp.float32),
-        "filled": jnp.int32(0),
-        "init_violations": jnp.float32(0.0),
-        "init_soft": jnp.float32(0.0),
-    }
 
 
 class ChainState(NamedTuple):
@@ -617,60 +608,6 @@ def backend_proposals_per_step(S: int) -> int:
     return default_proposals_per_step(S)
 
 
-@partial(jax.jit, static_argnames=("steps", "proposals_per_step", "unroll"))
-def anneal_states(prob: DeviceProblem, init_assignments: jax.Array,
-                  key: jax.Array, steps: int = 2000, t0: float = 1.0,
-                  t1: float = 1e-3, proposals_per_step: int | None = None,
-                  unroll: int = 1) -> ChainState:
-    """Run `steps` batched-Metropolis sweeps on C independent chains.
-
-    Returns each chain's FINAL carried state — unlike the adaptive path,
-    there is no best-ever tracking here: callers that rank these states
-    (api adaptive=False, tests comparing carried state against rebuilds)
-    rely on exact final-state semantics, and the production default is
-    the adaptive path.
-
-    init_assignments: (C, S) int32; returns refined assignments (C, S).
-    Each sweep evaluates `proposals_per_step` moves per chain in parallel
-    (one device dispatch), so total proposals = steps x M x C while the
-    sequential depth stays `steps` — the shape that keeps a TPU fed, vs the
-    classic one-move-per-step SA whose wall-clock is all dispatch latency.
-    Temperature decays geometrically t0 → t1 (in units of soft-score; hard
-    violation weights are orders of magnitude above t0, so hard-violating
-    moves are only ever accepted to escape an existing violation).
-    """
-    C, S = init_assignments.shape
-    M = (proposals_per_step if proposals_per_step is not None
-         else default_proposals_per_step(S))
-    states = jax.vmap(partial(chain_states_from_assignment, prob))(init_assignments)
-    keys = jax.random.split(key, C)
-
-    decay = (t1 / t0) ** (1.0 / max(steps - 1, 1))
-
-    def sweep(carry, i):
-        states, keys = carry
-        temp = t0 * decay ** i.astype(jnp.float32)
-        keys = jax.vmap(lambda k: jax.random.fold_in(k, i))(keys)
-        states, _acc = jax.vmap(
-            lambda st, k: _batched_step(prob, st, k, temp, M))(states, keys)
-        return (states, keys), None
-
-    (states, _), _ = jax.lax.scan(sweep, (states, keys),
-                                  jnp.arange(steps, dtype=jnp.int32),
-                                  unroll=unroll)
-    return states
-
-
-def anneal(prob: DeviceProblem, init_assignments: jax.Array, key: jax.Array,
-           steps: int = 2000, t0: float = 1.0, t1: float = 1e-3,
-           proposals_per_step: int | None = None,
-           unroll: int = 1) -> jax.Array:
-    """Fixed-budget anneal; returns refined assignments (C, S)."""
-    return anneal_states(prob, init_assignments, key, steps=steps, t0=t0,
-                         t1=t1, proposals_per_step=proposals_per_step,
-                         unroll=unroll).assignment
-
-
 @partial(jax.jit, static_argnames=("max_steps", "block",
                                    "proposals_per_step",
                                    "exit_on_feasible_init", "trace_blocks"))
@@ -690,6 +627,15 @@ def anneal_adaptive_states(prob: DeviceProblem, init_assignments: jax.Array,
     Metropolis moves per chain across every sweep that ran — the
     acceptance telemetry that surfaces through SolveResult and the
     fleet_solver_* metrics.
+
+    Each sweep evaluates `proposals_per_step` moves per chain in parallel
+    (one device dispatch), so total proposals = sweeps x M x C while the
+    sequential depth stays the sweep count — the shape that keeps a TPU
+    fed, vs the classic one-move-per-step SA whose wall-clock is all
+    dispatch latency. Temperature decays geometrically t0 → t1 (in units
+    of soft score; hard violation weights are orders of magnitude above
+    t0, so hard-violating moves are only ever accepted to escape an
+    existing violation).
 
     `trace_blocks` > 0 (static — see solve_trace_blocks) additionally
     carries a fixed-shape (trace_blocks, len(TRACE_COLS)) f32 buffer
@@ -855,16 +801,3 @@ def anneal_adaptive_states(prob: DeviceProblem, init_assignments: jax.Array,
         "init_soft": best_soft_of(viol0, soft0),
     }
     return best_assign, best_viol, best_soft, b * block, accepted, telemetry
-
-
-def anneal_adaptive(prob: DeviceProblem, init_assignments: jax.Array,
-                    key: jax.Array, max_steps: int = 128, block: int = 32,
-                    t0: float = 1.0, t1: float = 1e-3,
-                    proposals_per_step: int | None = None):
-    """Adaptive anneal; returns (assignments (C, S), sweeps_run,
-    accepted (C,))."""
-    best_assign, _viol, _soft, sweeps, accepted, _telem = \
-        anneal_adaptive_states(
-            prob, init_assignments, key, max_steps=max_steps, block=block,
-            t0=t0, t1=t1, proposals_per_step=proposals_per_step)
-    return best_assign, sweeps, accepted

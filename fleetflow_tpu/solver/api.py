@@ -25,10 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .anneal import (TRACE_COLS, anneal_adaptive_states, anneal_states,
-                     chain_states_from_assignment, empty_trace,
-                     prerepair_state_counted, solve_trace_blocks,
-                     state_soft_score, state_violation_stats)
+from .anneal import (TRACE_COLS, anneal_adaptive_states,
+                     chain_states_from_assignment, prerepair_state_counted,
+                     solve_trace_blocks)
 from .buckets import (bucket_config, pad_assignment, pad_problem_tiers,
                       record_bucket, soft_score_host, stage_problem_tiers,
                       _env_flag)
@@ -127,9 +126,9 @@ class SolveResult:
     # the proposal width the anneal actually ran (after backend defaults),
     # so artifacts report the config that produced the number
     proposals_per_step: int = 0
-    # Metropolis moves applied across all chains (adaptive path only;
-    # -1 = not tracked on the fixed-budget path). With sweeps/chains/
-    # proposals_per_step this yields the acceptance rate the anneal ran at.
+    # Metropolis moves applied across all chains (-1 = not counted: the
+    # mesh-sharded path). With sweeps/chains/proposals_per_step this
+    # yields the acceptance rate the anneal ran at.
     accepted_moves: int = -1
     # shape bucketing applied to this solve (solver/buckets.py), or None
     # for an exact-shape solve: {"orig_S", "padded_S", "pad_waste", "hit"}
@@ -152,7 +151,7 @@ class SolveResult:
     # sweep-block, "init": {violations, soft} of the prologue/seed,
     # "prerepair_moves": fused-prologue relocations, "exit_sweep",
     # "path": "full" | "subsolve"}. None when the dispatch ran with
-    # FLEET_SOLVE_TRACE_BLOCKS=0 or on the fixed-budget path.
+    # FLEET_SOLVE_TRACE_BLOCKS=0.
     telemetry: Optional[dict] = None
 
     @property
@@ -185,15 +184,14 @@ def make_chain_inits(prob: DeviceProblem, seed_assignment: jax.Array,
     return inits.at[0].set(seed_assignment)
 
 
-@partial(jax.jit, static_argnames=("chains", "steps", "warm", "adaptive",
+@partial(jax.jit, static_argnames=("chains", "steps", "warm",
                                    "anneal_block", "proposals_per_step",
                                    "sharding", "fused_prerepair",
                                    "prerepair_moves",
                                    "skip_feasible_polish", "trace_blocks"))
 def _refine(prob: DeviceProblem, seed_assignment: jax.Array, key: jax.Array,
             t0: float, t1: float, migration_weight: float, *,
-            chains: int, steps: int, warm: bool, adaptive: bool = False,
-            anneal_block: int = 8,
+            chains: int, steps: int, warm: bool, anneal_block: int = 8,
             proposals_per_step: Optional[int] = None,
             sharding=None, fused_prerepair: bool = False,
             prerepair_moves: int = 0, skip_feasible_polish: bool = False,
@@ -258,47 +256,27 @@ def _refine(prob: DeviceProblem, seed_assignment: jax.Array, key: jax.Array,
                                  perturb_frac=0.0 if warm else 0.08)
         if sharding is not None:
             inits = jax.lax.with_sharding_constraint(inits, sharding)
-        if adaptive:
-            # the adaptive anneal tracks each chain's best-ever state with its
-            # (violations, soft) as SEPARATE scalars; chain ranking is
-            # feasibility-first — a folded W_HARD*v+soft argmin would both
-            # prefer an infeasible chain whose warm-bonused soft undercuts
-            # W_HARD (aggregate bonus gap is unbounded in the fleet size) AND
-            # round the soft tie-break away in float32 at large v
-            (best_assign_c, best_viol_c, best_soft_c, sweeps_run, accepted_c,
-             telem) = anneal_adaptive_states(
-                    prob_a, inits, k_anneal, max_steps=steps, block=anneal_block,
-                    t0=t0, t1=t1,
-                    proposals_per_step=proposals_per_step,
-                    init_states=init_states,
-                    exit_on_feasible_init=skip_feasible_polish,
-                    trace_blocks=trace_blocks)
-            accepted = accepted_c.sum()
-            # exact lexicographic (violations, soft): among minimal-violation
-            # chains (0 when any chain saw feasibility), best soft wins
-            min_viol = best_viol_c.min()
-            best = jnp.argmin(jnp.where(best_viol_c == min_viol,
-                                        best_soft_c, jnp.inf))
-            winner = best_assign_c[best]
-        else:
-            states = anneal_states(prob_a, inits, k_anneal, steps=steps,
-                                   t0=t0, t1=t1,
-                                   proposals_per_step=proposals_per_step)
-            sweeps_run = jnp.int32(steps)
-            accepted = jnp.int32(-1)   # fixed-budget path does not track it
-            telem = empty_trace(trace_blocks)   # same treedef as adaptive
-            # rank from the CARRIED states: same exact numbers as the
-            # kernels.* functions, but elementwise reduces instead of (N, G)
-            # scatter rebuilds (~18 ms saved per evaluation at 10k x 1k)
-            viol = jax.vmap(
-                lambda st: state_violation_stats(prob_a, st)["total"])(states)
-            soft_rank = jax.vmap(
-                lambda st: state_soft_score(prob_a, st))(states)
-            # same two-stage lexicographic rank as the adaptive path (a folded
-            # W_HARD*viol+soft would drop the soft term in float32 at large v)
-            mv = viol.min()
-            winner = states.assignment[
-                jnp.argmin(jnp.where(viol == mv, soft_rank, jnp.inf))]
+        # the anneal tracks each chain's best-ever state with its
+        # (violations, soft) as SEPARATE scalars; chain ranking is
+        # feasibility-first — a folded W_HARD*v+soft argmin would both
+        # prefer an infeasible chain whose warm-bonused soft undercuts
+        # W_HARD (aggregate bonus gap is unbounded in the fleet size) AND
+        # round the soft tie-break away in float32 at large v
+        (best_assign_c, best_viol_c, best_soft_c, sweeps_run, accepted_c,
+         telem) = anneal_adaptive_states(
+                prob_a, inits, k_anneal, max_steps=steps, block=anneal_block,
+                t0=t0, t1=t1,
+                proposals_per_step=proposals_per_step,
+                init_states=init_states,
+                exit_on_feasible_init=skip_feasible_polish,
+                trace_blocks=trace_blocks)
+        accepted = accepted_c.sum()
+        # exact lexicographic (violations, soft): among minimal-violation
+        # chains (0 when any chain saw feasibility), best soft wins
+        min_viol = best_viol_c.min()
+        best = jnp.argmin(jnp.where(best_viol_c == min_viol,
+                                    best_soft_c, jnp.inf))
+        winner = best_assign_c[best]
     # The WINNER's stats are recomputed with the exact from-scratch kernels
     # (one scatter rebuild, ~5 ms): the carried float32 load accumulates
     # .add(+d)/.add(-d) round-off over thousands of proposals, and the
@@ -314,7 +292,7 @@ def _refine(prob: DeviceProblem, seed_assignment: jax.Array, key: jax.Array,
     # was scratch-built by the same prologue: trust them and skip the
     # final rebuild (~12 ms of the remaining warm CPU floor at 10k x 1k).
     with jax.named_scope(scope + "/polish"):
-        if adaptive and skip_feasible_polish:
+        if skip_feasible_polish:
             best_viol = best_viol_c[best]
             trust = (sweeps_run == 0) & (best_viol == 0)
             zero = jnp.float32(0)
@@ -372,7 +350,6 @@ def _solve(pt: ProblemTensors, *,
            seed_impl: Optional[str] = None,
            seed_batch: int = 256,
            seed_rounds: int = 2,
-           adaptive: bool = True,
            anneal_block: int = 1,
            warm_block: int = 1,
            proposals_per_step: Optional[int] = None,
@@ -617,7 +594,7 @@ def _solve(pt: ProblemTensors, *,
         # gathered rows instead of the full problem; the exact full-problem
         # gate below decides whether the localized result commits
         sub_plan = None
-        if resident_warm and adaptive and mesh is None:
+        if resident_warm and mesh is None:
             sub_plan = resident.take_active_plan()
         if binfo is not None:
             # hit = this process already ran the fused pipeline at these
@@ -626,10 +603,9 @@ def _solve(pt: ProblemTensors, *,
                 (prob.S, prob.N, prob.G, prob.Gc, prob.T, prob.strategy,
                  prob.max_skew, prob.conflict_ids.shape[1],
                  prob.coloc_ids.shape[1], chains, steps,
-                 bool(warm and migration_weight > 0), adaptive,
+                 bool(warm and migration_weight > 0),
                  min(warm_block, anneal_block) if warm else anneal_block,
-                 proposals_per_step, prerepair_moves,
-                 bool(resident_warm and adaptive),
+                 proposals_per_step, prerepair_moves, resident_warm,
                  prob.n_real is not None, trace_blocks,
                  # plane layout is part of the executable identity: a packed
                  # and a dense staging (or absent vs present preference) are
@@ -651,7 +627,7 @@ def _solve(pt: ProblemTensors, *,
             t0_d, t1_d, mw_d = t0, t1, migration_weight
         refine_kw = dict(
             chains=chains, steps=steps,
-            warm=bool(warm and migration_weight > 0), adaptive=adaptive,
+            warm=bool(warm and migration_weight > 0),
             anneal_block=min(warm_block, anneal_block) if warm else anneal_block,
             proposals_per_step=proposals_per_step, sharding=sharding,
             fused_prerepair=warm, prerepair_moves=prerepair_moves,
@@ -659,7 +635,7 @@ def _solve(pt: ProblemTensors, *,
             # fused prologue already landed feasible: stickiness rejects
             # nearly all polish moves, so the sweep bought latency only. The
             # host warm path keeps its 1-block polish.
-            skip_feasible_polish=bool(resident_warm and adaptive),
+            skip_feasible_polish=resident_warm,
             trace_blocks=trace_blocks)
         cache_before = _refine._cache_size()
         sub_info = None
@@ -809,10 +785,8 @@ def _solve(pt: ProblemTensors, *,
     timings["verify_repair_ms"] = ph_verify.ms
     timings["total_ms"] = (ph_verify.t1 - ph_stage.t0) * 1e3
     # -- flight-deck payload (docs/guide/10, "solver flight deck") ---------
-    # accepted >= 0 distinguishes the adaptive dispatch (which carried a
-    # real buffer) from the fixed-budget path's zero-filled treedef twin
     telemetry = None
-    if trace_blocks > 0 and accepted >= 0:
+    if trace_blocks > 0:
         filled = int(htelem["filled"])
         rows = np.asarray(htelem["blocks"])[:filled]
         telemetry = {
@@ -837,8 +811,7 @@ def _solve(pt: ProblemTensors, *,
                   warm="true" if warm else "false")
     _M_SOLVE_S.observe(timings["total_ms"] / 1e3)
     _M_SWEEPS.inc(int(sweeps_run))
-    if accepted >= 0:
-        _M_ACCEPTED.inc(accepted)
+    _M_ACCEPTED.inc(accepted)
     if compile_events > 0:
         _M_COMPILES.inc(compile_events)
     _M_VIOL.set(int(stats["total"]))
@@ -854,7 +827,7 @@ def _solve(pt: ProblemTensors, *,
     log.info("solve %s", kv(
         S=pt.S, N=prob.N, chains=chains, steps=steps,
         sweeps=int(sweeps_run),
-        accepted=accepted if accepted >= 0 else None,
+        accepted=accepted,
         compiles=compile_events or None,
         bucket=prob.S if bucketed else None,
         bucket_hit=(binfo.hit or None) if binfo is not None else None,

@@ -208,66 +208,15 @@ def _refine_cases() -> list[KernelCase]:
         out.append(KernelCase(
             tier=f"{prob.S}x{N}", fn=_refine,
             args=(prob, rp.assignment, key, t0_d, t1_d, mw_d),
-            kwargs=dict(chains=1, steps=16, warm=True, adaptive=True,
-                        anneal_block=1, proposals_per_step=proposals,
-                        sharding=None, fused_prerepair=True,
+            kwargs=dict(chains=1, steps=16, warm=True, anneal_block=1,
+                        proposals_per_step=proposals, sharding=None,
+                        fused_prerepair=True,
                         prerepair_moves=max(16, min(prob.S, 256)),
                         skip_feasible_polish=True,
                         # the flight-deck buffer length IS a static of
                         # the warm executable (ISSUE 15): auditing with
                         # it pins that telemetry stays compiled-in —
                         # zero extra dispatches, no donation drift
-                        trace_blocks=solve_trace_blocks()),
-            arg_names=_REFINE_ARG_NAMES,
-            out_shardings=None))
-    return out
-
-
-def _mux_refine_cases() -> list[KernelCase]:
-    """multiplex._mux_refine — the batched (vmapped) warm pipeline — at
-    K=2 stacked lanes per audit tier, statics derived exactly as
-    multiplex._solve_batch derives them. The leading lane axis is a
-    recompile axis by design (bucketed on the mux_k ladder); the
-    contract pins that the batched executable keeps the serial warm
-    path's structure: no donation, no host callbacks, packed planes."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..lower import synthetic_problem
-    from .anneal import backend_proposals_per_step, solve_trace_blocks
-    from .multiplex import stack_problems
-    from .multiplex import _mux_refine
-    from .resident import ResidentProblem
-
-    K = 2
-    out = []
-    for S, N in AUDIT_TIERS:
-        lanes = []
-        for lane in range(K):
-            pt = synthetic_problem(S, N, seed=lane, port_fraction=0.3,
-                                   volume_fraction=0.2)
-            rp = ResidentProblem(pt)
-            rp.adopt_host(np.zeros(pt.S, np.int32), pt.node_valid,
-                          warm=False)
-            lanes.append(rp)
-        prob = lanes[0].prob
-        stacked = stack_problems([rp.prob for rp in lanes])
-        seeds = jnp.stack([rp.assignment for rp in lanes])
-        keys = jnp.stack([jax.random.PRNGKey(i) for i in range(K)])
-        scal = [rp.warm_scalars(0.1, 1e-3, 0.5) for rp in lanes]
-        t0v = jnp.stack([s[0] for s in scal])
-        t1v = jnp.stack([s[1] for s in scal])
-        mwv = jnp.stack([s[2] for s in scal])
-        out.append(KernelCase(
-            tier=f"{prob.S}x{N}:k{K}", fn=_mux_refine,
-            args=(stacked, seeds, keys, t0v, t1v, mwv),
-            kwargs=dict(chains=1, steps=16, warm=True, adaptive=True,
-                        anneal_block=1,
-                        proposals_per_step=backend_proposals_per_step(
-                            prob.S),
-                        fused_prerepair=True,
-                        prerepair_moves=max(16, min(prob.S, 256)),
-                        skip_feasible_polish=True,
                         trace_blocks=solve_trace_blocks()),
             arg_names=_REFINE_ARG_NAMES,
             out_shardings=None))
@@ -363,9 +312,8 @@ def _anneal_sharded_cases() -> list[KernelCase]:
             tier=f"{rp.prob.S}x{N}", fn=anneal_sharded,
             args=(rp.prob, rp.assignment, key),
             kwargs=dict(steps=16, t0=t0_d, t1=t1_d,
-                        proposals_per_step=None, mesh=mesh, adaptive=True,
-                        block=8, ladder=lad_d, exchange_every=1,
-                        return_stats=True,
+                        proposals_per_step=None, mesh=mesh, block=8,
+                        ladder=lad_d, exchange_every=1, return_stats=True,
                         trace_blocks=solve_trace_blocks()),
             arg_names=_ANNEAL_SHARDED_ARG_NAMES,
             out_shardings=decl))
@@ -388,14 +336,6 @@ def hot_path_kernels() -> list[KernelContract]:
             module="fleetflow_tpu.solver.api",
             qualname="_refine",
             cases=_refine_cases),
-        KernelContract(
-            name="mux.anneal",
-            module="fleetflow_tpu.solver.multiplex",
-            qualname="_mux_refine",
-            # like refine.warm, donation-free by design: every lane's
-            # resident seed must outlive the dispatch (it re-seeds the
-            # serial path if the batch's exact gate rejects a lane)
-            cases=_mux_refine_cases),
         KernelContract(
             name="subsolve.localized",
             module="fleetflow_tpu.solver.subsolve",

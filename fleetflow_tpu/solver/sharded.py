@@ -91,7 +91,7 @@ SCOPES = ("propose",    # draw M moves a shard, price and accept them
           "reduce",     # the applied deltas summed over the svc axis (psum)
           "score",      # violations + soft of the state, best-ever kept
           "exchange",   # replica lanes trade whole states (ppermute)
-          "exit")       # the adaptive exit's predicate, pmin over lanes
+          "exit")       # the early exit's predicate, pmin over lanes
 
 # temperature ratio between neighboring tempering lanes: best of {1.3, 1.6,
 # 2.0, 3.0} on the partitioned-seed curve
@@ -164,8 +164,7 @@ class ShardedStats(NamedTuple):
     swap_accepts: jax.Array     # i32, accepted exchanges
     # flight-deck rows, (trace_blocks, len(SHARDED_TRACE_COLS)) f32,
     # replicated (every column is psum/pmin-derived, so the buffer is
-    # identical on every device) — zero-length when trace_blocks=0 and
-    # zero-FILLED on the fixed scan path (no block loop to observe)
+    # identical on every device) — zero-length when trace_blocks=0
     telemetry: jax.Array
 
     @property
@@ -258,15 +257,14 @@ def per_device_bytes(prob: DeviceProblem, *,
 
 
 @partial(jax.jit, static_argnames=("steps", "proposals_per_step", "mesh",
-                                   "adaptive", "block", "exchange_every",
+                                   "block", "exchange_every",
                                    "return_sweeps", "return_stats",
                                    "trace_blocks"))
 def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
                    key: jax.Array, steps: int = 64,
                    t0: float = 1.0, t1: float = 1e-3,
                    proposals_per_step: Optional[int] = None,
-                   *, mesh: Mesh, adaptive: bool = False,
-                   block: int = 16,
+                   *, mesh: Mesh, block: int = 16,
                    n_real=None,
                    ladder: float = 1.3,
                    exchange_every: int = 1,
@@ -279,8 +277,8 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
     Returns the refined (S,) assignment. S must be divisible by the mesh
     size (pad_problem handles ragged S).  `return_sweeps=True` returns
     (assignment, sweeps_run) instead — sweeps_run is the sweep count the
-    adaptive early exit actually executed (== steps when adaptive=False),
-    so artifacts can report effort, not just latency (VERDICT r4 weak #3).
+    early exit actually executed, so artifacts can report effort, not
+    just latency (VERDICT r4 weak #3).
     `return_stats=True` returns a ShardedStats carrying exact device-side
     violation parts + soft of the winner (recomputed from a scratch state
     rebuild, the same float-drift discipline as api._refine) and the
@@ -288,15 +286,15 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
 
     The returned assignment is the lexicographically best (violations,
     soft) state EVER VISITED, not the final Metropolis state (r5, same
-    monotonicity contract as anneal.anneal_adaptive): each sweep scores
+    monotonicity contract as anneal.anneal_adaptive_states): each sweep scores
     the replicated state — capacity/conflict/skew violations and the
     strategy/coloc soft terms are local math on the replicated node
     state; the eligibility count and the two service-axis soft terms add
     two scalar psums per sweep, noise next to the sweep's four (N,·)
-    state-delta psums. `adaptive=True` additionally runs in `block`-sweep
-    chunks inside a lax.while_loop and exits at the first block boundary
-    after any sweep visited a feasible state (any *replica* on a tempered
-    mesh — the exit predicate is pmin'd across lanes so it stays uniform).
+    state-delta psums. The sweeps run in `block`-sweep chunks inside a
+    lax.while_loop that exits at the first block boundary after any sweep
+    visited a feasible state (any *replica* on a tempered mesh — the exit
+    predicate is pmin'd across lanes so it stays uniform).
 
     `n_real` (TRACED — tier drift inside a shape bucket must not
     recompile, the same contract the resident path holds on one chip)
@@ -635,16 +633,7 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
                 att.astype(jnp.float32), acc.astype(jnp.float32)])
             return telem.at[b].set(row, mode="drop")
 
-        if not has_rep and not adaptive:
-            # fixed scan path: no block loop to observe — the buffer
-            # returns zero-filled (filled = 0 by the sweeps/block math)
-            (_a, _l, _u, _c, _t, _k, best_assign, best_viol, best_soft), _ \
-                = jax.lax.scan(sweep, carry0,
-                               jnp.arange(steps, dtype=jnp.int32))
-            sweeps_run = jnp.int32(steps)
-            att = acc = zero_i
-            telem = telem0
-        elif not has_rep:
+        if not has_rep:
             def cond(carry):
                 *_rest, b, done = carry
                 return (~done) & (b < n_blocks)
@@ -674,8 +663,7 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
             att = acc = zero_i
         else:
             # tempered mesh: block loop + replica exchange at boundaries.
-            # adaptive=False runs every block (the quality-curve config);
-            # the exit predicate is pmin'd across lanes so every device
+            # The exit predicate is pmin'd across lanes so every device
             # takes the same branch (a lane-local exit would deadlock the
             # collectives).
             def cond(carry):
@@ -722,7 +710,7 @@ def anneal_sharded(prob: DeviceProblem, init_assignment: jax.Array,
                     telem, b,
                     jnp.minimum((b + 1) * block, steps).astype(jnp.float32),
                     g_viol, g_soft, att, acc)
-                done = (g_viol == 0) if adaptive else jnp.bool_(False)
+                done = g_viol == 0
                 return (assign, load, used, coloc, topo, key, best_assign,
                         best_viol, best_soft, att, acc, telem, b + 1, done)
 
@@ -960,7 +948,7 @@ def solve_sharded(pt, *, resident: ShardedResident,
                   init_assignment=None,
                   steps: int = SHARDED_STEPS, seed: int = 0,
                   t0: float = 1.0, t1: float = 1e-3,
-                  adaptive: bool = True, block: int = 8,
+                  block: int = 8,
                   proposals_per_step: Optional[int] = None,
                   ladder: float = TEMPER_LADDER,
                   exchange_every: int = TEMPER_EXCHANGE,
@@ -1028,7 +1016,7 @@ def solve_sharded(pt, *, resident: ShardedResident,
             res = anneal_sharded(
                 prob, seed_assignment, key, steps=steps, t0=t0_d, t1=t1_d,
                 proposals_per_step=proposals_per_step, mesh=mesh,
-                adaptive=adaptive, block=block, ladder=lad_d,
+                block=block, ladder=lad_d,
                 exchange_every=exchange_every, return_stats=True,
                 trace_blocks=trace_blocks)
         compile_events = anneal_sharded._cache_size() - cache_before
@@ -1101,9 +1089,6 @@ def solve_sharded(pt, *, resident: ShardedResident,
         filled = min(-(-int(sweeps) // block) if block else 0,
                      trace_blocks)
         rows = np.asarray(htelem)[:filled]
-        # a written row always has temperature > 0; all-zero rows are
-        # the fixed scan path's unobserved buffer — drop, don't invent
-        rows = rows[~np.all(rows == 0, axis=1)]
         telemetry = {
             "schema": list(SHARDED_TRACE_COLS),
             "blocks": [[round(float(x), 6) for x in row] for row in rows],
@@ -1166,7 +1151,7 @@ def sharded_route(pt) -> Optional[Mesh]:
 # solve() kwargs the sharded path speaks; anything else pins the call to
 # the single-chip pipeline (an explicit chains= or seed_impl, a custom
 # mesh, ...) — a knob this path would silently drop must not route
-_ROUTED_KW = {"steps", "seed", "init_assignment", "t0", "t1", "adaptive",
+_ROUTED_KW = {"steps", "seed", "init_assignment", "t0", "t1",
               "do_repair", "overlap_host_work",
               "prob", "resident", "mesh"}
 
@@ -1192,6 +1177,5 @@ def maybe_solve_sharded(pt, **kw):
         seed=kw.get("seed", 0),
         init_assignment=kw.get("init_assignment"),
         t0=kw.get("t0", 1.0), t1=kw.get("t1", 1e-3),
-        adaptive=kw.get("adaptive", True),
         do_repair=kw.get("do_repair", True),
         overlap_host_work=kw.get("overlap_host_work"))

@@ -48,7 +48,6 @@ def registered() -> set[str]:
     import fleetflow_tpu.platform           # noqa: F401 (compile-cache gauge)
     import fleetflow_tpu.registry.aggregate  # noqa: F401
     import fleetflow_tpu.solver.api         # noqa: F401
-    import fleetflow_tpu.solver.multiplex   # noqa: F401 (mux batch families)
     import fleetflow_tpu.solver.sharded     # noqa: F401
     import fleetflow_tpu.solver.subsolve    # noqa: F401
     from fleetflow_tpu.obs.metrics import REGISTRY

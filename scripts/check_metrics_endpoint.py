@@ -68,7 +68,6 @@ def scrape() -> str:
     import fleetflow_tpu.cloud.provider   # noqa: F401  (degraded alarm)
     import fleetflow_tpu.cp.autoscaler    # noqa: F401  (pressure gauge)
     import fleetflow_tpu.solver.api       # noqa: F401
-    import fleetflow_tpu.solver.multiplex  # noqa: F401  (mux batch families)
     import fleetflow_tpu.solver.sharded   # noqa: F401  (pod-scale families)
     from fleetflow_tpu.cp.server import ServerConfig, start
     from fleetflow_tpu.daemon.web import WebServer

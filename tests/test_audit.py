@@ -274,7 +274,7 @@ class TestJitSpec:
         ("solver/resident.py", "_merge_fn.merge",
          ["has_demand", "has_eligible"]),
         ("solver/sharded.py", "anneal_sharded",
-         ["adaptive", "block", "exchange_every", "mesh",
+         ["block", "exchange_every", "mesh",
           "proposals_per_step", "return_stats", "return_sweeps",
           "steps", "trace_blocks"]),
     ])

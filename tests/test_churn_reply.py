@@ -355,7 +355,7 @@ def test_named_scopes_are_in_the_lowered_anneal():
     rp = sharded.ShardedResident(synthetic_problem(40, 8, seed=3), mesh=mesh)
     text = sharded.anneal_sharded.lower(
         rp.prob, jax.numpy.zeros((rp.prob.S,), jax.numpy.int32),
-        jax.random.PRNGKey(0), steps=8, mesh=mesh, adaptive=True, block=4,
+        jax.random.PRNGKey(0), steps=8, mesh=mesh, block=4,
         return_stats=True).as_text(debug_info=True)
     for part in sharded.SCOPES:
         assert sharded.SCOPE + part in text, part

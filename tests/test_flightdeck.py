@@ -15,8 +15,10 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 from fleetflow_tpu.lower import synthetic_problem
+from fleetflow_tpu.obs.metrics import REGISTRY
 from fleetflow_tpu.solver import solve
 from fleetflow_tpu.solver.anneal import TRACE_COLS, solve_trace_blocks
 from fleetflow_tpu.solver.api import _refine, _solve
@@ -124,10 +126,10 @@ class TestTelemetryParity:
 
 
 class TestTelemetryPayload:
-    def test_cold_adaptive_payload_shape(self):
+    def test_cold_payload_shape(self):
         pt = synthetic_problem(60, 12, seed=0, port_fraction=0.3,
                                volume_fraction=0.2)
-        res = solve(pt, steps=16, adaptive=True)
+        res = solve(pt, steps=16)
         t = res.telemetry
         assert t is not None
         assert t["schema"] == list(TRACE_COLS)
@@ -145,10 +147,56 @@ class TestTelemetryPayload:
             assert sweeps == sorted(sweeps)
             assert sweeps[-1] >= res.steps
 
-    def test_fixed_budget_path_has_no_telemetry(self):
-        pt = synthetic_problem(60, 12, seed=1, port_fraction=0.3)
-        res = solve(pt, steps=8, adaptive=False)
-        assert res.telemetry is None
+    @pytest.mark.parametrize("route", ["cold", "host-warm",
+                                       "resident-fused",
+                                       "resident-localized"])
+    def test_every_route_counts_acceptance_and_names_its_path(
+            self, route, monkeypatch):
+        """Every single-chip route runs the one anneal loop: it counts
+        the moves it accepted (never -1), the counter moves by exactly
+        that count, and the payload names the dispatch that ran."""
+        kw = dict(steps=32, anneal_block=1, warm_block=1, chains=1)
+        pt = synthetic_problem(140, 14, seed=0, port_fraction=0.25,
+                               volume_fraction=0.15)
+        if route == "resident-localized":
+            monkeypatch.setenv("FLEET_SUBSOLVE_MIN", "16")
+            monkeypatch.setenv("FLEET_SUBSOLVE_FRAC", "0.6")
+        else:
+            monkeypatch.setenv("FLEET_SUBSOLVE", "0")
+        rp = ResidentProblem(pt) if route.startswith("resident") else None
+        cold = _solve(pt, prob=rp.prob if rp else None, resident=rp,
+                      seed=0, bucket=True, **kw)
+        if route == "cold":
+            res, accepted_before = cold, None
+        else:
+            # kill the busiest node: real churn for the warm sweeps
+            loads = np.bincount(cold.assignment, minlength=pt.N)
+            valid = pt.node_valid.copy()
+            valid[int(loads.argmax())] = False
+            cur = dataclasses.replace(pt, node_valid=valid)
+            accepted_before = REGISTRY.get(
+                "fleet_solver_proposals_accepted_total").value()
+            if rp is None:
+                res = _solve(cur, init_assignment=cold.assignment, seed=1,
+                             bucket=True, **kw)
+            else:
+                rp.apply_delta(cur, ProblemDelta(node_valid=valid))
+                res = _solve(cur, prob=rp.prob, resident=rp,
+                             resident_warm=True, seed=1, bucket=True, **kw)
+        assert res.accepted_moves >= 0
+        if res.steps == 0:
+            assert res.accepted_moves == 0
+        if accepted_before is not None:
+            assert (REGISTRY.get("fleet_solver_proposals_accepted_total")
+                    .value() == accepted_before + res.accepted_moves)
+        t = res.telemetry
+        assert t is not None and t["exit_sweep"] == res.steps
+        if route == "resident-localized":
+            assert res.subsolve["outcome"] == "localized"
+            assert t["path"] == "subsolve"
+        else:
+            assert res.subsolve is None
+            assert t["path"] == "full"
 
     def test_zero_sweep_exit_keeps_init_story(self, monkeypatch):
         """A 0-sweep feasible-prologue exit has no block rows — the
@@ -178,7 +226,7 @@ class TestFlightRecorderIntegration:
         path = tmp_path / "flight.jsonl"
         monkeypatch.setenv("FLEET_TRACE_FILE", str(path))
         pt = synthetic_problem(60, 12, seed=2, port_fraction=0.3)
-        solve(pt, steps=16, adaptive=True)
+        solve(pt, steps=16)
         from fleetflow_tpu.obs.trace import read_trace_file
         events = [e for e in read_trace_file(str(path))
                   if e.get("kind") == "telemetry"
@@ -195,8 +243,8 @@ class TestFlightRecorderIntegration:
         path = tmp_path / "flight.jsonl"
         monkeypatch.setenv("FLEET_TRACE_FILE", str(path))
         pt = synthetic_problem(60, 12, seed=2, port_fraction=0.3)
-        solve(pt, steps=16, adaptive=True)
-        solve(pt, steps=16, adaptive=True, seed=9)
+        solve(pt, steps=16)
+        solve(pt, steps=16, seed=9)
         from fleetflow_tpu.cli.main import main
         assert main(["solve", "trace", "--last", "1"]) == 0
         out = capsys.readouterr().out

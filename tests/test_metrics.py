@@ -30,7 +30,6 @@ import fleetflow_tpu.agent.monitor    # noqa: F401
 import fleetflow_tpu.chaos.simulate   # noqa: F401  (plan-simulate families)
 import fleetflow_tpu.chaos.worldgen   # noqa: F401  (world families)
 import fleetflow_tpu.solver.api       # noqa: F401
-import fleetflow_tpu.solver.multiplex  # noqa: F401  (mux batch families)
 import fleetflow_tpu.solver.sharded   # noqa: F401  (pod-scale families)
 from fleetflow_tpu.agent import Agent, AgentConfig
 from fleetflow_tpu.core.loader import load_project_from_root_with_stage
@@ -430,7 +429,7 @@ class TestTraceCorrelation:
 
 
 # --------------------------------------------------------------------------
-# solver acceptance stats (surfaced from anneal_adaptive)
+# solver acceptance stats (surfaced from anneal_adaptive_states)
 # --------------------------------------------------------------------------
 
 class TestSolverMetrics:
@@ -443,7 +442,7 @@ class TestSolverMetrics:
         pt = synthetic_problem(16, 4, seed=0)
         res = solve(pt, chains=2, steps=8)
         assert res.feasible
-        assert res.accepted_moves >= 0         # adaptive path tracks it
+        assert res.accepted_moves >= 0
         assert 0.0 <= res.acceptance_rate <= 1.0
         assert (REGISTRY.get("fleet_solver_sweeps_total").value()
                 == sweeps_before + res.steps)
@@ -452,10 +451,18 @@ class TestSolverMetrics:
         assert math.isfinite(
             REGISTRY.get("fleet_solver_violations").value())
 
-    def test_fixed_budget_path_reports_unknown_acceptance(self):
+    def test_mesh_path_reports_acceptance_not_counted(self, monkeypatch):
+        """The sharded anneal does not count accepted moves: its result
+        says so with -1, and the acceptance counter does not move."""
         from fleetflow_tpu.lower import synthetic_problem
         from fleetflow_tpu.solver import solve
-        pt = synthetic_problem(12, 3, seed=1)
-        res = solve(pt, chains=1, steps=4, adaptive=False)
+        monkeypatch.setenv("FLEET_SHARDED", "1")
+        accepted_before = REGISTRY.get(
+            "fleet_solver_proposals_accepted_total").value()
+        pt = synthetic_problem(32, 4, seed=1)
+        res = solve(pt, steps=8)
+        assert res.tempering is not None          # the mesh path ran
         assert res.accepted_moves == -1
         assert res.acceptance_rate == -1.0
+        assert (REGISTRY.get("fleet_solver_proposals_accepted_total").value()
+                == accepted_before)

@@ -65,7 +65,8 @@ pt = synthetic_problem(32, 8, seed=5)
 prob = prepare_problem(pt)
 svc_mesh = Mesh(np.array(jax.devices()), (SVC_AXIS,))
 refined = anneal_sharded(prob, jnp.zeros((pt.S,), jnp.int32),
-                         jax.random.PRNGKey(0), steps=200, mesh=svc_mesh)
+                         jax.random.PRNGKey(0), steps=200, mesh=svc_mesh,
+                         block=200)
 # gather the sharded result to every host for the exact check
 from jax.experimental import multihost_utils
 host_assign = np.asarray(

@@ -301,23 +301,25 @@ class TestScheduleKeptWithTheStage:
             assert p.levels == _ref_level_schedule(pts[k])
         assert _since(before) == {"built": 3, "kept": 0}
 
-    def test_place_many_keeps_each_stages_own(self, finalize_phases):
+    def test_stages_in_turn_keep_each_stages_own(self, finalize_phases):
         pts = {k: synthetic_problem(40, 6, seed=30 + i)
                for i, k in enumerate("AB")}
         sched = TpuSolverScheduler(chains=1, steps=64)
-        reqs = [{"pt": pts[k], "stage": k, "warm_start": True} for k in "AB"]
         before = _schedules()
-        sched.place_many(reqs)
-        again = sched.place_many(
-            [{**r, "pt": _more_capacity(r["pt"])} for r in reqs])
+        for k in "AB":
+            sched.reschedule(pts[k], stage=k)
+        again = [sched.reschedule(_more_capacity(pts[k]), stage=k)
+                 for k in "AB"]
         assert _since(before) == {"built": 2, "kept": 2}
         assert [ph.fields["levels"] for ph in finalize_phases] == [
-            "built", "kept"]
+            "built", "built", "kept", "kept"]
         for k, p in zip("AB", again):
             assert p.levels == _ref_level_schedule(pts[k])
             assert p.assignment == _ref_assignment(pts[k], p.raw)
-        # one stage's graph changes: the phase says a schedule was built
-        reqs[1] = {**reqs[1], "pt": replace(
-            pts["B"], dep_depth=pts["B"].dep_depth.copy())}
-        sched.place_many(reqs)
-        assert finalize_phases[-1].fields["levels"] == "built"
+        # one stage's graph changes: its phase says a schedule was built,
+        # the other stage's is still kept
+        sched.reschedule(replace(
+            pts["B"], dep_depth=pts["B"].dep_depth.copy()), stage="B")
+        sched.reschedule(_more_capacity(pts["A"]), stage="A")
+        assert [ph.fields["levels"] for ph in finalize_phases[-2:]] == [
+            "built", "kept"]

@@ -1,6 +1,7 @@
 """Scheduler-layer tests: host greedy placer, level schedule, TPU backend."""
 
 import numpy as np
+import pytest
 
 from fleetflow_tpu.core.loader import load_project_from_root_with_stage
 from fleetflow_tpu.lower import lower_stage, synthetic_problem
@@ -184,22 +185,94 @@ class TestSlotManager:
         total = sum(s["bytes"] for s in st["slots"])
         assert st["resident_bytes"] == total
 
-    def test_place_many_matches_solo_reschedules(self, monkeypatch):
-        """The batched path through solve_multiplexed must commit the
-        same placements the solo warm reschedules would."""
-        monkeypatch.setenv("FLEET_SUBSOLVE", "0")
-        pts = self._pts()
-        solo_sched = TpuSolverScheduler(steps=32)
-        for k in "ABC":
-            solo_sched.place(pts[k], stage=k)
-        solo = {k: solo_sched.reschedule(pts[k], stage=k) for k in "ABC"}
 
-        many = TpuSolverScheduler(steps=32)
-        for k in "ABC":
-            many.place(pts[k], stage=k)
-        batch = many.place_many([{"pt": pts[k], "warm_start": True,
-                                  "stage": k} for k in "ABC"])
-        assert len(batch) == 3
-        for k, res in zip("ABC", batch):
-            assert np.array_equal(solo[k].raw, res.raw), k
-            assert res.feasible == solo[k].feasible
+class TestStagesInTurn:
+    """Stages are solved one at a time through one scheduler, each
+    through its own resident slot: a second stage of the same tier runs
+    the executables the first compiled, another tier compiles its own
+    once, and no stage's result depends on which stages ran between its
+    solves."""
+
+    @staticmethod
+    def _pt(seed, S=60, N=12):
+        return synthetic_problem(S, N, seed=seed, port_fraction=0.3,
+                                 volume_fraction=0.2)
+
+    @staticmethod
+    def _killed(pt, node):
+        from dataclasses import replace
+
+        from fleetflow_tpu.solver.resident import ProblemDelta
+        valid = np.asarray(pt.node_valid, bool).copy()
+        valid[node] = False
+        return replace(pt, node_valid=valid), ProblemDelta(node_valid=valid)
+
+    def _cold_then_warm(self, sched, stage, pt, node):
+        cold = sched.place(pt, stage=stage)
+        cur, delta = self._killed(pt, node)
+        return cold, sched.reschedule(cur, delta=delta, stage=stage)
+
+    @staticmethod
+    def _routes(sched, monkeypatch):
+        """Records each solve's route: (resident warm, sub-solve info)."""
+        routes = []
+        inner = sched._solve_one
+
+        def solve_one(pt, slot, resident_warm, *a, **kw):
+            res = inner(pt, slot, resident_warm, *a, **kw)
+            routes.append((resident_warm, res.subsolve))
+            return res
+        monkeypatch.setattr(sched, "_solve_one", solve_one)
+        return routes
+
+    def test_same_tier_stage_adds_no_executable(self, monkeypatch):
+        from fleetflow_tpu.solver.api import _refine
+        monkeypatch.setenv("FLEET_SUBSOLVE", "0")
+        sched = TpuSolverScheduler(steps=32)
+        routes = self._routes(sched, monkeypatch)
+        self._cold_then_warm(sched, "A", self._pt(0), 0)   # warm-up
+        before = _refine._cache_size()
+        _, warm = self._cold_then_warm(sched, "B", self._pt(1), 1)
+        assert warm.feasible
+        # the reschedule ran the resident-warm refine, not the sub-solve
+        assert routes[-1] == (True, None)
+        assert _refine._cache_size() == before
+
+    def test_other_tier_stage_adds_one_executable(self, monkeypatch):
+        from fleetflow_tpu.solver.api import _refine
+        monkeypatch.setenv("FLEET_SUBSOLVE", "0")
+        sched = TpuSolverScheduler(steps=32)
+        routes = self._routes(sched, monkeypatch)
+        self._cold_then_warm(sched, "A", self._pt(0), 0)   # warm-up
+        small = self._pt(2, S=30, N=7)
+        sched.place(small, stage="C")
+        cur, delta = self._killed(small, 3)
+        before = _refine._cache_size()
+        sched.reschedule(cur, delta=delta, stage="C")
+        assert routes[-1] == (True, None)
+        assert _refine._cache_size() == before + 1
+        # and the first stage's tier still needs nothing new
+        before = _refine._cache_size()
+        self._cold_then_warm(sched, "A", self._pt(0), 2)
+        assert _refine._cache_size() == before
+
+    @pytest.mark.parametrize("start", ["cold", "warm", "warm-fused"])
+    def test_alternating_stages_match_solo_runs(self, start, monkeypatch):
+        if start == "warm-fused":
+            # the full warm executable instead of the localized sub-solve
+            monkeypatch.setenv("FLEET_SUBSOLVE", "0")
+        pts = {"A": self._pt(3), "B": self._pt(4)}
+        kill = {"A": 5, "B": 6}
+        solo = {k: self._cold_then_warm(TpuSolverScheduler(steps=32, seed=7),
+                                        k, pts[k], kill[k])
+                for k in "AB"}
+        mixed = TpuSolverScheduler(steps=32, seed=7)
+        got = {k: [mixed.place(pts[k], stage=k)] for k in "AB"}
+        for k in "AB":
+            cur, delta = self._killed(pts[k], kill[k])
+            got[k].append(mixed.reschedule(cur, delta=delta, stage=k))
+        i = 0 if start == "cold" else 1
+        for k in "AB":
+            assert np.array_equal(solo[k][i].raw, got[k][i].raw), k
+            assert solo[k][i].soft == got[k][i].soft, k
+            assert solo[k][i].feasible == got[k][i].feasible, k

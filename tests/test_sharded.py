@@ -34,7 +34,7 @@ class TestShardedAnneal:
         mesh = _mesh()
         init = jnp.zeros((pt.S,), jnp.int32)
         out = anneal_sharded(prob, init, jax.random.PRNGKey(0),
-                             steps=600, mesh=mesh)
+                             steps=600, mesh=mesh, block=600)
         a = np.asarray(out)
         assert a.shape == (pt.S,)
         stats = verify(pt, a)
@@ -47,7 +47,7 @@ class TestShardedAnneal:
         mesh = _mesh()
         init = jnp.ones((pt.S,), jnp.int32)  # node 1: valid start
         out = np.asarray(anneal_sharded(prob, init, jax.random.PRNGKey(1),
-                                        steps=600, mesh=mesh))
+                                        steps=600, mesh=mesh, block=600))
         stats = verify(pt, out)
         assert stats["total"] == 0, stats
         assert not np.any(out == 0), "placed on an invalid node"
@@ -64,7 +64,7 @@ class TestShardedAnneal:
         mesh = _mesh()
         out = np.asarray(anneal_sharded(
             prob, jnp.asarray(res.assignment), jax.random.PRNGKey(2),
-            steps=64, mesh=mesh))
+            steps=64, mesh=mesh, block=64))
         stats = verify(pt, out)
         assert stats["total"] == 0, stats
 
@@ -79,7 +79,7 @@ class TestShardedParity:
         prob = shard_problem(prepare_problem(pt), mesh)
         out = np.asarray(anneal_sharded(prob, jnp.zeros((pt.S,), jnp.int32),
                                         jax.random.PRNGKey(3), steps=400,
-                                        mesh=mesh))
+                                        mesh=mesh, block=400))
         assert verify(pt, out)["total"] == 0
 
     def test_skew_constraint_respected(self):
@@ -95,7 +95,7 @@ class TestShardedParity:
         # spread seed: round-robin is perfectly balanced across domains
         init = jnp.asarray(np.arange(64, dtype=np.int32) % 8)
         out = np.asarray(anneal_sharded(prob, init, jax.random.PRNGKey(4),
-                                        steps=400, mesh=mesh))
+                                        steps=400, mesh=mesh, block=400))
         stats = verify(pt, out)
         assert stats["skew"] == 0, stats
         assert stats["total"] == 0, stats
@@ -114,7 +114,8 @@ class TestPadding:
         out = np.asarray(anneal_sharded(padded,
                                         jnp.zeros((padded.S,), jnp.int32),
                                         jax.random.PRNGKey(5), steps=500,
-                                        mesh=mesh, n_real=orig_s))[:orig_s]
+                                        mesh=mesh, block=500,
+                                        n_real=orig_s))[:orig_s]
         assert verify(pt, out)["total"] == 0
 
     def test_padded_adaptive_respects_skew_of_real_services(self):
@@ -132,7 +133,7 @@ class TestPadding:
         out = np.asarray(anneal_sharded(
             padded, jnp.zeros((padded.S,), jnp.int32),
             jax.random.PRNGKey(8), steps=600, mesh=mesh,
-            adaptive=True, block=50, n_real=orig_s))[:orig_s]
+            block=50, n_real=orig_s))[:orig_s]
         stats = verify(pt, out)
         assert stats["skew"] == 0, stats
         assert stats["total"] == 0, stats
@@ -145,28 +146,91 @@ class TestPadding:
         assert padded is prob and orig_s == 64
 
 
-class TestShardedAdaptive:
+class TestShardedEarlyExit:
+    """The one block loop on a 1 x D mesh with no replica axis (and on a
+    tempered one): the exit fires at the first block boundary after any
+    sweep saw a feasible state, and the best state ever visited is what
+    comes back."""
+
+    def _feasible_seed(self, pt):
+        from fleetflow_tpu.sched.host import greedy_host_place
+        seed, _ = greedy_host_place(pt)
+        assert verify(pt, np.asarray(seed))["total"] == 0
+        return jnp.asarray(seed, jnp.int32)
+
     def test_adaptive_reaches_feasibility(self):
         pt = synthetic_problem(128, 16, seed=10)
         prob = prepare_problem(pt)
         mesh = _mesh()
         out = np.asarray(anneal_sharded(
             prob, jnp.zeros((pt.S,), jnp.int32), jax.random.PRNGKey(6),
-            steps=600, mesh=mesh, adaptive=True, block=50))
+            steps=600, mesh=mesh, block=50))
         assert verify(pt, out)["total"] == 0
 
-    def test_adaptive_matches_fixed_contract(self):
+    def test_block_size_changes_effort_not_feasibility(self):
         pt = synthetic_problem(64, 8, seed=11)
         prob = prepare_problem(pt)
         mesh = _mesh()
-        fixed = np.asarray(anneal_sharded(
+        runs = [anneal_sharded(
             prob, jnp.zeros((pt.S,), jnp.int32), jax.random.PRNGKey(7),
-            steps=400, mesh=mesh))
-        adapt = np.asarray(anneal_sharded(
-            prob, jnp.zeros((pt.S,), jnp.int32), jax.random.PRNGKey(7),
-            steps=400, mesh=mesh, adaptive=True, block=50))
-        assert verify(pt, fixed)["total"] == 0
-        assert verify(pt, adapt)["total"] == 0
+            steps=400, mesh=mesh, block=blk, return_sweeps=True)
+            for blk in (16, 50)]
+        for (out, sweeps), blk in zip(runs, (16, 50)):
+            assert verify(pt, np.asarray(out))["total"] == 0
+            assert int(sweeps) % blk == 0 or int(sweeps) == 400
+
+    def test_feasible_seed_exits_after_one_block(self):
+        pt = synthetic_problem(128, 16, seed=2)
+        prob = prepare_problem(pt)
+        out, sweeps = anneal_sharded(
+            prob, self._feasible_seed(pt), jax.random.PRNGKey(0),
+            steps=64, mesh=_mesh(), block=8, return_sweeps=True)
+        assert int(sweeps) == 8
+        assert verify(pt, np.asarray(out))["total"] == 0
+
+    def test_infeasible_seed_runs_until_feasible_returns_best(self):
+        pt = synthetic_problem(128, 16, seed=2)
+        prob = prepare_problem(pt)
+        res = anneal_sharded(
+            prob, jnp.zeros((pt.S,), jnp.int32), jax.random.PRNGKey(0),
+            steps=600, mesh=_mesh(), block=8, return_stats=True)
+        sweeps = int(res.sweeps)
+        # everything on node 0 is not feasible after one block, and the
+        # loop stops at the first block boundary once it is
+        assert 8 < sweeps < 600 and sweeps % 8 == 0
+        assert float(res.violations) == 0
+        assert verify(pt, np.asarray(res.assignment))["total"] == 0
+
+    def test_tempered_mesh_runs_until_feasible(self):
+        from fleetflow_tpu.solver.sharded import tempering_mesh
+        if len(jax.devices()) < 8:
+            pytest.skip("needs 8 devices")
+        pt = synthetic_problem(128, 16, seed=2)
+        prob = prepare_problem(pt)
+        res = anneal_sharded(
+            prob, jnp.zeros((pt.S,), jnp.int32), jax.random.PRNGKey(0),
+            steps=600, mesh=tempering_mesh(2, 4), block=8,
+            return_stats=True)
+        sweeps = int(res.sweeps)
+        assert 8 < sweeps < 600 and sweeps % 8 == 0
+        # a round a block; two lanes make a pair on every other round
+        assert int(res.swap_attempts) == -(-(sweeps // 8) // 2)
+        assert float(res.violations) == 0
+        assert verify(pt, np.asarray(res.assignment))["total"] == 0
+
+    def test_tempered_mesh_exits_after_one_block(self):
+        from fleetflow_tpu.solver.sharded import tempering_mesh
+        if len(jax.devices()) < 8:
+            pytest.skip("needs 8 devices")
+        pt = synthetic_problem(128, 16, seed=2)
+        prob = prepare_problem(pt)
+        res = anneal_sharded(
+            prob, self._feasible_seed(pt), jax.random.PRNGKey(0),
+            steps=64, mesh=tempering_mesh(2, 4), block=8,
+            return_stats=True)
+        assert int(res.sweeps) == 8
+        assert int(res.swap_attempts) > 0          # one exchange round ran
+        assert float(res.violations) == 0
 
 
 @pytest.mark.slow
@@ -195,7 +259,7 @@ class TestShardedRobustness:
             out = np.asarray(anneal_sharded(
                 padded, jnp.full((padded.S,), 1, jnp.int32),
                 jax.random.PRNGKey(seed), steps=1200, mesh=mesh,
-                adaptive=True, block=32, n_real=orig_s))[:orig_s]
+                block=32, n_real=orig_s))[:orig_s]
             stats = verify(pt, out)
             assert stats["total"] == 0, (seed, stats)
             assert not np.any(np.isin(out, [5, 41])), "placed on dead node"
@@ -204,18 +268,21 @@ class TestShardedRobustness:
             assert counts.max() - counts.min() <= 600
 
     def test_long_run_state_stays_consistent(self):
-        """A long non-adaptive run (256 sweeps, every sweep applying psum
-        deltas) must end with carried replicated state matching reality —
-        checked by exact host verify AND by the soft score being sane
-        (a drifted load matrix accepts capacity-violating moves)."""
+        """A long run (256 sweeps in one block, so the exit check never
+        cuts it short, every sweep applying psum deltas) must end with
+        carried replicated state matching reality — checked by exact host
+        verify, and by the device's violation count of the winner agreeing
+        with it (a drifted load matrix accepts capacity-violating moves)."""
         pt = synthetic_problem(512, 64, seed=13, port_fraction=0.3)
         prob = prepare_problem(pt)
         mesh = _mesh()
-        out = np.asarray(anneal_sharded(
+        res = anneal_sharded(
             prob, jnp.zeros((pt.S,), jnp.int32), jax.random.PRNGKey(7),
-            steps=256, mesh=mesh))
-        stats = verify(pt, out)
+            steps=256, mesh=mesh, block=256, return_stats=True)
+        assert int(res.sweeps) == 256
+        stats = verify(pt, np.asarray(res.assignment))
         assert stats["total"] == 0, stats
+        assert float(res.violations) == stats["total"]
 
 
 class TestMemoryScaling:
@@ -261,13 +328,9 @@ class TestMemoryScaling:
         prob = prepare_problem(pt)
         mesh = _mesh()
         init = jnp.zeros((pt.S,), jnp.int32)
-        out, sweeps = anneal_sharded(prob, init, jax.random.PRNGKey(0),
-                                     steps=600, mesh=mesh,
-                                     return_sweeps=True)
-        assert int(sweeps) == 600          # fixed-length path: all sweeps
         out2, sweeps2 = anneal_sharded(prob, init, jax.random.PRNGKey(0),
-                                       steps=600, mesh=mesh, adaptive=True,
-                                       block=16, return_sweeps=True)
+                                       steps=600, mesh=mesh, block=16,
+                                       return_sweeps=True)
         s2 = int(sweeps2)
         assert 0 < s2 <= 600
         assert s2 % 16 == 0 or s2 == 600   # whole blocks (or the cap)
@@ -301,7 +364,7 @@ class TestPartitionedSeed:
         mesh = Mesh(np.array(jax.devices()[:D]), (SVC_AXIS,))
         out = np.asarray(anneal_sharded(
             prob, jnp.asarray(seed, jnp.int32), jax.random.PRNGKey(5),
-            steps=128, mesh=mesh, adaptive=True, block=4))
+            steps=128, mesh=mesh, block=4))
         assert verify(pt, out)["total"] == 0
 
     def test_partitioned_seed_single_part_matches_whole_native(self):
@@ -359,6 +422,5 @@ class TestPartitionedSeed:
         mesh = Mesh(np.array(jax.devices()[:8]), (SVC_AXIS,))
         out = np.asarray(anneal_sharded(
             prepare_problem(pt), jnp.asarray(seed, jnp.int32),
-            jax.random.PRNGKey(3), steps=256, mesh=mesh, adaptive=True,
-            block=8))
+            jax.random.PRNGKey(3), steps=256, mesh=mesh, block=8))
         assert verify(pt, out)["total"] == 0

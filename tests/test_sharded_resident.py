@@ -221,22 +221,35 @@ class TestTemperingCriterion:
 
 class TestExchangeDeterminism:
     """A tempered 2-lane mesh run is deterministic: same key, same
-    problem => bit-identical winner and identical swap counters."""
+    problem => bit-identical winner and identical swap counters. The
+    instance demands more than the fleet holds, so no state is feasible,
+    the early exit never fires and every block's exchange round runs."""
+
+    @staticmethod
+    def _overfull():
+        pt = synthetic_problem(64, 10, seed=5, port_fraction=0.2)
+        # the generator sizes capacity at ~1.5x the demand: half of it
+        # holds ~77%, so every placement overloads some node
+        pt = dataclasses.replace(pt, capacity=pt.capacity * 0.5)
+        assert (pt.demand.sum(axis=0) > pt.capacity.sum(axis=0)).all()
+        return pt
 
     def test_two_lane_exchange_is_deterministic(self):
         _need_devices(2)
-        pt = synthetic_problem(64, 10, seed=5, port_fraction=0.2)
+        pt = self._overfull()
         prob = prepare_problem(pt)
         padded, orig = pad_problem(prob, 1)
         mesh = tempering_mesh(2, 1)
         assert mesh.shape == {REPLICA_AXIS: 2, SVC_AXIS: 1}
         init = jnp.zeros((padded.S,), jnp.int32)
-        kw = dict(steps=STEPS, mesh=mesh, adaptive=False, block=4,
+        kw = dict(steps=STEPS, mesh=mesh, block=4,
                   n_real=orig, return_stats=True)
         r1 = anneal_sharded(padded, init, jax.random.PRNGKey(9), **kw)
         r2 = anneal_sharded(padded, init, jax.random.PRNGKey(9), **kw)
         assert np.array_equal(np.asarray(r1.assignment),
                               np.asarray(r2.assignment))
+        assert int(r1.sweeps) == STEPS            # no exit: every block ran
+        assert float(r1.violations) > 0
         # exchanges actually ran, and their outcome is pinned by the key
         assert int(r1.swap_attempts) > 0
         assert int(r1.swap_attempts) == int(r2.swap_attempts)
@@ -250,12 +263,12 @@ class TestExchangeDeterminism:
         blocks skip the collectives entirely) and the pairing parity
         advances per ROUND — a 2-lane ladder must still trade."""
         _need_devices(2)
-        pt = synthetic_problem(64, 10, seed=5, port_fraction=0.2)
+        pt = self._overfull()
         prob = prepare_problem(pt)
         padded, orig = pad_problem(prob, 1)
         mesh = tempering_mesh(2, 1)
         init = jnp.zeros((padded.S,), jnp.int32)
-        kw = dict(steps=STEPS, mesh=mesh, adaptive=False, block=4,
+        kw = dict(steps=STEPS, mesh=mesh, block=4,
                   n_real=orig, exchange_every=2, return_stats=True)
         r1 = anneal_sharded(padded, init, jax.random.PRNGKey(9), **kw)
         r2 = anneal_sharded(padded, init, jax.random.PRNGKey(9), **kw)

@@ -120,8 +120,8 @@ class TestShardedPropertySweep:
         mesh = Mesh(np.array(jax.devices()[:8]), (SVC_AXIS,))
         out, sweeps = anneal_sharded(
             padded, jnp.zeros((padded.S,), jnp.int32),
-            jax.random.PRNGKey(seed), steps=400, mesh=mesh, adaptive=True,
-            block=16, n_real=orig_s, return_sweeps=True)
+            jax.random.PRNGKey(seed), steps=400, mesh=mesh, block=16,
+            n_real=orig_s, return_sweeps=True)
         a = np.asarray(out)[:orig_s]
         assert (a >= 0).all() and (a < N).all()
         pre = verify(pt, a)

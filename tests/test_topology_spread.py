@@ -474,7 +474,7 @@ def _trace_refine(pt, warm: bool):
     def refine(p, s, k):
         return solver_api._refine.__wrapped__(
             p, s, k, 1.0, 1e-3, 0.5, chains=2, steps=8, warm=warm,
-            adaptive=True, anneal_block=1, proposals_per_step=16,
+            anneal_block=1, proposals_per_step=16,
             fused_prerepair=warm, prerepair_moves=16 if warm else 0,
             skip_feasible_polish=False, trace_blocks=4)
 
@@ -559,8 +559,7 @@ def _sharded_from(pt, init, steps):
     mesh = Mesh(np.array(devs[:8]), (SVC_AXIS,))
     return np.asarray(anneal_sharded(
         prepare_problem(pt), jnp.asarray(init, jnp.int32),
-        jax.random.PRNGKey(steps), steps=steps, mesh=mesh, adaptive=True,
-        block=4))
+        jax.random.PRNGKey(steps), steps=steps, mesh=mesh, block=4))
 
 
 def _blind_seed(pt) -> np.ndarray:
