@@ -43,11 +43,17 @@ zero-host-transfer):
     delta's `n_real` bump — when the free list is empty. At steady state
     (arrivals ~ departures) rows recirculate and S is constant.
   * streamed services must be SIMPLE: resources + optional node
-    eligibility, one replica, no ports/volumes/anti-affinity/colocation/
-    dependencies — exactly the churn the delta path can express
-    (solver/resident.py `_arrivals_compatible`). Richer services go through
-    the full deploy path (`deploy.execute`), which re-lowers and
-    cold-stages honestly.
+    eligibility + label-style anti-affinity (`anti_affinity`, and its
+    reach `anti_affinity_stages`), one replica, no ports/volumes/
+    colocation/dependencies — exactly the churn the delta path can
+    express (solver/resident.py `_arrivals_compatible`: an arrival's
+    conflict ids ride the delta's `conflict_rows`). Its anti-affinity
+    keys are the lowering's (lower/tensors.py `anti_keys`): the fold
+    writes the row's group id and the stage's `holds` / `barred_by`, a
+    departure clears them, and `PlacementService.admit_batch` bars the
+    arrival from the servers on which another stage holds its key.
+    Richer services go through the full deploy path (`deploy.execute`),
+    which re-lowers and cold-stages honestly.
   * when the row count would cross its shape tier and tombstones exist,
     the stream COMPACTS (drops tombstone rows and cold-restages once) —
     amortized, counted, and absent at steady state.
@@ -75,7 +81,8 @@ from typing import Callable, Optional
 import numpy as np
 
 from ..core.errors import ControlPlaneError
-from ..core.model import Flow, ResourceSpec, Service
+from ..core.model import Flow, ResourceSpec, Service, ServiceType
+from ..lower.tensors import anti_keys
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
@@ -289,22 +296,45 @@ class _Stream:
     owner: dict[str, str] = field(default_factory=dict)     # name -> tenant
 
 
+# the keys of a streamed arrival's wire spec (make_arrival); a spec that
+# carries any other is refused, never dropped
+ARRIVAL_KEYS = frozenset({"name", "image", "version", "cpu", "memory",
+                          "disk", "labels", "eligible_nodes",
+                          "anti_affinity", "anti_affinity_stages"})
+
+
 def _simple_reject(svc: Service) -> Optional[str]:
     """Why `svc` cannot ride the streaming delta path (None = it can).
-    Mirrors solver/resident._arrivals_compatible: appended rows must bring
-    no hard-constraint ids, no dependencies, one replica."""
+    Mirrors solver/resident._arrivals_compatible: appended rows bring no
+    port, volume or colocation id, no dependency, one replica; a
+    label-style anti-affinity id rides the delta (`_anti_reject` says
+    which keys are label-style in a stage)."""
     if svc.ports:
         return "ports"
     if svc.volumes:
         return "volumes"
-    if svc.anti_affinity:
-        return "anti_affinity"
     if svc.colocate_with:
         return "colocate_with"
     if svc.depends_on:
         return "depends_on"
     if svc.replicas != 1:
         return f"replicas={svc.replicas}"
+    return None
+
+
+def _anti_reject(svc: Service, stream: "_Stream",
+                 arriving: set[str]) -> Optional[str]:
+    """Why `svc`'s anti-affinity cannot stream into `stream` (None = it
+    can): a key that names a service of the stage, or one arriving with
+    it, or the service itself is target-style (lower/tensors.py lowers it
+    to pair groups, which a row appended alone cannot join); a reach given
+    for a label the service does not declare would be dropped."""
+    for k in svc.anti_affinity:
+        if k == svc.name or k in stream.flow.services or k in arriving:
+            return f"anti_affinity {k!r} names a service"
+    extra = sorted(set(svc.anti_affinity_stages) - set(svc.anti_affinity))
+    if extra:
+        return f"anti_affinity_stages for undeclared labels {extra}"
     return None
 
 
@@ -391,6 +421,10 @@ class AdmissionController:
                 "version": svc.version, "cpu": svc.resources.cpu,
                 "memory": svc.resources.memory, "disk": svc.resources.disk,
                 "labels": dict(svc.labels or {})}
+        if svc.anti_affinity:
+            spec["anti_affinity"] = list(svc.anti_affinity)
+            spec["anti_affinity_stages"] = {
+                k: list(v) for k, v in svc.anti_affinity_stages.items()}
         self._store.create("admission_parked", ParkedArrival(
             id=r.id, tenant=r.tenant, name=r.name, stage_key=r.stage_key,
             submitted_at=r.submitted_at, seq=r.seq, reason=reason,
@@ -474,6 +508,13 @@ class AdmissionController:
             if key in self._streams:
                 return key
         entry = self.placement.retained(key)
+        if entry is None and not any(
+                s.service_type is not ServiceType.STATIC
+                for s in flow.stage(stage_name).resolved_services(flow)):
+            # opened empty: the first arrivals fold into no row, and the
+            # stage's first micro-solve is its first solve
+            entry = (self.placement.open_empty(flow, stage_name,
+                                               tenant=tenant), None)
         if entry is None:
             placement, rid = self.placement.solve_stage(
                 flow, stage_name, tenant=tenant)
@@ -536,8 +577,21 @@ class AdmissionController:
 
     def make_arrival(self, spec: dict) -> Service:
         """Build a streamed Service from a wire spec: {name, image?,
-        version?, cpu?, memory?, disk?, eligible_nodes?, labels?}."""
-        return Service(
+        version?, cpu?, memory?, disk?, labels?, eligible_nodes?,
+        anti_affinity?, anti_affinity_stages?} — the last two spelled as
+        core/serialize.py spells a service's (a list of labels; label ->
+        the stages its reach covers), so a pod is the same bytes in
+        placement.solve and in deploy.submit. `eligible_nodes` is the
+        request's, not the service's (`submit`). Any other key (ports,
+        volumes, ...) is refused with ValueError: what the stream cannot
+        honour is never dropped."""
+        if not ARRIVAL_KEYS.issuperset(spec):
+            raise ValueError(
+                f"arrival {spec.get('name')!r} carries "
+                f"{sorted(set(spec) - ARRIVAL_KEYS)}, which a streamed "
+                f"arrival cannot: constrained services deploy via "
+                f"deploy.execute (docs/guide/14-streaming-admission.md)")
+        svc = Service(
             name=str(spec["name"]),
             image=spec.get("image") or "app",
             version=spec.get("version") or "latest",
@@ -546,6 +600,21 @@ class AdmissionController:
                                    disk=float(spec.get("disk", 0.0))),
             labels=dict(spec.get("labels") or {}),
         )
+        anti = spec.get("anti_affinity")
+        reach = spec.get("anti_affinity_stages")
+        if anti or reach:
+            if not (isinstance(anti or [], list)
+                    and isinstance(reach or {}, dict)
+                    and all(isinstance(v, list)
+                            for v in (reach or {}).values())):
+                raise ValueError(
+                    f"arrival {svc.name!r}: anti_affinity is a list of "
+                    f"labels, anti_affinity_stages a map of label -> "
+                    f"stages")
+            svc.anti_affinity = [str(k) for k in anti or ()]
+            svc.anti_affinity_stages = {str(k): [str(t) for t in v]
+                                        for k, v in (reach or {}).items()}
+        return svc
 
     def submit(self, tenant: str, arrivals=(), departures=(), *,
                stage: Optional[str] = None,
@@ -562,12 +631,23 @@ class AdmissionController:
             stream = self._stream_for(stage)
             self._resync(stream)
             svcs: list[Service] = []
+            # arrival name -> the nodes its request may land on
+            where: dict[str, list[str]] = {}
             queued_names = {r.name for q in self._queues.values() for r in q
                             if r.kind == "arrival"
                             and r.stage_key == stream.key}
+            arriving: Optional[set[str]] = None
             for a in arrivals:
                 svc = a if isinstance(a, Service) else self.make_arrival(a)
+                if not isinstance(a, Service) and a.get("eligible_nodes"):
+                    where[svc.name] = [str(n) for n in a["eligible_nodes"]]
                 why = _simple_reject(svc)
+                if why is None and (svc.anti_affinity
+                                    or svc.anti_affinity_stages):
+                    if arriving is None:
+                        arriving = {a.name if isinstance(a, Service)
+                                    else str(a["name"]) for a in arrivals}
+                    why = _anti_reject(svc, stream, arriving)
                 if why is not None:
                     raise ValueError(
                         f"arrival {svc.name!r} is not streamable ({why}): "
@@ -638,7 +718,7 @@ class AdmissionController:
             if svcs and depth + incoming > self.cfg.max_queue:
                 if self.cfg.on_full == "park":
                     result = self._park_on_full(stream, tenant, svcs, deps,
-                                                now)
+                                                now, where)
                 else:
                     _M_SHEDS.inc(len(svcs), reason="depth")
                     self.stats["sheds"] += len(svcs)
@@ -648,12 +728,14 @@ class AdmissionController:
                         retry_after_s=max(self.cfg.drain_interval_s * 2,
                                           1.0))
             else:
-                accepted = self._enqueue(stream, tenant, svcs, deps, now)
+                accepted = self._enqueue(stream, tenant, svcs, deps, now,
+                                         where)
                 result = {"accepted": accepted,
                           "queued": depth + incoming,
                           "stage": stream.key}
             if quota_overflow:
-                ids = self._park_quota(stream, tenant, quota_overflow, now)
+                ids = self._park_quota(stream, tenant, quota_overflow, now,
+                                       where)
                 result["accepted"] = list(result["accepted"]) + ids
                 result["parked"] = result.get("parked", 0) + len(ids)
                 result["quota_parked"] = len(ids)
@@ -666,8 +748,23 @@ class AdmissionController:
             self._wake_loop()
             return result
 
+    def _arrival(self, stream: _Stream, tenant: str, svc: Service,
+                 now: float, where: Optional[dict], **state
+                 ) -> AdmissionRequest:
+        """A new arrival request of `svc`, on the nodes `where` names for
+        it (any, where it names none)."""
+        r = AdmissionRequest(
+            id=f"adm_{next(self._ids)}", tenant=tenant, kind="arrival",
+            name=svc.name, stage_key=stream.key, submitted_at=now,
+            seq=next(self._seq), service=svc,
+            demand=np.array(svc.resources.as_tuple(), dtype=np.float64),
+            eligible_nodes=(where or {}).get(svc.name), **state)
+        self.requests[r.id] = r
+        return r
+
     def _enqueue(self, stream: _Stream, tenant: str, svcs: list[Service],
-                 deps: list[str], now: float) -> list[str]:
+                 deps: list[str], now: float,
+                 where: Optional[dict] = None) -> list[str]:
         q = self._queues.get(tenant)
         if q is None:
             q = self._queues[tenant] = deque()
@@ -675,13 +772,8 @@ class AdmissionController:
             self._rr.append(tenant)
         accepted = []
         for svc in svcs:
-            r = AdmissionRequest(
-                id=f"adm_{next(self._ids)}", tenant=tenant, kind="arrival",
-                name=svc.name, stage_key=stream.key, submitted_at=now,
-                seq=next(self._seq), service=svc,
-                demand=np.array(svc.resources.as_tuple(), dtype=np.float64))
+            r = self._arrival(stream, tenant, svc, now, where)
             q.append(r)
-            self.requests[r.id] = r
             accepted.append(r.id)
         for name in deps:
             r = AdmissionRequest(
@@ -695,18 +787,13 @@ class AdmissionController:
 
     def _park_on_full(self, stream: _Stream, tenant: str,
                       svcs: list[Service], deps: list[str],
-                      now: float) -> dict:
+                      now: float, where: Optional[dict] = None) -> dict:
         """on_full="park": accept but defer the arrivals past the depth
         bound (departures always enqueue — they free capacity)."""
         accepted = self._enqueue(stream, tenant, [], deps, now)
         for svc in svcs:
-            r = AdmissionRequest(
-                id=f"adm_{next(self._ids)}", tenant=tenant, kind="arrival",
-                name=svc.name, stage_key=stream.key, submitted_at=now,
-                seq=next(self._seq), service=svc,
-                demand=np.array(svc.resources.as_tuple(), dtype=np.float64),
-                state="parked")
-            self.requests[r.id] = r
+            r = self._arrival(stream, tenant, svc, now, where,
+                              state="parked")
             self._parked.append(r)
             self._journal_park(r, "depth")
             accepted.append(r.id)
@@ -720,19 +807,15 @@ class AdmissionController:
                 "stage": stream.key, "parked": n}
 
     def _park_quota(self, stream: _Stream, tenant: str,
-                    svcs: list[Service], now: float) -> list[str]:
+                    svcs: list[Service], now: float,
+                    where: Optional[dict] = None) -> list[str]:
         """Park arrivals a tenant hard cap refused headroom for. Accepted
         (ids returned, journaled) but deferred: they re-queue only once
         the tenant's own live+queued count drops under its cap."""
         ids = []
         for svc in svcs:
-            r = AdmissionRequest(
-                id=f"adm_{next(self._ids)}", tenant=tenant, kind="arrival",
-                name=svc.name, stage_key=stream.key, submitted_at=now,
-                seq=next(self._seq), service=svc,
-                demand=np.array(svc.resources.as_tuple(), dtype=np.float64),
-                state="parked")
-            self.requests[r.id] = r
+            r = self._arrival(stream, tenant, svc, now, where,
+                              state="parked")
             self._parked.append(r)
             self._journal_park(r, "quota")
             ids.append(r.id)
@@ -1084,6 +1167,28 @@ class AdmissionController:
 
         if not changed_rows and not cancelled:
             return None, None, None
+        # anti-affinity keys: the rows this batch vacates give theirs up,
+        # the arrivals that declare one take theirs. A stage whose rows
+        # declare none (and a batch that brings none) pays two truth tests
+        declaring = ([(row, r) for row, r, _old in reuse
+                      if r.service.anti_affinity]
+                     + [(S + j, r) for j, r in enumerate(appended)
+                        if r.service.anti_affinity])
+        vacated = np.asarray([row for row, _n in tomb_rows]
+                             + [row for row, _r, _old in reuse],
+                             dtype=np.int64)
+        if vacated.size and (pt.anti_ids >= 0).any():
+            vacated = vacated[(pt.anti_ids[vacated] >= 0).any(axis=1)]
+        else:
+            vacated = vacated[:0]
+        keys = {}
+        conflict_rows = None
+        if declaring or vacated.size:
+            anti_ids, keys = self._fold_anti(
+                stream, pt, np.array(ids["anti_ids"]), vacated, declaring)
+            ids["anti_ids"] = anti_ids
+            conflict_rows = np.union1d(
+                vacated, [row for row, _r in declaring]).astype(np.int32)
         rows = np.asarray(sorted(set(changed_rows)), dtype=np.int32)
         erows = np.asarray(sorted(set(elig_rows)), dtype=np.int32)
         # always carry BOTH scatter planes (possibly empty): one static
@@ -1092,16 +1197,79 @@ class AdmissionController:
         delta = ProblemDelta(
             demand_rows=(rows, demand[rows]),
             eligible_rows=(erows, eligible[erows]),
-            n_real=S2 if k_app else None)
+            n_real=S2 if k_app else None,
+            conflict_rows=conflict_rows)
         pt2 = _dc.replace(pt, demand=demand, eligible=eligible,
                           dep_adj=dep_adj, dep_depth=dep_depth,
                           service_names=names, replica_of=replica_of,
-                          **ids)
+                          **ids, **keys)
         plan = {"appended": appended, "reuse": reuse,
                 "tomb_rows": tomb_rows, "free": free,
                 "cancelled": cancelled,
                 "events": [r for r in events if r not in cancelled]}
         return pt2, delta, plan
+
+    @staticmethod
+    def _fold_anti(stream: _Stream, pt, anti_ids: np.ndarray,
+                   vacated: np.ndarray, declaring: list) -> tuple:
+        """The anti-affinity part of a fold, on `anti_ids` (the
+        candidate's, a private copy): the rows `vacated` (departed, or
+        handed to an arrival) lose their group ids and leave every key
+        list; each (row, request) of `declaring` takes the group id of
+        each label it declares — the stage's where the stage has the
+        label (`ProblemTensors.anti_groups`), a new one after every id in
+        use where not — and the keys lower/tensors.py `anti_keys` spells.
+        Returns (anti_ids, the candidate's new holds / barred_by /
+        anti_groups); the maps and lists of `pt` are not written."""
+        gone = set(vacated.tolist())
+
+        def without(keyed: dict) -> dict:
+            if not gone:
+                return dict(keyed)
+            out = {}
+            for k, rows in keyed.items():
+                if not gone.isdisjoint(rows):
+                    rows = [i for i in rows if i not in gone]
+                if rows:
+                    out[k] = rows
+            return out
+
+        holds, barred_by = without(pt.holds), without(pt.barred_by)
+        anti_ids[vacated] = -1
+        groups = dict(pt.anti_groups)
+        fresh = max(max(groups.values(), default=-1),
+                    int(pt.anti_ids.max(initial=-1))) + 1
+        held_by: dict[str, list[int]] = {}
+        barring: dict[str, list[int]] = {}
+        for row, r in declaring:
+            svc = r.service
+            gids = []
+            for label in svc.anti_affinity:
+                gid = groups.get(label)
+                if gid is None:
+                    gid = groups[label] = fresh
+                    fresh += 1
+                gids.append(gid)
+                held, barred = anti_keys(stream.flow.name, stream.stage_name,
+                                         label,
+                                         svc.anti_affinity_stages.get(label,
+                                                                      ()))
+                for k in held:
+                    held_by.setdefault(k, []).append(row)
+                for k in barred:
+                    barring.setdefault(k, []).append(row)
+            gids = list(dict.fromkeys(gids))
+            if len(gids) > anti_ids.shape[1]:
+                anti_ids = np.hstack([anti_ids, np.full(
+                    (anti_ids.shape[0], len(gids) - anti_ids.shape[1]), -1,
+                    dtype=anti_ids.dtype)])
+            anti_ids[row] = -1
+            anti_ids[row, :len(gids)] = gids
+        for keyed, added in ((holds, held_by), (barred_by, barring)):
+            for k, rows in added.items():
+                keyed[k] = keyed.get(k, []) + rows
+        return anti_ids, {"holds": holds, "barred_by": barred_by,
+                          "anti_groups": groups}
 
     def _should_compact(self, stream: _Stream, n_new: int) -> bool:
         """Compact (drop tombstone rows, cold-restage once) before a
@@ -1129,8 +1297,23 @@ class AdmissionController:
         keep = np.asarray([i for i in range(pt.S) if i not in drop],
                           dtype=np.int64)
         names = [pt.service_names[i] for i in keep]
+        at = np.full(pt.S, -1, dtype=np.int64)
+        at[keep] = np.arange(keep.size)
+
+        def renumbered(keyed: dict) -> dict:
+            out = {}
+            for k, rows in keyed.items():
+                rows = [int(at[i]) for i in rows if at[i] >= 0]
+                if rows:
+                    out[k] = rows
+            return out
+
         stream.pt = _dc_replace(
             pt,
+            holds=renumbered(pt.holds),
+            barred_by=renumbered(pt.barred_by),
+            priority=None if pt.priority is None else pt.priority[keep],
+            preferred=None if pt.preferred is None else pt.preferred[keep],
             demand=pt.demand[keep],
             eligible=pt.eligible[keep],
             dep_adj=pt.dep_adj[np.ix_(keep, keep)],

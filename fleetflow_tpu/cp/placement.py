@@ -311,9 +311,9 @@ class PlacementService:
     label declared to reach a stage, are held at most once per server over
     every committed and reserved placement the CP knows, not only inside
     the stage being solved. It holds on `solve_stage` (so on
-    `placement.solve`, `deploy.execute`), on `rehydrate`, and on the churn
-    re-solve of `node_events`; `admit_batch` does not yet bar its arrivals
-    (its docstring). A stage alone on its servers takes the same path with
+    `placement.solve`, `deploy.execute`), on `rehydrate`, on the churn
+    re-solve of `node_events` and on `admit_batch` (streaming admission:
+    its docstring). A stage alone on its servers takes the same path with
     nothing held.
 
     Priority and preemption (`Service.priority`, default 0). A stage that
@@ -376,6 +376,10 @@ class PlacementService:
         # commitment it wrote it from): what a commit's record is written
         # by difference against (_persist_committed)
         self._recorded: dict[str, tuple[PlacementRecord, Reservation]] = {}
+        # stage key -> (every other stage's placement that holds a key,
+        # paired with its held-key map, as last read; what those hold):
+        # _held_by_others_kept
+        self._held_kept: dict[str, tuple[list, dict[str, list[str]]]] = {}
         # the committed book explains servers.allocated: rebuild it from
         # the store's placements table so a restarted (or promoted
         # standby, docs/guide/13-cp-replication.md) CP's next commit
@@ -577,6 +581,71 @@ class PlacementService:
                 have = out.get(k)
                 out[k] = slugs if have is None else have + slugs
         return out
+
+    def _held_by_others_kept(self, key: str) -> dict[str, list[str]]:
+        """`_held_by_others`, kept with the stage and read again only
+        once another stage's placement that holds a key was committed,
+        reserved, changed or dropped since the last read: the same
+        object while nothing did. A held-key map is replaced, never
+        written in place, so the check is by identity. Caller holds the
+        lock."""
+        holders = [(r, r.held_keys) for r in itertools.chain(
+            self._committed.values(), self._reservations.values())
+            if r.stage_key != key and r.held_keys]
+        kept = self._held_kept.get(key)
+        if (kept is not None and len(kept[0]) == len(holders)
+                and all(a is c and b is d
+                        for (a, b), (c, d) in zip(kept[0], holders))):
+            return kept[1]
+        held = self._held_by_others(key)
+        self._held_kept[key] = (holders, held)
+        return held
+
+    def _bar_admitted(self, key: str, pt: ProblemTensors, delta
+                      ) -> tuple[ProblemTensors, object]:
+        """`admit_batch`'s candidate barred from the servers on which
+        another stage holds a key that its rows are barred by: the rows
+        the delta brings in (arrivals) — or, where what other stages hold
+        changed since `pt` was barred (`pt.held`), every barred row, the
+        rows whose bits moved joining the delta. The `cp.admit_batch.held`
+        phase. Returns (pt, delta): pt a copy where a bit was cleared."""
+        with phase("cp.admit_batch.held", stage=key) as ph:
+            held = self._held_by_others_kept(key)
+            if held is not pt.held and held == pt.held:
+                pt = _dc_replace(pt, held=held)     # the same, read anew
+            every = held is not pt.held
+            new = (np.asarray(delta.eligible_rows[0], dtype=np.int64)
+                   if delta is not None and delta.eligible_rows is not None
+                   else np.empty(0, dtype=np.int64))
+            barring = {k: (rows if every else
+                           np.intersect1d(rows, new, assume_unique=True))
+                       for k, rows in pt.barred_by.items() if k in held}
+            barring = {k: rows for k, rows in barring.items() if len(rows)}
+            if not barring:
+                return (_dc_replace(pt, held=held) if every else pt), delta
+            touched = np.unique(np.concatenate(
+                [np.asarray(r, dtype=np.int64) for r in barring.values()]))
+            eligible = pt.eligible
+            standing = self._last.get(key)
+            if every or (standing is not None
+                         and standing[0].eligible is eligible):
+                eligible = eligible.copy()
+            before = eligible[touched] if every else None
+            pairs = sum(map(len, held.values()))
+            _M_HELD_KEYS.inc(pairs)
+            cleared = bar_held(eligible, barring, pt.node_names, held)
+            ph.set(keys=len(barring), rows=int(touched.size), pairs=pairs,
+                   cleared=cleared)
+            pt = _dc_replace(pt, eligible=eligible, held=held)
+            if delta is not None:
+                rows = new
+                if every:
+                    moved = touched[(eligible[touched] != before).any(axis=1)]
+                    rows = np.union1d(new, moved)
+                if rows.size:
+                    delta.eligible_rows = (rows.astype(np.int32),
+                                           eligible[rows])
+            return pt, delta
 
     def _held_for_lowering(self, key: str) -> dict[str, list[str]]:
         """`_held_by_others` as the `cp.solve_stage.held` phase."""
@@ -834,6 +903,20 @@ class PlacementService:
                                                rows=pt.S))
         return True
 
+    def open_empty(self, flow: Flow, stage_name: str, *,
+                   tenant: str = "default") -> ProblemTensors:
+        """A stage with no service yet, lowered against live inventory to
+        no row (`lower_stage(empty=True)`): what streaming admission folds
+        the stage's first arrivals into (cp/admission.py). Nothing is
+        solved, reserved or retained."""
+        key = f"{flow.name}/{stage_name}"
+        with self._locked():
+            nodes, free, valid, _ = self._inventory(
+                tenant, flow.stage(stage_name).servers or None)
+            return lower_stage(flow, stage_name, nodes=nodes,
+                               held=self._held_for_lowering(key),
+                               capacity=free, valid=valid, empty=True)
+
     def admit_batch(self, stage_key: str, pt: ProblemTensors, delta=None,
                     *, tenant: str = "default", masked=None,
                     ) -> tuple[Placement, Optional[str], ProblemTensors]:
@@ -854,12 +937,15 @@ class PlacementService:
         standing (the stage IS still feasibly placed without the batch)
         and reservation_id is None.
 
-        NOT YET: the candidate is built by cp/admission.py, not lowered
-        here, so the cross-stage guarantee of the class docstring is not
-        held for it — a row is not barred from a server on which another
-        stage holds its key (streamed arrivals carry no ports, volumes or
-        anti-affinity; the rows the stage was opened with can). The
-        reservation does record what the stage's own rows hold.
+        The cross-stage guarantee of the class docstring holds for the
+        candidate too, though cp/admission.py built it and nothing here
+        lowers it: the rows the delta brings in are barred from the
+        servers on which another stage holds a key they are barred by,
+        and where what other stages hold has changed since the stage was
+        last barred, every row of it is (`_bar_admitted`). A stage that
+        declares no key pays one truth test. The reservation records
+        what the stage's rows hold, arrivals included, so a solve of
+        another stage is barred by them in turn.
 
         An arrival through admission does NOT preempt either: capacity
         here is live capacity, what lower-ranking committed rows hold is
@@ -876,6 +962,8 @@ class PlacementService:
                 if not np.array_equal(valid, pt.node_valid):
                     pt = _dc_replace(pt, node_valid=valid)
                 pt = self._refresh_capacity(pt, stage_key, on=(view, row))
+            if pt.barred_by:
+                pt, delta = self._bar_admitted(stage_key, pt, delta)
             if delta is not None:
                 # the delta always re-ships the small planes; keep them
                 # coherent with the refreshed candidate
@@ -1310,6 +1398,7 @@ class PlacementService:
             if forget:
                 self._last.pop(stage_key, None)
                 self._node_rows.pop(stage_key, None)
+                self._held_kept.pop(stage_key, None)
                 self._masked.pop(stage_key, None)
                 self._sched_tpu.forget(stage_key)
             c = self._committed.pop(stage_key, None)

@@ -541,11 +541,12 @@ def check_placement_prelint(r: Rule, ctx: LintContext, stage: Stage):
 def check_non_streamable(r: Rule, ctx: LintContext, stage: Stage):
     """A stage declared `placement { streaming #true }` (aimed at the
     deploy.submit continuous-arrival path) carries services the streaming
-    delta path must reject at runtime: ports, volumes, anti-affinity,
-    colocation, dependencies, or replicas > 1 all bring hard-constraint
-    ids or multi-row shapes the resident delta kernel cannot express
-    (solver/resident._arrivals_compatible), so cp/admission.py sheds them
-    with AdmissionRejected mid-stream — this is the pre-deploy signal."""
+    delta path must reject at runtime: ports, volumes, colocation,
+    dependencies, or replicas > 1 all bring hard-constraint ids or
+    multi-row shapes the resident delta kernel cannot express
+    (solver/resident._arrivals_compatible; label-style anti-affinity
+    streams), so cp/admission.py refuses them mid-stream — this is the
+    pre-deploy signal."""
     if stage.placement is None or not stage.placement.streaming:
         return
     # the SAME predicate the CP applies at submit time (cp/admission.py)

@@ -79,7 +79,7 @@ from ..core.model import (ServiceType, Flow, PlacementPolicy, PlacementStrategy,
 from ..obs import phase
 from ..obs.metrics import REGISTRY
 
-__all__ = ["ProblemTensors", "Node", "lower_stage", "bar_held",
+__all__ = ["ProblemTensors", "Node", "lower_stage", "anti_keys", "bar_held",
            "with_preemptible", "dependency_depths",
            "LOCAL_NODE_NAME", "local_node", "synthetic_problem"]
 
@@ -133,6 +133,10 @@ class ProblemTensors:
     holds: dict[str, list[int]] = field(default_factory=dict)
     barred_by: dict[str, list[int]] = field(default_factory=dict)
     held: dict[str, list[str]] = field(default_factory=dict)
+    # label-style anti-affinity label -> its group id in `anti_ids`: what
+    # a row appended later (cp/admission.py) declaring the label joins.
+    # Not read by the solver.
+    anti_groups: dict[str, int] = field(default_factory=dict)
     # Priority (module docstring); neither is read by the solver.
     #   priority     (S,) i32 per row, or None: every row ranks 0
     #   preemptible  (N, R) f32, the part of `capacity` that lower-ranking
@@ -299,6 +303,18 @@ def local_node(name: str = LOCAL_NODE_NAME) -> ServerResource:
         capacity=ResourceSpec(cpu=1e6, memory=1e9, disk=1e9))
 
 
+def anti_keys(project: str, stage: str, label: str,
+              stages: Sequence[str] = ()) -> tuple[list[str], list[str]]:
+    """The cross-stage keys (module docstring) of one row of stage `stage`
+    of flow `project` declaring anti-affinity label `label` that reaches
+    into `stages` (its own stage is left out): (keys it holds, keys it is
+    barred by)."""
+    label = f"anti:{project}:{label}"
+    reach = [t for t in stages if t != stage]
+    return ([f"{label}@{stage}", *(f"{label}>{t}" for t in reach)],
+            [f"{label}>{stage}", *(f"{label}@{t}" for t in reach)])
+
+
 def bar_held(eligible: np.ndarray, barred_by: dict[str, list[int]],
              node_names: list[str], held: dict[str, list[str]]) -> int:
     """Clear, in place, the eligibility bit of every (row, server) whose
@@ -411,8 +427,13 @@ def lower_stage(flow: Flow, stage_name: str,
                 preemptible: Optional[np.ndarray] = None,
                 capacity: Optional[np.ndarray] = None,
                 valid: Optional[np.ndarray] = None,
+                empty: bool = False,
                 ) -> ProblemTensors:
     """Lower one stage of a Flow into ProblemTensors.
+
+    A stage with no service is refused, unless `empty`: then it lowers to
+    no row over its nodes (a stage that streaming admission opens before
+    its first arrival, cp/admission.py).
 
     `held` (key -> servers) is what OTHER stages hold on the servers, in
     the keys of the module docstring; the stage's rows are barred from
@@ -505,7 +526,7 @@ def lower_stage(flow: Flow, stage_name: str,
             replica_of.extend([name] * reps)
             base_index[name] = idxs
     S, N = len(rows), len(nodes)
-    if S == 0:
+    if S == 0 and not empty:
         raise SolverError(f"stage {stage_name!r} has no services")
 
     # ---- demand / capacity -------------------------------------------------
@@ -626,7 +647,6 @@ def lower_stage(flow: Flow, stage_name: str,
     # services that declare one
     holds: dict[str, list[int]] = {}
     barred_by: dict[str, list[int]] = {}
-    anti_scope = f"anti:{flow.name}:"
 
     def host_key(key: str, first: int, reps: int) -> None:
         # a fact about the host: what holds it is what it bars
@@ -661,14 +681,12 @@ def lower_stage(flow: Flow, stage_name: str,
                 if k in base_index:
                     continue
                 base_ag.append(anti_key_ids.setdefault(k, len(anti_key_ids)))
-                label = anti_scope + k
-                reach = [t for t in svc.anti_affinity_stages.get(k, ())
-                         if t != stage_name]
-                for key in [f"{label}@{stage_name}",
-                            *(f"{label}>{t}" for t in reach)]:
+                held_keys, barring = anti_keys(
+                    flow.name, stage_name, k,
+                    svc.anti_affinity_stages.get(k, ()))
+                for key in held_keys:
                     holds.setdefault(key, []).extend(range(i, i + reps))
-                for key in [f"{label}>{stage_name}",
-                            *(f"{label}@{t}" for t in reach)]:
+                for key in barring:
                     barred_by.setdefault(key, []).extend(range(i, i + reps))
         cg = _empty
         if svc.colocate_with or svc.name in coloc_targets:
@@ -792,6 +810,8 @@ def lower_stage(flow: Flow, stage_name: str,
         holds=holds,
         barred_by=barred_by,
         held=held,
+        anti_groups={k: g for k, g in anti_key_ids.items()
+                     if isinstance(k, str)},
         priority=priority,
     )
     pt.validate()

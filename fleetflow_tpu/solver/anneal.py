@@ -122,7 +122,8 @@ def prerepair_state(prob: DeviceProblem, st: ChainState,
 
 
 def prerepair_state_counted(prob: DeviceProblem, st: ChainState,
-                            max_moves: int) -> tuple[ChainState, jax.Array]:
+                            max_moves: int, *, conflicted: bool = False
+                            ) -> tuple[ChainState, jax.Array]:
     """Fused churn pre-repair: relocate services stranded on invalid or
     ineligible nodes, one per `lax.while_loop` iteration, entirely on
     device. This replaces the host `repair.py` pre-pass on the warm path
@@ -140,14 +141,27 @@ def prerepair_state_counted(prob: DeviceProblem, st: ChainState,
     incoming state is preserved: a clean relocation only ever lands on a
     node it verified against the live carried state.
 
+    With `conflicted` (static), a service that shares a conflict id with
+    another on its node counts as stranded too, the lowest row of such a
+    pair moving first: the localized sub-solve's use (solver/subsolve.py),
+    whose fresh arrivals start parked together on one node and whose
+    incumbents sit in the frozen base, so a clean relocation is exactly
+    the arrival finding a server its key leaves free.
+
     Returns ``(state, moves)`` — `moves` counts the relocations actually
     APPLIED (attempts on genuinely unplaceable services don't count):
     the prologue half of the solver flight-deck telemetry."""
     ar = jnp.arange(prob.S)
 
     def stranded_of(st):
-        return (~eligible_lookup(prob.eligible, ar, st.assignment)
-                | ~prob.node_valid[st.assignment])
+        out = (~eligible_lookup(prob.eligible, ar, st.assignment)
+               | ~prob.node_valid[st.assignment])
+        if conflicted:
+            ids = prob.conflict_ids
+            held = ids >= 0
+            here = st.used[st.assignment[:, None], jnp.where(held, ids, 0)]
+            out = out | ((here > 1) & held).any(-1)
+        return out
 
     def cond(carry):
         st, attempted, i, _moves = carry

@@ -100,7 +100,7 @@ def _synthetic(S: int, N: int):
 
 def _rich_delta(pt, n_rows: int = 3):
     """A delta exercising every merge input: validity + capacity drift
-    plus demand/eligibility row scatters (has_demand/has_eligible both
+    plus demand/eligibility/conflict-id row scatters (every has_* flag
     True — the richest static variant, the one whose lowering touches
     every donated plane)."""
     from .resident import ProblemDelta
@@ -109,11 +109,13 @@ def _rich_delta(pt, n_rows: int = 3):
         node_valid=np.asarray(pt.node_valid, dtype=bool).copy(),
         capacity=np.asarray(pt.capacity, dtype=np.float32).copy(),
         demand_rows=(rows, np.asarray(pt.demand, np.float32)[rows]),
-        eligible_rows=(rows, np.asarray(pt.eligible, bool)[rows]))
+        eligible_rows=(rows, np.asarray(pt.eligible, bool)[rows]),
+        conflict_rows=rows)
 
 
 _MERGE_ARG_NAMES = ("prob", "assignment", "node_valid", "capacity",
-                    "dem_idx", "dem_val", "elig_idx", "elig_rows", "n_real")
+                    "dem_idx", "dem_val", "elig_idx", "elig_rows",
+                    "conf_idx", "conf_val", "n_real")
 
 # the donated (S, .) buffers whose in-place reuse the merge kernels exist
 # for; small node-state leaves may or may not alias (XLA's choice) and
@@ -128,14 +130,13 @@ _MERGE_MUST_ALIAS = ("prob.demand", "prob.eligible", "prob.conflict_ids",
 
 def _merge_case(rp, pt, tier: str,
                 out_shardings: Optional[dict]) -> KernelCase:
-    uploads, n_real, has_demand, has_eligible = rp.merge_inputs(
-        pt, _rich_delta(pt))
+    uploads, n_real, statics = rp.merge_inputs(pt, _rich_delta(pt))
     if rp.assignment is None:
         rp.adopt_host(np.zeros(pt.S, np.int32), pt.node_valid, warm=False)
     return KernelCase(
         tier=tier, fn=rp._merge(),
         args=(rp.prob, rp.assignment, *uploads, n_real),
-        kwargs=dict(has_demand=has_demand, has_eligible=has_eligible),
+        kwargs=statics,
         arg_names=_MERGE_ARG_NAMES,
         out_shardings=out_shardings)
 

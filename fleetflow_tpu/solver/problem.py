@@ -225,27 +225,63 @@ class DeviceProblem:
         return self.eligible.dtype == jnp.uint32
 
 
-def _unify_conflict_ids(pt: ProblemTensors) -> np.ndarray:
-    """Concatenate the three id families into one id space, compacting out
-    unused slots per row."""
-    parts = []
-    offset = 0
-    for arr in (pt.port_ids, pt.volume_ids, pt.anti_ids):
-        shifted = np.where(arr >= 0, arr + offset, -1)
-        if arr.size:
-            offset += int(arr.max(initial=-1)) + 1
-        parts.append(shifted)
-    merged = np.concatenate(parts, axis=1)
+def conflict_offsets(pt: ProblemTensors) -> tuple[int, int]:
+    """Where the volume ids and the anti-affinity ids start in the one id
+    space `_unify_conflict_ids` builds: past every port id, then past
+    every volume id."""
+    ports = int(pt.port_ids.max(initial=-1)) + 1
+    return ports, ports + int(pt.volume_ids.max(initial=-1)) + 1
+
+
+def _unify_rows(port_ids, volume_ids, anti_ids,
+                offsets: tuple[int, int]) -> np.ndarray:
+    """The three families of some rows in one id space, each row's ids
+    in descending order and -1 after them, at the families' summed
+    width."""
+    merged = np.concatenate(
+        [port_ids,
+         np.where(volume_ids >= 0, volume_ids + offsets[0], -1),
+         np.where(anti_ids >= 0, anti_ids + offsets[1], -1)], axis=1)
     # dedupe within each row (a repeated id on one service is one constraint,
-    # not a self-conflict): sort descending, blank repeats, then trim all-pad
-    # columns
+    # not a self-conflict): sort descending, blank repeats, sort again
     merged = -np.sort(-merged, axis=1)
     dup = np.zeros_like(merged, dtype=bool)
     dup[:, 1:] = (merged[:, 1:] == merged[:, :-1]) & (merged[:, 1:] >= 0)
     merged = np.where(dup, -1, merged)
-    merged = -np.sort(-merged, axis=1)
+    return -np.sort(-merged, axis=1)
+
+
+def _unify_conflict_ids(pt: ProblemTensors) -> np.ndarray:
+    """Concatenate the three id families into one id space, compacting out
+    unused slots per row."""
+    merged = _unify_rows(pt.port_ids, pt.volume_ids, pt.anti_ids,
+                         conflict_offsets(pt))
     keep = int((merged >= 0).sum(axis=1).max(initial=1))
     return merged[:, : max(keep, 1)].astype(np.int32)
+
+
+def unified_conflict_rows(pt: ProblemTensors, rows: np.ndarray,
+                          width: Optional[int] = None,
+                          offsets: Optional[tuple[int, int]] = None,
+                          ) -> Optional[np.ndarray]:
+    """Rows `rows` of `_unify_conflict_ids(pt)` at `width` columns, -1
+    padded — what a staging of `pt` at that width holds there — or None
+    where a row carries more ids than `width` (None: as many columns as
+    the rows need, at least one). `offsets` defaults to
+    `conflict_offsets(pt)`."""
+    rows = np.asarray(rows, dtype=np.int64)
+    merged = _unify_rows(pt.port_ids[rows], pt.volume_ids[rows],
+                         pt.anti_ids[rows],
+                         conflict_offsets(pt) if offsets is None
+                         else offsets)
+    if width is None:
+        width = max(int((merged >= 0).sum(axis=1).max(initial=1)), 1)
+    elif (merged[:, width:] >= 0).any():
+        return None
+    out = np.full((rows.shape[0], width), -1, dtype=np.int32)
+    k = min(width, merged.shape[1])
+    out[:, :k] = merged[:, :k]
+    return out
 
 
 def prepare_problem(pt: ProblemTensors,

@@ -13,7 +13,9 @@ remaining host round-trips:
                        assignment as device buffers across bursts
   ProblemDelta         what churn actually is: node up/down (valid-mask
                        flip), capacity drift, demand drift, arrivals into
-                       phantom rows (row scatters + an n_real bump)
+                       phantom rows (row scatters + an n_real bump), the
+                       conflict ids of the rows an arrival or departure
+                       changed
   apply_delta          ONE jitted dispatch, `donate_argnums` on the problem
                        and assignment buffers (SNIPPETS.md [1]-[3] donation
                        pattern) — the old buffers are reused in place, and
@@ -97,9 +99,10 @@ class ProblemDelta:
     Fields left None mean "unchanged" (node_valid/capacity then upload from
     the accompanying ProblemTensors, which is the truth either way). The
     contract for delta staging: the new ProblemTensors differs from the
-    resident one ONLY by fields this delta covers — anything else (new
-    conflict ids, a relowered fleet) must cold-stage, and
-    `ResidentProblem.compatible` enforces it by object identity."""
+    resident one ONLY by fields this delta covers — anything else (a
+    relowered fleet, conflict ids of rows `conflict_rows` does not name)
+    must cold-stage, and `ResidentProblem.compatible` enforces it by
+    object identity."""
     node_valid: Optional[np.ndarray] = None       # (N,) new validity mask
     capacity: Optional[np.ndarray] = None         # (N, R) new capacity
     # demand drift / arrivals: (rows (k,), values (k, R))
@@ -108,6 +111,11 @@ class ProblemDelta:
     eligible_rows: Optional[tuple[np.ndarray, np.ndarray]] = None
     # new real-row count (arrivals activate phantom rows; None = unchanged)
     n_real: Optional[int] = None
+    # rows (k,) whose conflict ids (ports, volumes, anti-affinity) changed:
+    # an arrival that declares a key, a departure whose ids are cleared.
+    # Their ids are read from the accompanying ProblemTensors, as
+    # node_valid and capacity are, and scattered at the staging's width
+    conflict_rows: Optional[np.ndarray] = None
 
 
 def _row_tier(k: int) -> int:
@@ -129,7 +137,8 @@ def _merge_fn():
     import jax.numpy as jnp
 
     def merge(prob, assignment, node_valid, capacity, dem_idx, dem_val,
-              elig_idx, elig_rows, n_real, *, has_demand, has_eligible):
+              elig_idx, elig_rows, conf_idx, conf_val, n_real, *,
+              has_demand, has_eligible, has_conflict):
         with jax.named_scope("resident.merge"):     # metadata only
             # scatter rows ride padded tiers; pad slots carry an out-of-range
             # index and mode="drop" discards them. The static has_* flags keep
@@ -139,6 +148,9 @@ def _merge_fn():
                       if has_demand else prob.demand)
             eligible = (prob.eligible.at[elig_idx].set(elig_rows, mode="drop")
                         if has_eligible else prob.eligible)
+            conflict_ids = (
+                prob.conflict_ids.at[conf_idx].set(conf_val, mode="drop")
+                if has_conflict else prob.conflict_ids)
             # re-park phantom rows on a valid node: the previous winner may
             # have left them on a node this delta just killed, and a phantom
             # on an invalid node is the one way it stops being inert
@@ -146,7 +158,8 @@ def _merge_fn():
             ar = jnp.arange(prob.S)
             assignment = jnp.where(ar >= n_real, first_valid, assignment)
             prob = dataclasses.replace(
-                prob, demand=demand, eligible=eligible, node_valid=node_valid,
+                prob, demand=demand, eligible=eligible,
+                conflict_ids=conflict_ids, node_valid=node_valid,
                 capacity=capacity, n_real=n_real)
             return prob, assignment
 
@@ -154,7 +167,8 @@ def _merge_fn():
     # the merge lands, so XLA reuses them in place — no second copy of the
     # (S, N) planes ever exists (SNIPPETS.md [1]-[3])
     return jax.jit(merge, donate_argnums=(0, 1),
-                   static_argnames=("has_demand", "has_eligible"))
+                   static_argnames=("has_demand", "has_eligible",
+                                    "has_conflict"))
 
 
 class ResidentProblem:
@@ -203,7 +217,14 @@ class ResidentProblem:
         self._mirror_feasible: bool = False
         self._index: Any = None
         self._pending_rows: Optional[np.ndarray] = None
+        # of those rows, the ones that held nothing before the delta that
+        # named them: arrivals, whose conflict partners the planner keeps
+        # frozen (solver/subsolve.py `ActiveIndex.closure`)
+        self._pending_fresh: Optional[np.ndarray] = None
         self._pending_churn: bool = False
+        # where the volume and anti-affinity ids start in the staged
+        # conflict plane (solver/problem.conflict_offsets)
+        self._conf_offsets: tuple[int, int] = (0, 0)
         self.cold_stage(pt)
 
     # -- staging -----------------------------------------------------------
@@ -214,7 +235,7 @@ class ResidentProblem:
         import jax.numpy as jnp
 
         from .buckets import stage_problem_tiers
-        from .problem import prepare_problem
+        from .problem import conflict_offsets, prepare_problem
 
         if self.bucket:
             # arena staging (compile-free), but with PRIVATE device
@@ -244,7 +265,9 @@ class ResidentProblem:
         self._mirror_feasible = False
         self._index = None
         self._pending_rows = None
+        self._pending_fresh = None
         self._pending_churn = False
+        self._conf_offsets = conflict_offsets(pt)
         _M_REUSE.inc(outcome="cold")
 
     def compatible(self, pt, delta: Optional[ProblemDelta] = None) -> bool:
@@ -266,10 +289,12 @@ class ResidentProblem:
             return self._arrivals_compatible(pt, delta, old)
         if self.bucket and self._expected_padded_S(pt) != self.prob.S:
             return False
-        same = (pt.port_ids is old.port_ids
+        if not (pt.port_ids is old.port_ids
                 and pt.volume_ids is old.volume_ids
                 and pt.anti_ids is old.anti_ids
-                and pt.coloc_ids is old.coloc_ids
+                or self._ids_fit(pt, delta)):
+            return False
+        same = (pt.coloc_ids is old.coloc_ids
                 and pt.node_topology is old.node_topology
                 and pt.preferred is old.preferred)
         if delta is None or delta.demand_rows is None:
@@ -284,11 +309,12 @@ class ResidentProblem:
         still ride the delta path? Yes when the new rows activate phantom
         rows already on device: the fleet stays inside the padded tier,
         the delta writes the arrivals' demand + eligibility and bumps
-        n_real, and the appended rows bring no new hard-constraint ids
-        (the padded id planes already read -1 there). Anything richer —
-        a crossed tier, an arrival with ports/volumes/anti-affinity, a
-        preference plane — cold-stages. `same_tier=False` asks the same
-        of the rows and leaves the tier out (`grown_by`)."""
+        n_real, and the appended rows bring no hard-constraint id the
+        delta does not scatter (`conflict_rows`, within the staged width
+        and group count: `_ids_fit`; the padded id planes read -1 in
+        every other phantom row). Anything richer — a crossed tier, a
+        colocation id, a preference plane — cold-stages. `same_tier=False`
+        asks the same of the rows and leaves the tier out (`grown_by`)."""
         if delta is None or delta.n_real != pt.S or pt.S <= old.S:
             return False
         if same_tier and (not self.bucket or
@@ -303,13 +329,32 @@ class ResidentProblem:
         if (pt.node_topology is not old.node_topology
                 or pt.preferred is not None or old.preferred is not None):
             return False
+        scattered = delta.conflict_rows is not None
         for name in ("port_ids", "volume_ids", "anti_ids", "coloc_ids"):
+            if scattered and name != "coloc_ids":
+                continue        # the delta's rows; `_ids_fit` below
             a, b = getattr(pt, name), getattr(old, name)
             if (a.shape[1] != b.shape[1]
                     or not np.array_equal(a[:old.S], b)
                     or (a[old.S:] != -1).any()):
                 return False
-        return True
+        return not (scattered and same_tier) or self._ids_fit(pt, delta)
+
+    def _ids_fit(self, pt, delta: Optional[ProblemDelta]) -> bool:
+        """Can the delta's `conflict_rows` be scattered into this staging?
+        The id space starts its families where the staged one does, and
+        each row's ids fit the staged width and fall below its (padded)
+        group count — an arrival whose key the stage already has adds no
+        column. Otherwise the ids cold-stage."""
+        if delta is None or delta.conflict_rows is None:
+            return False
+        from .problem import conflict_offsets, unified_conflict_rows
+        if conflict_offsets(pt) != self._conf_offsets:
+            return False
+        vals = unified_conflict_rows(pt, delta.conflict_rows,
+                                     self.prob.conflict_ids.shape[1],
+                                     self._conf_offsets)
+        return vals is not None and not (vals >= self.prob.G).any()
 
     def grown_by(self, pt, delta: Optional[ProblemDelta]) -> bool:
         """Is `pt` this staging's problem with plain arrivals appended —
@@ -338,13 +383,15 @@ class ResidentProblem:
         self._mirror_feasible = old._mirror_feasible
         # capacity that shrank since `old` was solved puts its rows in the
         # active set, as on the delta path
-        self._note_churn(self.pt, delta, since=old._cap_fp)
+        self._note_churn(self.pt, delta, since=old._cap_fp, before=old.pt)
 
     def merge_inputs(self, pt, delta: Optional[ProblemDelta] = None):
         """Stage the per-burst merge-kernel inputs for `delta`: returns
-        ``(uploads, n_real, has_demand, has_eligible)`` where `uploads`
-        is the device-staged small tuple the merge kernel consumes after
-        ``(prob, assignment)``. Split out of :meth:`apply_delta` so the
+        ``(uploads, n_real, statics)`` where `uploads` is the
+        device-staged small tuple the merge kernel consumes after
+        ``(prob, assignment)`` and `statics` its static flags
+        (has_demand, has_eligible, has_conflict). Split out of
+        :meth:`apply_delta` so the
         compile-contract auditor (solver/contracts.py) can lower the
         EXACT argument shapes the production dispatch uses — not a
         hand-built approximation that would drift. Mutates `self.n_real`
@@ -393,6 +440,16 @@ class ResidentProblem:
                 elig_idx, elig_rows = pad_rows((idx, masks), N, bool)
         else:
             elig_idx, elig_rows = None, None
+        has_conflict = delta.conflict_rows is not None
+        conf_idx = conf_val = None
+        if has_conflict:
+            from .problem import unified_conflict_rows
+            K = self.prob.conflict_ids.shape[1]
+            rows = np.asarray(delta.conflict_rows, dtype=np.int32)
+            conf_idx, conf_val = pad_rows(
+                (rows, unified_conflict_rows(pt, rows, K,
+                                             self._conf_offsets)),
+                K, np.int32)
         if delta.n_real is not None:
             self.n_real = int(delta.n_real)
         n_real = self._put_n_real()
@@ -400,31 +457,48 @@ class ResidentProblem:
         # explicit small uploads; the warm solve after the merge runs
         # with everything already resident
         uploads = self._put_small(
-            (valid, cap, dem_idx, dem_val, elig_idx, elig_rows))
+            (valid, cap, dem_idx, dem_val, elig_idx, elig_rows, conf_idx,
+             conf_val))
         # host fingerprints adopted by apply_delta AFTER a successful
         # merge (drifted() must keep matching the pre-merge staging when
         # the merge fails and cold_stage recovers)
         self._staged_fp = (valid, cap)
-        return uploads, n_real, has_demand, has_eligible
+        return uploads, n_real, dict(has_demand=has_demand,
+                                     has_eligible=has_eligible,
+                                     has_conflict=has_conflict)
 
     def _note_churn(self, pt, delta: Optional[ProblemDelta],
-                    since: Optional[np.ndarray] = None) -> None:
+                    since: Optional[np.ndarray] = None,
+                    before=None) -> None:
         """Accumulate the row set this delta touches for the active-set
         planner (solver/subsolve.py) — called BEFORE the fingerprints
         roll over so capacity shrink is measured against the staging the
         mirror assignment was solved on (`since`, where that was another
         staging's: `inherit`). Node kills need no bookkeeping here:
         stranded rows are recomputed from the post-delta tensors at plan
-        time."""
+        time. Of the rows, those the delta gives demand that held none in
+        `before` (the problem the mirror was solved on; default this
+        staging's) are fresh: arrivals into a phantom or tombstone row."""
         if not self.supports_subsolve or self._mirror is None:
             return    # nothing to localize against (no previous solve)
         rows = [np.empty(0, dtype=np.int64)]
+        fresh = [np.empty(0, dtype=np.int64)]
         if delta is not None:
             if delta.demand_rows is not None:
-                rows.append(np.asarray(delta.demand_rows[0],
-                                       dtype=np.int64))
+                drows = np.asarray(delta.demand_rows[0], dtype=np.int64)
+                rows.append(drows)
+                old = np.asarray((self.pt if before is None
+                                  else before).demand)
+                held = np.zeros(drows.shape[0], dtype=bool)
+                inside = drows < old.shape[0]
+                held[inside] = old[drows[inside]].any(axis=1)
+                asks = np.asarray(delta.demand_rows[1]).any(axis=1)
+                fresh.append(drows[asks & ~held])
             if delta.eligible_rows is not None:
                 rows.append(np.asarray(delta.eligible_rows[0],
+                                       dtype=np.int64))
+            if delta.conflict_rows is not None:
+                rows.append(np.asarray(delta.conflict_rows,
                                        dtype=np.int64))
         # capacity shrink: frozen rows on a shrunk node may overflow the
         # new capacity — they must join the active set (growth is safe)
@@ -441,6 +515,10 @@ class ResidentProblem:
         if self._pending_rows is not None:
             pending = np.union1d(self._pending_rows, pending)
         self._pending_rows = pending
+        fresh = np.unique(np.concatenate(fresh))
+        if self._pending_fresh is not None:
+            fresh = np.union1d(self._pending_fresh, fresh)
+        self._pending_fresh = fresh
         self._pending_churn = True
 
     def apply_delta(self, pt, delta: Optional[ProblemDelta] = None) -> float:
@@ -451,14 +529,12 @@ class ResidentProblem:
         KB — the (S, N) problem planes are what never move)."""
         with phase("sched.stage.delta") as ph:
             self._note_churn(pt, delta)
-            uploads, n_real, has_demand, has_eligible = self.merge_inputs(
-                pt, delta)
+            uploads, n_real, statics = self.merge_inputs(pt, delta)
             valid, cap = self._staged_fp
             # ONE donated merge dispatch
             try:
                 self.prob, self.assignment = self._merge()(
-                    self.prob, self.assignment, *uploads, n_real,
-                    has_demand=has_demand, has_eligible=has_eligible)
+                    self.prob, self.assignment, *uploads, n_real, **statics)
             except Exception:
                 # a failed merge leaves donated buffers in an unknown state:
                 # the only safe recovery is a full cold restage
@@ -469,6 +545,11 @@ class ResidentProblem:
             self.pt = pt
             self._valid_fp = valid.copy()
             self._cap_fp = cap.copy()
+            changed = None if delta is None else delta.conflict_rows
+            if self._index is not None and (changed is not None
+                                            or pt.S != self._index.S):
+                # the planner's index follows the rows the delta changed
+                self._index.update(pt, () if changed is None else changed)
             if self._mirror is not None:
                 # replay the merge kernel's deterministic phantom re-park so
                 # the mirror stays an exact host copy of the device assignment
@@ -607,6 +688,7 @@ class ResidentProblem:
         if feasible is not None:
             self._mirror_feasible = bool(feasible)
         self._pending_rows = None
+        self._pending_fresh = None
         self._pending_churn = False
 
     def take_active_plan(self):
@@ -616,6 +698,7 @@ class ResidentProblem:
         "localized"/"fallback_infeasible" are counted by the caller after
         the exact gate rules."""
         pending, self._pending_rows = self._pending_rows, None
+        fresh, self._pending_fresh = self._pending_fresh, None
         churn, self._pending_churn = self._pending_churn, False
         if not churn:
             return None
@@ -627,16 +710,14 @@ class ResidentProblem:
         if self._mirror is None or not self._mirror_feasible:
             return None
         if self._index is None:
-            # ids cannot drift on the delta path (compatible() pins them
-            # by object identity; appended arrival rows carry none), so
-            # the index built from the current tensors stays valid for
-            # the staging's whole life
+            # built once a staging; a delta that changes rows' conflict
+            # ids or appends rows updates it for those rows (apply_delta)
             self._index = ActiveIndex(self.pt)
         plan, outcome = plan_active(
             self._index, self.pt, self._mirror, self.prob.S, self.prob.T,
             pending if pending is not None
             else np.empty(0, dtype=np.int64), cfg,
-            G_full=self.prob.G, Gc_full=self.prob.Gc)
+            G_full=self.prob.G, Gc_full=self.prob.Gc, fresh_rows=fresh)
         if plan is None:
             record_outcome(outcome)
         return plan

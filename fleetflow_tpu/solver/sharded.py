@@ -818,7 +818,8 @@ def _merge_fn_sharded(mesh: Mesh):
     rep = NamedSharding(mesh, P())
 
     def merge(prob, assignment, node_valid, capacity, dem_idx, dem_val,
-              elig_idx, elig_rows, n_real, *, has_demand, has_eligible):
+              elig_idx, elig_rows, conf_idx, conf_val, n_real, *,
+              has_demand, has_eligible, has_conflict):
         cst = jax.lax.with_sharding_constraint
         demand = (cst(prob.demand.at[dem_idx].set(dem_val, mode="drop"),
                       svc2)
@@ -826,19 +827,23 @@ def _merge_fn_sharded(mesh: Mesh):
         eligible = (cst(prob.eligible.at[elig_idx].set(elig_rows,
                                                        mode="drop"), svc2)
                     if has_eligible else prob.eligible)
+        conflict_ids = (cst(prob.conflict_ids.at[conf_idx].set(
+            conf_val, mode="drop"), svc2)
+            if has_conflict else prob.conflict_ids)
         # re-park phantom rows on a valid node (see resident._merge_fn)
         first_valid = jnp.argmax(node_valid).astype(jnp.int32)
         ar = jnp.arange(prob.S)
         assignment = cst(jnp.where(ar >= n_real, first_valid, assignment),
                          svc1)
         prob = dataclasses.replace(
-            prob, demand=demand, eligible=eligible,
+            prob, demand=demand, eligible=eligible, conflict_ids=conflict_ids,
             node_valid=cst(node_valid, rep), capacity=cst(capacity, rep),
             n_real=n_real)
         return prob, assignment
 
     return jax.jit(merge, donate_argnums=(0, 1),
-                   static_argnames=("has_demand", "has_eligible"))
+                   static_argnames=("has_demand", "has_eligible",
+                                    "has_conflict"))
 
 
 class ShardedResident(ResidentProblem):

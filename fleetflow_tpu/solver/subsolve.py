@@ -12,10 +12,14 @@ exactly that set:
                  staging: unified conflict ids, coloc ids, dependency
                  adjacency and replica groups, each inverted id -> rows
   plan_active    the closure rule: affected rows ∪ rows sharing any
-                 conflict/coloc id ∪ dependency neighbors ∪ replica
-                 siblings, padded onto a mini tier ladder
-                 (256/512/1024/... — buckets.subsolve_tier) so the
-                 localized executable compiles once per tier
+                 conflict/coloc id with an affected incumbent ∪
+                 dependency neighbors ∪ replica siblings, padded onto a
+                 mini tier ladder (256/512/1024/... —
+                 buckets.subsolve_tier) so the localized executable
+                 compiles once per tier. A fresh arrival (a row that
+                 held nothing before the delta) pulls in no conflict
+                 partner: they are incumbents, which the frozen
+                 occupancy makes it avoid exactly
   subsolve       ONE jitted dispatch: gather the closure rows' planes
                  from the resident problem, seed the mini anneal's
                  carried state with the FROZEN remainder (load / conflict
@@ -38,8 +42,12 @@ dispatch's last act is `kernels.exact_stats_and_soft` on the full
 problem: a gate-rejected sub-solve is DISCARDED and the full fused path
 re-runs from the ORIGINAL seed (which is why the kernel never donates
 the assignment — see the scatter note in the kernel body). Closures
-above ``FLEET_SUBSOLVE_FRAC`` of the real rows (or past the tier
-ladder) fall back up front.
+whose incumbents (the rows that held something before the churn) pass
+``FLEET_SUBSOLVE_FRAC`` of the real rows, or that run past the tier
+ladder, fall back up front; so does a closure with an incumbent in a
+problem no bigger than its tier. A closure of fresh arrivals alone is
+localized whatever its share: the full path has no placement of theirs
+to keep, and it may move the incumbents they land beside.
 
 Knobs: FLEET_SUBSOLVE=0 disables; FLEET_SUBSOLVE_FRAC (default 0.25) is
 the closure cap as a fraction of real rows; FLEET_SUBSOLVE_MIN (default
@@ -76,6 +84,11 @@ _M_SUB = REGISTRY.counter(
     "a sub-problem to win, fallback_infeasible = the sub-solve landed "
     "infeasible and the full fused path re-ran",
     labels=("outcome",))
+_M_SUB_CLOSURE_ROWS = REGISTRY.counter(
+    "fleet_solver_subsolve_closure_rows_total",
+    "Real rows of the closures the active-set planner built, one closure "
+    "an attempt: over fleet_solver_subsolve_total, the mean closure of a "
+    "sub-solve attempt")
 _M_SUB_ROWS = REGISTRY.gauge(
     "fleet_solver_subsolve_rows",
     "Closure size (real rows) of the most recent active-set sub-solve")
@@ -150,7 +163,8 @@ class ActiveIndex:
     """Host constraint index over a resident staging's ProblemTensors:
     everything the closure rule needs to expand an affected set, built
     once per cold staging (O(S*K) numpy — the same order as staging
-    itself) and reused every burst."""
+    itself) and reused every burst; a delta that changes rows' conflict
+    ids or appends rows `update`s it for those rows."""
 
     def __init__(self, pt):
         from .problem import _unify_conflict_ids
@@ -166,6 +180,35 @@ class ActiveIndex:
         for i, base in enumerate(pt.replica_of or ()):
             self._groups.setdefault(base, []).append(i)
 
+    def update(self, pt, rows) -> None:
+        """Follow `pt`, which differs from the problem indexed by the
+        conflict ids of rows `rows` and by rows appended: those rows'
+        ids re-read, the id inversion redone, appended rows given their
+        replica group. Dependencies are not re-read: an appended row has
+        none (cp/admission.py streams no dependency)."""
+        from .problem import unified_conflict_rows
+        grow = pt.S - self.S
+        if grow > 0:
+            self.conflict = np.vstack([self.conflict, np.full(
+                (grow, self.conflict.shape[1]), -1, dtype=np.int32)])
+            self.coloc = np.vstack([self.coloc, np.full(
+                (grow, self.coloc.shape[1]), -1, dtype=np.int32)])
+            for i, base in enumerate(pt.replica_of[self.S:pt.S], self.S):
+                self._groups.setdefault(base, []).append(i)
+            self.S = pt.S
+        self.pt = pt
+        rows = np.asarray(rows, dtype=np.int64)
+        if not rows.size:
+            return
+        ids = unified_conflict_rows(pt, rows)
+        wider = ids.shape[1] - self.conflict.shape[1]
+        if wider > 0:
+            self.conflict = np.hstack([self.conflict, np.full(
+                (self.S, wider), -1, dtype=np.int32)])
+        self.conflict[rows] = -1
+        self.conflict[rows, :ids.shape[1]] = ids
+        self._conf_inv = _invert_ids(self.conflict)
+
     @staticmethod
     def _rows_sharing(inv, ids: np.ndarray) -> np.ndarray:
         uniq, offs, rows = inv
@@ -179,24 +222,33 @@ class ActiveIndex:
             return np.empty(0, np.int64)
         return np.concatenate([rows[offs[p]:offs[p + 1]] for p in pos])
 
-    def closure(self, affected: np.ndarray) -> np.ndarray:
+    def closure(self, affected: np.ndarray,
+                fresh: Optional[np.ndarray] = None) -> np.ndarray:
         """One-level constraint closure of `affected` (sorted, unique):
-        rows sharing any conflict or coloc id, dependency neighbors
-        (either direction), replica siblings. One level suffices for
-        correctness — the frozen-base occupancy makes second-order
-        interactions exact in the sub-problem — and keeps the closure
-        from percolating to the whole fleet through id chains."""
+        rows sharing any conflict or coloc id with an affected row that
+        is not `fresh`, dependency neighbors (either direction), replica
+        siblings. One level suffices for correctness — the frozen-base
+        occupancy makes second-order interactions exact in the
+        sub-problem — and keeps the closure from percolating to the
+        whole fleet through id chains. A fresh row (an arrival: it held
+        nothing before) pulls in no id partner: they are incumbents with
+        no reason to move, and the frozen occupancy keeps it off them."""
         affected = np.unique(affected)
         inside = affected[affected < self.S]
+        placed = (inside if fresh is None
+                  else inside[~np.isin(inside, fresh)])
         out = [affected]
-        if inside.size:
+        if placed.size:
             out.append(self._rows_sharing(self._conf_inv,
-                                          self.conflict[inside].ravel()))
+                                          self.conflict[placed].ravel()))
             out.append(self._rows_sharing(self._coloc_inv,
-                                          self.coloc[inside].ravel()))
-            if self._dep.size:
-                nbr = (self._dep[inside].any(axis=0)
-                       | self._dep[:, inside].any(axis=1))
+                                          self.coloc[placed].ravel()))
+        if inside.size:
+            # rows appended since the index was built have no dependency
+            dep = inside[inside < self._dep.shape[0]]
+            if self._dep.size and dep.size:
+                nbr = (self._dep[dep].any(axis=0)
+                       | self._dep[:, dep].any(axis=1))
                 out.append(np.nonzero(nbr)[0])
             for i in inside:
                 base = (self.pt.replica_of[i]
@@ -249,7 +301,8 @@ class ActivePlan:
 def plan_active(index: ActiveIndex, pt, mirror: np.ndarray, padded_S: int,
                 T: int, pending_rows: np.ndarray,
                 cfg: Optional[SubsolveConfig] = None,
-                G_full: int = 1 << 30, Gc_full: int = 1 << 30
+                G_full: int = 1 << 30, Gc_full: int = 1 << 30,
+                fresh_rows: Optional[np.ndarray] = None,
                 ) -> tuple[Optional[ActivePlan], str]:
     """Build the localized sub-problem for the churn accumulated since
     the last solve. Returns (plan, outcome): plan None means the caller
@@ -260,9 +313,12 @@ def plan_active(index: ActiveIndex, pt, mirror: np.ndarray, padded_S: int,
     `mirror` is the host copy of the resident PADDED assignment as of the
     previous solve (phantom re-parks replayed); `pending_rows` the rows
     churn deltas touched (arrivals, tombstones, demand/eligibility
-    drift, rows on capacity-shrunk nodes). Stranded rows (previous node
-    now invalid or ineligible) are recomputed here from the post-delta
-    tensors, so killed nodes need no separate bookkeeping."""
+    drift, rows on capacity-shrunk nodes), `fresh_rows` those of them
+    that held nothing before (arrivals: `ActiveIndex.closure`; the size
+    caps count the closure's other rows, the incumbents). Stranded rows
+    (previous node now invalid or ineligible) are recomputed here from
+    the post-delta tensors, so killed nodes need no separate
+    bookkeeping."""
     cfg = cfg or subsolve_config()
     S = pt.S                         # real rows of the post-delta problem
     prev = mirror[:S]
@@ -277,16 +333,21 @@ def plan_active(index: ActiveIndex, pt, mirror: np.ndarray, padded_S: int,
         # 0-sweep exit is already optimal, and a 0-row sub-problem would
         # only add a gate pass
         return None, "fallback_small"
-    rows = index.closure(affected)
+    rows = index.closure(affected, fresh_rows)
     rows = rows[rows < S]
     k = int(rows.size)
-    if k > max(cfg.frac * S, 1):
+    _M_SUB_CLOSURE_ROWS.inc(k)
+    # the caps weigh the incumbents a mini anneal would re-decide: a
+    # fresh arrival has no placement to keep
+    placed = (k if fresh_rows is None
+              else k - int(np.isin(rows, fresh_rows).sum()))
+    if placed > max(cfg.frac * S, 1):
         return None, "fallback_closure"
     tier = subsolve_tier(k, minimum=cfg.min_tier,
                          maximum=SUBSOLVE_MAX_TIER)
     if tier == 0:
         return None, "fallback_closure"
-    if tier >= S:
+    if tier >= S and placed:
         return None, "fallback_small"
 
     N = pt.N
@@ -419,8 +480,11 @@ def _subsolve_fn():
                 sticky_w=jnp.asarray(migration_weight, jnp.float32))
             st0 = chain_states_from_assignment(
                 sub_a, seed_sub, base=(load0, used0, coloc0, topo0))
+            # a closure row that shares a conflict id with another on its
+            # node (fresh arrivals parked together, or on a frozen
+            # carrier's server) is relocated up front, like a stranded one
             st0, prerepair_applied = prerepair_state_counted(
-                sub_a, st0, prerepair_moves)
+                sub_a, st0, prerepair_moves, conflicted=True)
             init_states = jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x[None], (chains,) + x.shape), st0)
             inits = jnp.broadcast_to(st0.assignment[None], (chains, S_sub))

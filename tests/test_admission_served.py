@@ -427,15 +427,16 @@ def test_a_stage_that_outgrows_its_tier_keeps_its_incumbents():
     _run(go())
 
 
-@pytest.mark.xfail(strict=True, reason=(
-    "Not held yet (PERF.md §7, ROADMAP 2A): a stage too small for the "
-    "sub-solve (under 256 rows, FLEET_SUBSOLVE_MIN) takes the full warm "
-    "path, where arrivals are parked on the first valid server and the "
-    "stickiness bonus is the same for an incumbent as for a parked "
-    "arrival: once that server overflows the annealer moves whichever it "
-    "likes. 150 nodes, 30 init pods, 40 arrivals at batch_max 8: the "
-    "fifth micro-solve moves 12 rows (1 init pod, 11 pods of this wave)"))
 def test_the_full_warm_path_moves_no_incumbent_of_a_small_stage():
+    """A stage too small for the sub-solve's first tier (under 256 rows,
+    FLEET_SUBSOLVE_MIN as it ships): 150 nodes, 30 init pods, 40 arrivals
+    at batch_max 8. The full warm path parks arrivals on the first valid
+    server with the same stickiness bonus as an incumbent, and once that
+    server overflows moves whichever it likes (12 rows in the fifth
+    micro-solve, 1 init pod and 11 pods of this wave, before arrivals were
+    told apart). A closure of fresh arrivals alone is localized whatever
+    the stage's size (solver/subsolve.py), so the full path is not taken
+    and no incumbent moves."""
     async def go():
         model = ref.cluster(7, NODES, INIT, WAVE)
         cp = await _Cp.start(model)

@@ -141,14 +141,13 @@ class TestCanaries:
                                volume_fraction=0.2)
         rp = ResidentProblem(pt)
         rp.adopt_host(np.zeros(pt.S, np.int32), pt.node_valid, warm=False)
-        uploads, n_real, has_demand, has_eligible = rp.merge_inputs(
-            pt, _rich_delta(pt))
+        uploads, n_real, statics = rp.merge_inputs(pt, _rich_delta(pt))
         contract = KernelContract(
             name="canary.packed", module="", qualname="", cases=lambda: [])
         case = KernelCase(
             tier="dense", fn=rp._merge(),
             args=(rp.prob, rp.assignment, *uploads, n_real),
-            kwargs=dict(has_demand=has_demand, has_eligible=has_eligible),
+            kwargs=statics,
             arg_names=_MERGE_ARG_NAMES)
         rec, violations = audit_case(contract, case)
         assert rec["problem_dtypes"]["prob.eligible"] == "bool"
@@ -272,7 +271,7 @@ class TestJitSpec:
 
     @pytest.mark.parametrize("module,qualname,expect_static", [
         ("solver/resident.py", "_merge_fn.merge",
-         ["has_demand", "has_eligible"]),
+         ["has_conflict", "has_demand", "has_eligible"]),
         ("solver/sharded.py", "anneal_sharded",
          ["block", "exchange_every", "mesh",
           "proposals_per_step", "return_stats", "return_sweeps",

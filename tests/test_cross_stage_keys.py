@@ -326,10 +326,11 @@ def test_eligibility_relaxation_keeps_the_bars():
     assert relax_problem(pt, "eligibility") is None
 
 
-@pytest.mark.xfail(strict=True, reason="admit_batch solves a candidate "
-                   "that cp/admission.py built, not one lowered against "
-                   "what other stages hold (PlacementService.admit_batch)")
 def test_admit_batch_bars_held_keys():
+    """A stage's retained problem handed back to admit_batch after another
+    stage committed its key on the server one of its rows runs on: the
+    row is barred there (every barred row is, since what others hold
+    changed) and moves."""
     svc = _service()
     flow_b = _flow("q", ["live"], "port")
     placement, _ = svc.solve_stage(flow_b, "live", reserve=False)
