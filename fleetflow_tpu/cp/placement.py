@@ -1016,18 +1016,25 @@ class PlacementService:
         sums are the loop's to the last bit), and a node that carries
         only zero-demand rows (admission tombstones) has no entry. The
         values are rows of one array: consumers build new arrays from
-        them and never write in place."""
-        raw = np.asarray(placement.raw)
-        demand = np.asarray(pt.demand)
-        live = demand.any(axis=1)
-        nodes, first, slot = np.unique(raw[live], return_index=True,
-                                       return_inverse=True)
-        acc = np.zeros((nodes.shape[0], demand.shape[1]), dtype=np.float64)
-        np.add.at(acc, slot, demand[live].astype(np.float64))
-        order = np.argsort(first)
+        them and never write in place.
+
+        No sort of the rows: a bincount per resource sums the live rows
+        by server (float64, in row order, as the loop adds them), and
+        each server's first live row, taken by `minimum.at`, orders the
+        at most N servers present."""
         names = pt.node_names
-        return dict(zip([names[j] for j in nodes[order].tolist()],
-                        acc[order]))
+        n = len(names)
+        cols = np.asarray(pt.demand).T.astype(np.float64, order="C")
+        live = np.flatnonzero((cols != 0).any(axis=0))
+        at = np.asarray(placement.raw, dtype=np.intp)[live]
+        acc = np.empty((n, cols.shape[0]), dtype=np.float64)
+        for k, col in enumerate(cols):
+            acc[:, k] = np.bincount(at, weights=col[live], minlength=n)
+        first = np.full(n, live.shape[0], dtype=np.intp)
+        np.minimum.at(first, at, np.arange(live.shape[0]))
+        present = np.flatnonzero(first < live.shape[0])
+        order = present[np.argsort(first[present])]
+        return dict(zip([names[j] for j in order.tolist()], acc[order]))
 
     @staticmethod
     def _held_keys(pt: ProblemTensors,

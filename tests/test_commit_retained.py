@@ -380,7 +380,8 @@ def _row_loop(pt, placement) -> dict[str, np.ndarray]:
     return out
 
 
-def _case(S, N, R=3, *, seed=0, tombstones=(), raw=None, as_raw=np.asarray):
+def _case(S, N, R=3, *, seed=0, tombstones=(), dead=(), raw=None,
+          as_raw=np.asarray):
     rng = np.random.default_rng(seed)
     demand = (rng.random((S, R)) * (4.0, 8192.0, 500.0, 7.0, 0.1)[:R]
               ).astype(np.float32)
@@ -389,6 +390,7 @@ def _case(S, N, R=3, *, seed=0, tombstones=(), raw=None, as_raw=np.asarray):
     raw = np.asarray(raw, dtype=np.int32)
     if tombstones:      # every row on these nodes departs, and a tenth
         demand[np.isin(raw, tombstones) | (rng.random(S) < 0.1)] = 0.0
+    demand[list(dead)] = 0.0        # these rows depart
     pt = SimpleNamespace(demand=demand,
                          node_names=[f"n{j}" for j in range(N)])
     return pt, SimpleNamespace(raw=as_raw(raw))
@@ -410,6 +412,17 @@ DEMAND_CASES = {
     # R is the demand's second axis, not a literal 3
     "five_resources": lambda: _case(300, 20, R=5, seed=4),
     "one_resource": lambda: _case(300, 20, R=1, seed=5),
+    # the benchmark's pod stage: 100,000 rows over 1,000 nodes, nodes 7
+    # and 500 tombstones only, and a tenth of the others departed
+    "pod_size": lambda: _case(100_000, 1000, seed=6, tombstones=(7, 500)),
+    # first live rows on n15, n14, ..., n0: the dict runs against the
+    # nodes' index order
+    "first_rows_reverse_node_order": lambda: _case(
+        320, 16, seed=7, raw=np.tile(np.arange(16)[::-1], 20)),
+    # n1's rows 0 and 2 are tombstones, its row 4 is live: it enters
+    # after n0 (row 1) and n2 (row 3)
+    "live_row_after_tombstones": lambda: _case(
+        6, 3, seed=8, raw=[1, 0, 1, 2, 1, 0], dead=(0, 2)),
 }
 
 
@@ -433,6 +446,14 @@ def test_demand_by_node_is_the_row_loops(case):
         assert list(got) == ["n2", "n0"]
     if case in ("no_rows", "every_row_a_tombstone"):
         assert got == {}
+    if case == "pod_size":
+        assert len(got) == 998 and not {"n7", "n500"} & set(got)
+    if case == "first_rows_reverse_node_order":
+        assert list(got) == [f"n{j}" for j in range(15, -1, -1)]
+    if case == "live_row_after_tombstones":
+        assert list(got) == ["n0", "n2", "n1"]
+        assert np.array_equal(got["n1"],
+                              pt.demand[4].astype(np.float64))
 
 
 BIG_SERVERS, BIG_SERVICES = 24, 300
