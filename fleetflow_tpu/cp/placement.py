@@ -1092,34 +1092,20 @@ class PlacementService:
 
     def _write_allocations(self, slugs, vectors) -> int:
         """Add row i of `vectors` ((n, 3): cpu, memory, disk) to server
-        `slugs[i]`'s `allocated`, clamped at 0, in ONE Store.update_many:
-        the new `allocated` of every server goes to the journal and to
-        the replication sink in one `upd` entry, under the store lock,
-        before this returns. Returns the server records written; a slug
-        the store no longer has is skipped."""
-        changes = {}
-        vectors = np.asarray(vectors, dtype=np.float64).tolist()
-        for slug, (cpu, memory, disk) in zip(slugs, vectors):
-            s = self.store.server_by_slug(slug)
-            if s is None:
-                continue
-            a = s.allocated
-            changes[s.id] = {"allocated": type(a)(
-                cpu=max(a.cpu + cpu, 0.0),
-                memory=max(a.memory + memory, 0.0),
-                disk=max(a.disk + disk, 0.0),
-                reserved_cpu=a.reserved_cpu,
-                reserved_memory=a.reserved_memory,
-                reserved_disk=a.reserved_disk,
-            )}
-        return self.store.update_many("servers", changes)
+        `slugs[i]`'s `allocated`, clamped at 0, in ONE
+        Store.book_allocated: the new `allocated` of every server goes to
+        the journal and to the replication sink in one `upd` entry, under
+        the store lock, before this returns. Returns the server records
+        written; a slug the store no longer has is skipped."""
+        return self.store.book_allocated(slugs, vectors)
 
     def _apply_allocation(self, r: Reservation, sign: float) -> int:
         """Add (`sign` +1) or return (-1) the whole of `r` on every node
         that carries demand of it. Returns the server records written."""
-        slugs = list(r.demand_by_node)
+        demand = r.demand_by_node
         return self._write_allocations(
-            slugs, sign * _by_slug(slugs, r.demand_by_node))
+            list(demand),
+            sign * np.array(list(demand.values()), dtype=np.float64))
 
     def _apply_allocation_delta(self, prev: Reservation,
                                 new: Reservation) -> tuple[int, list[str]]:
@@ -1137,7 +1123,7 @@ class PlacementService:
         apply(new, +1) would leave it, to floating-point rounding (the
         clamp at 0 acts on the same quantity wherever the book is
         consistent: the server still holds what `prev` booked on it), and
-        every changed record goes through Store.update_many, in one
+        every changed record goes through Store.book_allocated, in one
         journal entry. A record whose value does not change is not
         rewritten — the same state, with one visible consequence: a
         server the commit does not touch does not have `updated_at`
@@ -1364,8 +1350,8 @@ class PlacementService:
         that took its rows, not every server of the stage twice. When it
         returns, every server's `allocated` is what subtract-then-add
         would have left (to rounding), each changed record went through
-        Store.update_many — journaled, replicated — and the placement record
-        is persisted (by difference: the rows that moved, the servers
+        Store.book_allocated — journaled, replicated — and the placement
+        record is persisted (by difference: the rows that moved, the servers
         whose demand changed), so a standby or a restart reloads the same
         book: the op is committed before it is acknowledged. Servers the
         commit does not touch keep their `updated_at` (what reads it:
