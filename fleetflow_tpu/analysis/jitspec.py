@@ -13,7 +13,7 @@ from the decorator / wrapping call in the source file:
         def merge(prob, assignment, ...): ...
         return jax.jit(merge, donate_argnums=(0, 1),
                        static_argnames=("has_demand", "has_eligible",
-                                        "has_conflict"))
+                                        "has_conflict", "has_price"))
 
 Both shapes resolve to a :class:`JitDecl` carrying the static argnames
 and the donated *parameter names* (donate_argnums indices mapped through

@@ -24,8 +24,11 @@ batcher in front of that warm solve path:
               problem (tombstoned departures, row-reusing arrivals), and
               ONE micro-solve rides the resident delta path through
               `PlacementService.admit_batch`, committed as ONE reservation.
-              An arrival never preempts, whatever its `priority`: the
-              micro-solve sees live capacity only (admit_batch's docstring).
+              An arrival with a `priority` above another stage's committed
+              rows preempts them: the micro-solve counts what they hold as
+              capacity, its reservation claims the fewest per server that
+              make room, and its commit evicts them (admit_batch's
+              docstring).
   pressure()  the autoscaler feedback signal (cp/autoscaler.py): sustained
               queue age or infeasible-parked arrivals mean the SOLVER is
               the bottleneck or the fleet is full — provision nodes; a
@@ -43,16 +46,18 @@ zero-host-transfer):
     delta's `n_real` bump — when the free list is empty. At steady state
     (arrivals ~ departures) rows recirculate and S is constant.
   * streamed services must be SIMPLE: resources + optional node
-    eligibility + label-style anti-affinity (`anti_affinity`, and its
-    reach `anti_affinity_stages`), one replica, no ports/volumes/
+    eligibility + a `priority` + label-style anti-affinity
+    (`anti_affinity`, and its reach `anti_affinity_stages`), one
+    replica, no ports/volumes/
     colocation/dependencies — exactly the churn the delta path can
     express (solver/resident.py `_arrivals_compatible`: an arrival's
     conflict ids ride the delta's `conflict_rows`). Its anti-affinity
     keys are the lowering's (lower/tensors.py `anti_keys`): the fold
     writes the row's group id and the stage's `holds` / `barred_by`, a
     departure clears them, and `PlacementService.admit_batch` bars the
-    arrival from the servers on which another stage holds its key.
-    Richer services go through the full deploy path (`deploy.execute`),
+    arrival from the servers on which another stage holds its key. The
+    fold writes the row's priority into the stream's `priority`, a
+    departure clears it. Richer services go through the full deploy path (`deploy.execute`),
     which re-lowers and cold-stages honestly.
   * when the row count would cross its shape tier and tombstones exist,
     the stream COMPACTS (drops tombstone rows and cold-restages once) —
@@ -299,7 +304,7 @@ class _Stream:
 # the keys of a streamed arrival's wire spec (make_arrival); a spec that
 # carries any other is refused, never dropped
 ARRIVAL_KEYS = frozenset({"name", "image", "version", "cpu", "memory",
-                          "disk", "labels", "eligible_nodes",
+                          "disk", "labels", "eligible_nodes", "priority",
                           "anti_affinity", "anti_affinity_stages"})
 
 
@@ -421,6 +426,8 @@ class AdmissionController:
                 "version": svc.version, "cpu": svc.resources.cpu,
                 "memory": svc.resources.memory, "disk": svc.resources.disk,
                 "labels": dict(svc.labels or {})}
+        if svc.priority:
+            spec["priority"] = svc.priority
         if svc.anti_affinity:
             spec["anti_affinity"] = list(svc.anti_affinity)
             spec["anti_affinity_stages"] = {
@@ -578,7 +585,8 @@ class AdmissionController:
     def make_arrival(self, spec: dict) -> Service:
         """Build a streamed Service from a wire spec: {name, image?,
         version?, cpu?, memory?, disk?, labels?, eligible_nodes?,
-        anti_affinity?, anti_affinity_stages?} — the last two spelled as
+        priority?, anti_affinity?, anti_affinity_stages?} — `priority` an
+        integer (0 where it is absent), the last two spelled as
         core/serialize.py spells a service's (a list of labels; label ->
         the stages its reach covers), so a pod is the same bytes in
         placement.solve and in deploy.submit. `eligible_nodes` is the
@@ -600,6 +608,11 @@ class AdmissionController:
                                    disk=float(spec.get("disk", 0.0))),
             labels=dict(spec.get("labels") or {}),
         )
+        priority = spec.get("priority", 0)
+        if isinstance(priority, bool) or not isinstance(priority, int):
+            raise ValueError(f"arrival {svc.name!r}: priority is an "
+                             f"integer, not {priority!r}")
+        svc.priority = priority
         anti = spec.get("anti_affinity")
         reach = spec.get("anti_affinity_stages")
         if anti or reach:
@@ -1167,6 +1180,21 @@ class AdmissionController:
 
         if not changed_rows and not cancelled:
             return None, None, None
+        # priorities: a stage of default priorities carries None
+        # (lower/tensors.py), so a batch that brings none pays two tests
+        priority = pt.priority
+        if priority is not None or any(
+                r.service.priority for r in appended) or any(
+                r.service.priority for _row, r, _old in reuse):
+            priority = np.zeros(S2, dtype=np.int32)
+            if pt.priority is not None:
+                priority[:S] = pt.priority
+            for row, _name in tomb_rows:
+                priority[row] = 0
+            for row, r, _old in reuse:
+                priority[row] = r.service.priority
+            for j, r in enumerate(appended):
+                priority[S + j] = r.service.priority
         # anti-affinity keys: the rows this batch vacates give theirs up,
         # the arrivals that declare one take theirs. A stage whose rows
         # declare none (and a batch that brings none) pays two truth tests
@@ -1202,7 +1230,7 @@ class AdmissionController:
         pt2 = _dc.replace(pt, demand=demand, eligible=eligible,
                           dep_adj=dep_adj, dep_depth=dep_depth,
                           service_names=names, replica_of=replica_of,
-                          **ids, **keys)
+                          priority=priority, **ids, **keys)
         plan = {"appended": appended, "reuse": reuse,
                 "tomb_rows": tomb_rows, "free": free,
                 "cancelled": cancelled,

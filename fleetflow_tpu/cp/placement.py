@@ -26,7 +26,8 @@ Priority reaches across stages too: a commitment keeps a row-level view of
 itself (`Reservation.rows`), so that a stage whose rows all rank above some
 of them can be lowered with what those rows hold counted as capacity, and a
 commit of it evicts the fewest of them that make room (`PlacementService`'s
-docstring has the rules).
+docstring has the rules): a stage solved whole (`solve_stage`) and a
+micro-batch of streaming admission (`admit_batch`) alike.
 """
 
 from __future__ import annotations
@@ -41,7 +42,7 @@ import numpy as np
 
 from ..core.model import Flow, ServerLabels
 from ..lower.tensors import (Node, ProblemTensors, bar_held, lower_stage,
-                             with_preemptible)
+                             with_preemptible, with_price)
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 from ..obs.slo import observe as slo_observe
@@ -344,9 +345,11 @@ class PlacementService:
        not preempted for (kube-scheduler would evict the holder). A
        victim's keys are released with it.
 
-    `solve_stage` (so `placement.solve`) and `commit` hold these;
-    `deploy.execute` refuses a placement that needs victims, `admit_batch`
-    and the churn re-solve of `node_events` never preempt. A commitment
+    `solve_stage` (so `placement.solve`), `admit_batch` (streaming
+    admission: its docstring) and `commit` hold these; `deploy.execute`
+    refuses a placement that needs victims, the churn re-solve of
+    `node_events` never preempts. A stage that admission streams is no
+    victim (`_streamed`): its rows are the queue's book. A commitment
     reloaded from the store is no victim until `rehydrate` has lowered its
     stage again. Victims stay in their stage's retained problem as
     tombstones (no demand, masked from every view), so a later churn
@@ -380,6 +383,9 @@ class PlacementService:
         # paired with its held-key map, as last read; what those hold):
         # _held_by_others_kept
         self._held_kept: dict[str, tuple[list, dict[str, list[str]]]] = {}
+        # the stages admit_batch has solved: streaming admission keeps
+        # their rows, so no stage's commit evicts any (_lower_ranking)
+        self._streamed: set[str] = set()
         # the committed book explains servers.allocated: rebuild it from
         # the store's placements table so a restarted (or promoted
         # standby, docs/guide/13-cp-replication.md) CP's next commit
@@ -512,11 +518,13 @@ class PlacementService:
         """The commitments of stages other than `key` that have rows a
         stage of priority `p` may evict, each with the (S,) mask of those
         rows: committed, live, ranking strictly below `p`, and not yet
-        claimed as a victim by an open reservation. One truth test a
-        commitment where no row ranks below `p`. Caller holds the lock."""
+        claimed as a victim by an open reservation; none of a stage that
+        admission streams. One truth test a commitment where no row ranks
+        below `p`. Caller holds the lock."""
         out = []
         for c in self._committed.values():
-            if c.stage_key == key or c.rows is None or c.rows.floor >= p:
+            if (c.stage_key == key or c.rows is None or c.rows.floor >= p
+                    or c.stage_key in self._streamed):
                 continue
             claimed = [v[1] for r in self._reservations.values()
                        if (v := r.victim_rows.get(c.stage_key))
@@ -525,6 +533,15 @@ class PlacementService:
             if mask.any():
                 out.append((c, mask))
         return out
+
+    def _lower_floor(self, key: str) -> Optional[int]:
+        """The priority of the lowest row another stage has committed that
+        could be a victim (none of a stage admission streams), or None
+        where there is none: a stage whose rows all rank no higher need
+        not ask what it may evict. Caller holds the lock."""
+        return min((c.rows.floor for c in self._committed.values()
+                    if c.rows is not None and c.stage_key != key
+                    and c.stage_key not in self._streamed), default=None)
 
     def _preemptible_by_node(self, key: str, p: int,
                              slugs: list[str]) -> Optional[np.ndarray]:
@@ -698,9 +715,7 @@ class PlacementService:
                 # only a stage that ranks above some committed row of
                 # another pays for its own priority: its lowest row's
                 preemptor = None
-                floor = min((c.rows.floor for c in self._committed.values()
-                             if c.rows is not None and c.stage_key != key),
-                            default=None)
+                floor = self._lower_floor(key)
                 if floor is not None:
                     p = min((s.priority
                              for s in stage.resolved_services(flow)),
@@ -947,12 +962,24 @@ class PlacementService:
         what the stage's rows hold, arrivals included, so a solve of
         another stage is barred by them in turn.
 
-        An arrival through admission does NOT preempt either: capacity
-        here is live capacity, what lower-ranking committed rows hold is
-        never added to it, and no victim is selected, whatever the rows'
-        priority (tests/test_preemption.py has the `xfail`). The stage's
-        committed rows can be another stage's victims."""
+        An arrival preempts under the class docstring's rules, as a stage
+        solved whole does. Where the candidate's lowest live row ranks
+        above some other stage's committed row (`_admit_priority`: one
+        comparison where none does), what the rows it may evict hold is
+        added to its capacity and priced (the phase
+        `cp.admit_batch.preemptible`; lower/tensors.py `with_price`: the
+        delta carries it and the merge prices on device). Solved once:
+        where an arrival
+        fits on no server it may use beside the stage's rows that stand
+        (`_arrivals_fit`), with that capacity at once; else first against
+        what is free, at a price of nothing, and only if that fails again
+        with it, as solve_stage does. The victims are selected after the
+        solve (`cp.admit_batch.victims`) and the reservation claims them,
+        so its commit evicts them. A stage once priced stays priced (at
+        nothing where it may evict nothing): its staging keeps one plane.
+        Victims are never rows of a stage that admission streams."""
         with self._lock:
+            self._streamed.add(stage_key)
             with phase("cp.admit_batch.refresh", stage=stage_key):
                 view, row = self._server_rows(stage_key, pt)
                 # a node no server carries keeps the bit it has
@@ -961,50 +988,152 @@ class PlacementService:
                 valid[known] = view.schedulable[row[known]]
                 if not np.array_equal(valid, pt.node_valid):
                     pt = _dc_replace(pt, node_valid=valid)
-                pt = self._refresh_capacity(pt, stage_key, on=(view, row))
+                free = self._live_free(pt, stage_key, on=(view, row))
+                pt = self._clamped(pt, free)
             if pt.barred_by:
                 pt, delta = self._bar_admitted(stage_key, pt, delta)
-            if delta is not None:
-                # the delta always re-ships the small planes; keep them
-                # coherent with the refreshed candidate
-                delta.node_valid = pt.node_valid
-                delta.capacity = pt.capacity
-            degraded = False
-            try:
-                if self.use_tpu:
-                    new = self._sched_tpu.reschedule(pt, delta=delta,
-                                                     stage=stage_key)
-                else:
-                    new = self._sched_host.place(pt)
-            except Exception as e:
-                # same degradation contract as node_events: an admission
-                # micro-solve must cost quality, not liveness
-                _M_CHURN_FALLBACKS.inc()
-                degraded = True
-                log.error("admission solve failed; greedy host fallback %s",
-                          kv(stage=stage_key, error=e))
-                new = self._sched_host.place(pt)
-            if not new.feasible and pt.relax_order:
-                sched = (self._sched_host if degraded or not self.use_tpu
-                         else self._sched_tpu)
-                new, _ = place_with_fallback(
-                    sched, pt, initial=new,
-                    place_kwargs=({"stage": stage_key}
-                                  if sched is self._sched_tpu else None))
+            standing = self._last.get(stage_key)
+            p = self._admit_priority(stage_key, pt)
+            pre, evicts, cand = None, False, pt
+            if p is not None:
+                with phase("cp.admit_batch.preemptible") as ph:
+                    pre = self._preemptible_by_node(stage_key, p,
+                                                    pt.node_names)
+                    if pre is not None:
+                        holding = int(pre.any(axis=1).sum())
+                        _M_PREEMPTIBLE_SERVERS.inc(holding)
+                        ph.set(servers=holding)
+                        # what each node would gain: a deficit is taken
+                        # off first, as _inventory takes it
+                        pre = np.maximum(free + pre, 0.0) - pt.capacity
+                        evicts = not self._arrivals_fit(pt, delta, standing)
+                        cand = with_price(pt, pre if evicts
+                                          else np.zeros_like(pre))
+            if pre is None and pt.priced:
+                cand = with_price(pt, np.zeros_like(pt.capacity))
+            new = self._admit_solve(stage_key, cand, delta)
+            if not new.feasible and pre is not None and not evicts:
+                # it does not fit in what is free: with what lower ranks
+                # hold, then, from what stands
+                self._restore(stage_key, standing)
+                evicts = True
+                cand = with_price(pt, pre)
+                new = self._admit_solve(stage_key, cand, delta)
             if not new.feasible:
-                standing = self._last.get(stage_key)
-                if (self.use_tpu and standing is not None
-                        and standing[1].raw is not None):
-                    # the candidate is dropped: the scheduler's resident
-                    # state goes back to what stands
-                    self._sched_tpu.restore(stage_key, standing[0],
-                                            standing[1].raw)
-                return self._apply_mask(stage_key, new), None, pt
+                # the candidate is dropped: the scheduler's resident state
+                # goes back to what stands
+                self._restore(stage_key, standing)
+                return self._apply_mask(stage_key, new), None, cand
+            victims = None
+            if evicts:
+                with phase("cp.admit_batch.victims") as ph:
+                    victims = self._select_victims(stage_key, cand, new, p)
+                    ph.set(victims=sum(len(idx) for _c, idx in victims))
             self._masked[stage_key] = frozenset(masked or ())
             new = self._apply_mask(stage_key, new)
-            self._last[stage_key] = (pt, new)
-            rid = self._reserve(stage_key, pt, new)
-        return new, rid, pt
+            self._last[stage_key] = (cand, new)
+            rid = self._reserve(stage_key, cand, new, victims)
+        return new, rid, cand
+
+    def _admit_priority(self, key: str, pt: ProblemTensors
+                        ) -> Optional[int]:
+        """For `admit_batch`: the priority of the candidate's lowest live
+        row, where it ranks above some other stage's committed row that
+        could be a victim; else None — one comparison where no such row
+        ranks below any. None too for a stage that scores nodes itself: a
+        price rides no plane of its own (lower/tensors.py `with_price`)."""
+        floor = self._lower_floor(key)
+        if floor is None or (pt.preferred is not None and not pt.priced):
+            return None
+        if pt.priority is None:
+            p = 0
+        else:
+            live = np.asarray(pt.demand).any(axis=1)
+            if not live.any():
+                return None
+            p = int(pt.priority[live].min())
+        return p if p > floor else None
+
+    @staticmethod
+    def _arrivals_fit(pt: ProblemTensors, delta, standing) -> bool:
+        """Whether each arrival of the candidate fits, alone, on some valid
+        server it is eligible for beside the stage's rows that stand where
+        they are: `solve_stage`'s `_fits_free`, but sized with the stage's
+        own rows counted, which the candidate's capacity leaves out. The
+        arrivals are the rows the delta gives demand; without a delta or a
+        standing placement every live row is one."""
+        demand = np.asarray(pt.demand, dtype=np.float64)
+        arriving = demand.any(axis=1)
+        room = np.asarray(pt.capacity, dtype=np.float64)
+        raw = None if standing is None else standing[1].raw
+        if delta is not None and delta.demand_rows is not None \
+                and raw is not None:
+            stay = arriving.copy()
+            fresh = np.zeros(pt.S, dtype=bool)
+            fresh[np.asarray(delta.demand_rows[0], dtype=np.intp)] = True
+            arriving &= fresh
+            stay &= ~fresh
+            n = min(len(raw), pt.S)
+            stay[n:] = False
+            at = np.asarray(raw, dtype=np.intp)[:n][stay[:n]]
+            load = np.stack([np.bincount(at, weights=col[:n][stay[:n]],
+                                         minlength=pt.N)
+                             for col in demand.T], axis=1)
+            room = room - load
+        rows = np.flatnonzero(arriving)
+        if not rows.size:
+            return True
+        shapes, shape_of = np.unique(demand[rows], axis=0,
+                                     return_inverse=True)
+        fits = ((shapes[:, None, :] <= room[None] * (1 + _CAP_RTOL))
+                .all(axis=2) & np.asarray(pt.node_valid, dtype=bool)[None])
+        return bool((fits[shape_of.reshape(-1)]
+                     & np.asarray(pt.eligible, dtype=bool)[rows])
+                    .any(axis=1).all())
+
+    def _admit_solve(self, stage_key: str, pt: ProblemTensors,
+                     delta) -> Placement:
+        """The solve of `admit_batch`: the resident delta path (the delta
+        made coherent with the candidate it rides), or the host scheduler;
+        the greedy host fallback where the device fails, and the declared
+        relaxation ladder behind either."""
+        if delta is not None:
+            # the delta always re-ships the small planes; keep them
+            # coherent with the refreshed candidate
+            delta.node_valid = pt.node_valid
+            delta.capacity = pt.capacity
+            delta.preemptible = pt.preemptible
+        degraded = False
+        try:
+            if self.use_tpu:
+                new = self._sched_tpu.reschedule(pt, delta=delta,
+                                                 stage=stage_key)
+            else:
+                new = self._sched_host.place(pt)
+        except Exception as e:
+            # same degradation contract as node_events: an admission
+            # micro-solve must cost quality, not liveness
+            _M_CHURN_FALLBACKS.inc()
+            degraded = True
+            log.error("admission solve failed; greedy host fallback %s",
+                      kv(stage=stage_key, error=e))
+            new = self._sched_host.place(pt)
+        if not new.feasible and pt.relax_order:
+            sched = (self._sched_host if degraded or not self.use_tpu
+                     else self._sched_tpu)
+            new, _ = place_with_fallback(
+                sched, pt, initial=new,
+                place_kwargs=({"stage": stage_key}
+                              if sched is self._sched_tpu else None))
+        return new
+
+    def _restore(self, stage_key: str, standing) -> None:
+        """A candidate of `admit_batch` was not adopted: the scheduler's
+        resident state goes back to `standing`, the stage's retained
+        (problem, placement), where it has one."""
+        if (self.use_tpu and standing is not None
+                and standing[1].raw is not None):
+            self._sched_tpu.restore(stage_key, standing[0], standing[1].raw)
 
     @staticmethod
     def _demand_by_node(pt: ProblemTensors,
@@ -1393,6 +1522,7 @@ class PlacementService:
                 self._node_rows.pop(stage_key, None)
                 self._held_kept.pop(stage_key, None)
                 self._masked.pop(stage_key, None)
+                self._streamed.discard(stage_key)
                 self._sched_tpu.forget(stage_key)
             c = self._committed.pop(stage_key, None)
             if c is None:
@@ -1543,6 +1673,15 @@ class PlacementService:
         already. Returns pt unchanged (same object, so device stagings
         keyed on identity stay warm) when nothing moved; otherwise a copy
         with fresh capacity."""
+        return self._clamped(pt, self._live_free(pt, key, overrides, on))
+
+    def _live_free(self, pt: ProblemTensors, key: str,
+                   overrides: Optional[dict[str, tuple]] = None,
+                   on: Optional[tuple[ServerColumns, np.ndarray]] = None,
+                   ) -> np.ndarray:
+        """`_refresh_capacity`'s capacity before the clamp at zero, in the
+        order of `pt`'s nodes and its dtype: a node no server carries keeps
+        the capacity `pt` gives it."""
         view, row = on or self._server_rows(key, pt)
         # every server's, then the rows of this problem's nodes
         alloc = (view.booked + view.scatter(self._reserved_by_node())
@@ -1552,8 +1691,16 @@ class PlacementService:
                 alloc = (alloc - view.scatter(old_dem)
                          + view.scatter(new_dem))
         known = np.flatnonzero(row >= 0)
-        cap = pt.capacity.copy()
-        cap[known] = np.maximum(view.capacity - alloc, 0.0)[row[known]]
+        free = pt.capacity.copy()
+        free[known] = (view.capacity - alloc)[row[known]]
+        return free
+
+    @staticmethod
+    def _clamped(pt: ProblemTensors, free: np.ndarray) -> ProblemTensors:
+        """`pt` with capacity `free` clamped at zero; `pt` itself (the
+        same object, so device stagings keyed on identity stay warm) where
+        that is the capacity it has."""
+        cap = np.maximum(free, 0.0)
         if np.array_equal(cap, pt.capacity):
             return pt
         return _dc_replace(pt, capacity=cap)

@@ -46,7 +46,12 @@ may land a row on a server that is full of such rows; what using it costs
 goes into the soft plane `preferred` as the share of a server's preemptible
 capacity the row would have to take, so the annealer leans to the servers
 that need fewer evictions. Which rows are evicted is decided after the
-solve, by the caller; the solver never sees a victim.
+solve, by the caller; the solver never sees a victim. Streaming admission
+prices its candidates with `with_price` instead: the same share, in f32 and
+kept where it is constant, and the plane is all of `preferred` (`priced`),
+so that solver/resident.py's merge can compute it again on device from
+`demand`, `capacity` and `preemptible` and the price rides the resident
+delta.
 
 Spread. A stage's `placement { spread topology_key=K max_skew=M }` is the
 PodTopologySpread constraint with DoNotSchedule over all of the stage's
@@ -80,7 +85,8 @@ from ..obs import phase
 from ..obs.metrics import REGISTRY
 
 __all__ = ["ProblemTensors", "Node", "lower_stage", "anti_keys", "bar_held",
-           "with_preemptible", "dependency_depths",
+           "with_preemptible", "with_price", "preemption_price",
+           "dependency_depths",
            "LOCAL_NODE_NAME", "local_node", "synthetic_problem"]
 
 # metric catalog: docs/guide/10-observability.md
@@ -142,8 +148,12 @@ class ProblemTensors:
     #   preemptible  (N, R) f32, the part of `capacity` that lower-ranking
     #                committed rows of other stages hold, or None: none of
     #                it is
+    #   priced       `preferred` is `preemption_price` of `demand`,
+    #                `capacity` and `preemptible` and nothing else
+    #                (`with_price`)
     priority: Optional[np.ndarray] = None
     preemptible: Optional[np.ndarray] = None
+    priced: bool = False
     # Spread (module docstring): (N,) bool, the nodes that lack the spread
     # constraint's topology key, already cleared from `eligible`; None
     # where the stage spreads over nothing or every node carries the key.
@@ -355,14 +365,21 @@ def preemption_cost(demand: np.ndarray, free: np.ndarray,
     Computed over the distinct demand rows, and in steps of 1/256 so that
     the last bit of a server's book does not make a plane."""
     shapes, row_shape = np.unique(demand, axis=0, return_inverse=True)
-    over = np.maximum(shapes[:, None, :].astype(np.float64)
-                      - free[None].astype(np.float64), 0.0)
-    with np.errstate(divide="ignore", invalid="ignore"):
-        share = np.where(over > 0.0, over / preemptible[None], 0.0)
-    cost = np.rint(np.clip(share.max(axis=2), 0.0, 1.0) * 256.0) / 256.0
+    cost = _cost_of_shapes(shapes.astype(np.float64),
+                           free.astype(np.float64), preemptible)
     if (cost == cost.flat[0]).all():
         return None
     return cost.astype(np.float32)[row_shape.reshape(-1)]
+
+
+def _cost_of_shapes(shapes: np.ndarray, free: np.ndarray,
+                    preemptible: np.ndarray) -> np.ndarray:
+    """(k, N): `preemption_cost` of each distinct demand row, in the
+    arrays' own float type."""
+    over = np.maximum(shapes[:, None, :] - free[None], 0.0)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        share = np.where(over > 0.0, over / preemptible[None], 0.0)
+    return np.rint(np.clip(share.max(axis=2), 0.0, 1.0) * 256.0) / 256.0
 
 
 def with_preemptible(pt: ProblemTensors,
@@ -381,6 +398,38 @@ def with_preemptible(pt: ProblemTensors,
     return dataclasses.replace(
         pt, capacity=pt.capacity + preemptible, preemptible=preemptible,
         preferred=preferred)
+
+
+def preemption_price(demand: np.ndarray, capacity: np.ndarray,
+                     preemptible: np.ndarray) -> np.ndarray:
+    """(S, N) f32 in [-1, 0]: `preemption_cost` of each row against what
+    is free (`capacity` less `preemptible`, the capacity a priced
+    candidate carries), negated as `preferred` takes it, in f32 throughout
+    and kept where every cell reads the same. solver/resident.py's merge
+    computes the same plane on device from the same f32 arrays. Over the
+    distinct demand rows."""
+    cap = np.asarray(capacity, dtype=np.float32)
+    pre = np.asarray(preemptible, dtype=np.float32)
+    shapes, row_shape = np.unique(np.asarray(demand, dtype=np.float32),
+                                  axis=0, return_inverse=True)
+    return (-_cost_of_shapes(shapes, cap - pre, pre))[row_shape.reshape(-1)]
+
+
+def with_price(pt: ProblemTensors,
+               preemptible: np.ndarray) -> ProblemTensors:
+    """`pt`, lowered against what is free, with `preemptible` ((N, R), in
+    the order of its nodes; all zero for a stage that may evict nothing)
+    added to its capacity, recorded on it and priced into `preferred` by
+    `preemption_price` — streaming admission's `with_preemptible`. `pt`
+    has no preference plane of its own: none, or a price (`priced`), which
+    the new one replaces."""
+    if pt.preferred is not None and not pt.priced:
+        raise ValueError("with_price: the stage scores nodes itself")
+    pre = np.asarray(preemptible, dtype=np.float32)
+    cap = np.asarray(pt.capacity, dtype=np.float32) + pre
+    return dataclasses.replace(
+        pt, capacity=cap, preemptible=pre, priced=True,
+        preferred=preemption_price(pt.demand, cap, pre))
 
 
 def _topology_domains(nodes, key: str, usable: np.ndarray

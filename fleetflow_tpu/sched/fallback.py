@@ -45,7 +45,7 @@ def relax_problem(pt: ProblemTensors, what: str) -> Optional[ProblemTensors]:
     if what in _PREF:
         if pt.preferred is None:
             return None
-        return dataclasses.replace(pt, preferred=None)
+        return dataclasses.replace(pt, preferred=None, priced=False)
     if what in _SPREAD:
         if pt.max_skew <= 0:
             return None

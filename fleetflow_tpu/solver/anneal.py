@@ -122,7 +122,8 @@ def prerepair_state(prob: DeviceProblem, st: ChainState,
 
 
 def prerepair_state_counted(prob: DeviceProblem, st: ChainState,
-                            max_moves: int, *, conflicted: bool = False
+                            max_moves: int, *, conflicted: bool = False,
+                            overfull: bool = False
                             ) -> tuple[ChainState, jax.Array]:
     """Fused churn pre-repair: relocate services stranded on invalid or
     ineligible nodes, one per `lax.while_loop` iteration, entirely on
@@ -146,7 +147,13 @@ def prerepair_state_counted(prob: DeviceProblem, st: ChainState,
     pair moving first: the localized sub-solve's use (solver/subsolve.py),
     whose fresh arrivals start parked together on one node and whose
     incumbents sit in the frozen base, so a clean relocation is exactly
-    the arrival finding a server its key leaves free.
+    the arrival finding a server its key leaves free. With `overfull`
+    (static) as well, so does a service that asks for something on a node
+    over its capacity, until the node is within it: a priced sub-solve's
+    (streaming admission preempting, cp/placement.py `admit_batch`), whose
+    arrivals parked together each need a server of their own, and whose
+    fallback to the full path would move incumbents that victims were
+    evicted for.
 
     Returns ``(state, moves)`` — `moves` counts the relocations actually
     APPLIED (attempts on genuinely unplaceable services don't count):
@@ -161,6 +168,9 @@ def prerepair_state_counted(prob: DeviceProblem, st: ChainState,
             held = ids >= 0
             here = st.used[st.assignment[:, None], jnp.where(held, ids, 0)]
             out = out | ((here > 1) & held).any(-1)
+        if overfull:
+            over = (st.load > prob.capacity * (1 + 1e-6)).any(-1)
+            out = out | (over[st.assignment] & (prob.demand > 0).any(-1))
         return out
 
     def cond(carry):

@@ -658,7 +658,8 @@ def _solve(pt: ProblemTensors, *,
                             t0_d, t1_d, mw_d, chains=chains, steps=steps,
                             block=min(warm_block, anneal_block),
                             proposals_per_step=sub_props,
-                            trace_blocks=trace_blocks)
+                            trace_blocks=trace_blocks,
+                            overfull=resident.pt.priced)
                 if overlap_host_work is not None:
                     # the gate decision below synchronizes with the in-flight
                     # sub dispatch, so the overlapped host work must run NOW —

@@ -115,7 +115,7 @@ def _rich_delta(pt, n_rows: int = 3):
 
 _MERGE_ARG_NAMES = ("prob", "assignment", "node_valid", "capacity",
                     "dem_idx", "dem_val", "elig_idx", "elig_rows",
-                    "conf_idx", "conf_val", "n_real")
+                    "conf_idx", "conf_val", "preemptible", "n_real")
 
 # the donated (S, .) buffers whose in-place reuse the merge kernels exist
 # for; small node-state leaves may or may not alias (XLA's choice) and

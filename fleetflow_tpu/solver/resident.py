@@ -15,7 +15,8 @@ remaining host round-trips:
                        flip), capacity drift, demand drift, arrivals into
                        phantom rows (row scatters + an n_real bump), the
                        conflict ids of the rows an arrival or departure
-                       changed
+                       changed, what lower-ranking rows hold (a priced
+                       candidate's preference plane, recomputed on device)
   apply_delta          ONE jitted dispatch, `donate_argnums` on the problem
                        and assignment buffers (SNIPPETS.md [1]-[3] donation
                        pattern) — the old buffers are reused in place, and
@@ -116,6 +117,11 @@ class ProblemDelta:
     # Their ids are read from the accompanying ProblemTensors, as
     # node_valid and capacity are, and scattered at the staging's width
     conflict_rows: Optional[np.ndarray] = None
+    # (N, R): what lower-ranking rows hold in the candidate's capacity,
+    # which is priced (lower/tensors.py `with_price`): the merge writes
+    # the staged preference plane anew from it, `capacity` and the merged
+    # demand (`price_plane`), so the plane never crosses the host boundary
+    preemptible: Optional[np.ndarray] = None
 
 
 def _row_tier(k: int) -> int:
@@ -128,6 +134,21 @@ def _row_tier(k: int) -> int:
     return tier
 
 
+def price_plane(demand, capacity, preemptible):
+    """lower/tensors.py `preemption_price` on device: (S, N) f32, the
+    price of each row of `demand` ((S, R)) on each server of `capacity`
+    ((N, R), what lower-ranking rows hold, `preemptible`, included). The
+    same f32 arithmetic, so the plane staged from the host and the plane
+    a merge writes are one plane."""
+    import jax.numpy as jnp
+
+    over = jnp.maximum(demand[:, None, :] - (capacity - preemptible)[None],
+                       0.0)
+    share = jnp.where(over > 0.0, over / preemptible[None], 0.0)
+    return -(jnp.rint(jnp.clip(share.max(axis=2), 0.0, 1.0) * 256.0)
+             / 256.0)
+
+
 @lru_cache(maxsize=1)
 def _merge_fn():
     """The donated delta-merge kernel, built lazily so importing
@@ -137,8 +158,8 @@ def _merge_fn():
     import jax.numpy as jnp
 
     def merge(prob, assignment, node_valid, capacity, dem_idx, dem_val,
-              elig_idx, elig_rows, conf_idx, conf_val, n_real, *,
-              has_demand, has_eligible, has_conflict):
+              elig_idx, elig_rows, conf_idx, conf_val, preemptible, n_real,
+              *, has_demand, has_eligible, has_conflict, has_price):
         with jax.named_scope("resident.merge"):     # metadata only
             # scatter rows ride padded tiers; pad slots carry an out-of-range
             # index and mode="drop" discards them. The static has_* flags keep
@@ -151,6 +172,8 @@ def _merge_fn():
             conflict_ids = (
                 prob.conflict_ids.at[conf_idx].set(conf_val, mode="drop")
                 if has_conflict else prob.conflict_ids)
+            preferred = (price_plane(demand, capacity, preemptible)
+                         if has_price else prob.preferred)
             # re-park phantom rows on a valid node: the previous winner may
             # have left them on a node this delta just killed, and a phantom
             # on an invalid node is the one way it stops being inert
@@ -159,8 +182,8 @@ def _merge_fn():
             assignment = jnp.where(ar >= n_real, first_valid, assignment)
             prob = dataclasses.replace(
                 prob, demand=demand, eligible=eligible,
-                conflict_ids=conflict_ids, node_valid=node_valid,
-                capacity=capacity, n_real=n_real)
+                conflict_ids=conflict_ids, preferred=preferred,
+                node_valid=node_valid, capacity=capacity, n_real=n_real)
             return prob, assignment
 
     # donation: the stale problem/assignment buffers are dead the moment
@@ -168,7 +191,7 @@ def _merge_fn():
     # (S, N) planes ever exists (SNIPPETS.md [1]-[3])
     return jax.jit(merge, donate_argnums=(0, 1),
                    static_argnames=("has_demand", "has_eligible",
-                                    "has_conflict"))
+                                    "has_conflict", "has_price"))
 
 
 class ResidentProblem:
@@ -296,7 +319,8 @@ class ResidentProblem:
             return False
         same = (pt.coloc_ids is old.coloc_ids
                 and pt.node_topology is old.node_topology
-                and pt.preferred is old.preferred)
+                and (pt.preferred is old.preferred
+                     or self._prices(pt, delta)))
         if delta is None or delta.demand_rows is None:
             same = same and pt.demand is old.demand
         if delta is None or delta.eligible_rows is None:
@@ -313,8 +337,9 @@ class ResidentProblem:
         delta does not scatter (`conflict_rows`, within the staged width
         and group count: `_ids_fit`; the padded id planes read -1 in
         every other phantom row). Anything richer — a crossed tier, a
-        colocation id, a preference plane — cold-stages. `same_tier=False`
-        asks the same of the rows and leaves the tier out (`grown_by`)."""
+        colocation id, a preference plane other than a price the delta
+        carries (`_prices`) — cold-stages. `same_tier=False` asks the same
+        of the rows and leaves the tier out (`grown_by`)."""
         if delta is None or delta.n_real != pt.S or pt.S <= old.S:
             return False
         if same_tier and (not self.bucket or
@@ -326,8 +351,10 @@ class ResidentProblem:
         if not (np.isin(new, np.asarray(delta.demand_rows[0])).all()
                 and np.isin(new, np.asarray(delta.eligible_rows[0])).all()):
             return False
-        if (pt.node_topology is not old.node_topology
-                or pt.preferred is not None or old.preferred is not None):
+        if pt.node_topology is not old.node_topology:
+            return False
+        if ((pt.preferred is not None or old.preferred is not None)
+                and not self._prices(pt, delta, staged=same_tier)):
             return False
         scattered = delta.conflict_rows is not None
         for name in ("port_ids", "volume_ids", "anti_ids", "coloc_ids"):
@@ -339,6 +366,16 @@ class ResidentProblem:
                     or (a[old.S:] != -1).any()):
                 return False
         return not (scattered and same_tier) or self._ids_fit(pt, delta)
+
+    def _prices(self, pt, delta: Optional[ProblemDelta],
+                staged: bool = True) -> bool:
+        """Is `pt`'s preference plane a price the delta carries, which the
+        merge writes anew (`price_plane`)? Where `staged`, this staging has
+        to have a plane to write: one staged without (a stage that began to
+        preempt) stages anew."""
+        return (delta is not None and delta.preemptible is not None
+                and pt.priced and pt.preferred is not None
+                and (not staged or self.prob.preferred is not None))
 
     def _ids_fit(self, pt, delta: Optional[ProblemDelta]) -> bool:
         """Can the delta's `conflict_rows` be scattered into this staging?
@@ -390,7 +427,7 @@ class ResidentProblem:
         ``(uploads, n_real, statics)`` where `uploads` is the
         device-staged small tuple the merge kernel consumes after
         ``(prob, assignment)`` and `statics` its static flags
-        (has_demand, has_eligible, has_conflict). Split out of
+        (has_demand, has_eligible, has_conflict, has_price). Split out of
         :meth:`apply_delta` so the
         compile-contract auditor (solver/contracts.py) can lower the
         EXACT argument shapes the production dispatch uses — not a
@@ -450,6 +487,9 @@ class ResidentProblem:
                 (rows, unified_conflict_rows(pt, rows, K,
                                              self._conf_offsets)),
                 K, np.int32)
+        has_price = delta.preemptible is not None
+        pre = (np.asarray(delta.preemptible, dtype=np.float32)
+               if has_price else None)
         if delta.n_real is not None:
             self.n_real = int(delta.n_real)
         n_real = self._put_n_real()
@@ -458,14 +498,15 @@ class ResidentProblem:
         # with everything already resident
         uploads = self._put_small(
             (valid, cap, dem_idx, dem_val, elig_idx, elig_rows, conf_idx,
-             conf_val))
+             conf_val, pre))
         # host fingerprints adopted by apply_delta AFTER a successful
         # merge (drifted() must keep matching the pre-merge staging when
         # the merge fails and cold_stage recovers)
         self._staged_fp = (valid, cap)
         return uploads, n_real, dict(has_demand=has_demand,
                                      has_eligible=has_eligible,
-                                     has_conflict=has_conflict)
+                                     has_conflict=has_conflict,
+                                     has_price=has_price)
 
     def _note_churn(self, pt, delta: Optional[ProblemDelta],
                     since: Optional[np.ndarray] = None,

@@ -51,7 +51,7 @@ from .anneal import (W_CAP, W_CONF, W_ELIG, _move_delta_core, _skew_pen,
                      violation_total_from_parts)
 from .buckets import pad_problem
 from .problem import DeviceProblem, eligible_lookup
-from .resident import ResidentProblem, transfer_guard_ctx
+from .resident import ResidentProblem, price_plane, transfer_guard_ctx
 from ..obs import get_logger, kv, phase
 from ..obs.metrics import REGISTRY
 
@@ -818,8 +818,8 @@ def _merge_fn_sharded(mesh: Mesh):
     rep = NamedSharding(mesh, P())
 
     def merge(prob, assignment, node_valid, capacity, dem_idx, dem_val,
-              elig_idx, elig_rows, conf_idx, conf_val, n_real, *,
-              has_demand, has_eligible, has_conflict):
+              elig_idx, elig_rows, conf_idx, conf_val, preemptible, n_real,
+              *, has_demand, has_eligible, has_conflict, has_price):
         cst = jax.lax.with_sharding_constraint
         demand = (cst(prob.demand.at[dem_idx].set(dem_val, mode="drop"),
                       svc2)
@@ -830,6 +830,8 @@ def _merge_fn_sharded(mesh: Mesh):
         conflict_ids = (cst(prob.conflict_ids.at[conf_idx].set(
             conf_val, mode="drop"), svc2)
             if has_conflict else prob.conflict_ids)
+        preferred = (cst(price_plane(demand, capacity, preemptible), svc2)
+                     if has_price else prob.preferred)
         # re-park phantom rows on a valid node (see resident._merge_fn)
         first_valid = jnp.argmax(node_valid).astype(jnp.int32)
         ar = jnp.arange(prob.S)
@@ -837,13 +839,13 @@ def _merge_fn_sharded(mesh: Mesh):
                          svc1)
         prob = dataclasses.replace(
             prob, demand=demand, eligible=eligible, conflict_ids=conflict_ids,
-            node_valid=cst(node_valid, rep), capacity=cst(capacity, rep),
-            n_real=n_real)
+            preferred=preferred, node_valid=cst(node_valid, rep),
+            capacity=cst(capacity, rep), n_real=n_real)
         return prob, assignment
 
     return jax.jit(merge, donate_argnums=(0, 1),
                    static_argnames=("has_demand", "has_eligible",
-                                    "has_conflict"))
+                                    "has_conflict", "has_price"))
 
 
 class ShardedResident(ResidentProblem):

@@ -442,7 +442,7 @@ def _subsolve_fn():
                  used0, coloc0, topo0, n_sub, key, t0, t1,
                  migration_weight, *, chains, steps, block,
                  proposals_per_step, prerepair_moves, Gc_sub,
-                 trace_blocks=0):
+                 trace_blocks=0, overfull=False):
         # named scopes are metadata: the profiler shows the device's ops
         # under subsolve.localized/<step>, the program is the same
         with jax.named_scope("subsolve.localized/gather"):
@@ -484,7 +484,8 @@ def _subsolve_fn():
             # node (fresh arrivals parked together, or on a frozen
             # carrier's server) is relocated up front, like a stranded one
             st0, prerepair_applied = prerepair_state_counted(
-                sub_a, st0, prerepair_moves, conflicted=True)
+                sub_a, st0, prerepair_moves, conflicted=True,
+                overfull=overfull)
             init_states = jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x[None], (chains,) + x.shape), st0)
             inits = jnp.broadcast_to(st0.assignment[None], (chains, S_sub))
@@ -521,7 +522,7 @@ def _subsolve_fn():
                    static_argnames=("chains", "steps", "block",
                                     "proposals_per_step",
                                     "prerepair_moves", "Gc_sub",
-                                    "trace_blocks"))
+                                    "trace_blocks", "overfull"))
 
 
 def subsolve_cache_size() -> int:
@@ -554,10 +555,13 @@ SUB_MAX_STEPS = 16   # mini-anneal sweep budget: a feasible closure exits
 def subsolve_dispatch(prob, assignment, staged, plan: ActivePlan, key,
                       t0, t1, migration_weight, *, chains: int, steps: int,
                       block: int, proposals_per_step: int,
-                      trace_blocks: int = 0):
+                      trace_blocks: int = 0, overfull: bool = False):
     """Run the localized kernel (call under the transfer guard: every
     argument is already resident). Returns the device outputs
-    (new_assignment, stats, soft, sweeps_run, accepted, telemetry)."""
+    (new_assignment, stats, soft, sweeps_run, accepted, telemetry).
+    `overfull`: the problem is priced (lower/tensors.py `with_price`), and
+    the prologue also moves a row off a node over its capacity (anneal.py
+    `prerepair_state_counted`)."""
     prerepair_moves = max(16, min(plan.tier, 256))
     _M_SUB_ROWS.set(plan.n_sub)
     _M_SUB_TIER.set(plan.tier)
@@ -566,7 +570,7 @@ def subsolve_dispatch(prob, assignment, staged, plan: ActivePlan, key,
         chains=chains, steps=min(steps, SUB_MAX_STEPS), block=block,
         proposals_per_step=proposals_per_step,
         prerepair_moves=prerepair_moves, Gc_sub=plan.Gc_sub,
-        trace_blocks=trace_blocks)
+        trace_blocks=trace_blocks, overfull=overfull)
 
 
 def record_subsolve_ms(ms: float) -> None:

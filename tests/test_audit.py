@@ -271,7 +271,7 @@ class TestJitSpec:
 
     @pytest.mark.parametrize("module,qualname,expect_static", [
         ("solver/resident.py", "_merge_fn.merge",
-         ["has_conflict", "has_demand", "has_eligible"]),
+         ["has_conflict", "has_demand", "has_eligible", "has_price"]),
         ("solver/sharded.py", "anneal_sharded",
          ["block", "exchange_every", "mesh",
           "proposals_per_step", "return_stats", "return_sweeps",

@@ -745,10 +745,6 @@ def test_victims_ride_the_wire_and_deploy_execute_refuses_them():
     asyncio.run(asyncio.wait_for(go(), 60))
 
 
-@pytest.mark.xfail(strict=True, reason="admit_batch solves a candidate "
-                   "that cp/admission.py built against live capacity: an "
-                   "arrival through admission does not preempt "
-                   "(PlacementService.admit_batch)")
 def test_admit_batch_preempts():
     c = _Cluster.basic(6, 2)
     preview, _ = c.solve(MEASURED, reserve=False)
