@@ -644,11 +644,19 @@ class AdmissionController:
             stream = self._stream_for(stage)
             self._resync(stream)
             svcs: list[Service] = []
+            # every name is checked against a set built once a submit,
+            # so validating a wave is linear in it
+            names: set[str] = set()  # the names of `svcs`
             # arrival name -> the nodes its request may land on
             where: dict[str, list[str]] = {}
-            queued_names = {r.name for q in self._queues.values() for r in q
-                            if r.kind == "arrival"
-                            and r.stage_key == stream.key}
+            queued_names: set[str] = set()
+            # departures queued, then also those this submit accepts
+            pending_deps: set[str] = set()
+            for q in self._queues.values():
+                for r in q:
+                    if r.stage_key == stream.key:
+                        (queued_names if r.kind == "arrival"
+                         else pending_deps).add(r.name)
             arriving: Optional[set[str]] = None
             for a in arrivals:
                 svc = a if isinstance(a, Service) else self.make_arrival(a)
@@ -671,16 +679,15 @@ class AdmissionController:
                     raise ValueError(
                         f"arrival {svc.name!r} already live or queued in "
                         f"{stream.key}")
-                if svc.name in {s.name for s in svcs}:
+                if svc.name in names:
                     raise ValueError(f"duplicate arrival {svc.name!r}")
                 svcs.append(svc)
+                names.add(svc.name)
             deps: list[str] = []
-            pending_deps = {r.name for q in self._queues.values() for r in q
-                            if r.kind == "departure"
-                            and r.stage_key == stream.key}
+            parked_names: Optional[set[str]] = None
             for name in departures:
                 name = str(name)
-                if name in pending_deps or name in deps:
+                if name in pending_deps:
                     # a doubled departure would tombstone one row twice
                     # (double free-list entry -> one row handed to two
                     # arrivals); draining is idempotent, not cumulative
@@ -698,15 +705,16 @@ class AdmissionController:
                             f"service; tear it down via deploy.down")
                 live = (name in stream.row_of
                         and name not in stream.tombstones)
-                queued = name in queued_names or any(
-                    s.name == name for s in svcs)
-                parked = any(r.name == name and r.stage_key == stream.key
-                             for r in self._parked)
-                if not (live or queued or parked):
-                    raise ValueError(
-                        f"departure {name!r}: no such live, queued or "
-                        f"parked service in {stream.key}")
+                if not (live or name in queued_names or name in names):
+                    if parked_names is None:
+                        parked_names = {r.name for r in self._parked
+                                        if r.stage_key == stream.key}
+                    if name not in parked_names:
+                        raise ValueError(
+                            f"departure {name!r}: no such live, queued or "
+                            f"parked service in {stream.key}")
                 deps.append(name)
+                pending_deps.add(name)
 
             # tenant hard quota (policy, not backpressure): arrivals past
             # the cap's headroom PARK with reason "quota" — accepted and

@@ -17,6 +17,7 @@ Four layers:
 from __future__ import annotations
 
 import asyncio
+import time
 
 import pytest
 
@@ -115,6 +116,69 @@ class TestSubmitValidation:
         # the freed row is handed out exactly once
         st = ctrl.status()["streams"][key]
         assert (st["tombstones"], st["free_rows"]) == (1, 1)
+
+    @pytest.mark.parametrize("before, arrivals, departures, want", [
+        # an arrival duplicated within one wave
+        ("", ["a1", "a1"], [], "duplicate arrival 'a1'"),
+        # a departure of an arrival in the same submit is queued
+        ("", ["a1"], ["a1"], [("arrival", "a1"), ("departure", "a1")]),
+        # a departure doubled within one submit
+        ("live", [], ["a0", "a0"], "departure 'a0' is already pending"),
+        # a departure of a parked arrival is accepted
+        ("parked", [], ["whale"], [("departure", "whale")]),
+        # one unknown departure refuses the whole submit
+        ("live", ["b0"], ["a0", "nope"], "departure 'nope': no such live"),
+        # already queued AND duplicated: the first refusal wins
+        ("queued", ["a1", "a1"], [], "arrival 'a1' already live or queued"),
+    ], ids=["dup-arrival", "depart-queued-in-submit", "doubled-departure",
+            "depart-parked", "unknown-departure", "queued-beats-duplicate"])
+    def test_wave_name_checks(self, before, arrivals, departures, want):
+        """Each refusal that a wave's own names decide, with its message
+        and precedence; an accepted submit lists arrivals then
+        departures in order, a refused one enqueues nothing."""
+        w = _world()
+        ctrl = _ctrl(w)
+        ctrl.attach(w.flow, "app0")
+        if before == "live":
+            ctrl.submit("t0", arrivals=[{"name": "a0"}])
+            _drain(w, ctrl)
+        elif before == "queued":
+            ctrl.submit("t0", arrivals=[{"name": "a1"}])
+        elif before == "parked":
+            ctrl.submit("t0", arrivals=[{"name": "whale", "cpu": 1e6,
+                                         "memory": 1e9}])
+            _drain(w, ctrl)
+            assert ctrl.pressure()["parked"] == 1
+        n_requests = len(ctrl.requests)
+        depth = ctrl.pressure()["queue_depth"]
+        call = dict(arrivals=[{"name": n} for n in arrivals],
+                    departures=departures)
+        if isinstance(want, str):
+            with pytest.raises(ValueError, match=want):
+                ctrl.submit("t0", **call)
+            assert len(ctrl.requests) == n_requests
+            assert ctrl.pressure()["queue_depth"] == depth
+        else:
+            out = ctrl.submit("t0", **call)
+            assert [(ctrl.requests[i].kind, ctrl.requests[i].name)
+                    for i in out["accepted"]] == want
+
+    def test_wave_validation_is_linear(self):
+        """20,000 arrivals and their 20,000 departures in one submit:
+        every name is checked against a set, so it takes well under a
+        second on a CPU, where a check that scans the wave per name
+        takes tens of seconds."""
+        w = _world()
+        ctrl = _ctrl(w, max_queue=50_000)
+        ctrl.attach(w.flow, "app0")
+        names = [f"p{i:05d}" for i in range(20_000)]
+        t0 = time.perf_counter()
+        out = ctrl.submit("t0", arrivals=[{"name": n} for n in names],
+                          departures=names)
+        took = time.perf_counter() - t0
+        assert len(out["accepted"]) == 40_000
+        assert ctrl.pressure()["queue_depth"] == 40_000
+        assert took < 5.0, f"submit of 40,000 requests took {took:.2f} s"
 
 
 class TestBackpressure:
