@@ -23,6 +23,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from ..core.model import Backend
 from ..runtime.backend import ContainerBackend, DockerCliBackend
 from ..runtime.engine import DeployEngine, DeployRequest
 from ..sched.base import Placement, level_schedule
@@ -30,9 +31,9 @@ from ..lower.tensors import lower_stage
 from .guard import confine_path, validate_container_name
 from .monitor import AnomalyDetector, inventory_report, snapshot_backend
 from ..cp.protocol import Connection, ProtocolClient
-from ..obs import get_logger, kv, span
+from ..obs import get_logger, kv, phase, span
 from ..obs.metrics import REGISTRY
-from ..obs.trace import use_trace
+from ..obs.trace import bound_as, use_trace
 
 __all__ = ["Agent", "AgentConfig"]
 
@@ -271,8 +272,7 @@ class Agent:
             await asyncio.sleep(self.config.monitor_interval_s)
 
     async def monitor_once(self) -> None:
-        snaps = await asyncio.get_running_loop().run_in_executor(
-            None, lambda: snapshot_backend(self.backend))
+        snaps = await self._in_pool(lambda: snapshot_backend(self.backend))
         anomalies = list(self.detector.observe(snaps))
         try:
             await self.conn.send_event(
@@ -298,9 +298,25 @@ class Agent:
     # command dispatch (the envelope protocol)
     # ------------------------------------------------------------------
 
+    async def _in_pool(self, fn):
+        """`fn()` on the loop's default pool, in this context (the
+        command's trace and open phase): the hop is `agent.wait.executor`."""
+        return await asyncio.get_running_loop().run_in_executor(
+            None, bound_as("agent.wait.executor", fn))
+
     async def _on_command(self, conn: Connection, method: str,
                           envelope: dict) -> None:
-        """agent.rs command loop :129-208 + envelope :215-254.
+        """One command, from its handler running to its result written:
+        phase `agent.command` (under the trace the frame carried; a child
+        of the CP's `agents.send_batch` where both share a process)."""
+        with phase("agent.command", method=method, slug=self.config.slug):
+            await self._command(conn, method, envelope)
+
+    async def _command(self, conn: Connection, method: str,
+                       envelope: dict) -> None:
+        """agent.rs command loop :129-208 + envelope :215-254. The result
+        is a reply to the command's frame (`in_reply`): the CP files it
+        under the phase that sent the command.
 
         Idempotent redelivery: a payload carrying `idempotency_key` is
         executed AT MOST ONCE per window — a replay (the CP reconverger
@@ -324,7 +340,7 @@ class Agent:
                         await conn.send_event("agent", "command_result", {
                             "request_id": request_id,
                             "error": f"fenced: controller epoch {epoch} < "
-                                     f"{self._max_epoch}"})
+                                     f"{self._max_epoch}"}, in_reply=True)
                     except Exception:
                         pass
                 return
@@ -376,7 +392,8 @@ class Agent:
                     self._idem_inflight.pop(idem_key, None)
         if request_id:
             try:
-                await conn.send_event("agent", "command_result", reply)
+                await conn.send_event("agent", "command_result", reply,
+                                      in_reply=True)
             except Exception as e:
                 _M_SEND_FAILURES.inc(loop="command_result")
                 log.debug("command_result send failed %s", kv(
@@ -412,23 +429,22 @@ class Agent:
             return {"pong": True, "slug": self.config.slug}
 
         if method == "status":
-            snaps = await loop.run_in_executor(
-                None, lambda: snapshot_backend(self.backend))
+            snaps = await self._in_pool(lambda: snapshot_backend(self.backend))
             return {"containers": inventory_report(snaps)}
 
         if method == "restart":
             name = validate_container_name(payload["container"])
-            await loop.run_in_executor(None, lambda: self.backend.restart(name))
+            await self._in_pool(lambda: self.backend.restart(name))
             return {"restarted": name}
 
         if method == "start":
             name = validate_container_name(payload["container"])
-            await loop.run_in_executor(None, lambda: self.backend.start(name))
+            await self._in_pool(lambda: self.backend.start(name))
             return {"started": name}
 
         if method == "stop":
             name = validate_container_name(payload["container"])
-            await loop.run_in_executor(None, lambda: self.backend.stop(name))
+            await self._in_pool(lambda: self.backend.stop(name))
             return {"stopped": name}
 
         if method == "logs":
@@ -439,13 +455,18 @@ class Agent:
             raw_tail = payload.get("tail")
             tail = 100 if raw_tail is None else int(raw_tail)  # 0 is valid
             since = payload.get("since")
-            text = await loop.run_in_executor(
-                None, lambda: self.backend.logs(name, tail=tail,
-                                                since=since))
+            text = await self._in_pool(
+                lambda: self.backend.logs(name, tail=tail, since=since))
             return {"logs": text}
 
         if method == "deploy.execute":
-            req = DeployRequest.from_dict(payload["request"])
+            # the payload made into a request and this node's placement
+            with phase("agent.command.parse"):
+                req = DeployRequest.from_dict(payload["request"])
+                backend = req.flow.stage(req.stage_name).backend
+                placement = (self._placement_from(
+                    req, payload.get("assignment"))
+                    if backend is Backend.DOCKER else None)
             if not req.node:
                 req.node = self.config.slug
             # live streaming (agent.rs:257-333 mpsc analog): each deploy
@@ -458,18 +479,13 @@ class Agent:
             # dispatch by the stage's execution backend
             # (agent.rs:374-445 executes Quadlet stages via apply_stage;
             # the docker path runs the placement-sliced DeployEngine)
-            from ..core.model import Backend
-            stage = req.flow.stage(req.stage_name)
-            if stage.backend is Backend.QUADLET:
-                return await loop.run_in_executor(
-                    None, lambda: self._run_traced(
-                        req, lambda: self._deploy_quadlet(req, emit)))
-            if stage.backend is Backend.COMPOSE:
-                return await loop.run_in_executor(
-                    None, lambda: self._run_traced(
-                        req, lambda: self._deploy_compose(req, emit)))
+            if backend is Backend.QUADLET:
+                return await self._in_pool(lambda: self._run_traced(
+                    req, lambda: self._deploy_quadlet(req, emit)))
+            if backend is Backend.COMPOSE:
+                return await self._in_pool(lambda: self._run_traced(
+                    req, lambda: self._deploy_compose(req, emit)))
 
-            placement = self._placement_from(req, payload.get("assignment"))
             engine = DeployEngine(self.backend, sleep=self.sleep)
 
             def run_deploy():
@@ -481,33 +497,32 @@ class Agent:
                         req, on_event=lambda e: emit(str(e)),
                         placement=placement))
 
-            res = await loop.run_in_executor(None, run_deploy)
+            res = await self._in_pool(run_deploy)
             if not res.ok:
                 raise RuntimeError(f"failed services: {res.failed}")
             return {"deployed": res.deployed, "removed": res.removed,
                     "duration_s": res.duration_s}
 
         if method == "deploy.down":
-            req = DeployRequest.from_dict(payload["request"])
+            with phase("agent.command.parse"):
+                req = DeployRequest.from_dict(payload["request"])
             emit = self._live_emitter(loop, f"deploy/{req.stage_name}")
-            return await loop.run_in_executor(
-                None, lambda: self._run_traced(
-                    req, lambda: self._down(
-                        req, bool(payload.get("remove")), emit),
-                    name="agent.down"))
+            return await self._in_pool(lambda: self._run_traced(
+                req, lambda: self._down(
+                    req, bool(payload.get("remove")), emit),
+                name="agent.down"))
 
         if method == "build":
-            return await loop.run_in_executor(
-                None, lambda: self._run_build(payload))
+            return await self._in_pool(lambda: self._run_build(payload))
 
         raise ValueError(f"unknown agent command {method!r}")
 
     def _run_traced(self, req: DeployRequest, fn, name: str = "agent.deploy"):
         """Run a deploy-shaped command inside the request's trace with an
-        agent-side span. Commands execute on executor threads, where the
-        session loop's contextvars are absent — the trace is re-entered
-        from the id the CP carried in DeployRequest.trace_id, which is
-        what makes one `fleet deploy` correlate across machines."""
+        agent-side span, a child of `agent.command` (the pool thread runs
+        in the command's context, `_in_pool`). The trace is the id the CP
+        carried in DeployRequest.trace_id, which makes one `fleet deploy`
+        correlate across machines even where the frame carried none."""
         with use_trace(req.trace_id) as tid:
             req.trace_id = tid
             with span(log, name, slug=self.config.slug,
@@ -536,7 +551,6 @@ class Agent:
         backend like deploy.execute — the CP-routed complement of `fleet
         down` (the reference's down is local-only, commands/down.rs; a
         CP-routed deploy needs a CP-routed teardown)."""
-        from ..core.model import Backend
         stage = req.flow.stage(req.stage_name)
         if stage.backend is not Backend.DOCKER and req.target_services:
             # same semantics as the local CLI path: whole-stage only (the

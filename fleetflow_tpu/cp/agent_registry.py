@@ -335,7 +335,7 @@ class AgentRegistry:
             self.last_batch_stats = {"items": 0, "label_lookups": 0,
                                      "epoch_lookups": 0, "shards": 0}
             return []
-        with phase("agents.send_batch", items=len(items)):
+        with phase("agents.send_batch"):
             counts: dict[str, int] = {}
             for _, command, _ in items:
                 counts[command] = counts.get(command, 0) + 1

@@ -13,12 +13,18 @@ wrapped in TLS from cp/cert.py:
   request  = {"type":"request","id":int,"channel":str,"method":str,
               "payload":{},"trace":str,"span":str}
   response = {"type":"response","id":int,"payload":{},"error":str|None}
-  event    = {"type":"event","channel":str,"method":str,"payload":{}}
+  event    = {"type":"event","channel":str,"method":str,"payload":{},
+              "trace":str,"span":str}
 
 `trace` is the sender's trace id (obs.trace) and `span` the phase that sent
-the request, as `<process token>:<id>`: the receiver serves the request
-under that trace and, where the token is its own process's, as a child of
-that phase. Both are optional: a frame without them is served all the same.
+the frame, as `<process token>:<id>`: the receiver handles the request or
+event under that trace and, where the token is its own process's, as a
+child of that phase. An event carries both only where the sender is inside
+a trace (a heartbeat outside one is the bare frame); a reply to an event
+(`send_event(..., in_reply=True)`: an agent's `command_result`) carries the
+keys of the event it answers, so it is filed where that was sent from, as a
+response is filed under its caller. Both are optional: a frame without them
+is handled all the same.
 
 Requests flow BOTH ways on a connection (the agent channel is duplex: the
 CP sends commands to agents, handlers/agent.rs:129-159), so both endpoints
@@ -28,6 +34,7 @@ run the same dispatch loop; only the handshake differs.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import itertools
 import json
 import ssl
@@ -37,7 +44,8 @@ from typing import Any, Awaitable, Callable, Optional
 
 from ..core.errors import ControlPlaneError
 from ..obs import get_logger, kv, phase, use_trace
-from ..obs.trace import record_interval, wire_span
+from ..obs.trace import (current_trace_id, in_trace, record_interval,
+                         wire_span)
 
 log = get_logger("cp.protocol")
 
@@ -45,6 +53,11 @@ __all__ = ["Connection", "ProtocolServer", "ProtocolClient", "RpcError",
            "Reply", "MAX_FRAME"]
 
 MAX_FRAME = 1 << 20
+
+# the (trace, span) the event being handled carried: what a reply to it is
+# filed under (`Connection.send_event(..., in_reply=True)`)
+_event_origin: contextvars.ContextVar[tuple] = contextvars.ContextVar(
+    "fleet_event_origin", default=(None, None))
 
 
 class RpcError(ControlPlaneError):
@@ -166,16 +179,31 @@ class Connection:
             self._callers.pop(mid, None)
 
     async def send_event(self, channel: str, method: str,
-                         payload: dict | None = None) -> None:
-        """Fire-and-forget (club-unison send_event)."""
-        await self._send({"type": "event", "channel": channel,
-                          "method": method, "payload": payload or {}})
+                         payload: dict | None = None, *,
+                         in_reply: bool = False) -> None:
+        """Fire-and-forget (club-unison send_event). Inside a trace the
+        frame carries it and the phase open here; outside one, neither.
+        `in_reply`: the event answers the one being handled, and carries
+        that one's `trace` and `span` instead."""
+        msg = {"type": "event", "channel": channel, "method": method,
+               "payload": payload or {}}
+        if in_reply:
+            trace, span = _event_origin.get()
+        else:
+            trace = current_trace_id()
+            span = wire_span() if trace else None
+        if trace:
+            msg["trace"] = trace
+            if span:
+                msg["span"] = span
+        await self._send(msg)
 
     def _whose(self, msg: dict) -> tuple:
-        """(trace, span) a frame's decode belongs to: a request's own, a
-        response's caller's; (None, None) for anything else."""
+        """(trace, span) a frame's decode belongs to: a request's or an
+        event's own, a response's caller's; (None, None) for anything
+        else."""
         if isinstance(msg, dict):
-            if msg.get("type") == "request":
+            if msg.get("type") in ("request", "event"):
                 return msg.get("trace"), msg.get("span")
             if msg.get("type") == "response":
                 return self._callers.get(msg.get("id"), (None, None))
@@ -202,10 +230,27 @@ class Connection:
                 elif t == "event":
                     handler = self.event_handlers.get(msg.get("channel", ""))
                     if handler is not None:
-                        self._spawn(handler(
-                            self, msg.get("method", ""), msg.get("payload", {})))
+                        self._spawn(self._on_event(handler, msg,
+                                                   time.perf_counter()))
         finally:
             await self.close()
+
+    async def _on_event(self, handler: EventHandler, msg: dict,
+                        decoded: float) -> None:
+        """Handle one event, under the trace and the sender's phase its
+        frame carried where it carried them: `protocol.wait.event` is the
+        loop's queue, from the frame decoded to this task running."""
+        method, payload = msg.get("method", ""), msg.get("payload", {})
+        trace, span = msg.get("trace"), msg.get("span")
+        if not isinstance(trace, str) or not trace:
+            record_interval("protocol.wait.event", decoded)
+            await handler(self, method, payload)
+            return
+        span = span if isinstance(span, str) else None
+        _event_origin.set((trace, span))
+        with in_trace(trace, span):
+            record_interval("protocol.wait.event", decoded)
+            await handler(self, method, payload)
 
     async def _dispatch(self, msg: dict, decoded: float) -> None:
         """Serve one request under the trace and the caller's phase its

@@ -44,6 +44,8 @@ in `fleet cp heal status`, and docs/guide/13-cp-replication.md (topology
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import contextvars
 import json
 import threading
 from collections import deque
@@ -52,6 +54,7 @@ from typing import Callable, Optional
 
 from ..obs import get_logger, kv
 from ..obs.metrics import REGISTRY
+from ..obs.trace import current_trace_id, in_trace, wire_span
 from .failure_detector import FailureDetector, LeaseConfig
 from .store import ReplicationFenced, ReplicationGap, Store
 
@@ -161,9 +164,13 @@ class Replicator:
     # -- the asyncio side ----------------------------------------------
 
     def _fan_out(self, entries: list[tuple[int, str]]) -> None:
+        # this runs in a copy of the writer's context (call_soon_threadsafe):
+        # the append carries the write's trace and open phase, so the
+        # standby's apply is filed under the primary's commit
+        origin = (current_trace_id(), wire_span())
         for sb in list(self._standbys.values()):
             try:
-                sb.queue.put_nowait(entries)
+                sb.queue.put_nowait((entries, origin))
             except asyncio.QueueFull:
                 # slow consumer: drop its backlog; the seq gap it sees
                 # next forces a snapshot resync (never silent divergence)
@@ -175,11 +182,13 @@ class Replicator:
     async def _sender(self, sb: _Standby) -> None:
         try:
             while True:
-                entries = await sb.queue.get()
-                await sb.conn.send_event("replication", "append", {
-                    "epoch": self.store.epoch,
-                    "entries": entries,
-                })
+                entries, (trace, span) = await sb.queue.get()
+                with (in_trace(trace, span) if trace
+                      else contextlib.nullcontext()):
+                    await sb.conn.send_event("replication", "append", {
+                        "epoch": self.store.epoch,
+                        "entries": entries,
+                    })
                 sb.sent_seq = max(sb.sent_seq, entries[-1][0])
                 _M_SHIPPED.inc(len(entries))
                 _M_LAG.set(max(sb.sent_seq - sb.acked_seq, 0),
@@ -210,9 +219,11 @@ class Replicator:
         sb.acked_seq = from_seq
         sb.sent_seq = from_seq
         if backlog:
-            sb.queue.put_nowait(backlog)
+            sb.queue.put_nowait((backlog, ("", None)))
         self._standbys[id(conn)] = sb
-        sb.task = asyncio.ensure_future(self._sender(sb))
+        # the stream outlives the subscribe request: it starts in no trace
+        sb.task = asyncio.get_running_loop().create_task(
+            self._sender(sb), context=contextvars.Context())
         log.info("standby subscribed %s", kv(
             standby=identity, from_seq=from_seq, backlog=len(backlog)))
         return {"subscribed": True, "seq": store_seq, "epoch": store_epoch}
@@ -454,7 +465,8 @@ class StandbyRunner:
         self.detector.observe_heartbeat(PRIMARY_SLUG)
         try:
             await conn.send_event("replication", "ack",
-                                  {"seq": self.replica.last_seq})
+                                  {"seq": self.replica.last_seq},
+                                  in_reply=True)
         except Exception:
             pass   # the stream will resync on the next session
 

@@ -12,7 +12,8 @@ Contextvars propagate through async/await but NOT into
 `loop.run_in_executor` threads: a callable handed to a pool goes through
 `bound()`, which carries the caller's context (trace id, open phase) into
 the pool thread. Across machines the id is carried (`DeployRequest.trace_id`,
-the `trace` key of a request frame) and re-entered with `use_trace`.
+the `trace` key of a request or event frame) and re-entered with
+`use_trace`.
 
 `Phase` is the one primitive every span goes through, `obs.span` included.
 It is always on and has no switch: on exit it has written the span (a) into
@@ -56,8 +57,8 @@ __all__ = ["new_trace_id", "new_span_id", "current_trace_id",
            "flight_recorder", "record_span_event", "read_trace_file",
            "read_trace_files", "Phase", "SpanRing", "SpansDropped",
            "spans_between", "tree_between", "RING_CAPACITY",
-           "PROFILER_PREFIX", "PROCESS_TOKEN", "bound",
-           "record_interval", "wire_span", "watch_collector"]
+           "PROFILER_PREFIX", "PROCESS_TOKEN", "bound", "bound_as",
+           "in_trace", "record_interval", "wire_span", "watch_collector"]
 
 _trace_id: contextvars.ContextVar[str] = contextvars.ContextVar(
     "fleet_trace_id", default="")
@@ -67,7 +68,7 @@ _span_id: contextvars.ContextVar[str] = contextvars.ContextVar(
 _phase_id: contextvars.ContextVar[int] = contextvars.ContextVar(
     "fleet_phase_id", default=0)
 _phase_ids = itertools.count(1)
-# names this process in the `span` key of a request frame: a phase id means
+# names this process in the `span` key of a frame: a phase id means
 # something only to the process whose counter minted it
 PROCESS_TOKEN = uuid.uuid4().hex[:8]
 # whether FLEET_TRACE_FILE was set when a trace was last entered: a phase
@@ -93,18 +94,26 @@ def current_span_id() -> str:
     return _span_id.get()
 
 
-@contextlib.contextmanager
 def use_trace(trace_id: Optional[str] = None,
-              span: Optional[str] = None) -> Iterator[str]:
+              span: Optional[str] = None):
     """Enter a trace context: adopt `trace_id`, keep the already-active
     trace when none is given, or mint a fresh id. Restores the previous
     context on exit, so nested/sequential operations cannot leak ids into
-    each other. `span` is what a request frame carried (`wire_span` on the
+    each other. `span` is what a frame carried (`wire_span` on the
     sending side): where it names a phase of this process, that phase is
     the parent of what opens here, as if the caller's context had come
-    along."""
+    along. Looks up `FLEET_TRACE_FILE` once (the flight recorder's
+    switch); `in_trace` is the same without the lookup."""
     global _recording
     _recording = bool(os.environ.get("FLEET_TRACE_FILE"))
+    return in_trace(trace_id, span)
+
+
+@contextlib.contextmanager
+def in_trace(trace_id: Optional[str] = None,
+             span: Optional[str] = None) -> Iterator[str]:
+    """`use_trace` for what enters a trace once a frame: an event's
+    handler, whose trace the frame carried. It reads no environment."""
     tid = trace_id or _trace_id.get() or new_trace_id()
     token = _trace_id.set(tid)
     parent = _local_phase(span) if span else 0
@@ -118,8 +127,8 @@ def use_trace(trace_id: Optional[str] = None,
 
 
 def wire_span() -> str:
-    """The phase open in this context as a request frame carries it:
-    `<process token>:<id>`."""
+    """The phase open in this context as a frame carries it: `<process
+    token>:<id>`."""
     return f"{PROCESS_TOKEN}:{_phase_id.get()}"
 
 
@@ -136,11 +145,17 @@ def bound(fn, /, *args, **kwargs):
     caller's open phase as parent and the caller's trace id. The time
     between this call and the pool thread picking the callable up is
     written as `cp.wait.executor`."""
+    return bound_as("cp.wait.executor", fn, *args, **kwargs)
+
+
+def bound_as(wait: str, fn, /, *args, **kwargs):
+    """`bound`, with the pool's queue written as `wait`: a node agent's
+    hops are `agent.wait.executor`, apart from the CP's."""
     ctx = contextvars.copy_context()
     asked = time.perf_counter()
 
     def run():
-        record_interval("cp.wait.executor", asked)
+        record_interval(wait, asked)
         return fn(*args, **kwargs)
 
     return functools.partial(ctx.run, run)
@@ -291,7 +306,7 @@ class Phase:
     def adopt(self, trace, span) -> None:
         """For a phase that learns in its body whose it is — a frame's
         decode, from the frame: file it under `trace`, as a child of the
-        phase a request frame's `span` names (`wire_span`), where this
+        phase a frame's `span` names (`wire_span`), where this
         process minted it. Anything else leaves it as it opened."""
         if trace and isinstance(trace, str):
             self._trace = trace

@@ -78,13 +78,10 @@ class Series:
     def labels_dict(self) -> dict:
         return dict(self.labels)
 
-    def samples(self, since: Optional[float] = None,
-                until: Optional[float] = None) -> list:
+    def samples(self, since: Optional[float] = None) -> list:
         out = list(self.ring)
         if since is not None:
             out = [s for s in out if s[0] >= since]
-        if until is not None:
-            out = [s for s in out if s[0] <= until]
         return out
 
     def last(self) -> Optional[tuple]:
@@ -209,23 +206,6 @@ class TimeSeriesDB:
         out = []
         for s in self.match(name, labels):
             samples = s.samples(since=since)
-            out.append({"name": s.name, "labels": s.labels_dict(),
-                        "kind": s.kind, "agg": _aggregate(samples, s.kind)})
-        return out
-
-    def aggregate_range(self, since: Optional[float] = None,
-                        until: Optional[float] = None,
-                        name: Optional[str] = None,
-                        labels: Optional[dict] = None) -> list[dict]:
-        """Aggregates over an explicit [since, until] interval
-        (aggregate() is anchored to NOW; a window that closed minutes ago
-        needs absolute bounds). Series with no samples in the interval
-        are omitted."""
-        out = []
-        for s in self.match(name, labels):
-            samples = s.samples(since=since, until=until)
-            if not samples:
-                continue
             out.append({"name": s.name, "labels": s.labels_dict(),
                         "kind": s.kind, "agg": _aggregate(samples, s.kind)})
         return out

@@ -309,7 +309,8 @@ class _CaptureConn:
     def __init__(self):
         self.replies = []
 
-    async def send_event(self, channel, method, payload):
+    async def send_event(self, channel, method, payload, *, in_reply=False):
+        assert in_reply      # a command's result answers its frame
         self.replies.append((method, payload))
 
 
